@@ -62,8 +62,6 @@ def child(ckpt_dir: str, kill_after: int, flight: bool) -> int:
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from wittgenstein_tpu.engine import replicate_state

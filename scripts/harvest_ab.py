@@ -37,13 +37,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax  # noqa: E402
+from wittgenstein_tpu.utils.compile_cache import use_compile_cache  # noqa: E402
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-jax.config.update(
-    "jax_compilation_cache_dir", os.path.join(ROOT, ".jax_cache")
-)
+use_compile_cache()
 
 from wittgenstein_tpu.serve import BatchScheduler, JobState  # noqa: E402
 

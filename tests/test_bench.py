@@ -1,6 +1,6 @@
-"""bench.py headline-record contract: the parity field (VERDICT r4 #8)
-and the campaign-fallback provenance path.  Pure record assembly — no
-simulation runs, stays in the fast tier."""
+"""bench.py headline-record contract: the parity field and the core
+metric fields.  Pure record assembly — no simulation runs, stays in the
+fast tier."""
 
 import json
 import sys
@@ -43,25 +43,3 @@ class TestHeadlineRecord:
         assert rec["vs_baseline"] == round(2.0 / 0.0145, 3)
         assert rec["provenance"] == "measured live by this bench run"
         json.dumps(rec)  # one JSON line, serializable
-
-    def test_campaign_rung_parsing(self, tmp_path):
-        p = tmp_path / "campaign.jsonl"
-        lines = [
-            {"event": "campaign_start", "device": "TPU v5 lite0", "kind": "TPU v5 lite"},
-            {"event": "tpu_down"},
-            {"event": "rung", "nodes": 4096, "replicas": 8, "sims_per_sec": 1.5,
-             "run_s": 5.3, "chunk_ms": 20},
-            {"event": "rung", "nodes": 4096, "replicas": 16, "sims_per_sec": 2.5,
-             "run_s": 6.4, "chunk_ms": 20},
-            {"event": "campaign_end"},
-        ]
-        p.write_text("".join(json.dumps(r) + "\n" for r in lines))
-        rungs, kind = bench._campaign_tpu_rungs(str(p))
-        assert len(rungs) == 2
-        assert kind == "TPU v5 lite"
-        best = max(rungs, key=lambda x: x["sims_per_sec"])
-        assert (best["nodes"], best["replicas"]) == (4096, 16)
-
-    def test_campaign_missing_file_is_empty(self, tmp_path):
-        rungs, kind = bench._campaign_tpu_rungs(str(tmp_path / "nope.jsonl"))
-        assert rungs == []

@@ -110,7 +110,7 @@ class TestNodeSharding:
 
 class TestNodeShardedEngine:
     def test_run_ms_node_sharded_bit_identical(self):
-        """VERDICT r3 item 6: the REAL engine (batched Handel run_ms), one
+        """The REAL engine (batched Handel run_ms), one
         replica, node columns + channel/candidate buffers sharded over the
         8-device mesh via NamedSharding — bit-identical to the unsharded
         run, and the node-axis sharding survives to the outputs."""
@@ -148,7 +148,7 @@ class TestNodeShardedEngine:
 
 
 class TestExplicitExchange:
-    """VERDICT r4 #4: the send/channel commit through the explicit
+    """The send/channel commit through the explicit
     shard_map all_to_all exchange (BitsetAggBase._channel_commit_sharded)
     — bit identity held, channel arrays genuinely 1/P per device."""
 

@@ -11,7 +11,7 @@ delivery, channel displacement) do NOT drift as N grows:
 
 All are `slow` (minutes each, oracle-side): run with `-m slow`.  The
 default `-m "not slow"` run keeps the suite under the iteration-speed
-budget (VERDICT r3 item 9).
+budget.
 """
 
 import numpy as np

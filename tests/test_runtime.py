@@ -202,7 +202,7 @@ class TestSupervisorLoop:
         def flaky(s):
             calls["n"] += 1
             if calls["n"] == 3:  # fail mid-run, once
-                raise RuntimeError("UNAVAILABLE: tunnel reset")
+                raise RuntimeError("UNAVAILABLE: connection reset")
             return toy_chunk(s)
 
         rep = Supervisor(

@@ -109,7 +109,7 @@ class TestGenAnim:
 
 
 class TestDeepBattery:
-    """The HandelScenarios deep battery (VERDICT r4 #5): log* sweeps,
+    """The HandelScenarios deep battery: log* sweeps,
     delayedStartImpact arithmetic, window sweep, allScenarios plumbing."""
 
     def test_delayed_start_impact_arithmetic(self):
@@ -205,7 +205,7 @@ class TestDeepBattery:
 
 class TestGSFScenarios:
     """GSFSignature scenario mains (GSFSignature.java:668-768) as CLI
-    subcommands (VERDICT r4 #6)."""
+    subcommands."""
 
     def test_new_protocol_canonical_config(self):
         from wittgenstein_tpu.scenarios.gsf_scenarios import new_protocol

@@ -1,0 +1,149 @@
+"""The chip's compiler, without the chip: Mosaic and XLA:TPU compile the
+Pallas bitset kernels at the flagship word widths, and one 256-node
+run_ms_batched program, for a DESCRIBED v5e:2x2 (nothing runs — results
+are pinned by tests/test_bitops_pallas.py in interpret mode and by
+chip_smoke.py on the chip).  Interpret mode cannot see what Mosaic
+refuses (1-D blocks, 1-D iota, unsigned reductions, lane-splitting
+reshapes all passed it), and GSPMD refuses to partition a Mosaic kernel,
+so these compiles guard every later PR at no chip time.
+
+This is the only file that loads the TPU compiler: one process may hold
+libtpu, so the topology is described inside a module fixture (never at
+import) and everything compiles in the test's own process.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from wittgenstein_tpu.ops import bitops_pallas
+from wittgenstein_tpu.ops.bitops import BITOPS_ENV
+
+# popcount shapes the 4096-node Handel program passes (per replica);
+# lowest_set_bit / pack_bool at the occupancy shapes of a 512-row wheel
+# and at the full node width
+POPCOUNT_SHAPES = [
+    (4096, 1, 2), (4096, 1, 2, 64), (4096, 6, 1), (4096, 8, 1),
+    (4096, 8, 64), (4096, 128),
+]
+LOWEST_SHAPES = [(16,), (4096, 4), (4096, 128)]
+PACK_SHAPES = [(512,), (4096,), (8, 4096)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one (the next run warns and compiles
+    again): keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, topo, no_compile_cache):
+    """Trace the kernels for Mosaic (not the interpreter) although the
+    default backend here is the CPU."""
+    monkeypatch.setattr(bitops_pallas, "_interpret", lambda: False)
+    monkeypatch.setenv(BITOPS_ENV, "pallas")
+    return topo
+
+
+def _compile(fn, shapes):
+    compiled = jax.jit(fn).lower(shapes).compile()
+    return compiled.as_text()
+
+
+def _one_chip(topo, shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(topo.devices[0])
+    )
+
+
+@pytest.mark.parametrize("shape", POPCOUNT_SHAPES, ids=str)
+def test_popcount_kernel_compiles(mosaic, shape):
+    x = _one_chip(mosaic, shape, jnp.uint32)
+    assert "tpu_custom_call" in _compile(bitops_pallas.popcount_words_pallas, x)
+
+
+@pytest.mark.parametrize("shape", LOWEST_SHAPES, ids=str)
+def test_lowest_set_bit_kernel_compiles(mosaic, shape):
+    x = _one_chip(mosaic, shape, jnp.uint32)
+    assert "tpu_custom_call" in _compile(bitops_pallas.lowest_set_bit_pallas, x)
+
+
+@pytest.mark.parametrize("shape", PACK_SHAPES, ids=str)
+def test_pack_bool_kernel_compiles(mosaic, shape):
+    x = _one_chip(mosaic, shape, jnp.bool_)
+    assert "tpu_custom_call" in _compile(bitops_pallas.pack_bool_words_pallas, x)
+
+
+def test_kernels_compile_under_vmap(mosaic):
+    """The engine vmaps the whole step over replicas: the batching rule
+    adds a squeezed grid axis that Mosaic must accept too."""
+    for fn, shape, dtype in (
+        (bitops_pallas.popcount_words_pallas, (8, 4096, 1, 2), jnp.uint32),
+        (bitops_pallas.lowest_set_bit_pallas, (8, 16), jnp.uint32),
+        (bitops_pallas.pack_bool_words_pallas, (8, 512), jnp.bool_),
+    ):
+        x = _one_chip(mosaic, shape, dtype)
+        assert "tpu_custom_call" in _compile(jax.vmap(fn), x)
+
+
+@pytest.fixture(scope="module")
+def handel256():
+    """The flagship configuration at 256 nodes, as the chip builds it
+    (score cache on, fused step), 4 replicas.  Built with the default
+    (lax) kernels: construction runs eagerly on the CPU."""
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.profiling import flagship_params
+    from wittgenstein_tpu.protocols.handel_batched import make_handel
+
+    net, state = make_handel(flagship_params(256), fuse_step=True, score_cache=True)
+    return net, replicate_state(state, 4)
+
+
+def _described(states, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), states
+    )
+
+
+def test_handel256_program_compiles_for_one_chip(mosaic, handel256):
+    net, states = handel256
+    shapes = _described(states, SingleDeviceSharding(mosaic.devices[0]))
+    text = _compile(lambda s: net.run_ms_batched(s, 1000), shapes)
+    assert "tpu_custom_call" in text
+
+
+def test_handel256_replica_sharded_program_compiles_for_four_chips(mosaic, handel256):
+    """sharded_run_stats' program for replica-sharded states: the Mosaic
+    kernels sit inside a shard_map (GSPMD cannot partition them), and no
+    state is gathered — the only collectives reduce the statistics."""
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+
+    net, states = handel256
+    mesh = Mesh(np.array(mosaic.devices[:4]), ("replicas",))
+    shapes = _described(states, NamedSharding(mesh, P("replicas")))
+    compiled = _run_and_reduce(net, 1000)._jit_for(shapes).lower(shapes).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " all-gather(" not in text and " all-gather-start(" not in text
+    out_shapes, _stats = compiled.output_shardings
+    assert out_shapes.done_at.spec == P("replicas")

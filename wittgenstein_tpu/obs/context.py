@@ -5,8 +5,7 @@ traces (telemetry/trace.py), JSONL run records (telemetry/export.py),
 supervisor provenance + checkpoint manifests (runtime/supervisor.py,
 engine/checkpoint.py), serve metrics (serve/metrics.py) — but until
 this module they were uncorrelated: a failed job could not be
-reconstructed end-to-end without hand-joining logs (the r3-r5 tunnel
-postmortems).  A TraceContext is minted ONCE, at serve admission or
+reconstructed end-to-end without hand-joining logs.  A TraceContext is minted ONCE, at serve admission or
 bench entry, and threaded through everything; every record that
 carries ``run_id`` can be joined.
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -39,12 +40,7 @@ def bitops_backend() -> str:
     env = os.environ.get(BITOPS_ENV, "").strip().lower()
     if env in ("lax", "pallas"):
         return env
-    try:
-        import jax
-
-        return "pallas" if jax.default_backend() == "tpu" else "lax"
-    except Exception:  # no backend yet — the safe default
-        return "lax"
+    return "pallas" if jax.default_backend() == "tpu" else "lax"
 
 
 def _popcount_words_lax(words) -> jnp.ndarray:

@@ -50,6 +50,25 @@ def shard_replicas(states, mesh: Mesh, axis: str = "replicas"):
     return jax.tree_util.tree_map(lambda a: jax.device_put(a, sharding), states)
 
 
+def _replica_sharding(states):
+    """The one NamedSharding every leaf carries when a stacked state is
+    sharded along its leading (replica) axis over more than one device
+    and along nothing else; None for any other placement."""
+    first = None
+    for leaf in jax.tree_util.tree_leaves(states):
+        sh = getattr(leaf, "sharding", None)
+        if (
+            not isinstance(sh, NamedSharding)
+            or sh.mesh.size == 1
+            or len(sh.spec) != 1
+            or sh.spec[0] is None
+            or (first is not None and sh != first)
+        ):
+            return None
+        first = first or sh
+    return first
+
+
 # compiled-program cache, keyed EXPLICITLY on (net.cache_key(), sim_ms) —
 # protocol name + static engine knobs (see BatchedNetwork.cache_key) —
 # instead of hashing the network object through lru_cache.  Bounded FIFO
@@ -119,9 +138,38 @@ class _CachedRun:
             else None
         )
 
+        self._programs: "OrderedDict[tuple, object]" = OrderedDict()
+        self._summaries: "OrderedDict[tuple, dict]" = OrderedDict()
+        # XLA compiles release the GIL, so two threads calling with the
+        # same input geometry can BOTH observe "not compiled yet" and
+        # duplicate a multi-second compile (observed from concurrent
+        # serve batches).  Double-checked locking keeps the per-geometry
+        # compile a true singleton.
+        self._compile_lock = make_lock("runcache.compile")
+
+    def _jit_for(self, states):
+        """The jitted run + statistics program for these states.  GSPMD
+        cannot partition a Mosaic kernel ("wrap the call in a
+        shard_map"), so states sharded along the replica axis only (what
+        shard_replicas produces) run the per-device program under
+        shard_map: replicas are independent, so it is the same
+        computation, R/D rows per device and no collective.  Any other
+        placement is left to the partitioner."""
+        net, sim_ms = self.net, self.sim_ms
+        run = lambda s: net.run_ms_batched(s, sim_ms)
+        sharding = _replica_sharding(states)
+        if sharding is not None:
+            run = jax.shard_map(
+                run,
+                mesh=sharding.mesh,
+                in_specs=sharding.spec,
+                out_specs=sharding.spec,
+                check_vma=False,
+            )
+
         @jax.jit
         def fn(s):
-            out = net.run_ms_batched(s, sim_ms)
+            out = run(s)
             live = ~out.down
             done = jnp.where(live, out.done_at, 0)
             n_live = jnp.maximum(1, jnp.sum(live.astype(jnp.int32)))
@@ -137,15 +185,7 @@ class _CachedRun:
             }
             return out, stats
 
-        self._jit = fn
-        self._programs: "OrderedDict[tuple, object]" = OrderedDict()
-        self._summaries: "OrderedDict[tuple, dict]" = OrderedDict()
-        # XLA compiles release the GIL, so two threads calling with the
-        # same input geometry can BOTH observe "not compiled yet" and
-        # duplicate a multi-second compile (observed from concurrent
-        # serve batches).  Double-checked locking keeps the per-geometry
-        # compile a true singleton.
-        self._compile_lock = make_lock("runcache.compile")
+        return fn
 
     @staticmethod
     def _signature(states) -> tuple:
@@ -223,7 +263,7 @@ class _CachedRun:
                         }
                     else:
                         t0 = time.perf_counter()
-                        compiled = self._jit.lower(states).compile()
+                        compiled = self._jit_for(states).lower(states).compile()
                         dt = time.perf_counter() - t0
                         _COUNTERS["compiles"] += 1
                         _COUNTERS["compile_seconds_total"] += dt

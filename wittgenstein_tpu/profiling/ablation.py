@@ -1,6 +1,6 @@
 """Config-ablation matrix: price each engine/protocol lever per tick.
 
-The r4→r5 CPU regression (BENCH_r04 1.463 → BENCH_r05 1.174 sims/s at
+The r4→r5 CPU regression (round files since deleted: 1.463 → 1.174 sims/s at
 256x4, ~20%) came from two parity fixes whose per-tick price was never
 isolated: CHANNEL_DEPTH 8→32 and the boundary-view selection.  This
 module measures each lever alone AND the combined pre-r5 configuration,

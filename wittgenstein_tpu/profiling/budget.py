@@ -1,4 +1,4 @@
-"""The chip-independent feasibility budget (VERDICT.md's demand).
+"""The chip-independent feasibility budget.
 
 North star: 21 sims/s/chip of flagship Handel at 4096 nodes.  The
 budget that implies is pure arithmetic once two quantities are measured

@@ -465,7 +465,7 @@ class BitsetAggBase(BatchedProtocol):
         cap=None, time_overflow=0,
     ):
         """The channel commit of _send_stacked under node-axis sharding
-        (SURVEY §7 / VERDICT r4 #4): each device owns N/P node rows of the
+        (SURVEY §7): each device owns N/P node rows of the
         channel arrays; update rows are BUCKETED BY DESTINATION DEVICE and
         exchanged with ONE lax.all_to_all per tensor, then committed with
         the same min/max-scatter semantics on the LOCAL shard.  GSPMD's
@@ -489,21 +489,8 @@ class BitsetAggBase(BatchedProtocol):
         from functools import partial as _partial
 
         from jax import lax as _lax
+        from jax import shard_map as _shard_map
         from jax.sharding import PartitionSpec as _P
-
-        try:  # jax >= 0.8
-            from jax import shard_map as _shard_map
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map as _shard_map
-
-        import inspect
-
-        # the replication-check kwarg was renamed check_rep -> check_vma;
-        # pick whichever this jax accepts
-        _sig = inspect.signature(_shard_map).parameters
-        _check_kw = {
-            "check_vma" if "check_vma" in _sig else "check_rep": False
-        }
 
         proto = state.proto
         n, d = self.n_nodes, self.CHANNEL_DEPTH
@@ -539,7 +526,7 @@ class BitsetAggBase(BatchedProtocol):
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),
-            **_check_kw,
+            check_vma=False,
         )
         def island(meta_l, *rest):
             cnts = rest[:nb]

@@ -1143,7 +1143,7 @@ class BatchedHandel(BitsetAggBase):
         # selection must see candidates and aggregates as of the END of
         # the previous tick.  Selecting on same-tick state gave the
         # batched engine a 1-tick information lead per verification hop,
-        # measured as a -4..-9 ms CDF lead (docs/TPU_NOTES.md r5).  The
+        # measured as a -4..-9 ms CDF lead (r5, on the CPU).  The
         # busy gate stays post-commit (a commit at t frees the node for a
         # same-tick re-select, like the reference's minStartTime spacing).
         if not self.BOUNDARY_VIEW:  # pre-r5 ablation lever: same-tick view

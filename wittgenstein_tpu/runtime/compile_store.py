@@ -132,6 +132,9 @@ class CompileStore:
 
         try:
             payload = pickle.dumps(serialize_executable.serialize(compiled))
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
         except Exception:  # noqa: BLE001 — unserializable program
             _count("errors")
             return False
@@ -140,6 +143,9 @@ class CompileStore:
             "stable_key": stable_key,
             "payload_sha256": hashlib.sha256(payload).hexdigest(),
             "payload_bytes": len(payload),
+            # the devices the program was compiled for: load() must be
+            # handed exactly these, or it spreads over every local device
+            "device_ids": device_ids,
             **_environment(),
         }
         if mesh_geometry is not None:
@@ -223,10 +229,13 @@ class CompileStore:
             _count("corrupt")
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable
 
+            by_id = {d.id: d for d in jax.local_devices()}
             loaded = serialize_executable.deserialize_and_load(
-                *pickle.loads(payload)
+                *pickle.loads(payload),
+                execution_devices=[by_id[i] for i in manifest["device_ids"]],
             )
         except Exception:  # noqa: BLE001 — any decode failure degrades
             _count("corrupt")
