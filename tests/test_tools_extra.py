@@ -98,20 +98,23 @@ class TestKademlia:
 
 
 class TestProfiling:
-    def test_trace_and_annotate(self, tmp_path):
+    def test_trace_and_host_span(self, tmp_path):
         import jax.numpy as jnp
 
-        from wittgenstein_tpu.tools.profiling import WallClock, annotate, trace
+        from wittgenstein_tpu.tools.profiling import host_span, trace
 
         d = tmp_path / "trace"
+        totals = {"matmul_seconds_total": 0.0}
         with trace(str(d)):
-            with annotate("matmul"):
+            with host_span("matmul", totals, "matmul_seconds_total") as span:
                 x = jnp.ones((64, 64))
                 (x @ x).block_until_ready()
         produced = list(d.rglob("*"))
         assert produced, "no trace files written"
+        assert span.seconds > 0 and totals["matmul_seconds_total"] == span.seconds
 
-        with WallClock() as w:
+        # no counters: a span that only times (what WallClock was)
+        with host_span("idle") as w:
             pass
         assert w.seconds is not None and w.seconds >= 0
 

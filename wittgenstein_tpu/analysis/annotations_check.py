@@ -21,6 +21,11 @@ fingerprints of the annotated vs. un-annotated step must match) and
 concrete (one full step must be bitwise identical with `annotate`
 flipped off).
 
+A protocol that sends through the shared channel send path
+(`_send_stacked` of protocols/_agg_batched.py) must also carry every
+sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
+scripts/scope_profile.py are only as whole as these markers are live.
+
 If this jax version exposes no `name_stack` on source_info, the
 presence half is skipped (API drift guard) — neutrality still runs.
 """
@@ -118,6 +123,10 @@ def _check_presence(jax, name, net, state, path, line, suppress):
         required.append("witt.protocol_tick")
     if _hook_traces_ops(jax, lambda s: net.protocol.tick_beat(net, s), state):
         required.append("witt.beat")
+    if hasattr(net.protocol, "_send_stacked"):
+        from ..engine.core import CHANNEL_SCOPES
+
+        required.extend(CHANNEL_SCOPES.values())
     for want in required:
         if not any(want in s for s in scopes):
             f = _mk("SL601", path, line,

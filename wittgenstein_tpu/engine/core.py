@@ -103,6 +103,18 @@ ENGINE_PHASE_SCOPES = {
     "faults_deliver": "witt.faults.deliver",
 }
 
+# sub-scopes of the aggregation protocols' channel send path
+# (protocols/_agg_batched.py `_send_stacked`), nested under the engine
+# phase that sends (witt.protocol_tick, witt.beat) and switched by the
+# same `annotate`.  They name what the ops are FOR, not how XLA spells
+# them, so a rewrite of the send path keeps its time under the same name.
+CHANNEL_SCOPES = {
+    "arrivals": "witt.channel.arrivals",  # who arrives when: latency, counters, keys, slot
+    "readdress": "witt.channel.readdress",  # content from sender to receiver bit space
+    "claim": "witt.channel.claim",  # which offer wins which slot; displacement
+    "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
+}
+
 
 class SimState(NamedTuple):
     """Per-replica simulation state; every field is a jnp array so the whole
@@ -434,11 +446,12 @@ class BatchedNetwork:
             bitops_backend(),
         )
 
-    def _scope(self, name: str):
-        """jax.named_scope for engine phase `name` (ENGINE_PHASE_SCOPES)
-        when annotation is on; a no-op context otherwise."""
+    def _scope(self, name: str, scopes: dict = ENGINE_PHASE_SCOPES):
+        """jax.named_scope for engine phase `name` (ENGINE_PHASE_SCOPES,
+        or CHANNEL_SCOPES from the channel send path) when annotation is
+        on; a no-op context otherwise."""
         if self.annotate:
-            return jax.named_scope(ENGINE_PHASE_SCOPES[name])
+            return jax.named_scope(scopes[name])
         return contextlib.nullcontext()
 
     def with_telemetry(

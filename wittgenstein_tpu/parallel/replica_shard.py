@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import hashlib
 import threading
-import time
 from collections import OrderedDict
 from typing import Tuple
 
@@ -39,8 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..profiling.xla_cost import compiled_cost_summary
+from ..profiling.xla_cost import compiled_cost_summary, hlo_op_scopes
 from ..runtime.locks import make_lock, yield_point
+from ..tools.profiling import host_span
 
 
 def shard_replicas(states, mesh: Mesh, axis: str = "replicas"):
@@ -87,6 +87,10 @@ _CACHE_LOCK = make_lock("runcache.entry")
 # and prove the interleaving harness reproduces the duplicate compile
 _RECHECK_UNDER_LOCK = True
 
+# every add to _COUNTERS outside _CACHE_LOCK: serve lanes compile and
+# dispatch different entries at once, and `d[k] += x` is not atomic
+_COUNTER_LOCK = make_lock("runcache.counters")
+
 # monotonic across clear_run_cache() — Prometheus counters must never
 # step backwards just because a campaign flushed the program cache
 _COUNTERS = {
@@ -94,12 +98,36 @@ _COUNTERS = {
     "misses": 0,
     "evictions": 0,
     "compiles": 0,
-    "compile_seconds_total": 0.0,
+    # where set-up and dispatch happen, each region under a
+    # `witt.host.<span>` host_span that feeds one of these (PERF.md §3).
+    # `compile_seconds_total` is the sum of the first two and is not
+    # stored (`_counters`), so a compile that raises cannot part them.
+    # Python tracing and lowering to StableHLO
+    "lower_seconds_total": 0.0,
+    # XLA's compile, or the persistent compilation cache's load
+    "backend_compile_seconds_total": 0.0,
+    # cache key + LRU, layout placement, input signature, program table
+    "lookup_seconds_total": 0.0,
+    # compiled(states), call to return: the enqueue, not the device time
+    "execute_seconds_total": 0.0,
+    "calls": 0,
     # durable compile-store integration: programs adopted from /
     # published to the cross-process store (runtime.compile_store)
     "store_hits": 0,
     "store_puts": 0,
 }
+
+
+def _count(key: str, amount=1) -> None:
+    with _COUNTER_LOCK:
+        _COUNTERS[key] += amount
+
+
+def _span(name: str, key: "str | None" = None) -> host_span:
+    """`witt.host.<name>` around a region of set-up or dispatch, its
+    seconds added to `_COUNTERS[key]` (PERF.md §3 names each span's
+    reader)."""
+    return host_span(name, _COUNTERS if key else None, key, lock=_COUNTER_LOCK)
 
 
 class _CachedRun:
@@ -216,10 +244,11 @@ class _CachedRun:
         )
 
     def __call__(self, states):
-        if self.layout is not None:
-            states = self.layout.place(self.net, states)
-        sig = self._signature(states)
-        compiled = self._programs.get(sig)
+        with _span("lookup", "lookup_seconds_total"):
+            if self.layout is not None:
+                states = self.layout.place(self.net, states)
+            sig = self._signature(states)
+            compiled = self._programs.get(sig)
         if compiled is None:
             # the PR-11 race window: between this unlocked miss and the
             # locked recheck another thread can finish the same compile.
@@ -248,13 +277,14 @@ class _CachedRun:
                         else None
                     )
                     if skey is not None:
-                        compiled = store.get(skey, mesh_geometry=mesh_sig)
+                        with _span("store_get"):
+                            compiled = store.get(skey, mesh_geometry=mesh_sig)
                     if compiled is not None:
                         # adopted from the durable store: no lowering
                         # happened, so "compiles" must NOT tick (the
                         # zero-compile warm-start contract) and there is
                         # no fresh cost analysis to book
-                        _COUNTERS["store_hits"] += 1
+                        _count("store_hits")
                         self._summaries[sig] = {
                             "replicas": next(
                                 (s[0][0] for s in sig if s[0]), None
@@ -262,26 +292,46 @@ class _CachedRun:
                             "loaded_from_store": True,
                         }
                     else:
-                        t0 = time.perf_counter()
-                        compiled = self._jit_for(states).lower(states).compile()
-                        dt = time.perf_counter() - t0
-                        _COUNTERS["compiles"] += 1
-                        _COUNTERS["compile_seconds_total"] += dt
+                        with _span("lower", "lower_seconds_total") as lower:
+                            lowered = self._jit_for(states).lower(states)
+                        with _span(
+                            "compile", "backend_compile_seconds_total"
+                        ) as backend:
+                            compiled = lowered.compile()
+                        dt = lower.seconds + backend.seconds
+                        _count("compiles")
                         self._summaries[sig] = {
                             "replicas": next(
                                 (s[0][0] for s in sig if s[0]), None
                             ),
                             **compiled_cost_summary(compiled, dt),
                         }
-                        if skey is not None and store.put(
-                            skey, compiled, mesh_geometry=mesh_sig
-                        ):
-                            _COUNTERS["store_puts"] += 1
+                        if skey is not None:
+                            with _span("store_put"):
+                                put = store.put(
+                                    skey, compiled, mesh_geometry=mesh_sig
+                                )
+                            if put:
+                                _count("store_puts")
                     self._programs[sig] = compiled
-        return compiled(states)
+        with _span("enqueue", "execute_seconds_total"):
+            out = compiled(states)
+        _count("calls")
+        return out
 
     def summaries(self) -> list:
         return list(self._summaries.values())
+
+    def op_scopes(self, sig: tuple) -> dict:
+        """The program's half of the join between a device trace and
+        the `witt.*` scopes: {instruction name: {"scope", "op_name",
+        "source", "fed_by"}} (profiling.xla_cost.hlo_op_scopes) of the
+        program compiled for input signature `sig`, parsed from the
+        executable's own text ON DEMAND (set-up pays nothing).  A
+        program loaded from the persistent compilation cache carries
+        the names it was compiled with: scopes added since show only
+        after a cold compile (docs/profiling.md)."""
+        return hlo_op_scopes(self._programs[sig].as_text())
 
 
 def clear_run_cache() -> None:
@@ -291,15 +341,25 @@ def clear_run_cache() -> None:
     _RUN_CACHE.clear()
 
 
+def _counters() -> dict:
+    """`_COUNTERS` as exported: with `compile_seconds_total`, what
+    `.lower(states).compile()` took, as the sum of its two parts."""
+    out = dict(_COUNTERS)
+    out["compile_seconds_total"] = (
+        out["lower_seconds_total"] + out["backend_compile_seconds_total"]
+    )
+    return out
+
+
 def run_cache_info() -> dict:
-    return {"size": len(_RUN_CACHE), "maxsize": _RUN_CACHE_MAX, **_COUNTERS}
+    return {"size": len(_RUN_CACHE), "maxsize": _RUN_CACHE_MAX, **_counters()}
 
 
 def run_cache_metrics() -> dict:
     """The export view (server /metrics + run records): counters plus
     per-entry compiled-program cost/memory summaries."""
     return {
-        **_COUNTERS,
+        **_counters(),
         "size": len(_RUN_CACHE),
         "maxsize": _RUN_CACHE_MAX,
         "entries": [
@@ -313,31 +373,50 @@ def run_cache_metrics() -> dict:
     }
 
 
+def _entry_key(net, sim_ms: int, layout) -> tuple:
+    return (
+        net.cache_key(),
+        int(sim_ms),
+        layout.geometry() if layout is not None else None,
+    )
+
+
+def run_cache_op_scopes(net, sim_ms: int, layout=None) -> dict:
+    """{input signature: `_CachedRun.op_scopes`} of the programs cached
+    for this network, horizon and layout (one per replica count and
+    placement it was called with); {} where none is.  Per program and
+    never merged: instruction names such as `fusion.2655` recur from
+    one program to the next, so a trace is joined with the table of
+    the program that ran."""
+    with _CACHE_LOCK:
+        entry = _RUN_CACHE.get(_entry_key(net, sim_ms, layout))
+    if entry is None:
+        return {}
+    return {sig: entry.op_scopes(sig) for sig in list(entry._programs)}
+
+
 def _run_and_reduce(net, sim_ms: int, layout=None):
     """One cached entry per (net.cache_key(), sim_ms, layout geometry):
     repeated calls with an equivalent network AND layout hit the cache
     instead of re-tracing the full simulation.  The layout geometry is
     part of the key — the same network on a (2,4) vs (4,2) mesh is two
     distinct programs."""
-    key = (
-        net.cache_key(),
-        int(sim_ms),
-        layout.geometry() if layout is not None else None,
-    )
-    with _CACHE_LOCK:
-        fn = _RUN_CACHE.get(key)
-        if fn is not None:
-            _COUNTERS["hits"] += 1
-            _RUN_CACHE.move_to_end(key)
-            return fn
+    with _span("lookup", "lookup_seconds_total"):
+        key = _entry_key(net, sim_ms, layout)
+        with _CACHE_LOCK:
+            fn = _RUN_CACHE.get(key)
+            if fn is not None:
+                _COUNTERS["hits"] += 1
+                _RUN_CACHE.move_to_end(key)
+                return fn
 
-        _COUNTERS["misses"] += 1
-        fn = _CachedRun(net, sim_ms, key, layout=layout)
-        _RUN_CACHE[key] = fn
-        while len(_RUN_CACHE) > _RUN_CACHE_MAX:
-            _RUN_CACHE.popitem(last=False)
-            _COUNTERS["evictions"] += 1
-        return fn
+            _COUNTERS["misses"] += 1
+            fn = _CachedRun(net, sim_ms, key, layout=layout)
+            _RUN_CACHE[key] = fn
+            while len(_RUN_CACHE) > _RUN_CACHE_MAX:
+                _RUN_CACHE.popitem(last=False)
+                _COUNTERS["evictions"] += 1
+            return fn
 
 
 def sharded_run_stats(net, states, sim_ms: int, layout=None

@@ -43,12 +43,14 @@ fails loudly beyond that.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
 from ..engine import BatchedProtocol
+from ..engine.core import CHANNEL_SCOPES
 from ..ops.bitops import lowest_set_bit, popcount_words, xor_shuffle
 
 INT32_MAX = np.int32(2**31 - 1)
@@ -353,52 +355,58 @@ class BitsetAggBase(BatchedProtocol):
         proto = state.proto
         d = self.CHANNEL_DEPTH
         ss = d + 1
-        # masked rows may carry junk levels; clamp so every computed index
-        # is in range (their scatters are dropped via the n_nodes row)
-        level = jnp.clip(level.astype(jnp.int32), 1, self.n_levels - 1)
-        state, ok, arrival = net.latency_arrivals(
-            state, mask, from_idx, to_idx, state.time + 1, level
-        )
-        # receiver traffic counters tick here, at send time: every ok send
-        # is delivered by the oracle (Network.java:611-612), but the channel
-        # may displace it — counting at send keeps end-of-run totals exact
-        # at the cost of counters leading arrivals by the latency
-        okc = ok.astype(jnp.int32)
-        sizes = jnp.asarray(self._size_table(), jnp.int32)[level]
-        state = state._replace(
-            msg_received=state.msg_received.at[to_idx].add(okc, mode="drop"),
-            bytes_received=state.bytes_received.at[to_idx].add(
-                okc * sizes, mode="drop"
-            ),
-        )
-        rel = (to_idx ^ from_idx).astype(jnp.int32)
-        # ABSOLUTE arrival packing (no per-tick countdown — see
-        # _advance_channel).  Sims running past the int32 packing horizon
-        # (2^(31-rel_bits) ms: 524 s at 4096 nodes, 128 s at the 16384
-        # cap) would overflow the shift; such sends are dropped and
-        # counted in proto["displaced"] so a too-long sim fails loudly in
-        # the displacement stats rather than corrupting arrival order.
-        # strictly below the last in-horizon ms: at the boundary arrival,
-        # a max-rel send would pack to exactly INT32_MAX — the empty-slot
-        # sentinel — and vanish uncounted
-        fits_t = arrival < (jnp.int32(1) << (31 - self.rel_bits)) - 1
-        time_overflow = jnp.sum((ok & ~fits_t).astype(jnp.int32))
-        ok = ok & fits_t
-        key = jnp.where(ok, (arrival << self.rel_bits) | rel, INT32_MAX)
+        scope = functools.partial(net._scope, scopes=CHANNEL_SCOPES)
+        with scope("arrivals"):
+            # masked rows may carry junk levels; clamp so every computed
+            # index is in range (their scatters are dropped via the
+            # n_nodes row)
+            level = jnp.clip(level.astype(jnp.int32), 1, self.n_levels - 1)
+            state, ok, arrival = net.latency_arrivals(
+                state, mask, from_idx, to_idx, state.time + 1, level
+            )
+            # receiver traffic counters tick here, at send time: every ok
+            # send is delivered by the oracle (Network.java:611-612), but
+            # the channel may displace it — counting at send keeps
+            # end-of-run totals exact at the cost of counters leading
+            # arrivals by the latency
+            okc = ok.astype(jnp.int32)
+            sizes = jnp.asarray(self._size_table(), jnp.int32)[level]
+            state = state._replace(
+                msg_received=state.msg_received.at[to_idx].add(okc, mode="drop"),
+                bytes_received=state.bytes_received.at[to_idx].add(
+                    okc * sizes, mode="drop"
+                ),
+            )
+            rel = (to_idx ^ from_idx).astype(jnp.int32)
+            # ABSOLUTE arrival packing (no per-tick countdown — see
+            # _advance_channel).  Sims running past the int32 packing
+            # horizon (2^(31-rel_bits) ms: 524 s at 4096 nodes, 128 s at
+            # the 16384 cap) would overflow the shift; such sends are
+            # dropped and counted in proto["displaced"] so a too-long sim
+            # fails loudly in the displacement stats rather than
+            # corrupting arrival order.
+            # strictly below the last in-horizon ms: at the boundary
+            # arrival, a max-rel send would pack to exactly INT32_MAX —
+            # the empty-slot sentinel — and vanish uncounted
+            fits_t = arrival < (jnp.int32(1) << (31 - self.rel_bits)) - 1
+            time_overflow = jnp.sum((ok & ~fits_t).astype(jnp.int32))
+            ok = ok & fits_t
+            key = jnp.where(ok, (arrival << self.rel_bits) | rel, INT32_MAX)
 
-        slot = lax.rem(arrival, jnp.int32(d))
+            slot = lax.rem(arrival, jnp.int32(d))
 
-        # re-address sender-space content into the receiver's block-local
-        # space (bit j -> j ^ r0) for ALL rows, shared by both commit
-        # paths; r0 < bs keeps the permutation inside the level block, and
-        # rows outside the bucket are zeroed so the (dropped) shuffle
-        # can't gather out of range
-        bs_row = jnp.asarray(self.lv_bs)[level - 1]  # [M] level block sizes
-        cnt_list = []
-        for i, b in enumerate(self.buckets):
-            in_b = (level >= b.lo) & (level <= b.hi)
-            r0 = jnp.where(in_b, rel & (bs_row - 1), 0)
-            cnt_list.append(xor_shuffle(content[i].astype(jnp.uint32), r0))
+        with scope("readdress"):
+            # re-address sender-space content into the receiver's
+            # block-local space (bit j -> j ^ r0) for ALL rows, shared by
+            # both commit paths; r0 < bs keeps the permutation inside the
+            # level block, and rows outside the bucket are zeroed so the
+            # (dropped) shuffle can't gather out of range
+            bs_row = jnp.asarray(self.lv_bs)[level - 1]  # [M] level block sizes
+            cnt_list = []
+            for i, b in enumerate(self.buckets):
+                in_b = (level >= b.lo) & (level <= b.hi)
+                r0 = jnp.where(in_b, rel & (bs_row - 1), 0)
+                cnt_list.append(xor_shuffle(content[i].astype(jnp.uint32), r0))
 
         mesh = getattr(net, "node_mesh", None)
         if mesh is not None:
@@ -409,60 +417,72 @@ class BitsetAggBase(BatchedProtocol):
                 mesh, net.node_axis, state, ok, to_idx, level, key, slot,
                 cnt_list, aux,
                 cap=getattr(net, "exchange_capacity", None),
-                time_overflow=time_overflow,
+                time_overflow=time_overflow, scope=scope,
             )
 
-        col = (level - 1) * ss + slot
-        safe_to = jnp.where(ok, to_idx, self.n_nodes)
-        prev = proto["in_key"].at[to_idx, col].get(mode="fill", fill_value=INT32_MAX)
-        new_key = proto["in_key"].at[safe_to, col].min(key, mode="drop")
-        winner = ok & (new_key[to_idx, col] == key)
-
-        # freshest-offer backstop (empty at -1 so any real key wins the max)
-        fcol = (level - 1) * ss + d
-        new_key = new_key.at[safe_to, fcol].max(jnp.where(ok, key, -1), mode="drop")
-        fresh_win = ok & (new_key[to_idx, fcol] == key)
-
-        # displacement accounting (the channel's SimState.dropped analog):
-        # an ok send that won neither slot, or a winner that evicted a
-        # still-pending occupant with a later arrival
-        lost_entry = ok & ~winner & ~fresh_win
-        evicted = winner & (prev != INT32_MAX) & (prev > key)
-        displaced = (
-            jnp.sum((lost_entry | evicted).astype(jnp.int32)) + time_overflow
-        )
-
-        updates = dict(proto, in_key=new_key, displaced=proto["displaced"] + displaced)
-
-        win_to = jnp.where(winner, to_idx, self.n_nodes)
-        fwin_to = jnp.where(fresh_win, to_idx, self.n_nodes)
-        for i, b in enumerate(self.buckets):
-            in_b = (level >= b.lo) & (level <= b.hi)
-            li = level - b.lo  # level row inside the bucket
-            cw = jnp.arange(b.w_pad, dtype=jnp.int32)
-            cols = ((li * ss + slot) * b.w_pad)[:, None] + cw
-            fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
-            cnt = cnt_list[i]  # receiver-space content (hoisted above)
-            a = updates[f"in_sig{i}"]
-            a = a.at[jnp.where(in_b, win_to, self.n_nodes)[:, None], cols].set(
-                cnt, mode="drop"
+        with scope("claim"):
+            col = (level - 1) * ss + slot
+            safe_to = jnp.where(ok, to_idx, self.n_nodes)
+            prev = proto["in_key"].at[to_idx, col].get(
+                mode="fill", fill_value=INT32_MAX
             )
-            a = a.at[jnp.where(in_b, fwin_to, self.n_nodes)[:, None], fcols].set(
-                cnt, mode="drop"
+            new_key = proto["in_key"].at[safe_to, col].min(key, mode="drop")
+            winner = ok & (new_key[to_idx, col] == key)
+
+            # freshest-offer backstop (empty at -1 so any real key wins
+            # the max)
+            fcol = (level - 1) * ss + d
+            new_key = new_key.at[safe_to, fcol].max(
+                jnp.where(ok, key, -1), mode="drop"
             )
-            updates[f"in_sig{i}"] = a
-        if aux is not None:
-            new_aux = proto["in_aux"].at[win_to, col].set(
-                aux.astype(jnp.int32), mode="drop"
+            fresh_win = ok & (new_key[to_idx, fcol] == key)
+
+            # displacement accounting (the channel's SimState.dropped
+            # analog): an ok send that won neither slot, or a winner that
+            # evicted a still-pending occupant with a later arrival
+            lost_entry = ok & ~winner & ~fresh_win
+            evicted = winner & (prev != INT32_MAX) & (prev > key)
+            displaced = (
+                jnp.sum((lost_entry | evicted).astype(jnp.int32)) + time_overflow
             )
-            new_aux = new_aux.at[fwin_to, fcol].set(aux.astype(jnp.int32), mode="drop")
-            updates["in_aux"] = new_aux
+
+            updates = dict(
+                proto, in_key=new_key, displaced=proto["displaced"] + displaced
+            )
+
+            win_to = jnp.where(winner, to_idx, self.n_nodes)
+            fwin_to = jnp.where(fresh_win, to_idx, self.n_nodes)
+
+        with scope("commit"):
+            for i, b in enumerate(self.buckets):
+                in_b = (level >= b.lo) & (level <= b.hi)
+                li = level - b.lo  # level row inside the bucket
+                cw = jnp.arange(b.w_pad, dtype=jnp.int32)
+                cols = ((li * ss + slot) * b.w_pad)[:, None] + cw
+                fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
+                cnt = cnt_list[i]  # receiver-space content (hoisted above)
+                a = updates[f"in_sig{i}"]
+                a = a.at[jnp.where(in_b, win_to, self.n_nodes)[:, None], cols].set(
+                    cnt, mode="drop"
+                )
+                a = a.at[jnp.where(in_b, fwin_to, self.n_nodes)[:, None], fcols].set(
+                    cnt, mode="drop"
+                )
+                updates[f"in_sig{i}"] = a
+            if aux is not None:
+                new_aux = proto["in_aux"].at[win_to, col].set(
+                    aux.astype(jnp.int32), mode="drop"
+                )
+                new_aux = new_aux.at[fwin_to, fcol].set(
+                    aux.astype(jnp.int32), mode="drop"
+                )
+                updates["in_aux"] = new_aux
         return state._replace(proto=updates)
 
     # -- node-sharded channel commit (explicit all_to_all exchange) ----------
     def _channel_commit_sharded(
         self, mesh, axis, state, ok, to_idx, level, key, slot, cnt_list, aux,
-        cap=None, time_overflow=0,
+        cap=None, time_overflow=0, *, scope,
     ):
         """The channel commit of _send_stacked under node-axis sharding
         (SURVEY §7): each device owns N/P node rows of the
@@ -485,7 +505,10 @@ class BitsetAggBase(BatchedProtocol):
         and bucket overflow is counted in proto["displaced"] — the same
         bounded-loss semantics as channel displacement, which the
         protocols' periodic re-offers are already designed to absorb
-        (bit identity then becomes distribution parity)."""
+        (bit identity then becomes distribution parity).  `scope` is
+        _send_stacked's CHANNEL_SCOPES switch: the claim and the commit
+        carry the names they carry unsharded; the exchange before them
+        exists only here and stays under the engine's phase scope."""
         from functools import partial as _partial
 
         from jax import lax as _lax
@@ -572,53 +595,57 @@ class BitsetAggBase(BatchedProtocol):
 
             # 3. local commit — the unsharded scatter code with local
             # receiver rows (buffer fill rows have ok=0 and are masked)
-            to_r = meta_x[:, 0] - di * n_loc
-            lvl = jnp.clip(meta_x[:, 1], 1, L - 1)
-            key_r = meta_x[:, 2]
-            slot_r = meta_x[:, 3]
-            aux_r = meta_x[:, 4]
-            ok_r = meta_x[:, 5] > 0
-            col = (lvl - 1) * ss + slot_r
-            fcol = (lvl - 1) * ss + d
-            safe_to = jnp.where(ok_r, to_r, n_loc)
-            prev = ikey.at[safe_to, col].get(mode="fill", fill_value=INT32_MAX)
-            new_key = ikey.at[safe_to, col].min(
-                jnp.where(ok_r, key_r, INT32_MAX), mode="drop"
-            )
-            got = new_key.at[safe_to, col].get(mode="fill", fill_value=INT32_MAX)
-            winner = ok_r & (got == key_r)
-            new_key = new_key.at[safe_to, fcol].max(
-                jnp.where(ok_r, key_r, -1), mode="drop"
-            )
-            fgot = new_key.at[safe_to, fcol].get(mode="fill", fill_value=-1)
-            fresh_win = ok_r & (fgot == key_r)
-            lost_entry = ok_r & ~winner & ~fresh_win
-            evicted = winner & (prev != INT32_MAX) & (prev > key_r)
-            displaced = jnp.sum((lost_entry | evicted).astype(jnp.int32))
+            with scope("claim"):
+                to_r = meta_x[:, 0] - di * n_loc
+                lvl = jnp.clip(meta_x[:, 1], 1, L - 1)
+                key_r = meta_x[:, 2]
+                slot_r = meta_x[:, 3]
+                aux_r = meta_x[:, 4]
+                ok_r = meta_x[:, 5] > 0
+                col = (lvl - 1) * ss + slot_r
+                fcol = (lvl - 1) * ss + d
+                safe_to = jnp.where(ok_r, to_r, n_loc)
+                prev = ikey.at[safe_to, col].get(mode="fill", fill_value=INT32_MAX)
+                new_key = ikey.at[safe_to, col].min(
+                    jnp.where(ok_r, key_r, INT32_MAX), mode="drop"
+                )
+                got = new_key.at[safe_to, col].get(
+                    mode="fill", fill_value=INT32_MAX
+                )
+                winner = ok_r & (got == key_r)
+                new_key = new_key.at[safe_to, fcol].max(
+                    jnp.where(ok_r, key_r, -1), mode="drop"
+                )
+                fgot = new_key.at[safe_to, fcol].get(mode="fill", fill_value=-1)
+                fresh_win = ok_r & (fgot == key_r)
+                lost_entry = ok_r & ~winner & ~fresh_win
+                evicted = winner & (prev != INT32_MAX) & (prev > key_r)
+                displaced = jnp.sum((lost_entry | evicted).astype(jnp.int32))
 
-            for i, b in enumerate(self.buckets):
-                in_b = (lvl >= b.lo) & (lvl <= b.hi) & ok_r
-                li = lvl - b.lo
-                cw = jnp.arange(b.w_pad, dtype=jnp.int32)
-                cols = ((li * ss + slot_r) * b.w_pad)[:, None] + cw
-                fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
-                win_to = jnp.where(winner & in_b, to_r, n_loc)
-                fwin_to = jnp.where(fresh_win & in_b, to_r, n_loc)
-                sigs[i] = sigs[i].at[win_to[:, None], cols].set(
-                    cnt_x[i], mode="drop"
-                )
-                sigs[i] = sigs[i].at[fwin_to[:, None], fcols].set(
-                    cnt_x[i], mode="drop"
-                )
-            outs = [new_key] + sigs
-            if have_aux:
-                iaux = iaux.at[jnp.where(winner, to_r, n_loc), col].set(
-                    aux_r, mode="drop"
-                )
-                iaux = iaux.at[jnp.where(fresh_win, to_r, n_loc), fcol].set(
-                    aux_r, mode="drop"
-                )
-                outs.append(iaux)
+            with scope("commit"):
+                for i, b in enumerate(self.buckets):
+                    in_b = (lvl >= b.lo) & (lvl <= b.hi) & ok_r
+                    li = lvl - b.lo
+                    cw = jnp.arange(b.w_pad, dtype=jnp.int32)
+                    cols = ((li * ss + slot_r) * b.w_pad)[:, None] + cw
+                    fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
+                    win_to = jnp.where(winner & in_b, to_r, n_loc)
+                    fwin_to = jnp.where(fresh_win & in_b, to_r, n_loc)
+                    sigs[i] = sigs[i].at[win_to[:, None], cols].set(
+                        cnt_x[i], mode="drop"
+                    )
+                    sigs[i] = sigs[i].at[fwin_to[:, None], fcols].set(
+                        cnt_x[i], mode="drop"
+                    )
+                outs = [new_key] + sigs
+                if have_aux:
+                    iaux = iaux.at[jnp.where(winner, to_r, n_loc), col].set(
+                        aux_r, mode="drop"
+                    )
+                    iaux = iaux.at[jnp.where(fresh_win, to_r, n_loc), fcol].set(
+                        aux_r, mode="drop"
+                    )
+                    outs.append(iaux)
             outs.append(_lax.psum(displaced + overflow, axis))
             return tuple(outs)
 

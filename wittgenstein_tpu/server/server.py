@@ -224,6 +224,26 @@ class Server:
             p.add("run_cache_compile_seconds_total",
                   round(info["compile_seconds_total"], 3),
                   "wall-clock spent in run-cache XLA compiles", "counter")
+            # the split of the above and the dispatch path, each the
+            # total of one witt.host.* span (docs/observability.md)
+            p.add("run_cache_lower_seconds_total",
+                  round(info["lower_seconds_total"], 6),
+                  "wall-clock spent tracing and lowering run programs "
+                  "to StableHLO", "counter")
+            p.add("run_cache_backend_compile_seconds_total",
+                  round(info["backend_compile_seconds_total"], 6),
+                  "wall-clock spent in XLA's compile or the persistent "
+                  "compilation cache's load", "counter")
+            p.add("run_cache_lookup_seconds_total",
+                  round(info["lookup_seconds_total"], 6),
+                  "wall-clock spent finding the cached entry and "
+                  "program of a call", "counter")
+            p.add("run_cache_execute_seconds_total",
+                  round(info["execute_seconds_total"], 6),
+                  "wall-clock spent enqueueing compiled run programs "
+                  "(call to return, not device time)", "counter")
+            p.add("run_cache_calls_total", info["calls"],
+                  "compiled run programs enqueued", "counter")
         except Exception:
             pass
         try:

@@ -4,20 +4,22 @@ come from jax.profiler).
 
 Usage:
 
-    from wittgenstein_tpu.tools.profiling import trace
+    from wittgenstein_tpu.tools.profiling import host_span, trace
     with trace("/tmp/witt-trace"):
-        out = net.run_ms_batched(states, 1000)
-        jax.block_until_ready(out)
+        with host_span("run"):
+            out = net.run_ms_batched(states, 1000)
+            jax.block_until_ready(out)
 
-The trace directory opens in TensorBoard's profile plugin / Perfetto.
-`bench.py` exposes the same via WITT_BENCH_PROFILE=<dir>.
+The trace directory opens in TensorBoard's profile plugin / Perfetto;
+`benchmark/xplane.py` reads it with nothing but JAX.  `bench.py` exposes
+`trace` via WITT_BENCH_PROFILE=<dir>.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Iterator, Optional
+from typing import ContextManager, Iterator, MutableMapping, Optional
 
 
 @contextlib.contextmanager
@@ -33,23 +35,46 @@ def trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside a trace (shows up on the TraceMe track)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
+_NO_LOCK = contextlib.nullcontext()
 
 
-class WallClock:
-    """Tiny host-side timer for compile/run splits (the pattern bench.py
-    uses): `with WallClock() as w: ...; w.seconds`."""
+class host_span:
+    """The program's one span: a name, a start and an end.
 
-    def __enter__(self) -> "WallClock":
-        self._t0 = time.perf_counter()
+    Opens `jax.profiler.TraceAnnotation("witt.host." + name)`, so that
+    under a profiler trace the span lies on the `/host:CPU` plane, on
+    the device events' clock; with no trace running the annotation is a
+    no-op.  On exit the elapsed `perf_counter` seconds are in `.seconds`
+    and, when `counters` is given, added to `counters[key]` (a monotonic
+    total such as the run cache's `_COUNTERS`), under `lock` where the
+    total is shared between threads.  Nothing is kept per call and no
+    list grows."""
+
+    __slots__ = ("seconds", "_annotation", "_counters", "_key", "_lock", "_t0")
+
+    def __init__(
+        self,
+        name: str,
+        counters: Optional[MutableMapping[str, float]] = None,
+        key: Optional[str] = None,
+        lock: Optional[ContextManager] = None,
+    ):
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation("witt.host." + name)
+        self._counters = counters
+        self._key = key
+        self._lock = _NO_LOCK if lock is None else lock
         self.seconds: Optional[float] = None
+
+    def __enter__(self) -> "host_span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        if self._counters is not None:
+            with self._lock:
+                self._counters[self._key] += self.seconds
