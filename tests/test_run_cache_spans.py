@@ -23,6 +23,7 @@ from wittgenstein_tpu.engine import replicate_state
 from wittgenstein_tpu.engine.core import CHANNEL_SCOPES
 from wittgenstein_tpu.parallel import replica_shard as rs
 from wittgenstein_tpu.profiling.xla_cost import (
+    _HLO_NAME,
     hlo_op_scopes,
     scope_chain,
     scope_self_times,
@@ -103,11 +104,13 @@ def traced(request, cold_compiles, tmp_path_factory):
         jax.profiler.stop_trace()
     path = _xplane().find_xplane(trace_dir)
     (op_scopes,) = rs.run_cache_op_scopes(net, CHUNK_MS).values()
+    (program,) = rs._RUN_CACHE[rs._entry_key(net, CHUNK_MS, None)]._programs.values()
     return {
         "counters": (c0, c1, c2),
         "trace_path": path,
         "data": ProfileData.from_file(path),
         "op_scopes": op_scopes,
+        "text": program.as_text(),
     }
 
 
@@ -245,6 +248,74 @@ def test_every_channel_scope_names_an_instruction(traced):
     chains = {row["scope"] for row in traced["op_scopes"].values()}
     assert any(re.fullmatch(r"witt\.beat/witt\.channel\.commit", c) for c in chains)
     assert any(c.endswith("witt.protocol_tick/witt.channel.commit") for c in chains)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_CALLED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+
+
+def gathers_under(text: str, op_scopes: dict, scope: str, dtype: str = "") -> list:
+    """Names of the instructions of a compiled module's text whose scope
+    chain ends in `scope` and that are a gather (of `dtype` elements,
+    where one is named), or call a computation (a fusion's body) that
+    holds one."""
+    gather = re.compile(rf"= {dtype}\S* gather\(")
+    holds_gather = set()
+    scoped = []  # (name, line) under the scope
+    computation = None
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header:
+            computation = header.group(1)
+            continue
+        m = _HLO_NAME.match(line)
+        if m is None:
+            continue
+        if gather.search(line):
+            holds_gather.add(computation)
+        if op_scopes.get(m.group(1), {}).get("scope", "").endswith(scope):
+            scoped.append((m.group(1), line))
+    return [
+        name for name, line in scoped
+        if gather.search(line) or holds_gather & set(_CALLED.findall(line))
+    ]
+
+
+def test_the_readdress_scope_gathers_no_words(traced):
+    """PR 28's structural pin: `xor_shuffle` re-addresses the packed
+    uint32 planes by select stages; its word gather was 26-44% of a tick
+    on the chip (PERF.md section 5) and cannot come back unnoticed.  What
+    the scope still gathers is `_send_stacked`'s own `lv_bs[level - 1]`,
+    M int32 block sizes from a table of L."""
+    readdress = CHANNEL_SCOPES["readdress"]
+    rows = [n for n, r in traced["op_scopes"].items() if r["scope"].endswith(readdress)]
+    assert rows  # the scope is live: there is something to look at
+    assert gathers_under(traced["text"], traced["op_scopes"], readdress, "u32") == []
+
+
+def test_gathers_under_finds_a_gather_and_a_fusion_that_calls_one():
+    text = """
+%fused_computation.1 (p0: u32[4,8], p1: s32[4,8]) -> u32[4,8] {
+  %p0 = u32[4,8]{1,0} parameter(0)
+  %p1 = s32[4,8]{1,0} parameter(1)
+  ROOT %gather.3 = u32[4,8]{1,0} gather(%p0, %p1), offset_dims={}, metadata={op_name="jit(f)/witt.protocol_tick/witt.channel.readdress/gather"}
+}
+
+ENTRY %main (a: u32[4,8], b: s32[4,8], t: s32[5]) -> u32[4,8] {
+  %a = u32[4,8]{1,0} parameter(0)
+  %b = s32[4,8]{1,0} parameter(1)
+  %t = s32[5]{0} parameter(2)
+  %gather.9 = u32[4,8]{1,0} gather(%a, %b), offset_dims={}, metadata={op_name="jit(f)/witt.protocol_tick/gather"}
+  %gather.4 = s32[4,1]{1,0} gather(%t, %b), offset_dims={1}, metadata={op_name="jit(f)/witt.protocol_tick/witt.channel.readdress/gather"}
+  %fusion.7 = u32[4,8]{1,0} fusion(%a, %b), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/witt.protocol_tick/witt.channel.readdress/select_n"}
+  ROOT %add.1 = u32[4,8]{1,0} add(%fusion.7, %gather.9), metadata={op_name="jit(f)/witt.protocol_tick/witt.channel.readdress/add"}
+}
+"""
+    readdress = CHANNEL_SCOPES["readdress"]
+    table = hlo_op_scopes(text)
+    # not gather.9: another scope
+    assert gathers_under(text, table, readdress) == ["gather.3", "gather.4", "fusion.7"]
+    assert gathers_under(text, table, readdress, "u32") == ["gather.3", "fusion.7"]
 
 
 def test_op_scopes_keep_each_program_apart():

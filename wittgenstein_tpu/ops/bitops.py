@@ -6,9 +6,10 @@ Under that layout the binary-split level structure (Handel.allSigsAtLevel,
 Handel.java:634-647) becomes uniform across nodes — level l occupies bit
 block [2^(l-1), 2^l) for every node — and re-addressing a contribution
 from sender s's space into receiver r's space is the bit permutation
-j -> j ^ (r ^ s), implemented below as a word gather (high bits) plus a
-5-stage butterfly (low bits).  All ops are jnp-traceable and vmap over
-leading axes.
+j -> j ^ (r ^ s), implemented below as one butterfly over both levels:
+log2(w) conditional swaps of word blocks (high bits) and 5 conditional
+swaps of bit blocks inside each word (low bits), every stage a select —
+no gather.  All ops are jnp-traceable and vmap over leading axes.
 """
 
 from __future__ import annotations
@@ -109,35 +110,46 @@ def lowest_set_bit(words) -> jnp.ndarray:
 def xor_shuffle(words, v):
     """Permute bit positions j -> j ^ v of packed vectors.
 
-    words: [..., W] uint32; v: int32 scalar or [...] batch of xor values
-    (dynamic).  Word-level part uses a gather on index ^ (v >> 5); bit-level
-    part applies 5 conditional butterfly stages for v & 31.
+    words: [..., W] uint32, W a power of two (static; ValueError
+    otherwise: j ^ v leaves the vector for any other width); v: int32
+    scalar or [...] batch of xor values (dynamic).  A butterfly of
+    conditional swaps: stage b of the word level exchanges adjacent
+    blocks of 2^b words where bit b of v >> 5 is set (log2(W) stages,
+    none at W == 1), then 5 bit-level stages do the same inside every
+    word for v & 31.  Bits of v at or above log2(32 W) are ignored, so no
+    row can reach outside its vector whatever junk a masked row carries.
     """
     words = words.astype(jnp.uint32)
     w = words.shape[-1]
+    if w & (w - 1):
+        raise ValueError(f"xor_shuffle needs a power-of-two word count, got {w}")
     v = jnp.asarray(v, jnp.int32)
-    v_hi = lax.shift_right_logical(v, 5)
-    v_lo = v & 31
 
-    idx = jnp.arange(w, dtype=jnp.int32)
-    # broadcast v over the leading axes: gather words[..., idx ^ v_hi]
-    gathered = jnp.take_along_axis(
-        words,
-        jnp.broadcast_to(
-            idx ^ v_hi[..., None] if v.ndim else idx ^ v_hi,
-            words.shape,
-        ),
-        axis=-1,
-    )
+    def where_bit(b, swapped, x):
+        cond = (lax.shift_right_logical(v, b) & 1) == 1
+        return jnp.where(cond[..., None] if v.ndim else cond, swapped, x)
 
-    x = gathered
+    def shifted(x, s):
+        # x[..., k - s], zero where that leaves the vector: one pad with
+        # a negative edge, which XLA:TPU fuses into the stage's select (a
+        # roll's two slices it writes out, each a full lane tile a row)
+        edges = [(0, 0, 0)] * (x.ndim - 1) + [(s, -s, 0)]
+        return lax.pad(x, jnp.uint32(0), edges)
+
+    x = words
+    k = np.arange(w)
+    for b in range(w.bit_length() - 1):
+        s = 1 << b
+        # x[..., k ^ s]: the upper block of each pair comes from s below
+        # it, the lower from s above; the zero-filled lanes of either
+        # shift are never the ones selected
+        swapped = jnp.where((k & s) != 0, shifted(x, s), shifted(x, -s))
+        x = where_bit(5 + b, swapped, x)
     for b in range(5):
         m = jnp.uint32(_BUTTERFLY_MASKS[b])
         sh = jnp.uint32(1 << b)
         swapped = ((x & m) << sh) | (lax.shift_right_logical(x, sh) & m)
-        bit = lax.shift_right_logical(v_lo, b) & 1
-        cond = (bit == 1) if v.ndim == 0 else (bit == 1)[..., None]
-        x = jnp.where(cond, swapped, x)
+        x = where_bit(b, swapped, x)
     return x
 
 
