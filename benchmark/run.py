@@ -54,6 +54,14 @@ TICK_MS = 1  # the engine's tick is one simulated millisecond
 # a rehearsal cuts the measured rows; the twin is already small enough for a CPU
 REHEARSAL = {"params": {"node_count": 64}, "replicas": 2}
 
+
+def rehearsal_params(config: dict) -> dict:
+    """The overrides a rehearsal puts on the configuration's parameters:
+    its own `rehearsal.params` where it states them (one with nodes down
+    needs its `nodes_down` and threshold cut with the node count)."""
+    return config.get("rehearsal", {}).get("params", REHEARSAL["params"])
+
+
 _CACHE_EVENTS = collections.Counter()
 
 
@@ -134,7 +142,7 @@ def build(cell, seed: int, rehearse: bool):
 
     config = cell.config
     params = cells.build_params(
-        config, config["params_class"], REHEARSAL["params"] if rehearse else None
+        config, config["params_class"], rehearsal_params(config) if rehearse else None
     )
     net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
     replicas = cell.traffic["replicas"]
@@ -291,6 +299,8 @@ def reduce_layer_metrics(cell, context: dict) -> dict:
 
     def counter_delta(m):
         a, b = context["counters"][m["over"]]
+        if m["counter"] not in a or m["counter"] not in b:
+            return None  # a program without that counter: a parent, under a later PR's file
         return b[m["counter"]] - a[m["counter"]]
 
     def memory_stat_gb(m):
@@ -324,6 +334,7 @@ def set_up(cell, seed: int, rehearse: bool) -> dict:
     events = collections.Counter(_CACHE_EVENTS)
     t0 = time.perf_counter()
     rows = fresh(0)
+    timed_rows.named_leaves(cell.config, rows)  # a leaf that is not there: an error now
     at_start = row_stats(rows)
     warm, warm_stats = sharded_run_stats(net, rows, chunk_ms)
     del rows
@@ -362,10 +373,10 @@ def judge(cell, seed: int, window: dict, setup: dict, compiles_in_window: int, r
     deterministic = first == setup["warm_fingerprint"]
     note("determinism", first_window_chunk_fingerprint=first,
          must_equal=setup["warm_fingerprint"], ok=deterministic)
-    counts = timed_rows.program_counts(window["last"])
+    counts = timed_rows.program_counts(window["last"], cell.config)
     window["last"] = None  # free the rows before the reference and the twin
     rows = timed_rows.check(cell.config, twin.row_seeds(seed, 1)[0], counts,
-                            REHEARSAL["params"] if rehearse else None)
+                            rehearsal_params(cell.config) if rehearse else None)
     note("timed-rows", **rows, replicas=setup["replicas"])
     t0 = time.perf_counter()
     fidelity = twin.check(cell.config, seed, batch=setup["replicas"])

@@ -14,10 +14,17 @@ Two numbers for every row, each printed beside its limit:
   the same simulated time.  At thousands of nodes that mean moves by
   less than 1% from seed to seed, so one reference run stands for every
   row (`timed_rows.calibration` in the configuration file);
-- conservation: the program counts a message for its receiver when it
-  is sent, so with no node down a row's received total equals its sent
-  total exactly.  A scatter that loses an update, or a row whose send
-  path ran on part of its nodes, breaks it.
+- conservation: every message a live node has sent is either counted
+  for its receiver or counted in a state leaf that the configuration
+  names (`timed_rows.conservation`), exactly: sent total == received
+  total + the named leaves' sums, limit 0.  The aggregation protocols
+  count a message for its receiver when it is sent, so with no node
+  down no leaf is named and a row's received total equals its sent
+  total.  A configuration with nodes down names the program's count of
+  sends whose receiver could not take them; a protocol on the message
+  store, which counts at delivery, names the store's occupancy.  A
+  scatter that loses an update, or a row whose send path ran on part of
+  its nodes, breaks it, by as little as 1.
 
 What it cannot see: what the messages carry.  By the time an R=8 window
 ends (30 simulated ms) a tenth of the nodes have received anything, so
@@ -32,7 +39,7 @@ import time
 
 import numpy as np
 
-from cells import BENCH_DIR, build_params, resolve
+from cells import BENCH_DIR, BenchmarkFileError, build_params, resolve
 
 
 def reference_sent(config: dict, seed: int, stops, overrides: dict | None = None) -> list:
@@ -54,30 +61,79 @@ def reference_sent(config: dict, seed: int, stops, overrides: dict | None = None
     return out
 
 
-def program_counts(state) -> dict:
+def named_leaves(config: dict, state) -> list:
+    """(path, leaf, per node?) for every state leaf that
+    `timed_rows.conservation.received_plus` names: `per_node` paths are
+    leaves of `down`'s own shape, masked by `~down` as `msg_sent` is;
+    `whole` paths are summed whole, row by row.  A path is dotted, through
+    fields of the state and keys of `state.proto`.  A path that reaches
+    nothing, or a leaf that cannot be counted, is an error here, before
+    the window, never a sum of 0."""
+    plus = config.get("timed_rows", {}).get("conservation", {}).get("received_plus", {})
+    if set(plus) - {"per_node", "whole"}:
+        raise BenchmarkFileError(f"timed_rows.conservation.received_plus: {sorted(plus)} "
+                                 "are not all of per_node, whole")
+    out = []
+    for kind in ("per_node", "whole"):
+        for path in plus.get(kind, []):
+            where = f"timed_rows.conservation.received_plus.{kind} {path!r}"
+            leaf = state
+            for part in path.split("."):
+                if isinstance(leaf, dict) and part in leaf:
+                    leaf = leaf[part]
+                elif part in getattr(leaf, "_fields", ()):
+                    leaf = getattr(leaf, part)
+                else:
+                    raise BenchmarkFileError(f"{where}: the state has no {part!r} there")
+            dtype, shape = getattr(leaf, "dtype", None), getattr(leaf, "shape", None)
+            if dtype is None or not (dtype == bool or np.issubdtype(dtype, np.integer)):
+                raise BenchmarkFileError(f"{where}: not a leaf of whole numbers or booleans")
+            rows = state.down.shape[:-1]
+            fits = shape == state.down.shape if kind == "per_node" else shape[: len(rows)] == rows
+            if not fits:
+                raise BenchmarkFileError(f"{where}: shape {shape} against down's {state.down.shape}")
+            out.append((path, leaf, kind == "per_node"))
+    return out
+
+
+def program_counts(state, config: dict | None = None) -> dict:
     """Per row of a batched state: its time, the mean messages sent by a
-    live node, and the sent and received totals."""
+    live node, the sent and received totals, and the sum of every leaf
+    the configuration names beside the received total (a boolean leaf
+    counts its true entries)."""
     live = ~np.asarray(state.down)
     sent = np.where(live, np.asarray(state.msg_sent), 0).astype(np.int64)
     received = np.where(live, np.asarray(state.msg_received), 0).astype(np.int64)
+    rows = live.shape[:-1]
+    plus = {}
+    for path, leaf, per_node in named_leaves(config or {}, state):
+        leaf = np.asarray(leaf).astype(np.int64)
+        leaf = np.where(live, leaf, 0) if per_node else leaf.reshape(*rows, -1)
+        plus[path] = leaf.sum(-1).tolist()
     return {
         "time_ms": np.asarray(state.time).reshape(-1).tolist(),
         "sent_mean": (sent.sum(-1) / np.maximum(1, live.sum(-1))).tolist(),
         "sent_total": sent.sum(-1).tolist(),
         "received_total": received.sum(-1).tolist(),
+        "received_plus": plus,
     }
 
 
 def compare(counts: dict, reference_mean: float, limit: float) -> dict:
     """The comparison that decides this part of `correct`."""
     gaps = [abs(s - reference_mean) / reference_mean for s in counts["sent_mean"]]
-    lost = [abs(s - r) for s, r in zip(counts["sent_total"], counts["received_total"])]
+    plus = counts["received_plus"]
+    lost = [abs(s - r - sum(leaf[row] for leaf in plus.values()))
+            for row, (s, r) in enumerate(zip(counts["sent_total"], counts["received_total"]))]
+    named = {"sent_total": counts["sent_total"], "received_total": counts["received_total"],
+             "received_plus": plus} if plus else {}
     return {
         "program_sent_mean": counts["sent_mean"],
         "reference_sent_mean": reference_mean,
         "sent_rel_gap": gaps,
         "sent_rel_gap_worst": max(gaps),
         "sent_rel_gap_limit": limit,
+        **named,  # what was added to what, where the configuration names leaves
         "sent_minus_received": lost,
         "sent_minus_received_limit": 0,
         "ok": bool(max(gaps) <= limit and max(lost) == 0),
