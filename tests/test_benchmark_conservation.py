@@ -1,0 +1,54 @@
+"""The conservation law of the benchmark's timed rows, guarded by the
+tier-1 run: the numpy cases of benchmark/tests/test_conservation.py
+(no program runs, a second in all), loaded from that file so that there
+is one copy of them.
+
+One of its cases states what was true when it was written, that no
+configuration names a leaf; `handel-4096-byz20` (PR 31) names the
+program's `proto.sent_not_ok`.  That case is replaced here by the rule
+it stood for: a configuration names a leaf exactly where its network is
+built with nodes down, and the leaf it names is one the program places.
+"""
+
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+if BENCH_DIR not in sys.path:
+    sys.path.append(BENCH_DIR)  # `cells`, `timed_rows`: as benchmark/tests/conftest.py
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_tests_test_conservation",
+    os.path.join(BENCH_DIR, "tests", "test_conservation.py"),
+)
+_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cases)
+
+STALE = "test_the_configurations_there_are_name_no_leaf"
+globals().update(
+    {name: fn for name, fn in vars(_cases).items() if name.startswith("test_") and name != STALE}
+)
+
+
+def test_a_configuration_names_a_leaf_exactly_where_nodes_are_down():
+    import cells
+    import timed_rows
+
+    seen = set()
+    for workload in cells.load_benchmark()["workloads"]:
+        config = cells.load_cell(workload["name"]).config
+        nodes_down = config["params"].get("nodes_down", 0)
+        seen.add(nodes_down > 0)
+        if nodes_down == 0:
+            assert "conservation" not in config["timed_rows"], workload["name"]
+            assert timed_rows.named_leaves(config, _cases._state(15, 0)) == []
+            continue
+        named = config["timed_rows"]["conservation"]["received_plus"]
+        assert named == {"per_node": ["proto.sent_not_ok"]}, workload["name"]
+        params = cells.build_params(config, config["params_class"], config["rehearsal"]["params"])
+        _net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+        ((path, leaf, per_node),) = timed_rows.named_leaves(config, state)
+        assert per_node and leaf.shape == state.down.shape and int(leaf.sum()) == 0
+    assert seen == {False, True}

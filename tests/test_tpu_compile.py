@@ -147,3 +147,39 @@ def test_handel256_replica_sharded_program_compiles_for_four_chips(mosaic, hande
     assert " all-gather(" not in text and " all-gather-start(" not in text
     out_shapes, _stats = compiled.output_shardings
     assert out_shapes.done_at.spec == P("replicas")
+
+
+# the attack program at the size this file's other programs use: at 4096
+# nodes the compile takes 81 s here, more than the rest of the file (done
+# by hand in PR 31: it compiles)
+BYZ_SIZE = {"node_count": 256, "nodes_down": 51, "threshold": 202}
+
+
+@pytest.fixture(scope="module")
+def handel_byz():
+    """`handel-4096-byz20` as the benchmark builds it (`make_handel` with
+    its parameters, fused step, score cache on), one row: the `bl` and
+    `byz` planes, the forged-signature injection, the blacklist, the
+    emission's blacklist term and the `sent_not_ok` counter."""
+    import json
+    import os
+
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.protocols.handel import HandelParameters
+    from wittgenstein_tpu.protocols.handel_batched import make_handel
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "handel-4096-byz20.json")) as f:
+        config = json.load(f)
+    params = HandelParameters(**{**config["params"], **BYZ_SIZE})
+    net, state = make_handel(params, score_cache=True, **config["factory_kwargs"])
+    assert net.protocol.track_bad and "sent_not_ok" in state.proto
+    return net, replicate_state(state, 1)
+
+
+def test_handel_under_attack_compiles_for_one_chip(mosaic, handel_byz):
+    net, states = handel_byz
+    shapes = _described(states, SingleDeviceSharding(mosaic.devices[0]))
+    text = _compile(lambda s: net.run_ms_batched(s, 20), shapes)
+    assert "tpu_custom_call" in text
+    assert "witt.attack.inject" in text and "witt.attack.emission" in text

@@ -25,6 +25,8 @@ A protocol that sends through the shared channel send path
 (`_send_stacked` of protocols/_agg_batched.py) must also carry every
 sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
 scripts/scope_profile.py are only as whole as these markers are live.
+A Handel built with an attack (`track_bad`) must carry the sub-scopes of
+engine.core.ATTACK_SCOPES that its attack runs.
 
 If this jax version exposes no `name_stack` on source_info, the
 presence half is skipped (API drift guard) — neutrality still runs.
@@ -127,6 +129,14 @@ def _check_presence(jax, name, net, state, path, line, suppress):
         from ..engine.core import CHANNEL_SCOPES
 
         required.extend(CHANNEL_SCOPES.values())
+    if getattr(net.protocol, "track_bad", False):
+        # Handel built with an attack: what the attack adds to a tick
+        from ..engine.core import ATTACK_SCOPES
+
+        required.extend(
+            scope for name, scope in ATTACK_SCOPES.items()
+            if name != "inject" or net.protocol.params.byzantine_suicide
+        )
     for want in required:
         if not any(want in s for s in scopes):
             f = _mk("SL601", path, line,

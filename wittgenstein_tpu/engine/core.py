@@ -115,6 +115,16 @@ CHANNEL_SCOPES = {
     "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
 }
 
+# what Handel's byzantineSuicide attack adds to a tick
+# (protocols/handel_batched.py, live only where `track_bad` carries the
+# `bl` and `byz` planes), nested under the phase that runs it and
+# switched by the same `annotate`; an attack-free program has none.
+ATTACK_SCOPES = {
+    "inject": "witt.attack.inject",  # the forged full-block sig that wins a level's choice
+    "blacklist": "witt.attack.blacklist",  # the bl plane: written at commit, read in curation
+    "emission": "witt.attack.emission",  # dissemination moves on past blacklisted peers
+}
+
 
 class SimState(NamedTuple):
     """Per-replica simulation state; every field is a jnp array so the whole

@@ -296,6 +296,20 @@ class BitsetAggBase(BatchedProtocol):
             sigs,
         )
 
+    def _not_ok_init(self, n: int) -> dict:
+        """proto["sent_not_ok"], int32[N], where the network is built with
+        a node down, and nothing where it is not (as Handel's track_bad
+        places bl and byz): for every sender, its masked sends that were
+        not ok (the receiver down, or past the discard time), which tick
+        msg_sent and never msg_received.  Over live senders msg_sent ==
+        msg_received + sent_not_ok, exactly; with no node down every
+        masked send is ok, so the honest programs carry no such leaf and
+        stay the programs they were."""
+        p = self.params
+        if p.nodes_down > 0 or getattr(p, "bad_nodes", None):
+            return {"sent_not_ok": jnp.zeros(n, jnp.int32)}
+        return {}
+
     def _advance_channel(self, in_key, t):
         """Due mask at tick t; returns (in_key, due, empty_tpl).
 
@@ -377,6 +391,17 @@ class BitsetAggBase(BatchedProtocol):
                     okc * sizes, mode="drop"
                 ),
             )
+            if "sent_not_ok" in proto:
+                # the other side of the same ledger, by sender (see
+                # _not_ok_init): before fits_t, because a time_overflow
+                # send was counted for its receiver just above
+                proto = dict(
+                    proto,
+                    sent_not_ok=proto["sent_not_ok"]
+                    .at[from_idx]
+                    .add((mask & ~ok).astype(jnp.int32)),
+                )
+                state = state._replace(proto=proto)
             rel = (to_idx ^ from_idx).astype(jnp.int32)
             # ABSOLUTE arrival packing (no per-tick countdown — see
             # _advance_channel).  Sims running past the int32 packing

@@ -98,6 +98,7 @@ class BatchedGSF(BitsetAggBase):
             "in_key": in_key,
             **in_sigs,
             "displaced": jnp.int32(0),
+            **self._not_ok_init(n),
             "in_aux": jnp.zeros((n, (L - 1) * ss), jnp.int32),  # prefix k
             "cand_key": jnp.full((n, (L - 1) * K), INT32_MAX, jnp.int32),  # rel
             "cand_pk": jnp.zeros((n, (L - 1) * K), jnp.int32),

@@ -76,8 +76,21 @@ Distribution-parity approximations (deliberate, each noted inline):
     :826-830) becomes a +N penalty whenever the sender's individual sig
     is already verified.
   * emission order (:991-1013) is a counter-hash offset + cycling cursor
-    per level rather than the rank-derived emission lists; finished-peer
-    bookkeeping (levelFinished/finishedPeers) is not tracked.
+    per level rather than the rank-derived emission lists.  Of
+    getRemainingPeers' two skips (:484-503) the BLACKLIST one is carried
+    (PR 31, `_next_unlisted`, live only where track_bad is): the cursor
+    moves on to the next peer the node has not blacklisted and a level
+    whose every peer is blacklisted stops sending.  The FINISHED-PEER one
+    is not (levelFinished/finishedPeers are not tracked), and that is
+    what the program's extra traffic under attack is: at 256 nodes with
+    51 down it sends 259.5 messages a live node where the reference
+    sends 214.6 (+21%; the reference with both skips off sends 273.7,
+    with the finished-peer skip alone off 272.9, with the blacklist skip
+    alone off 232.0), less the fast-path burst it lacks (5.7 against
+    20.3); the blacklist skip pays 17 of those messages only once
+    levels can close (PERF.md section 6, PR 31; ROADMAP B11).  The
+    fast-path burst itself still goes to a blacklisted peer now and then
+    (0.2 messages a live node).
   * suicide-byz picks the lowest-block-index eligible peer, not the
     suicideBizAfter cursor order; hidden-byz re-attempts injection each
     selection instead of tracking the `last` candidate.
@@ -102,6 +115,7 @@ from jax import lax
 from ..core.node import Node, build_node_columns
 from ..core.registries import registry_network_latencies, registry_node_builders
 from ..engine import BatchedNetwork
+from ..engine.core import ATTACK_SCOPES
 from ..engine.rng import hash32
 from ..ops.bitops import popcount_words, xor_shuffle
 from ..utils.javarand import JavaRandom
@@ -285,6 +299,7 @@ class BatchedHandel(BitsetAggBase):
             "in_key": in_key,
             **in_sigs,
             "displaced": jnp.int32(0),
+            **self._not_ok_init(n),
             # stage 2: candidate buffer (toVerifyAgg)
             "cand_rank": jnp.full((n, (L - 1) * K), INT32_MAX, jnp.int32),
             "cand_rel": jnp.zeros((n, (L - 1) * K), jnp.int32),
@@ -381,11 +396,12 @@ class BatchedHandel(BitsetAggBase):
         new_bl = None
         if self.track_bad:
             # bad sig: blacklist the sender, nothing else (:687-694)
-            bad = due & proto["ver_bad"]
-            oh_full = self._onehot(rel, self.n_words)
-            new_bl = jnp.where(
-                bad[:, None], proto["bl"] | oh_full, proto["bl"]
-            )
+            with net._scope("blacklist", ATTACK_SCOPES):
+                bad = due & proto["ver_bad"]
+                oh_full = self._onehot(rel, self.n_words)
+                new_bl = jnp.where(
+                    bad[:, None], proto["bl"] | oh_full, proto["bl"]
+                )
 
         agg, ind, inc = proto["agg"], proto["ind"], proto["inc"]
         lvl = proto["ver_level"]
@@ -598,7 +614,14 @@ class BatchedHandel(BitsetAggBase):
         # onNewSig drop filters: not started, done, blacklisted sender
         accept = due2 & started[:, None, None] & not_done[:, None, None]
         if self.track_bad:
-            accept = accept & (self._getbit(proto["bl"], rel2) == 0)
+            with net._scope("blacklist", ATTACK_SCOPES):
+                accept = accept & ~jnp.concatenate(
+                    [
+                        self._listed(proto["bl"], b, rel2[:, b.lo - 1 : b.hi, :])
+                        for b in self.buckets
+                    ],
+                    axis=1,
+                )
 
         # rank + verified-sender demotion (receptionRanks += nodeCount)
         ind_bit = self._getbit(proto["ind"], rel2)
@@ -679,7 +702,8 @@ class BatchedHandel(BitsetAggBase):
             cur = popcount_words(inc_b)
             keep = valid & (s > cur[:, :, None])
             if self.track_bad:
-                keep = keep & (self._getbit(bl, all_rel) == 0)
+                with net._scope("blacklist", ATTACK_SCOPES):
+                    keep = keep & ~self._listed(bl, b, all_rel)
 
             # sort key: higher sizeIfIncluded first, then lower rank;
             # bounded (s <= bs <= N/2, rank < 3N) so s*4N + rank fits int32
@@ -773,10 +797,19 @@ class BatchedHandel(BitsetAggBase):
 
         offset = hash32(state.seed, ids[:, None], lv_all[None, :]) & (bs_all[None, :] - 1)
         pos = proto["pos"][:, 1:]
-        rel = (bs_all[None, :] + ((pos + offset) & (bs_all[None, :] - 1))).astype(
-            jnp.int32
-        )
-        new_pos = proto["pos"].at[:, 1:].set(jnp.where(mask, pos + 1, pos))
+        peer = (pos + offset) & (bs_all[None, :] - 1)  # block-local, [N, L-1]
+        step = 1
+        if self.track_bad:
+            # getRemainingPeers (:484-503) moves on to the next peer that
+            # is not blacklisted and closes a level that has none left;
+            # bl only grows, so "none left" needs no flag of its own
+            with net._scope("emission", ATTACK_SCOPES):
+                nxt, any_left = self._next_unlisted(proto["bl"], peer)
+                mask = mask & any_left
+                step = 1 + ((nxt - peer) & (bs_all[None, :] - 1))
+                peer = nxt
+        rel = (bs_all[None, :] + peer).astype(jnp.int32)
+        new_pos = proto["pos"].at[:, 1:].set(jnp.where(mask, pos + step, pos))
         state = state._replace(
             proto=dict(proto, added_cycle=new_added, pos=new_pos)
         )
@@ -800,6 +833,39 @@ class BatchedHandel(BitsetAggBase):
             content,
         )
         return state
+
+    def _listed(self, bl, b, rel):
+        """Has the node blacklisted the level-l peer `rel`, for the levels
+        of bucket b: bit rel & (bs - 1) of the level's block of the
+        rel-space blacklist.  [N, W], [N, nl, k] -> bool[N, nl, k]; a block
+        view and a one-hot mask, no gather: `_getbit`'s gather of one word
+        a candidate from the loop-carried plane cost 27.8 ms of a 111-ms
+        tick at 4096 nodes and ran 13% faster or slower with the buffer
+        the plane happened to be in (PERF.md section 6, PR 31)."""
+        bs = jnp.asarray([self.bs[l] for l in b.levels], jnp.int32)
+        bit = self._onehot(rel & (bs[None, :, None] - 1), b.w_pad)
+        return jnp.any((self._blocks(bl, b)[:, :, None, :] & bit) != 0, axis=-1)
+
+    def _next_unlisted(self, bl, peer):
+        """For every (node, level): the first block-local peer index at or
+        cyclically after `peer` whose bit of the rel-space blacklist is
+        clear, and whether the level has any such peer.  [N, W], [N, L-1]
+        -> int32[N, L-1], bool[N, L-1]; block views and two lowest-bit
+        scans a bucket, no gather."""
+        nxt_p, any_p = [], []
+        for b in self.buckets:
+            bs = jnp.asarray([self.bs[l] for l in b.levels], jnp.int32)
+            free = ~self._blocks(bl, b) & self._dyn_full_block(bs, b.w_pad)[None]
+            below = self._dyn_full_block(peer[:, b.lo - 1 : b.hi], b.w_pad)
+            at_or_after, before = free & ~below, free & below
+            ahead = popcount_words(at_or_after) > 0
+            nxt_p.append(
+                jnp.where(
+                    ahead, self._lowest_bit(at_or_after), self._lowest_bit(before)
+                )
+            )
+            any_p.append(ahead | (popcount_words(before) > 0))
+        return self._level_stats(nxt_p), self._level_stats(any_p)
 
     # -- tick phase 4: start new verifications (checkSigs) -------------------
     def _select(self, net, state, view=None):
@@ -869,7 +935,8 @@ class BatchedHandel(BitsetAggBase):
                 ccard_pieces.append(popcount_words(cur_sig))
             curated = valid & (s > popcount_words(inc_b)[:, :, None])
             if self.track_bad:
-                curated = curated & (self._getbit(bl, c_rel) == 0)
+                with net._scope("blacklist", ATTACK_SCOPES):
+                    curated = curated & ~self._listed(bl, b, c_rel)
             # permanent removal, like replaceToVerifyAgg (:612-618) —
             # recorded as a condemn mask, applied by ENTRY IDENTITY below
             condemn_pieces.append(valid & ~curated)
@@ -930,21 +997,22 @@ class BatchedHandel(BitsetAggBase):
                 # sig from an eligible Byzantine peer short-circuits the
                 # level's choice.  Eligible = down+byz, not blacklisted,
                 # rank inside windowIndex + currWindowSize, queue non-empty.
-                eligible = self._blocks(byz, b) & ~self._blocks(bl, b)
-                any_valid = jnp.any(valid, axis=2)
-                has_byz = popcount_words(eligible) > 0
-                # lowest block-local index (stand-in for cursor order)
-                m_byz = self._lowest_bit(eligible)
-                rel_byz = bs[None, :] + (m_byz & (bs[None, :] - 1))
-                rank_byz = self._rank(
-                    state.seed, ids[:, None], lv[None, :], rel_byz
-                )
-                inject = has_byz & any_valid & (rank_byz < win_hi)
-                lhas = lhas | inject
-                lbad = jnp.where(inject, True, lbad)
-                lrel = jnp.where(inject, rel_byz, lrel)
-                lrank = jnp.where(inject, rank_byz, lrank)
-                kidx = jnp.where(inject, -1, kidx)
+                with net._scope("inject", ATTACK_SCOPES):
+                    eligible = self._blocks(byz, b) & ~self._blocks(bl, b)
+                    any_valid = jnp.any(valid, axis=2)
+                    has_byz = popcount_words(eligible) > 0
+                    # lowest block-local index (stand-in for cursor order)
+                    m_byz = self._lowest_bit(eligible)
+                    rel_byz = bs[None, :] + (m_byz & (bs[None, :] - 1))
+                    rank_byz = self._rank(
+                        state.seed, ids[:, None], lv[None, :], rel_byz
+                    )
+                    inject = has_byz & any_valid & (rank_byz < win_hi)
+                    lhas = lhas | inject
+                    lbad = jnp.where(inject, True, lbad)
+                    lrel = jnp.where(inject, rel_byz, lrel)
+                    lrank = jnp.where(inject, rank_byz, lrank)
+                    kidx = jnp.where(inject, -1, kidx)
 
             has_p.append(lhas)
             b_rank_p.append(lrank)

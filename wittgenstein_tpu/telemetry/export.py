@@ -104,6 +104,11 @@ def counters(net, state) -> dict:
             "pending": pending_count(state),
         },
     }
+    if isinstance(state.proto, dict) and "sent_not_ok" in state.proto:
+        # the aggregation protocols' count of sends that ticked msg_sent
+        # and never msg_received (a network built with a node down,
+        # protocols/_agg_batched.py): sent == received + this, live nodes
+        out["node"]["sent_not_ok"] = ssum(state.proto["sent_not_ok"])
     if net.telemetry is not None:
         tele = state.tele
         out["store"].update(
@@ -261,6 +266,9 @@ def prometheus_from_counters(c: dict, prefix: str = "witt") -> str:
           "node bytesSent sum", "counter")
     p.add("node_bytes_received_total", n["bytes_received"],
           "node bytesReceived sum", "counter")
+    if "sent_not_ok" in n:
+        p.add("node_sent_not_ok_total", n["sent_not_ok"],
+              "sends whose receiver was down or past the discard time", "counter")
     p.add("done_nodes", n["done_nodes"], "nodes with done_at > 0")
     p.add("down_nodes", n["down_nodes"], "dead nodes")
     s = c["store"]
