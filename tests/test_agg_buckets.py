@@ -270,3 +270,140 @@ def test_send_stacked_stores_receiver_space_content():
             if (content_int >> bit) & 1:
                 want |= 1 << (bit ^ r0)
         assert fresh == want, (l, r0)
+
+
+# -- the send path's two entries (PR 32) ----------------------------------
+# Level as an axis ([N, L-1, k] rows, each bucket's [N, nl, w_pad] blocks)
+# against level as data (the same rows flattened, every bucket's content
+# padded to all M rows, as the call sites built it until PR 32): the same
+# state, leaf for leaf, from the same sends.
+
+
+def _flat_send_args(a, mask, frm, to, level, blocks, aux):
+    """The level-axis arguments as the dynamic entry takes them: [M]
+    vectors in axis order and, per bucket, a zero plane over all L-1
+    levels with the bucket's own blocks written in."""
+    n, nlv, k = mask.shape
+    flat = lambda x: jnp.broadcast_to(x, mask.shape).reshape(-1)
+    content = []
+    for b, lows in zip(a.buckets, blocks):
+        full = jnp.zeros((n, nlv, b.w_pad), jnp.uint32)
+        full = full.at[:, b.lo - 1 : b.hi, :].set(lows)
+        content.append(
+            jnp.broadcast_to(full[:, :, None, :], (n, nlv, k, b.w_pad)).reshape(-1, b.w_pad)
+        )
+    return (
+        flat(mask), flat(frm), flat(to), flat(level), content,
+        None if aux is None else flat(aux),
+    )
+
+
+def _random_send(a, rng, k, density, crowd=False):
+    """A send on the level axis: every (node, level, c) row offers to a
+    random level-l peer, or (crowd) a whole half-block to ONE receiver, so
+    that slots are contested, winners displace and the fresh slot has
+    many takers."""
+    n, nlv = a.n_nodes, a.n_levels - 1
+    ids = np.arange(n, dtype=np.int32)
+    bs = a.lv_bs[None, :, None]
+    if crowd:
+        off = np.broadcast_to(ids[:, None, None] & (bs - 1), (n, nlv, k))
+    else:
+        off = rng.integers(0, 1 << 30, size=(n, nlv, k)) & (bs - 1)
+    rel = (bs + off).astype(np.int32)
+    mask = rng.random((n, nlv, k)) < density
+    havings = rng.integers(0, 2**32, size=(n, a.n_words), dtype=np.uint32)
+    return (
+        jnp.asarray(mask),
+        jnp.asarray(ids[:, None, None]),
+        jnp.asarray(ids[:, None, None] ^ rel),
+        jnp.asarray(np.arange(1, a.n_levels, dtype=np.int32)[None, :, None]),
+        [a._lows(jnp.asarray(havings), b) for b in a.buckets],
+    )
+
+
+def _handel(n, **kw):
+    from wittgenstein_tpu.protocols.handel import HandelParameters
+    from wittgenstein_tpu.protocols.handel_batched import make_handel
+
+    return make_handel(HandelParameters(node_count=n, threshold=n // 2, **kw))
+
+
+def _gsf(n, **kw):
+    from wittgenstein_tpu.protocols.gsf import GSFSignatureParameters
+    from wittgenstein_tpu.protocols.gsf_batched import make_gsf
+
+    return make_gsf(GSFSignatureParameters(node_count=n, threshold=n // 2, **kw))
+
+
+SEND_CASES = {
+    # name: (factory, k, mask density, crowd, sends in a row)
+    "handel-64-k1": (lambda: _handel(64, nodes_down=0), 1, 0.6, False, 1),
+    "handel-256-k1": (lambda: _handel(256, nodes_down=0), 1, 0.6, False, 1),
+    "gsf-64-k1-aux": (lambda: _gsf(64, nodes_down=0), 1, 0.6, False, 1),
+    "gsf-256-k1-aux": (lambda: _gsf(256, nodes_down=0), 1, 0.6, False, 1),
+    "gsf-64-k10-aux": (lambda: _gsf(64, nodes_down=0), 10, 0.5, False, 1),
+    "gsf-256-k10-aux": (lambda: _gsf(256, nodes_down=0), 10, 0.5, False, 1),
+    "handel-64-mask-all-false": (lambda: _handel(64, nodes_down=0), 1, 0.0, False, 1),
+    "handel-256-crowded-cells": (lambda: _handel(256, nodes_down=0), 1, 0.9, True, 3),
+    "gsf-64-k10-crowded-cells": (lambda: _gsf(64, nodes_down=0), 10, 0.9, True, 3),
+    "handel-64-nodes-down": (lambda: _handel(64, nodes_down=16), 1, 0.7, False, 2),
+    "gsf-64-k10-nodes-down": (lambda: _gsf(64, nodes_down=16), 10, 0.7, False, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEND_CASES))
+def test_level_axis_send_equals_the_flattened_send(case):
+    import jax
+
+    factory, k, density, crowd, sends = SEND_CASES[case]
+    net, state = factory()
+    a = net.protocol
+    k = getattr(a.params, "accelerated_calls_count", k) if k > 1 else k
+    has_aux = "in_aux" in state.proto
+    rng = np.random.default_rng(len(case))
+    by_axis = by_data = state
+    for j in range(sends):
+        mask, frm, to, level, blocks = _random_send(a, rng, k, density, crowd)
+        aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1, 1)), jnp.int32) if has_aux else None
+        # later sends leave earlier: their arrivals evict pending occupants
+        at = jnp.int32(2 * (sends - 1 - j))
+        by_axis = a._send_stacked(
+            net, by_axis._replace(time=at), mask, frm, to, None, blocks, aux=aux
+        )
+        m, f, t, l, content, x = _flat_send_args(a, mask, frm, to, level, blocks, aux)
+        by_data = a._send_stacked(net, by_data._replace(time=at), m, f, t, l, content, aux=x)
+
+    paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(by_axis)]
+    for name in ["in_key", "displaced"] + [f"in_sig{i}" for i in range(len(a.buckets))]:
+        assert any(name in p for p in paths), name
+    for path, x, y in zip(
+        paths, jax.tree_util.tree_leaves(by_axis), jax.tree_util.tree_leaves(by_data)
+    ):
+        assert (np.asarray(x) == np.asarray(y)).all(), (case, path)
+
+    moved = int(np.asarray(by_axis.msg_received).sum())
+    assert (moved > 0) == (density > 0)
+    if density > 0:
+        # content landed, and not only in the fresh slot's column
+        assert any(np.asarray(by_axis.proto[f"in_sig{i}"]).any() for i in range(len(a.buckets)))
+    if crowd:
+        assert int(by_axis.proto["displaced"]) > 0
+    if "nodes-down" in case:
+        assert int(np.asarray(by_axis.proto["sent_not_ok"]).sum()) > 0
+    if has_aux and density > 0:
+        assert np.asarray(by_axis.proto["in_aux"]).any()
+
+
+def test_level_axis_send_numbers_its_own_levels():
+    """On the level axis a row's level is its position: a level argument
+    beside it (another numbering would give arrivals and the claim one
+    level and the content rows another) is refused, as is an axis that is
+    not the protocol's L-1 levels."""
+    net, state = _handel(64, nodes_down=0)
+    a = net.protocol
+    mask, frm, to, level, blocks = _random_send(a, np.random.default_rng(0), 1, 0.5)
+    with pytest.raises(ValueError, match="numbers its own levels"):
+        a._send_stacked(net, state, mask, frm, to, level, blocks)
+    with pytest.raises(ValueError, match="numbers its own levels"):
+        a._send_stacked(net, state, mask[:, 1:], frm, to[:, 1:], None, blocks)

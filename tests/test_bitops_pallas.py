@@ -158,11 +158,16 @@ def test_backend_env_override():
 def test_dispatch_follows_env():
     """The public bitops functions dispatch per-call on bitops_backend();
     forcing pallas on CPU must still give lax-identical results."""
-    from wittgenstein_tpu.ops.bitops import popcount_words
+    from wittgenstein_tpu.ops.bitops import lowest_set_bit, popcount_words
 
     w = _rng_words((5, 7), seed=3)
     want = np.asarray(_popcount_words_lax(w))
-    with _EnvGuard("pallas"):
-        assert np.array_equal(np.asarray(popcount_words(w)), want)
-    with _EnvGuard("lax"):
-        assert np.array_equal(np.asarray(popcount_words(w)), want)
+    low = np.asarray(_lowest_set_bit_lax(w))
+    for backend, calls in (("pallas", 1), ("lax", 0)):
+        with _EnvGuard(backend):
+            assert np.array_equal(np.asarray(popcount_words(w)), want)
+            assert np.array_equal(np.asarray(lowest_set_bit(w)), low)
+            # a fresh function each time: make_jaxpr caches a trace
+            for f in (popcount_words, lowest_set_bit):
+                traced = str(jax.make_jaxpr(lambda x: f(x))(w))
+                assert traced.count("pallas_call") == calls

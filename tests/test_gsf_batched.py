@@ -109,3 +109,34 @@ class TestBatchedGSF:
         state = net.run_ms(state, 800)
         done = np.asarray(state.done_at)
         assert (done > 0).all(), (done == 0).sum()
+
+
+def test_gsf_popcounts_are_the_lax_form_under_both_backends(monkeypatch):
+    """On the chip the 2048-node program is right with the lax popcount
+    only (gsf_batched.py's import; PERF.md section 6, PR 32; ROADMAP B0),
+    and no test on a CPU would see a change that undid it: the interpreted
+    kernel is exact here.  The lowest-bit scan follows the backend, as
+    every popcount of Handel does."""
+    import jax
+
+    from wittgenstein_tpu.ops import bitops_pallas
+    from wittgenstein_tpu.ops.bitops import BITOPS_ENV
+    from wittgenstein_tpu.protocols.handel import HandelParameters
+    from wittgenstein_tpu.protocols.handel_batched import make_handel
+
+    gsf = make_gsf(make_params())
+    handel = make_handel(HandelParameters(node_count=64, threshold=60, nodes_down=0))
+    monkeypatch.setenv(BITOPS_ENV, "pallas")
+    kernel, calls = bitops_pallas.popcount_words_pallas, []
+    monkeypatch.setattr(
+        bitops_pallas, "popcount_words_pallas", lambda w: calls.append(1) or kernel(w)
+    )
+
+    def traced(net, state):
+        del calls[:]
+        tick = str(jax.make_jaxpr(lambda s: net.protocol.tick(net, s))(state))
+        return len(calls), tick.count("pallas_call")
+
+    popcounts, kernels = traced(*gsf)
+    assert popcounts == 0 and kernels > 0
+    assert traced(*handel)[0] > 0

@@ -275,8 +275,9 @@ def test_a_blacklisted_peer_gets_nothing_more_from_the_node_that_listed_it():
     seen = {}
 
     def capture(net_, st, mask, from_idx, to_idx, level, content, aux=None):
+        assert level is None  # a level-axis send: a row's level is its place on axis 1
         seen.update(mask=np.asarray(mask), frm=np.asarray(from_idx), to=np.asarray(to_idx),
-                    level=np.asarray(level))
+                    level=np.arange(1, mask.shape[1] + 1)[None, :, None])
         return st
 
     proto._send_stacked = capture

@@ -284,13 +284,13 @@ def gathers_under(text: str, op_scopes: dict, scope: str, dtype: str = "") -> li
 def test_the_readdress_scope_gathers_no_words(traced):
     """PR 28's structural pin: `xor_shuffle` re-addresses the packed
     uint32 planes by select stages; its word gather was 26-44% of a tick
-    on the chip (PERF.md section 5) and cannot come back unnoticed.  What
-    the scope still gathers is `_send_stacked`'s own `lv_bs[level - 1]`,
-    M int32 block sizes from a table of L."""
+    on the chip (PERF.md section 5) and cannot come back unnoticed.  Since
+    PR 32 the scope gathers nothing at all: a level's block size is
+    arithmetic on the level (2^(l-1)), no longer `lv_bs[level - 1]`."""
     readdress = CHANNEL_SCOPES["readdress"]
     rows = [n for n, r in traced["op_scopes"].items() if r["scope"].endswith(readdress)]
     assert rows  # the scope is live: there is something to look at
-    assert gathers_under(traced["text"], traced["op_scopes"], readdress, "u32") == []
+    assert gathers_under(traced["text"], traced["op_scopes"], readdress) == []
 
 
 def test_gathers_under_finds_a_gather_and_a_fusion_that_calls_one():

@@ -37,7 +37,13 @@ def bitops_backend() -> str:
     anywhere else — correct but slow, so CPU/GPU default to lax).  Read
     at trace time, so it is a static program property; the engine folds
     it into `cache_key()` so a flipped env var cannot hit a stale jit
-    cache."""
+    cache.
+
+    One caller does not follow it: `protocols/gsf_batched.py` takes
+    `_popcount_words_lax` under both backends, because on the chip that is
+    the only popcount its 2048-node program is right with, as the kernel
+    is the only one Handel's 4096-node program is right with (PERF.md
+    section 6, PR 32; cause not found: ROADMAP B0)."""
     env = os.environ.get(BITOPS_ENV, "").strip().lower()
     if env in ("lax", "pallas"):
         return env

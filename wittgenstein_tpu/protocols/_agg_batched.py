@@ -20,18 +20,42 @@ of the tick to a minority share.  Displacements (an ok send that wins
 neither slot, or evicts a still-pending occupant) are counted in
 proto["displaced"] — the channel analog of SimState.dropped.
 
-Program-size design (the r4 rewrite): levels are grouped into WIDTH
-BUCKETS — consecutive levels whose word width w_l = max(1, 2^(l-1)/32)
-falls in the same class {1}, {2,4}, {8,16}, {32,64}, ... — and every
-per-level computation runs once per BUCKET on a stacked [N, nl, ...]
-level axis (w padded to the bucket max) instead of once per level.
+Program-size design (the r4 rewrite, regrouped by the PR-11 density
+pass): levels are grouped into WIDTH BUCKETS — consecutive levels of
+EQUAL word width w_l = max(1, 2^(l-1)/32): the sub-word levels [1-6]
+(w = 1) share one bucket and every wider level has its own, so w_pad is
+always the exact width and no padding words are carried (see
+_init_geometry) — and every per-level computation runs once per BUCKET
+on a stacked [N, nl, ...] level axis instead of once per level.
 Per-bucket channel/candidate content lives in flat 2D arrays
 [N, nl*slots*w_pad] (large minor dims dodge XLA's (8,128) tile padding),
 and block views of the full-width state vectors are pure
 reshape/concat/shift pipelines — no gathers or scatters.  At 4096 nodes
 this turns ~12 unrolled per-level bodies x 4 phases (plus ~24 per-level
-send calls at ~700 StableHLO lines each) into ~4 bucket bodies and 2
+send calls at ~700 StableHLO lines each) into 7 bucket bodies and 2
 stacked sends, which is what lets the flagship config compile.
+
+The send path (_send_stacked) has two entries and one algorithm, claim
+by key then commit by bucket; which rows a bucket's re-addressing and
+its two commit scatters run over is read from the shape of the input:
+
+  * level as DATA — mask/from/to/level [M], content[i] [M, w_pad]
+    (Handel's fast path, whose level is a per-node register): a row may
+    belong to any bucket, so every bucket carries all M rows and routes
+    the rows of other buckets' levels to the dropped row;
+  * level as an AXIS — mask/from/to [N, L-1, k] and no level, content[i]
+    the [N, nl, w_pad] block stack as _lows gives it (the dissemination
+    beats, k = 1; GSF's accelerated calls, k = accelerated_calls_count):
+    a row's position on axis 1 IS its level, so bucket i's rows are cut
+    from that axis by reshape and static slice and only they are
+    re-addressed and scattered — M_i = N x nl x k rows at w_pad words,
+    about a tenth of the word updates (tests/test_channel_rows.py).
+
+Arrivals and the claim are scalar per row and run over the flat M rows
+in the same row order in both; the state after a send is bit-identical
+(the level-axis entry only loses updates addressed to the dropped row).
+Under a node mesh a row's level is data again after the all_to_all, so
+the level-axis entry pads its blocks back to [M, w_pad] there.
 
 Keys pack (absolute_arrival << rel_bits) | rel — no per-tick countdown
 (see _advance_channel) — which bounds a sim at 2^(31-rel_bits) ms
@@ -360,16 +384,45 @@ class BitsetAggBase(BatchedProtocol):
         per-(receiver, level, slot) channel in ONE body: earliest arrival
         wins an arrival slot, the newest offer always takes the fresh slot.
 
-        mask/from_idx/to_idx/level: [M] (level in [1, L-1]); content: list
-        aligned with self.buckets of [M, w_pad] SENDER-space words (only
-        rows whose level lies in the bucket need valid values) — they are
-        re-addressed into the receiver's block-local space here, at send
-        time; aux: optional [M] int32 stored per slot in proto["in_aux"].
+        Level as data: mask/from_idx/to_idx/level [M] (level in [1, L-1]);
+        content: list aligned with self.buckets of [M, w_pad] SENDER-space
+        words (only rows whose level lies in the bucket need valid values);
+        aux: optional [M] int32 stored per slot in proto["in_aux"].
+
+        Level as an axis: mask [N, L-1, k], from_idx/to_idx/aux anything
+        that broadcasts to it, level None: row [n, j, c] is a level-(j+1)
+        message, numbered here from the axis the bucket cut slices;
+        content[i] [N, nl, w_pad], the bucket's block stack as _lows
+        gives it, shared by a node's k rows of a level.  The flat row
+        order is the axis order, so arrivals and the claim are the ones
+        the flattened send would get.
+
+        Content is re-addressed into the receiver's block-local space
+        here, at send time.  See the module docstring for which rows each
+        bucket's re-addressing and commit run over.
         """
         proto = state.proto
         d = self.CHANNEL_DEPTH
         ss = d + 1
         scope = functools.partial(net._scope, scopes=CHANNEL_SCOPES)
+        mesh = getattr(net, "node_mesh", None)
+        axis = mask.shape if mask.ndim == 3 else None  # rows on a level axis
+        if axis is not None:
+            if level is not None or axis[1] != self.n_levels - 1:
+                raise ValueError(
+                    "a level-axis send is [N, L-1, k] and numbers its own "
+                    f"levels: got {axis} with level {level!r}"
+                )
+            level = jnp.arange(1, self.n_levels, dtype=jnp.int32)[None, :, None]
+            mask, from_idx, to_idx, level = (
+                jnp.broadcast_to(x, axis).reshape(-1)
+                for x in (mask, from_idx, to_idx, level)
+            )
+            if aux is not None:
+                aux = jnp.broadcast_to(aux, axis).reshape(-1)
+        # node-sharded, a row's level is data again after the exchange:
+        # every bucket carries all M rows there, whichever the entry
+        cut = axis is not None and mesh is None
         with scope("arrivals"):
             # masked rows may carry junk levels; clamp so every computed
             # index is in range (their scatters are dropped via the
@@ -422,18 +475,25 @@ class BitsetAggBase(BatchedProtocol):
 
         with scope("readdress"):
             # re-address sender-space content into the receiver's
-            # block-local space (bit j -> j ^ r0) for ALL rows, shared by
-            # both commit paths; r0 < bs keeps the permutation inside the
-            # level block, and rows outside the bucket are zeroed so the
-            # (dropped) shuffle can't gather out of range
-            bs_row = jnp.asarray(self.lv_bs)[level - 1]  # [M] level block sizes
-            cnt_list = []
-            for i, b in enumerate(self.buckets):
-                in_b = (level >= b.lo) & (level <= b.hi)
-                r0 = jnp.where(in_b, rel & (bs_row - 1), 0)
-                cnt_list.append(xor_shuffle(content[i].astype(jnp.uint32), r0))
+            # block-local space (bit j -> j ^ r0) for each bucket's rows,
+            # shared by both commit passes; r0 < bs keeps the permutation
+            # inside the level block, and rows routed away from the bucket
+            # get r0 = 0 so the (dropped) shuffle stays in range
+            if axis is not None:
+                content = [
+                    self._level_rows(c, b, axis, whole=not cut)
+                    for c, b in zip(content, self.buckets)
+                ]
+            r0_row = rel & ((jnp.int32(1) << (level - 1)) - 1)  # bs_l = 2^(l-1)
+            rows = [
+                self._bucket_rows(b, level, axis if cut else None)
+                for b in self.buckets
+            ]
+            cnt_list = [
+                xor_shuffle(c.astype(jnp.uint32), own(r0_row, 0))
+                for own, c in zip(rows, content)
+            ]
 
-        mesh = getattr(net, "node_mesh", None)
         if mesh is not None:
             # node-axis sharding: the channel commit goes through an
             # explicit all_to_all exchange of update rows so the channel
@@ -479,21 +539,12 @@ class BitsetAggBase(BatchedProtocol):
             fwin_to = jnp.where(fresh_win, to_idx, self.n_nodes)
 
         with scope("commit"):
-            for i, b in enumerate(self.buckets):
-                in_b = (level >= b.lo) & (level <= b.hi)
-                li = level - b.lo  # level row inside the bucket
-                cw = jnp.arange(b.w_pad, dtype=jnp.int32)
-                cols = ((li * ss + slot) * b.w_pad)[:, None] + cw
-                fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
-                cnt = cnt_list[i]  # receiver-space content (hoisted above)
-                a = updates[f"in_sig{i}"]
-                a = a.at[jnp.where(in_b, win_to, self.n_nodes)[:, None], cols].set(
-                    cnt, mode="drop"
+            for i, (b, own) in enumerate(zip(self.buckets, rows)):
+                updates[f"in_sig{i}"] = self._commit_bucket(
+                    updates[f"in_sig{i}"], b,
+                    own(win_to, self.n_nodes), own(fwin_to, self.n_nodes),
+                    own(level) - b.lo, own(slot), cnt_list[i],
                 )
-                a = a.at[jnp.where(in_b, fwin_to, self.n_nodes)[:, None], fcols].set(
-                    cnt, mode="drop"
-                )
-                updates[f"in_sig{i}"] = a
             if aux is not None:
                 new_aux = proto["in_aux"].at[win_to, col].set(
                     aux.astype(jnp.int32), mode="drop"
@@ -503,6 +554,50 @@ class BitsetAggBase(BatchedProtocol):
                 )
                 updates["in_aux"] = new_aux
         return state._replace(proto=updates)
+
+    def _level_rows(self, blocks, b: Bucket, axis, whole: bool):
+        """Bucket b's content rows of a level-axis send: its [N, nl, w_pad]
+        block stack shared by the k rows a node sends at a level,
+        [N*nl*k, w_pad] in axis order; `whole` pads the other levels' rows
+        back in as zeros, [N*(L-1)*k, w_pad]."""
+        n, nlv, k = axis
+        c = jnp.broadcast_to(
+            blocks.astype(jnp.uint32)[:, :, None, :], (n, b.nl, k, b.w_pad)
+        )
+        if whole:
+            c = (
+                jnp.zeros((n, nlv, k, b.w_pad), jnp.uint32)
+                .at[:, b.lo - 1 : b.hi]
+                .set(c)
+            )
+        return c.reshape(-1, b.w_pad)
+
+    @staticmethod
+    def _bucket_rows(b: Bucket, level, axis):
+        """Which of a send's M rows bucket b's re-addressing and commit run
+        over, as own(x, fill): a per-row [M] vector -> the bucket's rows.
+        Level as data (axis None): all M rows, those of other buckets'
+        levels replaced by `fill` where one is given (the dropped row, a
+        zero shift).  Level as an axis [N, L-1, k]: the rows of levels
+        b.lo..b.hi alone, cut by reshape and static slice (an index array
+        would lower to a gather); nothing is left to route."""
+        if axis is None:
+            in_b = (level >= b.lo) & (level <= b.hi)
+            return lambda x, fill=None: x if fill is None else jnp.where(in_b, x, fill)
+        return lambda x, fill=None: x.reshape(axis)[:, b.lo - 1 : b.hi, :].reshape(-1)
+
+    def _commit_bucket(self, sig, b: Bucket, win_to, fwin_to, li, slot, cnt):
+        """A bucket's two content scatters, the one body of every commit:
+        rows [m] write their w_pad receiver-space words `cnt` into the
+        plane sig [rows, nl*ss*w_pad] at (win_to, level row li, slot) and
+        at (fwin_to, li, the fresh slot); a row index past the plane (a
+        loser, another bucket's row) is dropped."""
+        ss = self.CHANNEL_DEPTH + 1
+        cw = jnp.arange(b.w_pad, dtype=jnp.int32)
+        cols = ((li * ss + slot) * b.w_pad)[:, None] + cw
+        fcols = ((li * ss + ss - 1) * b.w_pad)[:, None] + cw
+        sig = sig.at[win_to[:, None], cols].set(cnt, mode="drop")
+        return sig.at[fwin_to[:, None], fcols].set(cnt, mode="drop")
 
     # -- node-sharded channel commit (explicit all_to_all exchange) ----------
     def _channel_commit_sharded(
@@ -650,17 +745,11 @@ class BitsetAggBase(BatchedProtocol):
             with scope("commit"):
                 for i, b in enumerate(self.buckets):
                     in_b = (lvl >= b.lo) & (lvl <= b.hi) & ok_r
-                    li = lvl - b.lo
-                    cw = jnp.arange(b.w_pad, dtype=jnp.int32)
-                    cols = ((li * ss + slot_r) * b.w_pad)[:, None] + cw
-                    fcols = ((li * ss + d) * b.w_pad)[:, None] + cw
-                    win_to = jnp.where(winner & in_b, to_r, n_loc)
-                    fwin_to = jnp.where(fresh_win & in_b, to_r, n_loc)
-                    sigs[i] = sigs[i].at[win_to[:, None], cols].set(
-                        cnt_x[i], mode="drop"
-                    )
-                    sigs[i] = sigs[i].at[fwin_to[:, None], fcols].set(
-                        cnt_x[i], mode="drop"
+                    sigs[i] = self._commit_bucket(
+                        sigs[i], b,
+                        jnp.where(winner & in_b, to_r, n_loc),
+                        jnp.where(fresh_win & in_b, to_r, n_loc),
+                        lvl - b.lo, slot_r, cnt_x[i],
                     )
                 outs = [new_key] + sigs
                 if have_aux:

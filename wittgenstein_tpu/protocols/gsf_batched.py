@@ -53,7 +53,19 @@ from ..core.node import Node, build_node_columns
 from ..core.registries import registry_network_latencies, registry_node_builders
 from ..engine import BatchedNetwork
 from ..engine.rng import hash32
-from ..ops.bitops import block_mask, popcount_words
+from ..ops.bitops import block_mask
+
+# GSF's popcounts are the lax form under both backends.  On the chip the
+# 2048-node program with `popcount_words_pallas` leaves the CPU's (and the
+# reference) from t = 190 ms on, `ind_seen` and `pend_ind` first, then half
+# the traffic by 350 ms; with this form it equals the CPU's leaf for leaf
+# through 520 ms.  The kernel alone is exact at every shape this program
+# passes it, and Handel's 4096-node program is the other way round: equal
+# to the CPU's through 430 ms with the kernel, off from t = 200 ms with
+# this form (PERF.md section 6, PR 32, my chip runs).  So it is not the
+# kernel but how each compiled program holds its popcounts: not found
+# (ROADMAP B0), and not a choice ops.bitops could make for both.
+from ..ops.bitops import _popcount_words_lax as popcount_words
 from ..utils.javarand import JavaRandom
 from ._agg_batched import INT32_MAX, BitsetAggBase
 from .gsf import GSFSignatureParameters
@@ -291,25 +303,17 @@ class BatchedGSF(BitsetAggBase):
                 & (bs_all[None, :, None] - 1)
             )  # [N, L-1, acc]
             mask_b = ks[None, None, :] < take[:, :, None]
-            content = []
-            for b in self.buckets:
-                lows = self._lows(havings, b)  # [N, nl, w_pad]
-                full = jnp.zeros((n, L - 1, b.w_pad), jnp.uint32)
-                full = full.at[:, b.lo - 1 : b.hi, :].set(lows)
-                content.append(
-                    jnp.broadcast_to(
-                        full[:, :, None, :], (n, L - 1, acc, b.w_pad)
-                    ).reshape(n * (L - 1) * acc, b.w_pad)
-                )
+            # the rows lie on the [N, L-1, acc] level axis: handed over as
+            # they are, with each bucket's blocks (see _send_stacked)
             state = self._send_stacked(
                 net,
                 state,
-                mask_b.reshape(-1),
-                jnp.repeat(ids, (L - 1) * acc),
-                (ids[:, None, None] ^ relb).reshape(-1),
-                jnp.broadcast_to(lv_all[None, :, None], (n, L - 1, acc)).reshape(-1),
-                content,
-                aux=jnp.repeat(k_new, (L - 1) * acc),
+                mask_b,
+                ids[:, None, None],
+                ids[:, None, None] ^ relb,
+                None,
+                [self._lows(havings, b) for b in self.buckets],
+                aux=k_new[:, None, None],
             )
 
         proto = state.proto
@@ -466,22 +470,16 @@ class BatchedGSF(BitsetAggBase):
             proto=dict(proto, pos=new_pos, remaining=new_remaining)
         )
 
-        content = []
-        for b in self.buckets:
-            lows = self._lows(havings, b)
-            full = jnp.zeros((n, L - 1, b.w_pad), jnp.uint32)
-            full = full.at[:, b.lo - 1 : b.hi, :].set(lows)
-            content.append(full.reshape(n * (L - 1), b.w_pad))
-
+        # one row per (node, level): the [N, L-1, 1] level axis
         state = self._send_stacked(
             net,
             state,
-            mask.reshape(-1),
-            jnp.repeat(ids, L - 1),
-            (ids[:, None] ^ rel).reshape(-1),
-            jnp.broadcast_to(lv_all[None, :], (n, L - 1)).reshape(-1),
-            content,
-            aux=jnp.repeat(k, L - 1),
+            mask[:, :, None],
+            ids[:, None, None],
+            (ids[:, None] ^ rel)[:, :, None],
+            None,
+            [self._lows(havings, b) for b in self.buckets],
+            aux=k[:, None, None],
         )
         return state
 

@@ -814,23 +814,16 @@ class BatchedHandel(BitsetAggBase):
             proto=dict(proto, added_cycle=new_added, pos=new_pos)
         )
 
-        # content: each level sends its outgoing prefix (zeros for levels
-        # outside a bucket — those rows are masked in the scatter)
-        content = []
-        for b in self.buckets:
-            lows = self._lows(inc, b)  # [N, nl, w_pad]
-            full = jnp.zeros((n, L - 1, b.w_pad), jnp.uint32)
-            full = full.at[:, b.lo - 1 : b.hi, :].set(lows)
-            content.append(full.reshape(n * (L - 1), b.w_pad))
-
+        # each level sends its outgoing prefix; one row per (node, level):
+        # the [N, L-1, 1] level axis (see _send_stacked)
         state = self._send_stacked(
             net,
             state,
-            mask.reshape(-1),
-            jnp.repeat(ids, L - 1),
-            (ids[:, None] ^ rel).reshape(-1),
-            jnp.broadcast_to(lv_all[None, :], (n, L - 1)).reshape(-1),
-            content,
+            mask[:, :, None],
+            ids[:, None, None],
+            (ids[:, None] ^ rel)[:, :, None],
+            None,
+            [self._lows(inc, b) for b in self.buckets],
         )
         return state
 
