@@ -185,16 +185,10 @@ def phase_kernels(args) -> None:
 
 def _build(node_ct, n_replicas, args):
     from wittgenstein_tpu.engine import replicate_state
-    from wittgenstein_tpu.profiling import flagship_params
     from wittgenstein_tpu.protocols.handel_batched import make_handel
+    from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
-    # score_cache=None is make_handel's own choice (on for a TPU); a
-    # rehearsal pins what the chip would choose
-    net, state = make_handel(
-        flagship_params(node_ct),
-        fuse_step=True,
-        score_cache=True if args.rehearse else None,
-    )
+    net, state = make_handel(flagship_params(node_ct), fuse_step=True)
     seeds = [args.seed + i for i in range(n_replicas)]
     return net, lambda: replicate_state(state, n_replicas, seeds=seeds)
 
@@ -246,9 +240,8 @@ def phase_flagship(args) -> None:
     r = 2 if args.rehearse else FLAGSHIP_REPLICAS
     with bitops_env("pallas" if args.rehearse else None):
         net, fresh_states = _build(n, r, args)
-        backend, score_cache = bitops_backend(), bool(net.protocol.SCORE_CACHE)
+        backend = bitops_backend()
         assert backend == "pallas", backend
-        assert score_cache
         out, first = _run(net, fresh_states())
         facts = _check_converged(out)
         # the compiled program again, on fresh copies of the same states
@@ -261,7 +254,6 @@ def phase_flagship(args) -> None:
         replicas=r,
         sim_ms=SIM_MS,
         bitops_backend=backend,
-        score_cache=score_cache,
         setup_compile_s=first["compile_s"],
         compile_was=(
             "persistent-cache hit"

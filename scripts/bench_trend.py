@@ -104,8 +104,8 @@ def _load_serve(root: str):
 
 def _load_mesh(root: str):
     """The 2D-mesh rung-ladder record (BENCH_MESH.json,
-    witt-bench-mesh/v1, written by scripts/tpu_campaign.py
-    --mesh-ladder): per-(P_replica, P_node) wall time, sims/s,
+    witt-bench-mesh/v1; its writer, the mesh ladder, is gone):
+    per-(P_replica, P_node) wall time, sims/s,
     bit-identity vs the unsharded singleton and the 1/P channel-
     ownership verdict.  Optional — absent until the ladder has run."""
     try:

@@ -50,24 +50,18 @@ def measure(node_ct: int) -> dict:
     import dataclasses
 
     from wittgenstein_tpu.engine.capacity import load_capacity, lookup
-    from wittgenstein_tpu.profiling import (
-        budget_from_parts,
-        flagship_params,
-        hbm_report,
-    )
+    from wittgenstein_tpu.profiling import budget_from_parts, hbm_report
     from wittgenstein_tpu.profiling.xla_cost import (
         compiled_cost_summary,
         format_bytes,
     )
     from wittgenstein_tpu.protocols.handel_batched import make_handel
+    from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
     from wittgenstein_tpu.telemetry import TelemetryConfig, counters
 
-    # The budget is the TPU feasibility statement, so it prices the TPU
-    # production config even though it always runs on CPU: fuse_step=True
-    # (bench_batched's config) and score_cache PINNED ON (the backend-auto
-    # default would drop the cache leaves on this CPU run and understate
-    # the TPU state the replicas/chip model must hold).  Tick counts are
-    # bit-identical across both levers, so ticks_per_sim is unaffected.
+    # The budget is the TPU feasibility statement, so it prices the
+    # program the benchmark's Handel cells build (fuse_step=True) even
+    # though it always runs on CPU.
     params = flagship_params(node_ct)
     # telemetry-sized capacity: the autotuned cand_slots for this node
     # count (scripts/density_autotune.py -> CAPACITY.json) — bit-identical
@@ -75,11 +69,11 @@ def measure(node_ct: int) -> dict:
     cap = lookup(load_capacity(ROOT), "handel", node_ct)
     if cap is not None and "cand_slots" in cap.sized:
         params = dataclasses.replace(params, cand_slots=cap.sized["cand_slots"])
-    net, state = make_handel(params, score_cache=True, fuse_step=True)
+    net, state = make_handel(params, fuse_step=True)
 
     # (2) the compiled bare program: compile cost + XLA cost/memory.
-    # stop_when_done=True is the bench path — the budget prices the
-    # program the ladder actually runs.
+    # stop_when_done=True: the budget prices a run that exits at
+    # quiescence.
     t0 = time.perf_counter()
     compiled = (
         jax.jit(lambda s: net.run_ms(s, SIM_MS, True)).lower(state).compile()

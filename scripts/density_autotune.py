@@ -99,10 +99,10 @@ def probe_handel_cand(node_ct: int, probe_ms: int):
     from jax import lax
 
     from wittgenstein_tpu.engine.capacity import CapacityEntry
-    from wittgenstein_tpu.profiling import flagship_params
     from wittgenstein_tpu.protocols.handel_batched import make_handel
+    from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
-    net, state = make_handel(flagship_params(node_ct), score_cache=True)
+    net, state = make_handel(flagship_params(node_ct))
     proto = net.protocol
     n, L, K = proto.n_nodes, proto.n_levels, proto.CAND_SLOTS
     # empty slots hold the dtype's own sentinel (engine.density maps

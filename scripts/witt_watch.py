@@ -7,8 +7,9 @@ the new ``GET /w/slo`` (burn-rate SLO states, active alerts, alert
 counters) and renders them side by side — the first place a paging
 alert becomes visible without grepping a flight-recorder dump.
 
-Campaign mode (``--campaign PATH``) tails a tpu_campaign.jsonl ledger
-(file or the directory holding it) and shows rung progress, the ETA of
+Campaign mode (``--campaign PATH``) tails a campaign ledger of rung
+events (file or the directory holding it; nothing in the repo writes
+one today, ROADMAP C1) and shows rung progress, the ETA of
 the in-flight rung projected from its own chunk times, and the
 tick-vs-budget margin (RUNG_BUDGET_S minus the pass cost so far) — the
 number that predicts a ``rung_aborted`` before it happens.
@@ -39,8 +40,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 CAMPAIGN_LEDGER = "tpu_campaign.jsonl"
-RUNG_BUDGET_S = 900.0  # tpu_campaign.RUNG_BUDGET_S (no jax import here)
-SILENCE_STALL_S = 900.0  # tpu_campaign.SILENCE_KILL_S
+RUNG_BUDGET_S = 900.0  # a rung's wall-clock budget in the ledger's writer
+SILENCE_STALL_S = 900.0  # silence after which that writer killed a rung
 
 
 # -- fleet mode --------------------------------------------------------------
@@ -173,8 +174,8 @@ def campaign_snapshot(path: str, budget_s: float = RUNG_BUDGET_S) -> dict:
 
     The in-flight rung is reconstructed from its own events: ``compiled``
     carries chunk_ms, per-chunk ``hb``/``chunk_over_safe`` heartbeats
-    carry chunk index + seconds, and 1000 sim-ms per rung (tpu_campaign
-    SIM_MS) fixes the chunk count.  ETA projects the median observed
+    carry chunk index + seconds, and 1000 sim-ms per rung fixes the
+    chunk count.  ETA projects the median observed
     chunk over the chunks remaining; margin is the budget minus the
     pass cost so far — negative margin means the next budget check
     aborts the pass."""
@@ -213,7 +214,7 @@ def campaign_snapshot(path: str, budget_s: float = RUNG_BUDGET_S) -> dict:
     )
     if compiled is not None or hbs:
         chunk_ms = (compiled or {}).get("chunk_ms") or 20
-        sim_ms = 1000  # tpu_campaign.SIM_MS — one program per rung
+        sim_ms = 1000  # one program per rung
         n_chunks = max(1, sim_ms // int(chunk_ms))
         chunk_s = sorted(
             float(e["chunk_s"]) for e in hbs if "chunk_s" in e

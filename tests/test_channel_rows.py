@@ -82,12 +82,15 @@ def _handel_node_mesh():
     return net, shard_state_by_node(net, state, mesh)
 
 
-# taken on the parent (commit 913a72b, PR 31), before the change
+# taken on the parent (commit 913a72b, PR 31), before the change; the
+# Handel ones again at commit 96b30ec (PR 32) with the carried score
+# caches, which every Handel state has held since PR 33 (they were
+# 4133656018 and 3555050404 over the trees without those four leaves)
 PINS = {
-    "handel_fused": (_handel_fused, 4133656018),
-    "handel_byz51": (_handel_byz, 3555050404),
+    "handel_fused": (_handel_fused, 3838452459),
+    "handel_byz51": (_handel_byz, 882315166),
     "gsf": (_gsf, 999241418),
-    "handel_node_mesh2": (_handel_node_mesh, 4133656018),
+    "handel_node_mesh2": (_handel_node_mesh, 3838452459),
 }
 
 

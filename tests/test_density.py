@@ -90,13 +90,13 @@ def test_narrow_bit_identity_fused_flat():
         fast_path=5,
         nodes_down=0,
     )
-    net_n, s_n = make_handel(p, score_cache=True, fuse_step=True)
+    net_n, s_n = make_handel(p, fuse_step=True)
     out_n = net_n.run_ms(s_n, 200)
 
     mp = pytest.MonkeyPatch()
     try:
         _int32_baseline(mp, BatchedHandel)
-        net_w, s_w = make_handel(p, score_cache=True, fuse_step=True)
+        net_w, s_w = make_handel(p, fuse_step=True)
         out_w = net_w.run_ms(s_w, 200)
     finally:
         mp.undo()
@@ -130,16 +130,14 @@ def test_cand_slots_reduction_bit_identity():
     """The autotuner's K lever: with cand_slots above the measured
     occupancy HWM, the reduced top-K buffer retains the same entries
     every tick (it is re-sorted), so observables are bit-identical."""
-    from wittgenstein_tpu.profiling import flagship_params
     from wittgenstein_tpu.protocols.handel_batched import make_handel
+    from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
     import dataclasses
 
     p = flagship_params(256)
-    net8, s8 = make_handel(p, score_cache=True)
-    net5, s5 = make_handel(
-        dataclasses.replace(p, cand_slots=5), score_cache=True
-    )
+    net8, s8 = make_handel(p)
+    net5, s5 = make_handel(dataclasses.replace(p, cand_slots=5))
     assert net5.protocol.CAND_SLOTS == 5
     out8 = net8.run_ms(s8, 400, True)
     out5 = net5.run_ms(s5, 400, True)

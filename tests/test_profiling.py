@@ -459,42 +459,3 @@ def test_server_metrics_includes_cost_families():
     assert "witt_run_cache_size" in text
     assert "witt_run_cache_hits_total" in text
     assert "witt_run_cache_compile_seconds_total" in text
-
-
-# ---------------------------------------------------------------------------
-# phase timing statistics (warmup discard, mean/std)
-# ---------------------------------------------------------------------------
-
-def test_scan_phase_seconds_stats_shape():
-    from wittgenstein_tpu.engine import replicate_state
-    from wittgenstein_tpu.protocols.pingpong_batched import make_pingpong
-    from wittgenstein_tpu.telemetry.phases import (
-        engine_phase_fns,
-        phase_means,
-        scan_phase_seconds,
-    )
-    from wittgenstein_tpu.telemetry.trace import SpanTracer, validate_chrome_trace
-
-    net, state = make_pingpong(16)
-    states = replicate_state(state, 2)
-    fns = engine_phase_fns(net)
-    tracer = SpanTracer()
-    stats = scan_phase_seconds(
-        states, {"full step": fns["full_step"]}, scans=2, tracer=tracer,
-        repeats=3,
-    )
-    s = stats["full step"]
-    assert s["repeats"] == 3 and s["scans"] == 2
-    assert len(s["samples_s"]) == 3
-    assert s["mean_s"] == pytest.approx(
-        sum(s["samples_s"]) / 3, rel=1e-6
-    )
-    assert s["min_s"] <= s["mean_s"]
-    assert s["std_s"] >= 0
-    assert phase_means(stats) == {"full step": s["mean_s"]}
-    # tracer saw compile, the discarded warmup, and 3 measured passes
-    names = [e.get("name") for e in tracer.events]
-    assert names.count("measure") == 3
-    assert names.count("warmup-discarded") == 1
-    assert names.count("compile") == 1
-    validate_chrome_trace(tracer.to_json())

@@ -1,17 +1,21 @@
-"""Candidate-score caching bit-identity (PR-8 lever 1).
+"""The carried candidate-score caches against their from-scratch oracle.
 
 Handel carries four cached candidate-slot quantities in `state.proto`
 (`cand_s`/`cand_card`/`cand_wind`/`cand_aggi`) so the per-tick `_select`
 reads int32 scores instead of re-popcounting signature words; P2PHandel
-carries `ver_card`.  Caching is a COST lever only: with it off
-(`score_cache=False`) every non-cache leaf of the trajectory must be
-bitwise unchanged, and with it on, the carried leaves must always equal
-`recompute_caches()`'s from-scratch oracle (the SL701 invariant).
+carries `ver_card`.  The caches are the programs' only form (no switch
+builds an uncached one), so what is held here is the SL701 invariant:
+the carried leaves always equal `recompute_caches()`'s from-scratch
+oracle, and the factories hide no other choice of program.
 """
+
+import glob
+import inspect
+import json
+import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from wittgenstein_tpu.protocols.handel import HandelParameters
@@ -22,7 +26,8 @@ from wittgenstein_tpu.protocols.handel_batched import (
 from wittgenstein_tpu.protocols.p2phandel import P2PHandelParameters
 from wittgenstein_tpu.protocols.p2phandel_batched import make_p2phandel
 
-CACHE_LEAVES = set(BatchedHandel.CACHE_LEAF_NAMES)
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIGS = sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs", "*.json")))
 
 
 def _two_replicas(state):
@@ -30,28 +35,10 @@ def _two_replicas(state):
     return states._replace(seed=states.seed.at[1].set(99))
 
 
-def _assert_equal_excluding_cache(on, off, cache_leaves, tag):
-    for f in on._fields:
-        a, b = getattr(on, f), getattr(off, f)
-        if f == "proto":
-            for k in b:  # the cached run has extra (cache) leaves
-                assert k not in cache_leaves or k in b
-                assert bool(jnp.array_equal(a[k], b[k])), (
-                    f"{tag}: proto[{k}] diverges with caching on"
-                )
-        else:
-            eq = jax.tree_util.tree_map(
-                lambda x, y: bool(jnp.array_equal(x, y)), a, b
-            )
-            assert all(jax.tree_util.tree_leaves(eq)), (
-                f"{tag}: field {f} diverges with caching on"
-            )
-
-
 def _assert_cache_consistent(net, out, tag):
     # out is replica-batched; recompute_caches is a per-replica kernel
     fresh = jax.vmap(net.protocol.recompute_caches)(out)
-    assert fresh, f"{tag}: recompute_caches returned nothing"
+    assert set(fresh) == set(net.protocol.CACHE_LEAF_NAMES), tag
     for k, v in fresh.items():
         assert bool(jnp.array_equal(out.proto[k], v)), (
             f"{tag}: carried cache '{k}' differs from from-scratch"
@@ -59,40 +46,64 @@ def _assert_cache_consistent(net, out, tag):
         )
 
 
-@pytest.mark.parametrize(
-    "boundary_view,wheel_rows",
-    [(True, 0), (True, 64), (False, 0)],
-    ids=["bv-flat", "bv-wheel64", "nobv-flat"],
-)
-def test_handel_cache_bit_identity(boundary_view, wheel_rows):
-    params = HandelParameters(node_count=64)
+def _handel(wheel_rows, attack):
+    # the attack at the benchmark's rehearsal size: 16 of 64 down and
+    # forging (handel-4096-byz20.json `rehearsal.params`), so `bl` grows
+    # and the curation's blacklist reads run beside the cache reads
+    kw = (
+        dict(nodes_down=16, threshold=47, pairing_time=4,
+             dissemination_period_ms=20, byzantine_suicide=True)
+        if attack
+        else {}
+    )
+    return make_handel(
+        HandelParameters(node_count=64, **kw), seed=3, wheel_rows=wheel_rows
+    )
 
-    def run(score_cache):
-        net, state = make_handel(
-            params,
-            seed=3,
-            wheel_rows=wheel_rows,
-            boundary_view=boundary_view,
-            score_cache=score_cache,
-        )
-        return net, net.run_ms_batched(_two_replicas(state), 150)
 
-    net_on, on = run(True)
-    _net_off, off = run(False)
-    tag = f"handel bv={boundary_view} wheel={wheel_rows}"
-    assert CACHE_LEAVES <= set(on.proto), tag
-    assert not (CACHE_LEAVES & set(off.proto)), tag
-    _assert_equal_excluding_cache(on, off, CACHE_LEAVES, tag)
-    _assert_cache_consistent(net_on, on, tag)
+def _p2phandel(das):
+    return make_p2phandel(
+        P2PHandelParameters(double_aggregate_strategy=das), seed=3
+    )
+
+
+CASES = {
+    "handel-flat-honest": lambda: _handel(0, False),
+    "handel-wheel64-honest": lambda: _handel(64, False),
+    "handel-flat-byz16": lambda: _handel(0, True),
+    "handel-wheel64-byz16": lambda: _handel(64, True),
+    "p2phandel-checksigs2": lambda: _p2phandel(True),
+    "p2phandel-checksigs1": lambda: _p2phandel(False),
+}
+
+
+@pytest.mark.parametrize("tag", sorted(CASES))
+def test_carried_caches_equal_recompute_at_every_stop(tag):
+    """Every carried cache leaf equals the from-scratch oracle at 50-ms
+    stops of a 400-ms run: delivery merges, commits (levels complete in
+    that span) and, under attack, blacklisting all happen between stops."""
+    net, state = CASES[tag]()
+    assert set(net.protocol.CACHE_LEAF_NAMES) <= set(state.proto), tag
+    states = _two_replicas(state)
+    _assert_cache_consistent(net, states, f"{tag} t=0")
+    at_start = {k: states.proto[k] for k in net.protocol.CACHE_LEAF_NAMES}
+    for stop in range(50, 401, 50):
+        states = net.run_ms_batched(states, 50, stop_when_done=False)
+        _assert_cache_consistent(net, states, f"{tag} t={stop}")
+    # the update paths ran: every cache moved, verifications committed
+    for k, v in at_start.items():
+        assert not bool(jnp.array_equal(states.proto[k], v)), (tag, k)
+    if tag.startswith("handel-"):
+        assert int(jnp.sum(states.done_at > 0)) > 0, f"{tag}: nobody finished"
+    if "byz" in tag:
+        assert int(jnp.sum(states.proto["bl"] != 0)) > 0, f"{tag}: no blacklisting"
 
 
 def test_handel_cache_survives_commits():
     """A long-enough run that levels actually complete: the _commit
     cache fix-up (recompute only the committed level) is the subtle
     invalidation path, so exercise it for real."""
-    net, state = make_handel(
-        HandelParameters(node_count=32), seed=5, score_cache=True
-    )
+    net, state = make_handel(HandelParameters(node_count=32), seed=5)
     states = _two_replicas(state)
     out = net.run_ms_batched(states, 400)
     assert int(jnp.sum(out.done_at > 0)) > 0, (
@@ -101,45 +112,58 @@ def test_handel_cache_survives_commits():
     _assert_cache_consistent(net, out, "handel 32-node 400ms")
 
 
-@pytest.mark.parametrize("das", [True, False], ids=["checksigs2", "checksigs1"])
-def test_p2phandel_ver_card_bit_identity(das):
-    p = P2PHandelParameters(double_aggregate_strategy=das)
+def _resolve(dotted):
+    import importlib
 
-    def run(score_cache):
-        net, state = make_p2phandel(p, seed=3, score_cache=score_cache)
-        return net, net.run_ms_batched(_two_replicas(state), 150)
-
-    net_on, on = run(True)
-    _net_off, off = run(False)
-    tag = f"p2phandel das={das}"
-    assert "ver_card" in on.proto and "ver_card" not in off.proto, tag
-    _assert_equal_excluding_cache(on, off, {"ver_card"}, tag)
-    _assert_cache_consistent(net_on, on, tag)
+    module, name = dotted.rsplit(".", 1)
+    return getattr(importlib.import_module(module), name)
 
 
-def test_cache_off_removes_declared_leaves():
-    """score_cache=False must also clear DERIVED_CACHE_LEAVES so simlint
-    SL701 skips the config instead of failing on missing leaves."""
-    net, _ = make_handel(HandelParameters(node_count=32), score_cache=False)
-    assert net.protocol.DERIVED_CACHE_LEAVES == ()
-    net, _ = make_p2phandel(P2PHandelParameters(), score_cache=False)
-    assert net.protocol.DERIVED_CACHE_LEAVES == ()
+@pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
+def test_benchmark_factory_call_is_the_whole_choice_of_program(path):
+    """The factory with the configuration's own `factory_kwargs` and no
+    other keyword, on the CPU, builds the program the configuration
+    expects on the chip (`expect.protocol_attrs`), and a second plain
+    call builds the same one: same state tree, same cache key.  Built at
+    the benchmark's rehearsal size; what is left to the environment is
+    `bitops_backend()` alone."""
+    with open(path) as f:
+        config = json.load(f)
+    small = config.get("rehearsal", {}).get("params", {"node_count": 64})
+    params_class = _resolve(config["params_class"])
+    factory = _resolve(config["factory"])
+
+    def call():
+        return factory(
+            params_class(**{**config["params"], **small}),
+            **config["factory_kwargs"],
+        )
+
+    (net, state), (net2, state2) = call(), call()
+    for attr, want in config.get("expect", {}).get("protocol_attrs", {}).items():
+        assert getattr(net.protocol, attr) == want, (attr, path)
+    assert set(net.protocol.DERIVED_CACHE_LEAVES) <= set(state.proto)
+    assert jax.tree_util.tree_structure(state) == jax.tree_util.tree_structure(state2)
+    assert jax.tree_util.tree_map(
+        lambda a: (a.shape, a.dtype), state
+    ) == jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), state2)
+    assert net.stable_cache_key() == net2.stable_cache_key()
 
 
-def test_cache_default_is_backend_auto():
-    """make_handel(score_cache=None) resolves by backend: the cache is an
-    HBM-bandwidth economy, ON for TPU, OFF elsewhere (the 256x4 CPU
-    ablation prices its maintenance at a 5-10% loss).  Explicit
-    True/False always wins."""
-    import jax
-
-    net, _ = make_handel(HandelParameters(node_count=32))
-    expect = jax.default_backend() == "tpu"
-    assert net.protocol.SCORE_CACHE is expect
-    assert bool(net.protocol.DERIVED_CACHE_LEAVES) is expect
-    net, _ = make_handel(HandelParameters(node_count=32), score_cache=True)
+def test_factories_take_no_switch_of_cache_or_view():
+    """The whole signatures: a keyword that picks the cached or the
+    uncached program, or the selection's view, would show here."""
+    assert list(inspect.signature(make_handel).parameters) == [
+        "params", "capacity", "seed", "wheel_rows", "telemetry", "annotate",
+        "fuse_step",
+    ]
+    assert list(inspect.signature(make_p2phandel).parameters) == [
+        "params", "capacity", "seed",
+    ]
+    net, state = make_handel(HandelParameters(node_count=32), fuse_step=True)
     assert net.protocol.SCORE_CACHE is True
     assert net.protocol.DERIVED_CACHE_LEAVES == BatchedHandel.CACHE_LEAF_NAMES
+    assert set(BatchedHandel.CACHE_LEAF_NAMES) <= set(state.proto)
 
 
 # -- SL701: the simlint rule guarding these invariants ----------------------

@@ -259,9 +259,9 @@ class TestExports:
 @pytest.mark.slow
 class TestHandelTelemetry:
     def _cfgs(self):
-        from bench import _params
+        from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
-        return _params(64)
+        return flagship_params(64)
 
     @pytest.mark.parametrize("wheel_rows", [0, 64], ids=["flat", "wheel"])
     def test_handel_parity(self, wheel_rows):
@@ -285,13 +285,13 @@ class TestHandelTelemetry:
         progress series from run_ms_batched (via the sweep driver)
         reproduces the done-at CDF the sweep computes host-side from the
         final state."""
-        from bench import _params
+        from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
         from wittgenstein_tpu.scenarios.sweep import SweepConfig, run_sweep
 
         cfg = TelemetryConfig(snapshots=256, snapshot_every_ms=10)
         tele_out = []
         stats = run_sweep(
-            [SweepConfig("base", 0, _params(64))],
+            [SweepConfig("base", 0, flagship_params(64))],
             replicas=2,
             sim_ms=1500,
             telemetry=cfg,

@@ -51,10 +51,10 @@ class TestFlatWheelParity:
         done_at / traffic columns (the agg channel bypasses the generic
         store, so this pins that the engine rewrite left the channel's
         tick scheduling untouched)."""
-        import bench as benchmod
         from wittgenstein_tpu.protocols.handel_batched import make_handel
+        from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
-        p = benchmod._params(256)
+        p = flagship_params(256)
         net_f, s_f = make_handel(p)
         net_w, s_w = make_handel(p, wheel_rows=512)
         out_f = net_f.run_ms_batched(replicate_state(s_f, 1), 700)

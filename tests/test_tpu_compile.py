@@ -109,13 +109,13 @@ def test_kernels_compile_under_vmap(mosaic):
 @pytest.fixture(scope="module")
 def handel256():
     """The flagship configuration at 256 nodes, as the chip builds it
-    (score cache on, fused step), 4 replicas.  Built with the default
+    (fused step), 4 replicas.  Built with the default
     (lax) kernels: construction runs eagerly on the CPU."""
     from wittgenstein_tpu.engine import replicate_state
-    from wittgenstein_tpu.profiling import flagship_params
     from wittgenstein_tpu.protocols.handel_batched import make_handel
+    from wittgenstein_tpu.scenarios.handel_scenarios import flagship_params
 
-    net, state = make_handel(flagship_params(256), fuse_step=True, score_cache=True)
+    net, state = make_handel(flagship_params(256), fuse_step=True)
     return net, replicate_state(state, 4)
 
 
@@ -158,7 +158,7 @@ BYZ_SIZE = {"node_count": 256, "nodes_down": 51, "threshold": 202}
 @pytest.fixture(scope="module")
 def handel_byz():
     """`handel-4096-byz20` as the benchmark builds it (`make_handel` with
-    its parameters, fused step, score cache on), one row: the `bl` and
+    its parameters, fused step), one row: the `bl` and
     `byz` planes, the forged-signature injection, the blacklist, the
     emission's blacklist term and the `sent_not_ok` counter."""
     import json
@@ -172,7 +172,7 @@ def handel_byz():
     with open(os.path.join(root, "benchmark", "configs", "handel-4096-byz20.json")) as f:
         config = json.load(f)
     params = HandelParameters(**{**config["params"], **BYZ_SIZE})
-    net, state = make_handel(params, score_cache=True, **config["factory_kwargs"])
+    net, state = make_handel(params, **config["factory_kwargs"])
     assert net.protocol.track_bad and "sent_not_ok" in state.proto
     return net, replicate_state(state, 1)
 

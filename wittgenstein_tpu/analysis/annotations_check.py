@@ -1,6 +1,6 @@
 """SL601: engine phase annotations — present AND bit-neutral.
 
-The cost-attribution layer (profiling/, bench --phase-profile) only
+The cost-attribution layer (profiling/, scripts/scope_profile.py) only
 works if (a) every engine kernel phase is wrapped in its
 `jax.named_scope` marker (engine.core.ENGINE_PHASE_SCOPES), so jaxprs /
 HLO metadata / device profiles can attribute ops to phases, and (b) the

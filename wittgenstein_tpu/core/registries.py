@@ -273,11 +273,7 @@ def _make_handel_small():
             dissemination_period_ms=10,
             fast_path=10,
             nodes_down=0,
-        ),
-        # pinned ON (the default is backend-auto): the registry entry is
-        # what simlint's SL701 derived-cache audit steps, so the cache
-        # path must be exercised on the CPU CI backend too
-        score_cache=True,
+        )
     )
 
 
@@ -330,9 +326,7 @@ def _make_p2phandel_small():
     from ..protocols.p2phandel import P2PHandelParameters
     from ..protocols.p2phandel_batched import make_p2phandel
 
-    # score_cache pinned for the same reason as the handel entry: SL701
-    # steps this factory's output
-    return make_p2phandel(P2PHandelParameters(), score_cache=True)
+    return make_p2phandel(P2PHandelParameters())
 
 
 def _make_sanfermin_small():

@@ -249,7 +249,7 @@ class BatchedNetwork:
         # ENGINE_PHASE_SCOPES): True wraps every phase in jax.named_scope
         # (trace-time metadata, zero runtime ops); False traces the bare
         # program — kept only so simlint SL601 can prove the two are
-        # bit-identical and bench can price the (nominally zero) overhead
+        # bit-identical
         self.annotate = bool(annotate)
         # STATIC switch for the fused delivery+tick step (_step_core_fused,
         # docs/engine_fused_step.md): one traced phase instead of
@@ -258,8 +258,8 @@ class BatchedNetwork:
         # sort/repack when the delivery window is a single row.
         # Bit-identical to the unfused path by construction (pinned by
         # tests/test_step_fusion.py); the unfused path stays the default
-        # because its per-phase scopes are what --phase-profile and the
-        # SL601 annotation checks attribute against.
+        # because its per-phase scopes are what the SL601 annotation
+        # checks attribute against.
         self.fuse_step = bool(fuse_step)
         # STATIC switch for the batched consensus-jump loop
         # (_run_ms_batched_jumps, docs/engine_timewheel.md): replicas
@@ -1134,18 +1134,6 @@ class BatchedNetwork:
             with self._scope("protocol_tick"):
                 return self.protocol.tick(self, state)
 
-    # -- phase hooks (bench --phase-profile) ---------------------------------
-    def _phase_deliver(self, state: SimState) -> SimState:
-        """Delivery + clear only (emissions discarded) — the per-tick cost
-        that the time wheel bounds at O(window*B + V) instead of O(C)."""
-        state, _ = self._deliver_and_clear(state)
-        return state
-
-    def _phase_deliver_apply(self, state: SimState) -> SimState:
-        """Delivery + emission apply (protocol.tick excluded)."""
-        state, emissions = self._deliver_and_clear(state)
-        return self.apply_emissions(state, emissions)
-
     def _tele_tick(self, state: SimState) -> SimState:
         """Per-executed-tick telemetry: tick census + (optionally) the
         progress-snapshot write, keyed by the tick just executed (called
@@ -1194,7 +1182,7 @@ class BatchedNetwork:
 
     def occupancy(self, state: SimState) -> dict:
         """Observability: wheel fill high-water and overflow census of the
-        CURRENT state (bench's occupancy probe samples this per tick)."""
+        CURRENT state."""
         return {
             "wheel_fill_max": jnp.max(state.whl_fill),
             "overflow_count": jnp.sum(state.ovf_valid.astype(jnp.int32)),
@@ -1476,7 +1464,7 @@ class BatchedNetwork:
         """Instrumented single-replica run: `ms` plain per-tick steps (no
         empty-ms jumps, so every tick's occupancy is sampled) returning
         (state, {wheel_fill_hwm, overflow_hwm}) — the wheel's high-water
-        marks for bench's --phase-profile record."""
+        marks (scripts/density_autotune.py sizes capacities from them)."""
 
         def body(_, carry):
             s, hw_fill, hw_ovf = carry

@@ -330,7 +330,7 @@ def default_serve_specs(
     the zero-objective error/restart SLOs are the sharp ones — any
     error kind or lane restart inside the window fires.  The sims/s
     floor arms only where a sims_per_sec series is actually fed
-    (tpu_campaign rungs; the serve path never feeds it)."""
+    (campaign rung records; the serve path never feeds it)."""
     specs = [
         SLOSpec(
             name="queue-wait-p95", metric="serve.queue_wait_s",

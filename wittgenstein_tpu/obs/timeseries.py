@@ -6,7 +6,7 @@ this repo's CI smokes, campaign rungs, and single-process fleets have
 nowhere to scrape FROM, so the history has to live in-process.  A
 ``TimeSeriesStore`` is that history: one bounded ring of (ts, value)
 samples per metric family, fed by ServeMetrics observations, the
-Supervisor's chunk-end sync point, and tpu_campaign rungs, and queried
+Supervisor's chunk-end sync point, and campaign rung records, and queried
 by the SLO burn-rate engine (obs/slo.py) with rate / delta / quantile
 over sliding windows.
 

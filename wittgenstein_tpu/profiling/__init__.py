@@ -12,13 +12,9 @@ PR 2's telemetry counts *protocol* events; this package attributes
                actual SimState leaves, HBM-bounded replicas/chip — the
                number behind the "~106 MiB/replica at D=32" claim and
                the feasibility budget's R.
-  ablation.py  the config-ablation matrix (channel depth, boundary
-               view, wheel, telemetry, faults, annotations) and the
-               ranked per-tick lever report that prices each lever —
-               bench.py --phase-profile and the r4→r5 attribution.
   probe.py     the TTL'd TPU probe-verdict cache file + the run-record /
-               Prometheus surface of the verdict (nothing writes one
-               since bench.py stopped probing; ROADMAP C1).
+               Prometheus surface of the verdict (nothing writes one;
+               ROADMAP C1).
   budget.py    the chip-independent feasibility arithmetic: measured
                ticks/sim × HBM-bounded replicas/chip → required tick_µs
                for the 21 sims/s/chip north star (BUDGET.json via
@@ -27,13 +23,6 @@ PR 2's telemetry counts *protocol* events; this package attributes
 See docs/profiling.md for the phase map and per-backend caveats.
 """
 
-from .ablation import (
-    ablation_matrix,
-    flagship_params,
-    format_lever_report,
-    lever_report,
-    smoke_ablation_configs,
-)
 from .budget import (
     budget_from_parts,
     budget_staleness,
@@ -52,15 +41,11 @@ from .xla_cost import compiled_cost_summary, cost_analysis_dict, memory_analysis
 
 __all__ = [
     "PROBE_CACHE_TTL_S",
-    "ablation_matrix",
     "budget_from_parts",
     "budget_staleness",
     "compiled_cost_summary",
     "cost_analysis_dict",
-    "flagship_params",
-    "format_lever_report",
     "hbm_report",
-    "lever_report",
     "load_budget",
     "memory_analysis_dict",
     "probe_cache_path",
@@ -68,7 +53,6 @@ __all__ = [
     "read_probe_cache",
     "replicas_per_chip",
     "required_tick_us",
-    "smoke_ablation_configs",
     "state_bytes_per_replica",
     "write_probe_cache",
 ]

@@ -17,9 +17,7 @@ Then, with R replicas advancing in lockstep:
 
 i.e. each batched tick may take at most that many microseconds of
 wall-clock for the chip to emit 21 finished sims per second.
-scripts/budget_report.py materializes this as BUDGET.json; bench.py's
-target_tick_us derives from the same arithmetic (and from BUDGET.json's
-measured ticks_per_sim when present) instead of being hand-set.
+scripts/budget_report.py materializes this as BUDGET.json.
 """
 
 from __future__ import annotations

@@ -1,12 +1,11 @@
 """TPU probe-verdict cache + export surface.
 
-bench.py no longer probes (it runs in one process and fails without a
-TPU), so nothing in the repo writes a verdict today; what lives here is
-the verdict's cache file and export surface, kept until the measurement
-rig is cleared out (ROADMAP C1):
+Nothing in the repo probes or writes a verdict today (the benchmark and
+chip_smoke.py run in one process and fail without a TPU); what lives
+here is the verdict's cache file and export surface, a debt ROADMAP C1
+names:
 
-  * the TTL'd /tmp cache (moved from bench.py r9) so a bench ladder's
-    children probe once per process tree;
+  * the TTL'd /tmp cache, so a process tree probes once;
   * probe_verdict_fields() — the flat run-record view of a verdict
     (attempts, last rc, fallback_reason, cache age) so every BENCH /
     rung JSONL line says WHY it ran where it ran;

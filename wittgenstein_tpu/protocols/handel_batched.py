@@ -134,26 +134,20 @@ class BatchedHandel(BitsetAggBase):
     # tick); the price is channel memory, ~3.7x on in_sig — ~106 MiB per
     # 4096-node replica, still 32+ replicas inside a v5e chip's HBM.
     CHANNEL_DEPTH = 32
-    # r5 parity fix: _select reads the END-of-previous-tick candidate and
-    # merge state (see tick() below).  Instance-overridable so the
-    # profiling ablation (profiling/ablation.py) can price the snapshot
-    # dicts the view costs per tick; False reproduces the pre-r5
-    # one-tick-lead selection and is NOT parity-correct.
-    BOUNDARY_VIEW = True
-    # Candidate-score caching (the PR-8 lever): carry the per-slot derived
-    # quantities _select needs — sizeIfIncluded, cardinality, |sig ∪ ind|
-    # and the agg-intersection flag — as int32 leaves in state.proto,
-    # refreshed only where delivery merges new content and where _commit
-    # moves the aggregates.  The selection and the channel merge then read
-    # cached int32 columns instead of re-popcounting every candidate's
-    # signature words each tick (the top bytes-accessed term in
-    # BUDGET.json).  End-of-tick invariant, pinned by simlint SL701 and
-    # tests/test_score_cache.py: each cache leaf equals its from-scratch
-    # recompute (_recompute_cache_dict) from (cand_sig*, inc, ind, agg).
-    # False restores the uncached program, leaf-for-leaf identical to the
-    # pre-cache tree (the ablation's score_cache_off lever).
+    # Candidate-score caching: carry the per-slot derived quantities
+    # _select needs — sizeIfIncluded, cardinality, |sig ∪ ind| and the
+    # agg-intersection flag — as int32 leaves in state.proto, refreshed
+    # only where delivery merges new content and where _commit moves the
+    # aggregates.  The selection and the channel merge then read cached
+    # int32 columns instead of re-popcounting every candidate's signature
+    # words each tick.  End-of-tick invariant, pinned by simlint SL701
+    # and by the tests at every stop of whole runs: each cache leaf equals
+    # its from-scratch recompute (_recompute_cache_dict) from (cand_sig*,
+    # inc, ind, agg).  This is the program's only form; the constant is
+    # what the benchmark's configs read through expect.protocol_attrs.
     SCORE_CACHE = True
     CACHE_LEAF_NAMES = ("cand_s", "cand_card", "cand_wind", "cand_aggi")
+    DERIVED_CACHE_LEAVES = CACHE_LEAF_NAMES
 
     def __init__(self, params: HandelParameters):
         self.params = params
@@ -170,9 +164,6 @@ class BatchedHandel(BitsetAggBase):
                 )
             self.CAND_SLOTS = params.cand_slots  # instance override
         self._init_geometry(params.node_count)
-        self.DERIVED_CACHE_LEAVES = (
-            self.CACHE_LEAF_NAMES if self.SCORE_CACHE else ()
-        )
         # blacklist + byzantine bitsets are carried only when an attack can
         # ever set a bit in them (byzantineSuicide writes bl, both attacks
         # read byz); attack-free replicas — the flagship density config —
@@ -201,8 +192,7 @@ class BatchedHandel(BitsetAggBase):
           cand_aggi  boolean flag carried as an integer
 
         Leaves whose bound already needs int32 are omitted (narrowing
-        would be a no-op); widen_proto/narrow_proto skip absent leaves, so
-        the cache entries are inert when SCORE_CACHE is off."""
+        would be a no-op)."""
         from ..engine.density import NarrowLeaf, narrowest_int
 
         p, n, L = self.params, self.n_nodes, self.n_levels
@@ -330,11 +320,10 @@ class BatchedHandel(BitsetAggBase):
             if byz_rel is None:
                 byz_rel = np.zeros((n, self.n_words), dtype=np.uint32)
             proto["byz"] = jnp.asarray(byz_rel)
-        if self.SCORE_CACHE:
-            proto.update(self._recompute_cache_dict(proto))
+        proto.update(self._recompute_cache_dict(proto))
         return self.narrow_proto(proto)
 
-    # -- candidate-score caches (SCORE_CACHE) --------------------------------
+    # -- candidate-score caches ----------------------------------------------
     def _recompute_cache_dict(self, proto) -> dict:
         """From-scratch values of the four candidate-score cache leaves,
         computed only from (cand_sig*, inc, ind, agg) — the oracle the
@@ -371,8 +360,6 @@ class BatchedHandel(BitsetAggBase):
         }
 
     def recompute_caches(self, state) -> dict:
-        if not self.SCORE_CACHE:
-            return {}
         # oracle recompute on the int32 view, re-narrowed so the returned
         # leaves match the carried storage dtypes exactly (the SL701 and
         # checkpoint-template comparisons are dtype-strict)
@@ -456,57 +443,52 @@ class BatchedHandel(BitsetAggBase):
         done_now = (
             improved_any & (state.done_at == 0) & ~state.down & (total >= p.threshold)
         )
-        cache_fix = {}
-        if self.SCORE_CACHE:
-            # a good commit moves (inc, ind, agg) at exactly ver_level, so
-            # the score caches of that one level's K slots are re-derived
-            # against the NEW aggregates; every other level's caches stay
-            # valid (cand_card depends on sig content only — untouched)
-            K = self.CAND_SLOTS
-            cs3 = proto["cand_s"].reshape(n, L - 1, K)
-            cw3 = proto["cand_wind"].reshape(n, L - 1, K)
-            ca3 = proto["cand_aggi"].reshape(n, L - 1, K)
-            lv_rows = jnp.arange(L - 1, dtype=jnp.int32)
-            for i, b in enumerate(self.buckets):
-                mlev = good & (lvl >= b.lo) & (lvl <= b.hi)
-                li = jnp.clip(lvl - b.lo, 0, b.nl - 1)
-                c_sig = self._sig_view(proto, i, K, prefix="cand_sig")
-                sig_lv = jnp.take_along_axis(
-                    c_sig, li[:, None, None, None], axis=1
-                )[:, 0]  # [N, K, w_pad]
-                inc_lv = jnp.take_along_axis(
-                    self._blocks(inc, b), li[:, None, None], axis=1
-                )[:, 0]
-                ind_lv = jnp.take_along_axis(
-                    self._blocks(ind, b), li[:, None, None], axis=1
-                )[:, 0]
-                agg_lv = jnp.take_along_axis(
-                    self._blocks(agg, b), li[:, None, None], axis=1
-                )[:, 0]
-                inter = popcount_words(sig_lv & inc_lv[:, None, :]) > 0
-                cc = jnp.where(
-                    inter[..., None], sig_lv, sig_lv | inc_lv[:, None, :]
-                )
-                s_lv = popcount_words(cc | ind_lv[:, None, :])
-                wind_lv = popcount_words(sig_lv | ind_lv[:, None, :])
-                aggi_lv = (
-                    popcount_words(sig_lv & agg_lv[:, None, :]) > 0
-                ).astype(jnp.int32)
-                lm = mlev[:, None] & (lv_rows[None, :] == (lvl - 1)[:, None])
-                cs3 = jnp.where(lm[..., None], s_lv[:, None, :], cs3)
-                cw3 = jnp.where(lm[..., None], wind_lv[:, None, :], cw3)
-                ca3 = jnp.where(lm[..., None], aggi_lv[:, None, :], ca3)
-            cache_fix = {
-                "cand_s": cs3.reshape(n, (L - 1) * K),
-                "cand_wind": cw3.reshape(n, (L - 1) * K),
-                "cand_aggi": ca3.reshape(n, (L - 1) * K),
-            }
+        # a good commit moves (inc, ind, agg) at exactly ver_level, so
+        # the score caches of that one level's K slots are re-derived
+        # against the NEW aggregates; every other level's caches stay
+        # valid (cand_card depends on sig content only — untouched)
+        K = self.CAND_SLOTS
+        cs3 = proto["cand_s"].reshape(n, L - 1, K)
+        cw3 = proto["cand_wind"].reshape(n, L - 1, K)
+        ca3 = proto["cand_aggi"].reshape(n, L - 1, K)
+        lv_rows = jnp.arange(L - 1, dtype=jnp.int32)
+        for i, b in enumerate(self.buckets):
+            mlev = good & (lvl >= b.lo) & (lvl <= b.hi)
+            li = jnp.clip(lvl - b.lo, 0, b.nl - 1)
+            c_sig = self._sig_view(proto, i, K, prefix="cand_sig")
+            sig_lv = jnp.take_along_axis(
+                c_sig, li[:, None, None, None], axis=1
+            )[:, 0]  # [N, K, w_pad]
+            inc_lv = jnp.take_along_axis(
+                self._blocks(inc, b), li[:, None, None], axis=1
+            )[:, 0]
+            ind_lv = jnp.take_along_axis(
+                self._blocks(ind, b), li[:, None, None], axis=1
+            )[:, 0]
+            agg_lv = jnp.take_along_axis(
+                self._blocks(agg, b), li[:, None, None], axis=1
+            )[:, 0]
+            inter = popcount_words(sig_lv & inc_lv[:, None, :]) > 0
+            cc = jnp.where(
+                inter[..., None], sig_lv, sig_lv | inc_lv[:, None, :]
+            )
+            s_lv = popcount_words(cc | ind_lv[:, None, :])
+            wind_lv = popcount_words(sig_lv | ind_lv[:, None, :])
+            aggi_lv = (
+                popcount_words(sig_lv & agg_lv[:, None, :]) > 0
+            ).astype(jnp.int32)
+            lm = mlev[:, None] & (lv_rows[None, :] == (lvl - 1)[:, None])
+            cs3 = jnp.where(lm[..., None], s_lv[:, None, :], cs3)
+            cw3 = jnp.where(lm[..., None], wind_lv[:, None, :], cw3)
+            ca3 = jnp.where(lm[..., None], aggi_lv[:, None, :], ca3)
         upd = dict(
             agg=agg,
             ind=ind,
             inc=inc,
+            cand_s=cs3.reshape(n, (L - 1) * K),
+            cand_wind=cw3.reshape(n, (L - 1) * K),
+            cand_aggi=ca3.reshape(n, (L - 1) * K),
             ver_active=proto["ver_active"] & ~due,
-            **cache_fix,
         )
         if self.track_bad:
             upd["bl"] = new_bl
@@ -654,53 +636,45 @@ class BatchedHandel(BitsetAggBase):
 
             inc_b = self._blocks(inc, b)  # [N, nl, w_pad]
             ind_b = self._blocks(ind, b)
-            if self.SCORE_CACHE:
-                # only the two due slots pay popcounts: the K resident
-                # slots' quantities ride in the caches, valid against the
-                # pre-commit aggregates by the end-of-tick invariant
-                # (deliver runs first; _commit re-fixes what it moves)
-                agg_b = self._blocks(agg, b)
-                inter2 = popcount_words(sig_new & inc_b[:, :, None, :]) > 0
-                c2 = jnp.where(
-                    inter2[..., None], sig_new, sig_new | inc_b[:, :, None, :]
-                )
-                s_new = popcount_words(c2 | ind_b[:, :, None, :])
-                all_s = jnp.concatenate(
-                    [proto["cand_s"].reshape(n, L - 1, K)[:, sl, :], s_new],
-                    axis=2,
-                )
-                all_card = jnp.concatenate(
-                    [
-                        proto["cand_card"].reshape(n, L - 1, K)[:, sl, :],
-                        popcount_words(sig_new),
-                    ],
-                    axis=2,
-                )
-                all_wind = jnp.concatenate(
-                    [
-                        proto["cand_wind"].reshape(n, L - 1, K)[:, sl, :],
-                        popcount_words(sig_new | ind_b[:, :, None, :]),
-                    ],
-                    axis=2,
-                )
-                all_aggi = jnp.concatenate(
-                    [
-                        proto["cand_aggi"].reshape(n, L - 1, K)[:, sl, :],
-                        (
-                            popcount_words(sig_new & agg_b[:, :, None, :]) > 0
-                        ).astype(jnp.int32),
-                    ],
-                    axis=2,
-                )
-                s = all_s
-            else:
-                inter = popcount_words(all_sig & inc_b[:, :, None, :]) > 0
-                c = jnp.where(
-                    inter[..., None], all_sig, all_sig | inc_b[:, :, None, :]
-                )
-                s = popcount_words(c | ind_b[:, :, None, :])  # sizeIfIncluded
+            # only the two due slots pay popcounts: the K resident
+            # slots' quantities ride in the caches, valid against the
+            # pre-commit aggregates by the end-of-tick invariant
+            # (deliver runs first; _commit re-fixes what it moves)
+            agg_b = self._blocks(agg, b)
+            inter2 = popcount_words(sig_new & inc_b[:, :, None, :]) > 0
+            c2 = jnp.where(
+                inter2[..., None], sig_new, sig_new | inc_b[:, :, None, :]
+            )
+            s_new = popcount_words(c2 | ind_b[:, :, None, :])
+            all_s = jnp.concatenate(
+                [proto["cand_s"].reshape(n, L - 1, K)[:, sl, :], s_new],
+                axis=2,
+            )
+            all_card = jnp.concatenate(
+                [
+                    proto["cand_card"].reshape(n, L - 1, K)[:, sl, :],
+                    popcount_words(sig_new),
+                ],
+                axis=2,
+            )
+            all_wind = jnp.concatenate(
+                [
+                    proto["cand_wind"].reshape(n, L - 1, K)[:, sl, :],
+                    popcount_words(sig_new | ind_b[:, :, None, :]),
+                ],
+                axis=2,
+            )
+            all_aggi = jnp.concatenate(
+                [
+                    proto["cand_aggi"].reshape(n, L - 1, K)[:, sl, :],
+                    (
+                        popcount_words(sig_new & agg_b[:, :, None, :]) > 0
+                    ).astype(jnp.int32),
+                ],
+                axis=2,
+            )
             cur = popcount_words(inc_b)
-            keep = valid & (s > cur[:, :, None])
+            keep = valid & (all_s > cur[:, :, None])
             if self.track_bad:
                 with net._scope("blacklist", ATTACK_SCOPES):
                     keep = keep & ~self._listed(bl, b, all_rel)
@@ -709,7 +683,7 @@ class BatchedHandel(BitsetAggBase):
             # bounded (s <= bs <= N/2, rank < 3N) so s*4N + rank fits int32
             r4 = 4 * self.n_nodes
             skey = jnp.where(
-                keep, s * r4 + (r4 - 1 - jnp.minimum(all_rank, r4 - 1)), -1
+                keep, all_s * r4 + (r4 - 1 - jnp.minimum(all_rank, r4 - 1)), -1
             )
             order = jnp.argsort(-skey, axis=2)[:, :, :K]  # top-K
             top_keep = jnp.take_along_axis(skey, order, axis=2) >= 0
@@ -724,38 +698,24 @@ class BatchedHandel(BitsetAggBase):
             cand_sig_updates[f"cand_sig{i}"] = sel_sig.reshape(
                 n, b.nl * K * b.w_pad
             )
-            if self.SCORE_CACHE:
-                s_pieces.append(jnp.take_along_axis(all_s, order, axis=2))
-                card_pieces.append(
-                    jnp.take_along_axis(all_card, order, axis=2)
-                )
-                wind_pieces.append(
-                    jnp.take_along_axis(all_wind, order, axis=2)
-                )
-                aggi_pieces.append(
-                    jnp.take_along_axis(all_aggi, order, axis=2)
-                )
+            s_pieces.append(jnp.take_along_axis(all_s, order, axis=2))
+            card_pieces.append(jnp.take_along_axis(all_card, order, axis=2))
+            wind_pieces.append(jnp.take_along_axis(all_wind, order, axis=2))
+            aggi_pieces.append(jnp.take_along_axis(all_aggi, order, axis=2))
 
-        cache_updates = {}
-        if self.SCORE_CACHE:
-            flat = lambda ps: jnp.concatenate(ps, axis=1).reshape(
-                n, (L - 1) * K
-            )
-            cache_updates = {
-                "cand_s": flat(s_pieces),
-                "cand_card": flat(card_pieces),
-                "cand_wind": flat(wind_pieces),
-                "cand_aggi": flat(aggi_pieces),
-            }
+        flat = lambda ps: jnp.concatenate(ps, axis=1).reshape(n, (L - 1) * K)
         state = state._replace(
             proto=dict(
                 proto,
+                cand_s=flat(s_pieces),
+                cand_card=flat(card_pieces),
+                cand_wind=flat(wind_pieces),
+                cand_aggi=flat(aggi_pieces),
                 in_key=jnp.where(due_all, empty_tpl[None, :], in_key),
-                cand_rank=jnp.concatenate(rank_pieces, axis=1).reshape(n, (L - 1) * K),
-                cand_rel=jnp.concatenate(rel_pieces, axis=1).reshape(n, (L - 1) * K),
+                cand_rank=flat(rank_pieces),
+                cand_rel=flat(rel_pieces),
                 msg_filtered=proto["msg_filtered"] + filtered,
                 **cand_sig_updates,
-                **cache_updates,
             )
         )
         return state
@@ -861,11 +821,11 @@ class BatchedHandel(BitsetAggBase):
         return self._level_stats(nxt_p), self._level_stats(any_p)
 
     # -- tick phase 4: start new verifications (checkSigs) -------------------
-    def _select(self, net, state, view=None):
+    def _select(self, net, state, view):
         """bestToVerify per level + uniform cross-level choice + attacks +
         window adaptation (Handel.java:566-630, 788-837).
 
-        `view` (tick() passes it) holds the BOUNDARY state — candidates
+        `view` holds the BOUNDARY state — candidates
         and aggregates as of the end of the previous tick — which is what
         the reference's boundary-fired checkSigs sees.  Candidate
         write-backs (curation removal, chosen-slot consumption) target
@@ -878,7 +838,7 @@ class BatchedHandel(BitsetAggBase):
         clearing those loses nothing."""
         p = self.params
         proto = state.proto
-        v = proto if view is None else {**proto, **view}
+        v = {**proto, **view}
         t = state.time
         n, L, K = self.n_nodes, self.n_levels, self.CAND_SLOTS
         ids = jnp.arange(n, dtype=jnp.int32)
@@ -902,30 +862,20 @@ class BatchedHandel(BitsetAggBase):
             bs = jnp.asarray([self.bs[l] for l in b.levels], jnp.int32)
             c_rank = v["cand_rank"].reshape(n, L - 1, K)[:, sl, :]
             c_rel = v["cand_rel"].reshape(n, L - 1, K)[:, sl, :]
-            c_sig = self._sig_view(v, i, K, prefix="cand_sig")
             valid = c_rank != INT32_MAX
 
             inc_b = self._blocks(inc, b)
-            ind_b = self._blocks(ind, b)
             agg_b = self._blocks(agg, b)
 
             # curation (bestToVerify :592-612): drop blacklisted senders and
-            # candidates that can no longer grow the aggregate
-            if self.SCORE_CACHE:
-                # sizeIfIncluded / cardinalities come from the carried
-                # int32 caches (the viewed snapshot for scoring, the
-                # current leaf for entry identity) — no signature-word
-                # popcounts on this path
-                s = v["cand_s"].reshape(n, L - 1, K)[:, sl, :]
-                ccard_pieces.append(
-                    proto["cand_card"].reshape(n, L - 1, K)[:, sl, :]
-                )
-            else:
-                inter = popcount_words(c_sig & inc_b[:, :, None, :]) > 0
-                cc = jnp.where(inter[..., None], c_sig, c_sig | inc_b[:, :, None, :])
-                s = popcount_words(cc | ind_b[:, :, None, :])
-                cur_sig = self._sig_view(proto, i, K, prefix="cand_sig")
-                ccard_pieces.append(popcount_words(cur_sig))
+            # candidates that can no longer grow the aggregate.
+            # sizeIfIncluded / cardinalities come from the carried int32
+            # caches (the viewed snapshot for scoring, the current leaf
+            # for entry identity) — no signature-word popcounts here
+            s = v["cand_s"].reshape(n, L - 1, K)[:, sl, :]
+            ccard_pieces.append(
+                proto["cand_card"].reshape(n, L - 1, K)[:, sl, :]
+            )
             curated = valid & (s > popcount_words(inc_b)[:, :, None])
             if self.track_bad:
                 with net._scope("blacklist", ATTACK_SCOPES):
@@ -947,14 +897,9 @@ class BatchedHandel(BitsetAggBase):
 
             # score (:650-664)
             agg_card = popcount_words(agg_b)  # [N, nl]
-            if self.SCORE_CACHE:
-                sig_card = v["cand_card"].reshape(n, L - 1, K)[:, sl, :]
-                agg_inter = v["cand_aggi"].reshape(n, L - 1, K)[:, sl, :] > 0
-                with_ind = v["cand_wind"].reshape(n, L - 1, K)[:, sl, :]
-            else:
-                sig_card = popcount_words(c_sig)
-                agg_inter = popcount_words(c_sig & agg_b[:, :, None, :]) > 0
-                with_ind = popcount_words(c_sig | ind_b[:, :, None, :])
+            sig_card = v["cand_card"].reshape(n, L - 1, K)[:, sl, :]
+            agg_inter = v["cand_aggi"].reshape(n, L - 1, K)[:, sl, :] > 0
+            with_ind = v["cand_wind"].reshape(n, L - 1, K)[:, sl, :]
             vcard_pieces.append(sig_card)
             score = jnp.where(
                 agg_card[:, :, None] >= bs[None, :, None],
@@ -1207,10 +1152,6 @@ class BatchedHandel(BitsetAggBase):
         # measured as a -4..-9 ms CDF lead (r5, on the CPU).  The
         # busy gate stays post-commit (a commit at t frees the node for a
         # same-tick re-select, like the reference's minStartTime spacing).
-        if not self.BOUNDARY_VIEW:  # pre-r5 ablation lever: same-tick view
-            state = self._channel_deliver(net, state)
-            state = self._commit(net, state)
-            return self._select(net, state)
         pre_cand = {k: state.proto[k] for k in self._cand_keys()}
         state = self._channel_deliver(net, state)
         merge_keys = ("inc", "ind", "agg") + (
@@ -1222,15 +1163,14 @@ class BatchedHandel(BitsetAggBase):
         return state
 
     def _cand_keys(self):
-        keys = ("cand_rank", "cand_rel") + tuple(
-            f"cand_sig{i}" for i in range(len(self.buckets))
+        # the boundary view scores on end-of-previous-tick caches, which
+        # by the invariant equal a recompute from the viewed (cand_sig,
+        # inc, ind, agg) exactly
+        return (
+            ("cand_rank", "cand_rel")
+            + tuple(f"cand_sig{i}" for i in range(len(self.buckets)))
+            + self.CACHE_LEAF_NAMES
         )
-        if self.SCORE_CACHE:
-            # the boundary view scores on end-of-previous-tick caches,
-            # which by the invariant equal a recompute from the viewed
-            # (cand_sig, inc, ind, agg) exactly
-            keys = keys + self.CACHE_LEAF_NAMES
-        return keys
 
     def all_done(self, state):
         live = ~state.down
@@ -1243,25 +1183,12 @@ def make_handel(
     seed: int = 0,
     wheel_rows: int = 0,  # flat by default; >0 = time wheel (parity tests)
     telemetry=None,  # telemetry.TelemetryConfig (None = uninstrumented)
-    boundary_view: bool = True,  # False = pre-r5 selection (ablation only)
     annotate: bool = True,  # False = strip named-scope phase markers
-    score_cache: Optional[bool] = None,  # None = auto: on for TPU only
     fuse_step: bool = False,  # True = engine's fused delivery+tick path
 ):
     """Host-side construction: build the node population with the oracle's
     RNG stream (positions, speed ratios, down set), bake into the engine."""
     params = params or HandelParameters()
-    if score_cache is None:
-        # The score cache trades bytes-accessed for carried int32 leaves —
-        # an HBM-bandwidth economy.  On TPU that is the budget's dominant
-        # cost (BUDGET.json: 1.93 GB/tick), so the cache defaults ON.  On
-        # CPU the masked delta-update scatters pay full width regardless
-        # of the due mask, and the 256x4 ablation prices the cache at a
-        # 5-10% LOSS — so it defaults OFF off-TPU.  Pass True/False to
-        # pin either way (bit-identical: tests/test_score_cache.py).
-        import jax
-
-        score_cache = jax.default_backend() == "tpu"
     n = params.node_count
     nb = registry_node_builders.get_by_name(params.node_builder_name)
     latency = registry_network_latencies.get_by_name(params.network_latency_name)
@@ -1288,11 +1215,6 @@ def make_handel(
     ).astype(np.int32)
 
     proto = BatchedHandel(params)
-    proto.BOUNDARY_VIEW = bool(boundary_view)
-    proto.SCORE_CACHE = bool(score_cache)
-    proto.DERIVED_CACHE_LEAVES = (
-        proto.CACHE_LEAF_NAMES if score_cache else ()
-    )
     # beat structure for the engine's real-branch gating: dissemination
     # fires at t with (t - (start_at + 1)) % period == 0
     proto.BEAT_PERIOD = params.dissemination_period_ms

@@ -60,7 +60,7 @@ class BatchedP2PHandel(BatchedProtocol):
     MSG_TYPES = ["SEND_SIGS", "STATE"]
     TICK_INTERVAL = 1  # periodic beat + conditional checkSigs per ms
     CAND_K = 8  # checkSigs1 to_verify pool depth
-    # ver_card cache (the PR-8 score-caching lever, p2phandel half):
+    # ver_card cache (the score cache, p2phandel half):
     # `verified` changes only in tick's commit, and the merged cardinality
     # obeys |verified ∪ ver_sig| = |verified| + |ver_sig \ verified| — so
     # one carried int32[N] column replaces the two [N, N] bool reductions
@@ -68,8 +68,10 @@ class BatchedP2PHandel(BatchedProtocol):
     # sum(verified, axis=1).  (peers_state cardinalities are NOT cacheable
     # this way: the delivery scatter-max can hit duplicate (to, slot)
     # destinations, which breaks the incremental identity.)
+    # The program's only form; the constant mirrors BatchedHandel's.
     SCORE_CACHE = True
     CACHE_LEAF_NAMES = ("ver_card",)
+    DERIVED_CACHE_LEAVES = CACHE_LEAF_NAMES
 
     def __init__(self, params: P2PHandelParameters, adjacency: np.ndarray, just_relay):
         self.params = params
@@ -77,16 +79,12 @@ class BatchedP2PHandel(BatchedProtocol):
         self.n_nodes = params.signing_node_count + params.relaying_node_count
         self.just_relay = jnp.asarray(just_relay)
         self.PAYLOAD_WIDTH = (self.n_nodes + 31) // 32
-        self.DERIVED_CACHE_LEAVES = (
-            self.CACHE_LEAF_NAMES if self.SCORE_CACHE else ()
-        )
         self.NARROW_LEAVES = self._narrow_plan()
 
     def _narrow_plan(self) -> tuple:
         """Density plan (engine.density, docs/density.md): ver_card is a
         verified-signature cardinality, provably <= N; carried narrow,
-        computed in int32 inside the widen/narrow hook boundary.  Inert
-        when SCORE_CACHE is off (the leaf is absent)."""
+        computed in int32 inside the widen/narrow hook boundary."""
         from ..engine.density import NarrowLeaf, narrowest_int
 
         dt = narrowest_int(self.n_nodes)
@@ -130,13 +128,10 @@ class BatchedP2PHandel(BatchedProtocol):
         }
         if not self.params.double_aggregate_strategy:
             proto["cand"] = jnp.zeros((n, self.CAND_K, n), bool)
-        if self.SCORE_CACHE:
-            proto["ver_card"] = jnp.sum(verified, axis=1)
+        proto["ver_card"] = jnp.sum(verified, axis=1)
         return self.narrow_proto(proto)
 
     def recompute_caches(self, state) -> dict:
-        if not self.SCORE_CACHE:
-            return {}
         # re-narrowed so the returned leaf matches the carried storage
         # dtype exactly (SL701 / checkpoint templates are dtype-strict)
         return self.narrow_proto(
@@ -224,22 +219,15 @@ class BatchedP2PHandel(BatchedProtocol):
 
         # 1. commit due verifications (updateVerifiedSignatures, :290-303)
         due = proto["ver_active"] & (t >= proto["ver_done_t"])
-        if self.SCORE_CACHE:
-            # carried cardinality + the union identity — one [N, N]
-            # reduction (the delta) instead of two full recounts
-            old_card = proto["ver_card"]
-            delta = jnp.sum(proto["ver_sig"] & ~verified, axis=1)
-            verified = jnp.where(
-                due[:, None], verified | proto["ver_sig"], verified
-            )
-            new_card = jnp.where(due, old_card + delta, old_card)
-            proto["ver_card"] = new_card
-        else:
-            old_card = jnp.sum(verified, axis=1)
-            verified = jnp.where(
-                due[:, None], verified | proto["ver_sig"], verified
-            )
-            new_card = jnp.sum(verified, axis=1)
+        # carried cardinality + the union identity — one [N, N]
+        # reduction (the delta) instead of two full recounts
+        old_card = proto["ver_card"]
+        delta = jnp.sum(proto["ver_sig"] & ~verified, axis=1)
+        verified = jnp.where(
+            due[:, None], verified | proto["ver_sig"], verified
+        )
+        new_card = jnp.where(due, old_card + delta, old_card)
+        proto["ver_card"] = new_card
         grew = due & (new_card > old_card)
         was_undone = state.done_at == 0
         reach = grew & was_undone & (new_card >= p.threshold)
@@ -369,11 +357,9 @@ def make_p2phandel(
     params: Optional[P2PHandelParameters] = None,
     capacity: int = 1 << 13,
     seed: int = 0,
-    score_cache: bool = True,
 ):
     """Host-side construction: oracle init builds the graph and the relay
-    set (same JavaRandom stream).  `score_cache=False` disables the
-    carried ver_card cardinality (ablation / bit-identity testing)."""
+    set (same JavaRandom stream)."""
     params = params or P2PHandelParameters()
     oracle = P2PHandel(params)
     oracle.init()
@@ -384,10 +370,6 @@ def make_p2phandel(
     city_index = getattr(latency, "city_index", None)
     cols = build_node_columns(net_o.all_nodes, city_index)
     proto = BatchedP2PHandel(params, adj, just_relay)
-    proto.SCORE_CACHE = bool(score_cache)
-    proto.DERIVED_CACHE_LEAVES = (
-        proto.CACHE_LEAF_NAMES if score_cache else ()
-    )
     net = BatchedNetwork(proto, latency, proto.n_nodes, capacity=capacity)
     state = net.init_state(cols, seed=seed, proto=proto.proto_init(proto.n_nodes))
     return net, state

@@ -47,8 +47,8 @@ class PreemptedError(TransientRunError):
 class WatchdogTimeoutError(FatalRunError):
     """A compile or chunk exceeded its deadline.  Fatal IN-PROCESS: a
     hung device call cannot be cancelled from Python, so the in-process
-    supervisor stops issuing work and reports; process-level supervisors
-    (tpu_campaign) own the actual kill."""
+    supervisor stops issuing work and reports; a process-level supervisor
+    owns the actual kill."""
 
     def __init__(self, phase: str, deadline_s: float):
         self.phase = phase

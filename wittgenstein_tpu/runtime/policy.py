@@ -57,8 +57,7 @@ class WatchdogPolicy:
     """Per-phase deadlines.  A chunk that misses its deadline is treated
     as a hung device and raises WatchdogTimeoutError; the first chunk of
     a cold process gets compile_deadline_s ON TOP of chunk_deadline_s
-    (jit compiles lazily inside the first call).  Defaults mirror
-    scripts/tpu_campaign.py's process-level limits."""
+    (jit compiles lazily inside the first call)."""
 
     chunk_deadline_s: float = 180.0
     compile_deadline_s: float = 780.0
@@ -82,7 +81,7 @@ class WatchdogWorker:
     never reused — a late result cannot be mistaken for a fresh one
     because the whole worker, result queue included, is discarded) and
     the caller creates a replacement.  Actually killing the hang stays a
-    process-level supervisor's job (scripts/tpu_campaign.py).
+    process-level supervisor's job.
     """
 
     # single-writer by construction: only the owning caller thread ever

@@ -22,8 +22,7 @@ with:
   supervised run.  A miss raises WatchdogTimeoutError rather than
   waiting forever.  Caveat: Python cannot cancel a hung device call — a
   worker whose call truly hangs is abandoned (and replaced); actually
-  killing the process is the job of a process-level supervisor
-  (scripts/tpu_campaign.py);
+  killing the process is the job of a process-level supervisor;
 - **retry with backoff**: transient failures (classify()) replay
   deterministically from the last host ANCHOR — a numpy snapshot taken
   at checkpoint cadence — so retried chunks produce the exact bytes a
@@ -103,7 +102,7 @@ CHUNK_HIST_BUCKETS_S = (0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 30.0, 60.0, 120.0)
 def chunk_time_histogram(times: List[float]) -> dict:
     """Prometheus-style cumulative histogram of chunk wall-times:
     {"buckets": {"0.1": n, ..., "+Inf": n}, "count", "sum_s", "max_s"}.
-    Shared by Supervisor provenance and the server/bench exports so one
+    Shared by Supervisor provenance and the server's exports so one
     bucket layout exists."""
     buckets = {}
     for le in CHUNK_HIST_BUCKETS_S:
@@ -383,16 +382,16 @@ class Supervisor:
     def _needs_anchor(self) -> bool:
         """Host anchors exist to replay retries and seed checkpoints;
         without either, skip them entirely — a bare supervised pass then
-        costs only the loop + sync bench's chunked_pass already paid."""
+        costs only the chunk loop and its sync."""
         return self.manager is not None or self.retry.max_attempts > 1
 
     def _resume(self):
         """-> (device_state, start_chunk, resumed_from_step, prior_times)."""
         if self.manager is None:
             if self.consume_template:
-                # hand the template straight to chunk_fn (bench
-                # semantics: a donating chunk_fn consumes it — the
-                # caller passed a disposable copy); anchoring, if
+                # hand the template straight to chunk_fn (a donating
+                # chunk_fn consumes it — the caller passed a disposable
+                # copy); anchoring, if
                 # needed, copies it first
                 return self.template, 0, None, []
             return self._place(self._snapshot(self.template)), 0, None, []
@@ -682,7 +681,7 @@ class Supervisor:
         ("corrupted double-linked list" aborts) on jaxlib 0.4.37 when
         the persistent compilation cache is enabled together with
         --xla_force_host_platform_device_count — exactly the tier-1 test
-        configuration.  bench's AOT `lower().compile()` donated chunk fn
+        configuration.  An AOT `lower().compile()` donated chunk fn
         does not exhibit this; callers that need donated buffers (TPU
         memory pressure) should compile that way and pass chunk_fn
         directly, or opt in here deliberately.

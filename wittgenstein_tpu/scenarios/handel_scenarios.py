@@ -83,6 +83,23 @@ def byzantine_configs(nodes: int, hidden: bool = False) -> List[SweepConfig]:
     return out
 
 
+def flagship_params(node_ct: int):
+    """The BASELINE.json flagship Handel configuration at `node_ct`
+    (benchmark/configs/handel-4096.json writes the same parameters out)."""
+    from ..protocols.handel import HandelParameters
+
+    return HandelParameters(
+        node_count=node_ct,
+        threshold=int(node_ct * 0.99),
+        pairing_time=3,
+        level_wait_time=50,
+        extra_cycle=10,
+        dissemination_period_ms=10,
+        fast_path=10,
+        nodes_down=0,
+    )
+
+
 def desync_configs(nodes: int) -> List[SweepConfig]:
     return [
         SweepConfig(

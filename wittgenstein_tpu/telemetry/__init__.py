@@ -14,8 +14,6 @@ capability of the reference, SURVEY §L5, captured *inside* jit):
   trace.py   SpanTracer — Chrome trace-event JSON for host phases
              (probe/compile/chunks); complements tools/profiling.py's
              device-level jax.profiler traces.
-  phases.py  the shared per-phase tick-cost harness behind
-             bench --phase-profile and scripts/phase_profile.py.
 
 Enable on any engine:
 
@@ -41,7 +39,6 @@ from .export import (
     prometheus_from_counters,
     read_run_records,
 )
-from .phases import engine_phase_fns, phase_means, scan_phase_seconds
 from .state import TelemetryConfig, TelemetryState, init_telemetry
 from .trace import SpanTracer, maybe_span, validate_chrome_trace
 
@@ -53,14 +50,11 @@ __all__ = [
     "TelemetryState",
     "counters",
     "done_counts_at",
-    "engine_phase_fns",
     "init_telemetry",
     "maybe_span",
     "pending_count",
-    "phase_means",
     "progress_series",
     "prometheus_from_counters",
     "read_run_records",
-    "scan_phase_seconds",
     "validate_chrome_trace",
 ]

@@ -14,7 +14,7 @@ module turns a FINAL state into:
 
 JSONL run records (`RunRecordWriter` / `read_run_records`) are the
 durable form: one self-describing line per run, append-only, safe for
-concurrent tails (the tpu_campaign jsonl pattern, given a schema).
+concurrent tails.
 
 Nothing here imports the engine — only numpy over pytree leaves — so the
 module is import-safe from anywhere (including engine/core.py's own
@@ -352,9 +352,8 @@ def prometheus_from_counters(c: dict, prefix: str = "witt") -> str:
 # -- JSONL run records -------------------------------------------------------
 class RunRecordWriter:
     """Append-only JSONL run records: one self-describing line per run
-    (ts + schema stamped), numpy leaves converted to plain python.  The
-    durable sibling of the BENCH stdout record — tail-safe like
-    tpu_campaign.jsonl."""
+    (ts + schema stamped), numpy leaves converted to plain python.  Safe
+    to tail while it is written."""
 
     def __init__(self, path: str):
         self.path = path
@@ -371,8 +370,8 @@ class RunRecordWriter:
 
 
 def read_run_records(path: str) -> List[dict]:
-    """Parse a JSONL run-record file (unparseable lines are skipped, the
-    campaign-log convention for torn tails)."""
+    """Parse a JSONL run-record file (unparseable lines are skipped: a torn
+    tail is not an error)."""
     out = []
     if os.path.exists(path):
         with open(path) as f:

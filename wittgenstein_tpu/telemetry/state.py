@@ -13,8 +13,7 @@ compiled program as a `TelemetryState` pytree side-car on `SimState`:
     updated in `latency_arrivals`, so the aggregation protocols whose
     channel messaging bypasses the generic store entirely
     (_agg_batched) still show per-mtype traffic;
-  * wheel / overflow high-water marks and the empty-ms jump census —
-    the signals bench's `--phase-profile` used to reconstruct post hoc;
+  * wheel / overflow high-water marks and the empty-ms jump census;
   * an optional fixed-size snapshot ring (one slot per
     `snapshot_every_ms` window of sim time) holding (time, done-node
     count, store-pending, cumulative node sent/received) so progress

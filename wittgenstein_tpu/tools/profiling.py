@@ -11,8 +11,7 @@ Usage:
             jax.block_until_ready(out)
 
 The trace directory opens in TensorBoard's profile plugin / Perfetto;
-`benchmark/xplane.py` reads it with nothing but JAX.  `bench.py` exposes
-`trace` via WITT_BENCH_PROFILE=<dir>.
+`benchmark/xplane.py` reads it with nothing but JAX.
 """
 
 from __future__ import annotations

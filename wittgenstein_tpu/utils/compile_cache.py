@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache — one rule for the
-tests, bench.py, chip_smoke.py and the scripts."""
+tests, the benchmark, chip_smoke.py and the scripts."""
 
 from __future__ import annotations
 
