@@ -204,3 +204,59 @@ def test_the_fast_path_keeps_whole_rows():
     rows = N * ((net.protocol.params.fast_path + 1) // 2)
     for i, b in enumerate(a.buckets):
         assert found[i] == [rows * b.w_pad] * 2, (i, found)
+
+
+# -- what the candidate merge lowers to (PR 34) ------------------------------
+# The merge engages on every (node, level) of every tick, so its counter
+# is static too: no sort and no gather under `witt.deliver.merge`, and
+# none of the seven argsorts left anywhere in the tick.  On a v5e the
+# gathers it replaced were 2.0 ms a tick each at 4096 nodes (PERF.md
+# section 6, PR 34).
+
+# stablehlo.gather bodies in the lowered Handel tick at 256 nodes since
+# PR 34 (88 and 100 at the parent, commit e662364, with 2 stablehlo.sort
+# each): a pick respelled as an index read would add to these
+HANDEL_TICK_GATHERS = {"handel_fused": 74, "handel_byz51": 86}
+
+
+def _scoped_primitives(jaxpr, scope: str, inside: bool = False, out=None):
+    """Names of the primitives traced under the named scope, through
+    every sub-jaxpr (an inner jaxpr's name stacks are relative to its
+    equation's)."""
+    from wittgenstein_tpu.analysis.annotations_check import _sub_jaxprs
+
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack)
+        subs = list(_sub_jaxprs(eqn.params))
+        if here and not subs:
+            out.append(eqn.primitive.name)
+        for sub in subs:
+            _scoped_primitives(sub, scope, here, out)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(HANDEL_TICK_GATHERS))
+def test_the_candidate_merge_has_no_sort_and_no_gather(name):
+    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+
+    net, state = PINS[name][0]()
+    tick = lambda s: net.protocol.tick(net, s)  # noqa: E731
+    prims = _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, DELIVER_SCOPES["merge"])
+    assert len(prims) > 100, prims  # the scope is live: the merge is under it
+    assert not {"sort", "gather", "dynamic_slice", "scatter"} & set(prims), sorted(set(prims))
+    text = _lowered(net, state, "tick")
+    assert len(re.findall(r"stablehlo\.sort", text)) == 0
+    gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
+    assert gathers <= HANDEL_TICK_GATHERS[name], gathers
+
+
+def test_gsf_keeps_its_own_merge_until_it_claims_in_its_own_cell():
+    """`gsf_batched.py` holds the same algorithm in its own lines, left as
+    it is so that `gsf-2048.single-r1` is the cell in which nothing may
+    move (ROADMAP A1 (c)): no merge scope in its program."""
+    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+
+    net, state = _gsf()
+    text = jax.jit(net.step).lower(state).as_text(debug_info=True)
+    assert "witt.protocol_tick" in text and DELIVER_SCOPES["merge"] not in text

@@ -22,7 +22,7 @@ import pytest
 
 from wittgenstein_tpu.core.registries import builder_name
 from wittgenstein_tpu.engine import replicate_state
-from wittgenstein_tpu.engine.core import ATTACK_SCOPES
+from wittgenstein_tpu.engine.core import ATTACK_SCOPES, DELIVER_SCOPES
 from wittgenstein_tpu.protocols.gsf import GSFSignatureParameters
 from wittgenstein_tpu.protocols.gsf_batched import make_gsf
 from wittgenstein_tpu.protocols.handel import Handel, HandelParameters
@@ -317,8 +317,15 @@ def test_sl601_passes_with_the_attack_scopes_live():
     assert check_annotations_entry(_attack_entry(), root=ROOT) == []
 
 
-@pytest.mark.parametrize("dead", sorted(ATTACK_SCOPES))
-def test_sl601_detects_a_dead_attack_scope(monkeypatch, dead):
+@pytest.mark.parametrize(
+    "registry, dead",
+    [
+        pytest.param(registry, name, id=name)
+        for registry in (ATTACK_SCOPES, DELIVER_SCOPES)
+        for name in sorted(registry)
+    ],
+)
+def test_sl601_detects_a_dead_attack_or_merge_scope(monkeypatch, registry, dead):
     import contextlib
 
     from wittgenstein_tpu.analysis.annotations_check import check_annotations_entry
@@ -327,14 +334,14 @@ def test_sl601_detects_a_dead_attack_scope(monkeypatch, dead):
     real = BatchedNetwork._scope
 
     def scope(self, name, scopes=None):
-        if scopes is ATTACK_SCOPES and name == dead:
+        if scopes is registry and name == dead:
             return contextlib.nullcontext()
         return real(self, name) if scopes is None else real(self, name, scopes)
 
     monkeypatch.setattr(BatchedNetwork, "_scope", scope)
     findings = check_annotations_entry(_attack_entry(), root=ROOT)
     assert [f.rule for f in findings] == ["SL601"]
-    assert ATTACK_SCOPES[dead] in findings[0].message
+    assert registry[dead] in findings[0].message
 
 
 def test_an_attack_free_program_carries_no_attack_scope():

@@ -26,7 +26,8 @@ A protocol that sends through the shared channel send path
 sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
 scripts/scope_profile.py are only as whole as these markers are live.
 A Handel built with an attack (`track_bad`) must carry the sub-scopes of
-engine.core.ATTACK_SCOPES that its attack runs.
+engine.core.ATTACK_SCOPES that its attack runs, and every Handel the
+candidate merge's engine.core.DELIVER_SCOPES.
 
 If this jax version exposes no `name_stack` on source_info, the
 presence half is skipped (API drift guard) — neutrality still runs.
@@ -137,6 +138,13 @@ def _check_presence(jax, name, net, state, path, line, suppress):
             scope for name, scope in ATTACK_SCOPES.items()
             if name != "inject" or net.protocol.params.byzantine_suicide
         )
+    from ..protocols.handel_batched import BatchedHandel
+
+    if isinstance(net.protocol, BatchedHandel):
+        # the deliver phase's candidate merge (ops/select.py)
+        from ..engine.core import DELIVER_SCOPES
+
+        required.extend(DELIVER_SCOPES.values())
     for want in required:
         if not any(want in s for s in scopes):
             f = _mk("SL601", path, line,

@@ -125,6 +125,14 @@ ATTACK_SCOPES = {
     "emission": "witt.attack.emission",  # dissemination moves on past blacklisted peers
 }
 
+# the deliver phase's candidate merge of an aggregation protocol
+# (ops/select.py `top_k_merge`, called by protocols/handel_batched.py
+# `_channel_deliver` on every (node, level) of every tick), nested under
+# the phase that delivers and switched by the same `annotate`.
+DELIVER_SCOPES = {
+    "merge": "witt.deliver.merge",  # keep the best K of the K resident and the 2 due candidates
+}
+
 
 class SimState(NamedTuple):
     """Per-replica simulation state; every field is a jnp array so the whole
@@ -458,8 +466,8 @@ class BatchedNetwork:
 
     def _scope(self, name: str, scopes: dict = ENGINE_PHASE_SCOPES):
         """jax.named_scope for engine phase `name` (ENGINE_PHASE_SCOPES,
-        or CHANNEL_SCOPES from the channel send path) when annotation is
-        on; a no-op context otherwise."""
+        or a protocol's CHANNEL_SCOPES, ATTACK_SCOPES, DELIVER_SCOPES)
+        when annotation is on; a no-op context otherwise."""
         if self.annotate:
             return jax.named_scope(scopes[name])
         return contextlib.nullcontext()

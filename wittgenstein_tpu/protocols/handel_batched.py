@@ -115,9 +115,10 @@ from jax import lax
 from ..core.node import Node, build_node_columns
 from ..core.registries import registry_network_latencies, registry_node_builders
 from ..engine import BatchedNetwork
-from ..engine.core import ATTACK_SCOPES
+from ..engine.core import ATTACK_SCOPES, DELIVER_SCOPES
 from ..engine.rng import hash32
 from ..ops.bitops import popcount_words, xor_shuffle
+from ..ops.select import take_slot, top_k_merge
 from ..utils.javarand import JavaRandom
 from ._agg_batched import INT32_MAX, BitsetAggBase
 from .handel import HandelParameters
@@ -685,23 +686,24 @@ class BatchedHandel(BitsetAggBase):
             skey = jnp.where(
                 keep, all_s * r4 + (r4 - 1 - jnp.minimum(all_rank, r4 - 1)), -1
             )
-            order = jnp.argsort(-skey, axis=2)[:, :, :K]  # top-K
-            top_keep = jnp.take_along_axis(skey, order, axis=2) >= 0
-            sel_rank = jnp.where(
-                top_keep, jnp.take_along_axis(all_rank, order, axis=2), INT32_MAX
-            )
-            sel_rel = jnp.take_along_axis(all_rel, order, axis=2)
-            sel_sig = jnp.take_along_axis(all_sig, order[..., None], axis=2)
+            with net._scope("merge", DELIVER_SCOPES):
+                top_key, (
+                    sel_rank, sel_rel, sel_s, sel_card, sel_wind, sel_aggi, sel_sig
+                ) = top_k_merge(
+                    skey,
+                    K,
+                    [all_rank, all_rel, all_s, all_card, all_wind, all_aggi, all_sig],
+                )
 
-            rank_pieces.append(sel_rank)
+            rank_pieces.append(jnp.where(top_key >= 0, sel_rank, INT32_MAX))
             rel_pieces.append(sel_rel)
             cand_sig_updates[f"cand_sig{i}"] = sel_sig.reshape(
                 n, b.nl * K * b.w_pad
             )
-            s_pieces.append(jnp.take_along_axis(all_s, order, axis=2))
-            card_pieces.append(jnp.take_along_axis(all_card, order, axis=2))
-            wind_pieces.append(jnp.take_along_axis(all_wind, order, axis=2))
-            aggi_pieces.append(jnp.take_along_axis(all_aggi, order, axis=2))
+            s_pieces.append(sel_s)
+            card_pieces.append(sel_card)
+            wind_pieces.append(sel_wind)
+            aggi_pieces.append(sel_aggi)
 
         flat = lambda ps: jnp.concatenate(ps, axis=1).reshape(n, (L - 1) * K)
         state = state._replace(
@@ -912,21 +914,17 @@ class BatchedHandel(BitsetAggBase):
             )
             in_score = jnp.where(inside & (score > 0), score, -1)
             k_in = jnp.argmax(in_score, axis=2)
-            sc_in = jnp.take_along_axis(in_score, k_in[..., None], axis=2)[..., 0]
+            sc_in = jnp.max(in_score, axis=2)
             exists_in = sc_in > 0
 
             out_rank = jnp.where(curated & ~inside, c_rank, INT32_MAX)
             k_out = jnp.argmin(out_rank, axis=2)
-            rk_out = jnp.take_along_axis(out_rank, k_out[..., None], axis=2)[..., 0]
+            rk_out = jnp.min(out_rank, axis=2)
             exists_out = rk_out < INT32_MAX
 
             kidx = jnp.where(exists_in, k_in, k_out)
-            lrank = jnp.where(
-                exists_in,
-                jnp.take_along_axis(c_rank, k_in[..., None], axis=2)[..., 0],
-                rk_out,
-            )
-            lrel = jnp.take_along_axis(c_rel, kidx[..., None], axis=2)[..., 0]
+            lrank = jnp.where(exists_in, take_slot(c_rank, k_in), rk_out)
+            lrel = take_slot(c_rel, kidx)
             lhas = exists_in | exists_out
             lbad = jnp.zeros((n, b.nl), bool)
 
