@@ -1,0 +1,47 @@
+"""A stated completion and rows that end at a horizon, guarded by the
+tier-1 run: the numpy cases of benchmark/tests/test_completion.py (its
+parts 1 to 3: `twin.compare` with and without `twin.completion` on
+recorded pairs and on arrays made by hand, `run_window` over a step made
+by hand, `check_invariants` with `done_at_ahead_ms`; no program runs, a
+second in all), loaded from that file so that there is one copy of them,
+as tests/test_benchmark_conservation.py loads test_conservation.py's.
+
+Part 4 of that file drives the draft `sanfermin-256` through the harness
+(two minutes) and stays with the tests that are run by hand; the cells
+that stand on these keys are `sanfermin-4096.*`.
+"""
+
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+for _p in (BENCH_DIR, os.path.join(BENCH_DIR, "tests")):
+    if _p not in sys.path:
+        sys.path.append(_p)  # `cells`, `run`, `twin`, `test_correct`: as benchmark/tests/conftest.py
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_tests_test_completion",
+    os.path.join(BENCH_DIR, "tests", "test_completion.py"),
+)
+_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cases)
+
+# part 4 starts where the draft's cell is made
+_PART_4 = _cases._draft_cell.__code__.co_firstlineno
+globals().update(
+    {
+        name: fn
+        for name, fn in vars(_cases).items()
+        if name.startswith("test_") and fn.__code__.co_firstlineno < _PART_4
+    }
+)
+
+
+def test_parts_one_to_three_are_loaded_and_part_four_is_not():
+    loaded = {name for name in globals() if name.startswith("test_")}
+    assert "test_with_no_completion_stated_compare_is_the_parents" in loaded
+    assert "test_rows_are_replaced_at_the_horizon_and_the_next_chunk_is_marked_first" in loaded
+    assert "test_the_drafts_rehearsal_runs_to_its_end_with_its_checks_passed" not in loaded
+    assert len(loaded) >= 16  # fifteen functions of theirs (34 cases) and this one

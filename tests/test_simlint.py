@@ -524,7 +524,7 @@ def test_sl601_detects_missing_scope():
     def factory():
         net, state = make_pingpong(32)
         net = copy.copy(net)
-        net._scope = lambda name: contextlib.nullcontext()
+        net._scope = lambda name, scopes=None: contextlib.nullcontext()
         return net, state
 
     findings = check_annotations_entry(

@@ -183,3 +183,43 @@ def test_handel_under_attack_compiles_for_one_chip(mosaic, handel_byz):
     text = _compile(lambda s: net.run_ms_batched(s, 20), shapes)
     assert "tpu_custom_call" in text
     assert "witt.attack.inject" in text and "witt.attack.emission" in text
+
+
+@pytest.fixture(scope="module")
+def sanfermin256():
+    """`sanfermin-4096` as the benchmark builds it (`make_sanfermin` with
+    its parameters and `factory_kwargs`), at 256 nodes with the store
+    scaled as the nodes are (capacity 65536 for 4096 nodes: 16 slots a
+    node), 4 rows: the first program on the generic message store."""
+    import json
+    import os
+
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.protocols.sanfermin import SanFerminSignatureParameters
+    from wittgenstein_tpu.protocols.sanfermin_batched import make_sanfermin
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "sanfermin-4096.json")) as f:
+        config = json.load(f)
+    scale = config["params"]["node_count"] // 256
+    params = SanFerminSignatureParameters(**{**config["params"], "node_count": 256, "threshold": 256})
+    net, state = make_sanfermin(params, capacity=config["factory_kwargs"]["capacity"] // scale)
+    assert not net.flat and (net.wheel_rows, net.overflow_capacity) == (512, 512)
+    return net, replicate_state(state, 4)
+
+
+def test_sanfermin_chunk_program_compiles_for_one_chip(topo, no_compile_cache, sanfermin256):
+    """The chunk program of `sharded_run_stats` on the message store, for
+    one described v5e chip: the time wheel's scatters, the insert ranks'
+    sorts and the delivery view's gathers under the replica axis, each
+    under its `witt.store.*` scope; no Mosaic call (ROADMAP B10)."""
+    from wittgenstein_tpu.engine.core import STORE_SCOPES
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+
+    net, states = sanfermin256
+    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
+    text = _run_and_reduce(net, 20)._jit_for(shapes).lower(shapes).compile().as_text()
+    for scope in STORE_SCOPES.values():
+        assert scope in text, scope
+    assert " sort(" in text and "scatter" in text
+    assert "tpu_custom_call" not in text

@@ -25,6 +25,9 @@ A protocol that sends through the shared channel send path
 (`_send_stacked` of protocols/_agg_batched.py) must also carry every
 sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
 scripts/scope_profile.py are only as whole as these markers are live.
+Every other protocol sends through the generic message store and must
+carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
+carries the view's and the repack's: its step still visits the store).
 A Handel built with an attack (`track_bad`) must carry the sub-scopes of
 engine.core.ATTACK_SCOPES that its attack runs, and every Handel the
 candidate merge's engine.core.DELIVER_SCOPES.
@@ -126,10 +129,17 @@ def _check_presence(jax, name, net, state, path, line, suppress):
         required.append("witt.protocol_tick")
     if _hook_traces_ops(jax, lambda s: net.protocol.tick_beat(net, s), state):
         required.append("witt.beat")
+    from ..engine.core import STORE_SCOPES
+
     if hasattr(net.protocol, "_send_stacked"):
         from ..engine.core import CHANNEL_SCOPES
 
         required.extend(CHANNEL_SCOPES.values())
+        # the channel replaces the store's insert; every step still
+        # gathers the (empty) delivery view and clears it
+        required.extend(v for k, v in STORE_SCOPES.items() if k != "insert")
+    else:
+        required.extend(STORE_SCOPES.values())
     if getattr(net.protocol, "track_bad", False):
         # Handel built with an attack: what the attack adds to a tick
         from ..engine.core import ATTACK_SCOPES

@@ -125,6 +125,17 @@ ATTACK_SCOPES = {
     "emission": "witt.attack.emission",  # dissemination moves on past blacklisted peers
 }
 
+# sub-scopes of the generic message store (the time wheel, its overflow
+# lane and the delivery view: every protocol that sends through
+# `apply_emission`), nested under the engine phase that runs them
+# (witt.send, witt.delivery, witt.fused_step, a protocol's tick) and
+# switched by the same `annotate`.
+STORE_SCOPES = {
+    "insert": "witt.store.insert",  # slot ranks, the wheel and overflow planes' scatters
+    "view": "witt.store.view",  # the due rows and the overflow lane gathered flat
+    "repack": "witt.store.repack",  # due entries cleared, visited rows dense again
+}
+
 # the deliver phase's candidate merge of an aggregation protocol
 # (ops/select.py `top_k_merge`, called by protocols/handel_batched.py
 # `_channel_deliver` on every (node, level) of every tick), nested under
@@ -466,7 +477,8 @@ class BatchedNetwork:
 
     def _scope(self, name: str, scopes: dict = ENGINE_PHASE_SCOPES):
         """jax.named_scope for engine phase `name` (ENGINE_PHASE_SCOPES,
-        or a protocol's CHANNEL_SCOPES, ATTACK_SCOPES, DELIVER_SCOPES)
+        or STORE_SCOPES, a protocol's CHANNEL_SCOPES, ATTACK_SCOPES,
+        DELIVER_SCOPES)
         when annotation is on; a no-op context otherwise."""
         if self.annotate:
             return jax.named_scope(scopes[name])
@@ -717,6 +729,38 @@ class BatchedNetwork:
         if self.payload_width and payload is None:
             payload = jnp.zeros((k, self.payload_width), dtype=jnp.int32)
         mtype_rows = jnp.broadcast_to(mtype, (k,)).astype(jnp.int32)
+        with self._scope("insert", STORE_SCOPES):
+            state, lost = self._insert_rows(
+                state, ok, arrival, from_idx, to_idx, mtype_rows, payload
+            )
+        if self.telemetry is not None:
+            # store accounting: every ok row is either inserted (wheel or
+            # overflow) or dropped (`lost`, the rows behind `_insert_rows`'
+            # scalar `overwritten`), so sent - dropped rows are live.
+            # HWMs sample post-insert, the only moment occupancy can peak.
+            with self._scope("telemetry"):
+                tele = state.tele
+                state = state._replace(
+                    tele=tele._replace(
+                        sent=count_by_type(tele.sent, ok, mtype_rows),
+                        dropped=count_by_type(tele.dropped, lost, mtype_rows),
+                        wheel_fill_hwm=jnp.maximum(
+                            tele.wheel_fill_hwm, jnp.max(state.whl_fill)
+                        ),
+                        ovf_hwm=jnp.maximum(
+                            tele.ovf_hwm,
+                            jnp.sum(state.ovf_valid.astype(jnp.int32)),
+                        ),
+                    )
+                )
+        return state
+
+    def _insert_rows(
+        self, state: SimState, ok, arrival, from_idx, to_idx, mtype_rows, payload
+    ):
+        """One emission's ok-rows into the wheel or the overflow lane.
+        Returns the state and the rows a full store dropped."""
+        k = ok.shape[0]
         n_ok = jnp.sum(ok.astype(jnp.int32))
         t = state.time
         w, b, v = self.wheel_rows, self.wheel_slots, self.overflow_capacity
@@ -809,29 +853,7 @@ class BatchedNetwork:
             state = state._replace(
                 ovf_payload=state.ovf_payload.at[pos].set(payload, mode="drop")
             )
-        if self.telemetry is not None:
-            # store accounting: every ok row is either inserted (wheel or
-            # overflow) or dropped (to_ovf & ~ofits — the rows behind the
-            # scalar `overwritten` above), so sent - dropped rows are live.
-            # HWMs sample post-insert, the only moment occupancy can peak.
-            with self._scope("telemetry"):
-                tele = state.tele
-                state = state._replace(
-                    tele=tele._replace(
-                        sent=count_by_type(tele.sent, ok, mtype_rows),
-                        dropped=count_by_type(
-                            tele.dropped, to_ovf & ~ofits, mtype_rows
-                        ),
-                        wheel_fill_hwm=jnp.maximum(
-                            tele.wheel_fill_hwm, jnp.max(state.whl_fill)
-                        ),
-                        ovf_hwm=jnp.maximum(
-                            tele.ovf_hwm,
-                            jnp.sum(state.ovf_valid.astype(jnp.int32)),
-                        ),
-                    )
-                )
-        return state
+        return state, to_ovf & ~ofits
 
     def apply_emissions(self, state: SimState, emissions) -> SimState:
         for em in emissions:
@@ -865,33 +887,34 @@ class BatchedNetwork:
         t = state.time
         w, b = self.wheel_rows, self.wheel_slots
         q = self._window()
-        rows = jnp.remainder(
-            t - q + 1 + jnp.arange(q, dtype=jnp.int32), jnp.int32(w)
-        )  # [q] distinct rows covering ticks (t-q, t]
-        wv = state.msg_valid[rows]  # [q, B]
-        wa = state.msg_arrival[rows]
-        wf = state.msg_from[rows]
-        wt = state.msg_to[rows]
-        wk = state.msg_type[rows]
-        wp = state.msg_payload[rows]  # [q, B, P]
+        with self._scope("view", STORE_SCOPES):
+            rows = jnp.remainder(
+                t - q + 1 + jnp.arange(q, dtype=jnp.int32), jnp.int32(w)
+            )  # [q] distinct rows covering ticks (t-q, t]
+            wv = state.msg_valid[rows]  # [q, B]
+            wa = state.msg_arrival[rows]
+            wf = state.msg_from[rows]
+            wt = state.msg_to[rows]
+            wk = state.msg_type[rows]
+            wp = state.msg_payload[rows]  # [q, B, P]
 
-        view_valid = jnp.concatenate([wv.reshape(-1), state.ovf_valid])
-        view_arrival = jnp.concatenate([wa.reshape(-1), state.ovf_arrival])
-        # the ONE widening point of the narrow-lane plan: protocols (and
-        # every engine consumer below) see int32 ids/types regardless of
-        # the storage dtypes, so kernels are unchanged by the plan
-        view_from = jnp.concatenate(
-            [wf.reshape(-1), state.ovf_from]
-        ).astype(jnp.int32)
-        view_to = jnp.concatenate(
-            [wt.reshape(-1), state.ovf_to]
-        ).astype(jnp.int32)
-        view_type = jnp.concatenate(
-            [wk.reshape(-1), state.ovf_type]
-        ).astype(jnp.int32)
-        view_payload = jnp.concatenate(
-            [wp.reshape(q * b, -1), state.ovf_payload], axis=0
-        )
+            view_valid = jnp.concatenate([wv.reshape(-1), state.ovf_valid])
+            view_arrival = jnp.concatenate([wa.reshape(-1), state.ovf_arrival])
+            # the ONE widening point of the narrow-lane plan: protocols (and
+            # every engine consumer below) see int32 ids/types regardless of
+            # the storage dtypes, so kernels are unchanged by the plan
+            view_from = jnp.concatenate(
+                [wf.reshape(-1), state.ovf_from]
+            ).astype(jnp.int32)
+            view_to = jnp.concatenate(
+                [wt.reshape(-1), state.ovf_to]
+            ).astype(jnp.int32)
+            view_type = jnp.concatenate(
+                [wk.reshape(-1), state.ovf_type]
+            ).astype(jnp.int32)
+            view_payload = jnp.concatenate(
+                [wp.reshape(q * b, -1), state.ovf_payload], axis=0
+            )
 
         due = view_valid & (view_arrival <= t)
         # delivery-time checks: down destination or cross-partition messages
@@ -1002,6 +1025,10 @@ class BatchedNetwork:
         to the slot prefix so whl_fill stays the next-free-slot index.
         `pstate` carries the protocol's post-deliver columns; the wheel
         fields are taken from the pre-view `state`."""
+        with self._scope("repack", STORE_SCOPES):
+            return self._clear_visited_rows_impl(pstate, state, ctx, due)
+
+    def _clear_visited_rows_impl(self, pstate, state, ctx, due) -> SimState:
         rows, wv, wa, wf, wt, wk, wp, q, b, _ = ctx
         keep = wv & ~due[: q * b].reshape(q, b)
         pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
@@ -1105,37 +1132,38 @@ class BatchedNetwork:
                 # the sort/cumsum/scatter repack is a constant fill (in
                 # flat mode the degenerate 1x1 row is never occupied and
                 # the same constants are what it already holds)
-                w_shape = (q, b)
-                state = pstate._replace(
-                    msg_valid=state.msg_valid.at[ctx[0]].set(
-                        jnp.zeros(w_shape, bool)
-                    ),
-                    msg_arrival=state.msg_arrival.at[ctx[0]].set(
-                        jnp.full(w_shape, INT_MAX, jnp.int32)
-                    ),
-                    msg_from=state.msg_from.at[ctx[0]].set(
-                        jnp.zeros(w_shape, dtype=self.lanes.idx)
-                    ),
-                    msg_to=state.msg_to.at[ctx[0]].set(
-                        jnp.zeros(w_shape, dtype=self.lanes.idx)
-                    ),
-                    msg_type=state.msg_type.at[ctx[0]].set(
-                        jnp.zeros(w_shape, dtype=self.lanes.mtype)
-                    ),
-                    msg_payload=(
-                        state.msg_payload.at[ctx[0]].set(
-                            jnp.zeros(
-                                w_shape + (self.payload_width,), jnp.int32
+                with self._scope("repack", STORE_SCOPES):
+                    w_shape = (q, b)
+                    state = pstate._replace(
+                        msg_valid=state.msg_valid.at[ctx[0]].set(
+                            jnp.zeros(w_shape, bool)
+                        ),
+                        msg_arrival=state.msg_arrival.at[ctx[0]].set(
+                            jnp.full(w_shape, INT_MAX, jnp.int32)
+                        ),
+                        msg_from=state.msg_from.at[ctx[0]].set(
+                            jnp.zeros(w_shape, dtype=self.lanes.idx)
+                        ),
+                        msg_to=state.msg_to.at[ctx[0]].set(
+                            jnp.zeros(w_shape, dtype=self.lanes.idx)
+                        ),
+                        msg_type=state.msg_type.at[ctx[0]].set(
+                            jnp.zeros(w_shape, dtype=self.lanes.mtype)
+                        ),
+                        msg_payload=(
+                            state.msg_payload.at[ctx[0]].set(
+                                jnp.zeros(
+                                    w_shape + (self.payload_width,), jnp.int32
+                                )
                             )
-                        )
-                        if self.payload_width
-                        else state.msg_payload
-                    ),
-                    whl_fill=state.whl_fill.at[ctx[0]].set(
-                        jnp.zeros(q, jnp.int32)
-                    ),
-                    ovf_valid=state.ovf_valid & ~due[q * b :],
-                )
+                            if self.payload_width
+                            else state.msg_payload
+                        ),
+                        whl_fill=state.whl_fill.at[ctx[0]].set(
+                            jnp.zeros(q, jnp.int32)
+                        ),
+                        ovf_valid=state.ovf_valid & ~due[q * b :],
+                    )
             else:
                 state = self._clear_visited_rows(pstate, state, ctx, due)
             state = self.apply_emissions(state, emissions)

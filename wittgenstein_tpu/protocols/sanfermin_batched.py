@@ -13,19 +13,40 @@ TPU-first design:
     { me ^ (bs + r) : r in [0, bs) } with bs = 2^(W-cpl-1), and the "exact"
     candidate (own-set index pick, SanFerminHelper.java:129-136) is r = 0
     (partner = me ^ bs).  No interval arithmetic at runtime — just XOR.
-  * pickNextNodes' used-candidate tracking collapses to ONE cursor per
-    node (levels never revisit): position 0 is the exact candidate,
-    positions >= 1 enumerate the rest of the block through a per-(node,
-    level) XOR bijection — a uniform-random untried pick, standing in for
-    the reference's index-order-with-shuffle (and its post-removal index
-    shift quirk, SanFerminHelper.java:123-157), which is not worth
-    reproducing bit-for-bit.
+  * pickNextNodes' used-index set collapses to ONE cursor per node
+    (levels never revisit), walked in the reference's own order
+    (SanFerminHelper.java:123-157), quirks included: a level's first call
+    takes the exact candidate (the block member at the node's own-set
+    index `idx`) and then candidate_count more by their index in the list
+    with that member REMOVED; every later call indexes the WHOLE list
+    again; both skip the indices used so far, `idx` among them.  So the
+    cursor is the next index to try, index i is member i + (i >= idx) in
+    the first call and member i after it (the member after the exact one
+    is never asked, the last member of the first call is asked twice),
+    and a call that finds no index left sends nothing and arms nothing:
+    the node is out of picks for good.  The order is load-bearing, not
+    cosmetic: every re-picker of a block asks its members 0, 1, 2, ... in
+    turn, and a node out of picks costs its exact partners of every later
+    level a reply timeout (a uniform walk of the block finishes 4% more
+    nodes than the reference and reads P50 12% early at 256 nodes).
+    The reference shuffles each call's list; with counter-based latencies
+    the order inside one multicast carries nothing.
   * pending_nodes is a packed absolute-id bitset [N, N/32]; reset on level
     entry, bit-tested on replies.
-  * one live timeout per node (re-armed on every send).  The oracle stacks
-    a timeout per send and fires ALL of them while the level is unchanged
-    (SanFerminSignature.java:356-366), so it can re-pick slightly faster
-    under repeated NO replies; documented approximation.
+  * reply timeouts are STACKED as the reference's (:356-366): every send
+    arms its own timeout at send time + reply_timeout in a per-node ring
+    of TIMEOUT_RING (deadline, level) slots, and every one that comes due
+    while `cpl` is still the level it was armed at re-picks, as does every
+    NO from a pending node.  One pick event runs per node and tick; more
+    wait in `resend` for the next tick.  A send that finds the ring full
+    is counted in `state.dropped` (the store's own "this run is not
+    exact" counter), never silently capped.  The ring's depth is from the
+    oracle: at 256 and 4096 nodes (candidate_count 1 and 4) no node ever
+    has a second live timeout at its level — a NO carries the REPLIER's
+    level, which is behind the request's (a replier ahead has the level
+    cached and answers OK), so the requester drops it at the level check
+    (:274) and the NO branch never re-picks — and 4 slots leave room for
+    the hand-made states of tests/test_sanfermin_batched.py that do stack.
   * same-tick transition races (multiple valid REQ/REP arrivals) resolve
     by lowest ring slot; the losers' content is simply not aggregated —
     the oracle's LIFO-in-ms processing picks an equally arbitrary winner
@@ -37,12 +58,10 @@ from __future__ import annotations
 from typing import Optional
 
 import jax.numpy as jnp
-import numpy as np
 
 from ..core.node import build_node_columns
 from ..core.registries import registry_network_latencies
 from ..engine import BatchedNetwork, BatchedProtocol, Emission
-from ..engine.rng import hash32
 from ..utils.more_math import log2
 from .sanfermin import SanFerminSignature, SanFerminSignatureParameters
 
@@ -53,6 +72,8 @@ class BatchedSanFermin(BatchedProtocol):
     MSG_TYPES = ["SWAP_REQ", "SWAP_REP_OK", "SWAP_REP_NO"]
     PAYLOAD_WIDTH = 2  # (level, agg_value)
     TICK_INTERVAL = 1  # timeouts + pairing commits need per-ms ticks
+    # slots of the per-node ring of stacked reply timeouts (module docstring)
+    TIMEOUT_RING = 4
 
     def __init__(self, params: SanFerminSignatureParameters):
         self.params = params
@@ -73,16 +94,14 @@ class BatchedSanFermin(BatchedProtocol):
         cache_ok = cache_ok.at[:, w - 1].set(True)
         # ... including its send bookkeeping (cursor/pending for the
         # exact-candidate + candidate_count initial contacts); the matching
-        # emission rows are built by initial_emissions from the same seed
+        # emission rows are built by initial_emissions from the same picks
         cc = max(1, self.params.candidate_count)
-        eng_seed = jnp.int32(np.int64(seed) & 0x7FFFFFFF)  # matches init_state
         ids = jnp.arange(n_nodes, dtype=jnp.int32)
         cpl0 = jnp.full(n_nodes, w - 1, jnp.int32)
         pending = jnp.zeros((n_nodes, self.n_words), jnp.uint32)
-        for j in range(1 + cc):
-            partner, ok = self._partner(
-                eng_seed, ids, cpl0, jnp.full(n_nodes, j, jnp.int32)
-            )
+        cursor0 = jnp.zeros(n_nodes, jnp.int32)
+        picks, cursor0 = self._picks(ids, cpl0, cursor0, jnp.ones(n_nodes, bool), cc)
+        for partner, ok in picks:
             pending = jnp.where(
                 ok[:, None], pending | self._onehot_words(partner), pending
             )
@@ -98,10 +117,16 @@ class BatchedSanFermin(BatchedProtocol):
             "cache_val": cache_val,
             "cache_ok": cache_ok,
             "pending": pending,
-            "cursor": jnp.full(n_nodes, 1 + cc, jnp.int32),
-            "resend": jnp.zeros(n_nodes, bool),  # NO-reply re-pick flag
-            "tmo_t": jnp.full(n_nodes, 1 + self.params.reply_timeout, jnp.int32),
-            "tmo_lvl": jnp.full(n_nodes, w - 1, jnp.int32),
+            "cursor": cursor0,
+            # pick events waiting for a tick of their own (NO replies from
+            # pending nodes, timeouts that came due together)
+            "resend": jnp.zeros(n_nodes, jnp.int32),
+            # the ring of stacked reply timeouts: deadline (0 = free) and
+            # the level it was armed at; slot 0 is the t=1 send's
+            "tmo_t": jnp.zeros((n_nodes, self.TIMEOUT_RING), jnp.int32)
+            .at[:, 0]
+            .set(1 + self.params.reply_timeout),
+            "tmo_lvl": jnp.full((n_nodes, self.TIMEOUT_RING), w - 1, jnp.int32),
             "sent_req": jnp.zeros(n_nodes, jnp.int32),
             "recv_req": jnp.zeros(n_nodes, jnp.int32),
         }
@@ -111,17 +136,28 @@ class BatchedSanFermin(BatchedProtocol):
         """Candidate-block size at prefix length cpl: 2^(W-cpl-1)."""
         return (jnp.int32(1) << (self.w - 1 - cpl)).astype(jnp.int32)
 
-    def _partner(self, seed, ids, cpl, position):
-        """The `position`-th candidate of node `ids` at level `cpl`:
-        position 0 = exact candidate (r=0), then an XOR-bijection walk of
-        the rest of the block.  Returns (partner, valid)."""
+    def _picks(self, ids, cpl, cursor, entering, cc):
+        """One call of pickNextNodes (SanFerminHelper.java:123-157) for
+        every node: `cursor` is the next index of the level's candidate
+        list to try, `entering` says the call is the level's first.  Returns
+        ([(partner, valid)] * (1 + cc), the cursor after the call): row 0
+        is the exact candidate (the first call's alone), rows 1..cc the
+        next cc indices that are neither used nor the own-set index `idx`;
+        the first call indexes the list with the exact candidate removed
+        (bs - 1 long: index i is member i + (i >= idx)), every later call
+        the whole list (bs long: index i is member i)."""
         bs = self._bs(cpl)
-        x = hash32(seed, ids, cpl, jnp.int32(0x5AFE)) & (bs - 1)
-        q = position - 1
-        p = q + (q >= x).astype(jnp.int32)  # skip the slot that maps to 0
-        r = jnp.where(position == 0, 0, p ^ x)
-        partner = ids ^ (bs + r)
-        return partner, position < bs
+        idx = ids & (bs - 1)
+        block = (ids ^ bs) & ~(bs - 1)
+        length = jnp.where(entering, bs - 1, bs)
+        picks = [(block | idx, entering)]
+        for _ in range(cc):
+            i = cursor + (cursor == idx).astype(jnp.int32)
+            valid = i < length
+            member = i + (entering & (i >= idx)).astype(jnp.int32)
+            picks.append((block | member, valid))
+            cursor = jnp.where(valid, i + 1, cursor)
+        return picks, cursor
 
     def _onehot_words(self, idx):
         """Absolute-id onehot over the packed [n_words] axis."""
@@ -137,23 +173,21 @@ class BatchedSanFermin(BatchedProtocol):
         w = words[rows, idx // 32]
         return (w >> (idx % 32).astype(jnp.uint32)) & jnp.uint32(1)
 
-    def _send_requests(self, state, mask, entering, proto):
+    def _send_requests(self, state, mask, picks, cursor, proto):
         """_send_to_nodes (SanFerminSignature.java:329-369): contact the
-        next candidates — exact-first on level entry, candidate_count per
-        re-pick — update pending/cursor, arm the timeout."""
-        cc = max(1, self.params.candidate_count)
-        k = 1 + cc
+        candidates of one `_picks` call (exact-first on level entry,
+        candidate_count per re-pick), update pending and the cursor (to
+        `cursor`, the call's), arm this send's own timeout.
+        Returns (proto, emission, sends that found the ring full)."""
+        k = len(picks)
         n = self.n_nodes
         ids = jnp.arange(n, dtype=jnp.int32)
-        cpl, cursor, agg = proto["cpl"], proto["cursor"], proto["agg"]
-        npick = jnp.where(entering, 1 + cc, cc)
+        cpl, agg = proto["cpl"], proto["agg"]
 
         rows_mask, rows_from, rows_to = [], [], []
         pending = proto["pending"]
-        for j in range(k):
-            pos = cursor + j
-            partner, in_block = self._partner(state.seed, ids, cpl, pos)
-            m = mask & (j < npick) & in_block
+        for partner, valid in picks:
+            m = mask & valid
             rows_mask.append(m)
             rows_from.append(ids)
             rows_to.append(partner)
@@ -176,19 +210,22 @@ class BatchedSanFermin(BatchedProtocol):
                 axis=1,
             ),
         )
+        free = proto["tmo_t"] == 0
+        arm = mask[:, None] & free & (jnp.cumsum(free.astype(jnp.int32), axis=1) == 1)
         proto = dict(
             proto,
             pending=pending,
-            cursor=jnp.where(mask, cursor + npick, cursor),
+            cursor=jnp.where(mask, cursor, proto["cursor"]),
             sent_req=proto["sent_req"]
             + jnp.sum(
                 jnp.stack(rows_mask, 1).astype(jnp.int32), axis=1
             ),
-            # re-arm the reply timeout (one live timeout per node)
-            tmo_t=jnp.where(mask, state.time + 1 + self.params.reply_timeout, proto["tmo_t"]),
-            tmo_lvl=jnp.where(mask, cpl, proto["tmo_lvl"]),
+            # this send's own reply timeout, in the ring's first free slot
+            # (register_task at net.time + replyTimeout, :356-366)
+            tmo_t=jnp.where(arm, state.time + self.params.reply_timeout, proto["tmo_t"]),
+            tmo_lvl=jnp.where(arm, cpl[:, None], proto["tmo_lvl"]),
         )
-        return proto, em
+        return proto, em, jnp.sum((mask & ~jnp.any(free, axis=1)).astype(jnp.int32))
 
     # -- message handling ----------------------------------------------------
     def deliver(self, net, state, deliver_mask):
@@ -280,10 +317,11 @@ class BatchedSanFermin(BatchedProtocol):
         proto["swap_add"] = jnp.where(has_t, add_val, proto["swap_add"])
         proto["swap_t"] = jnp.where(has_t, t + p.pairing_time, proto["swap_t"])
 
-        # NO replies from pending partners re-pick next candidates in the
-        # tick phase (flag survives until consumed)
-        got_no = jnp.zeros(n, bool).at[to].max(no_trigger, mode="drop")
-        proto["resend"] = proto["resend"] | got_no
+        # every NO from a pending partner is one more pick event for the
+        # tick phase (they wait in `resend` until consumed)
+        proto["resend"] = proto["resend"].at[to].add(
+            no_trigger.astype(jnp.int32), mode="drop"
+        )
 
         return state._replace(proto=proto), [reply_em]
 
@@ -328,45 +366,46 @@ class BatchedSanFermin(BatchedProtocol):
             descend[:, None], jnp.uint32(0), proto["pending"]
         )
         proto["cursor"] = jnp.where(descend, 0, proto["cursor"])
-        proto["resend"] = proto["resend"] & ~commit
+        # a level left takes its waiting pick events and its timeouts along
+        # (levels never revisit, so none of them could fire again)
+        proto["resend"] = jnp.where(commit, 0, proto["resend"])
+        tmo_t = jnp.where(commit[:, None], 0, proto["tmo_t"])
 
-        # 2. reply timeout (fires while the level is unchanged, :356-366)
-        tmo = (
-            ~proto["done"]
-            & (proto["tmo_t"] > 0)
-            & (t >= proto["tmo_t"])
-            & (proto["tmo_lvl"] == proto["cpl"])
+        # 2. reply timeouts: every one that comes due while the level is
+        # the one it was armed at is a pick event (:356-366); due slots
+        # are free again
+        due = (tmo_t > 0) & (t >= tmo_t)
+        fired = due & (proto["tmo_lvl"] == proto["cpl"][:, None])
+        proto["tmo_t"] = jnp.where(due, 0, tmo_t)
+        events = proto["resend"] + jnp.sum(fired.astype(jnp.int32), axis=1)
+
+        # 3. sends: level entry (exact-first) or ONE re-pick (timeout / NO);
+        # a node out of picks consumes its events and sends nothing
+        # ("is OUT", :334-338), the others keep theirs for the next tick
+        ids = jnp.arange(n, dtype=jnp.int32)
+        cc = max(1, p.candidate_count)
+        picks, cursor = self._picks(ids, proto["cpl"], proto["cursor"], descend, cc)
+        has_pick = descend | picks[1][1]
+        send = (descend | (events > 0)) & ~proto["done"] & has_pick
+        proto["resend"] = jnp.where(
+            has_pick & ~proto["done"], jnp.maximum(events - 1, 0), 0
         )
-        # disarm on fire (or when the level moved on); _send_requests
-        # re-arms for the nodes that actually send
-        stale = (proto["tmo_t"] > 0) & (t >= proto["tmo_t"])
-        proto["tmo_t"] = jnp.where(stale, 0, proto["tmo_t"])
-
-        # 3. sends: level entry (exact-first) or re-pick (timeout / NO)
-        send = (descend | tmo | proto["resend"]) & ~proto["done"]
-        send = send & (proto["cursor"] < self._bs(proto["cpl"]))
-        proto["resend"] = proto["resend"] & ~send
-        proto, em = self._send_requests(state, send, descend, proto)
-        state = state._replace(proto=proto)
+        proto, em, ring_full = self._send_requests(state, send, picks, cursor, proto)
+        state = state._replace(proto=proto, dropped=state.dropped + ring_full)
         return net.apply_emission(state, em)
 
     def initial_emissions(self, net, state):
         """The pre-applied t=1 goNextLevel's sends: every node contacts its
         exact candidate (+ candidate_count more).  The matching cursor /
         pending / timeout bookkeeping is already baked into proto_init
-        (same seed, same _partner walk), so this only builds the rows."""
+        (the same _picks call), so this only builds the rows."""
         cc = max(1, self.params.candidate_count)
         k = 1 + cc
         n = self.n_nodes
         ids = jnp.arange(n, dtype=jnp.int32)
         cpl = state.proto["cpl"]
-        rows_mask, rows_to = [], []
-        for j in range(k):
-            partner, in_block = self._partner(
-                state.seed, ids, cpl, jnp.full(n, j, jnp.int32)
-            )
-            rows_mask.append(in_block)
-            rows_to.append(partner)
+        picks, _ = self._picks(ids, cpl, jnp.zeros(n, jnp.int32), jnp.ones(n, bool), cc)
+        rows_to, rows_mask = zip(*picks)
         return [
             Emission(
                 mask=jnp.stack(rows_mask, 1).reshape(-1),

@@ -18,8 +18,10 @@ Differences from the batched SanFerminSignature worth naming:
     levels ahead), so the descent is a bounded unrolled loop over the
     log2(N) levels with shrinking masks.
 
-Shared machinery (XOR candidate blocks, position->partner bijection, the
-single live timeout approximation) comes from sanfermin_batched."""
+The XOR candidate blocks are sanfermin_batched's; the position->partner
+bijection (a uniform walk of the block) and the single live timeout are
+this module's own approximations, which sanfermin_batched has replaced by
+the reference's pick order and stacked timeouts."""
 
 from __future__ import annotations
 
