@@ -3,7 +3,8 @@
 Knobs (combinable):
   --depth D      channel depth (default 8): displacement-loss hypothesis
   --replicas R   batched replicas
-Prints quantiles + displaced counts vs the SAME oracle population used by
+Prints quantiles + displaced counts (and the fast path's commit rounds and
+landing high-water mark) vs the SAME oracle population used by
 scripts/parity_residual.py (oracle side re-run here for self-containment;
 cache it with --oracle-json to iterate on batched-only changes).
 """
@@ -69,6 +70,10 @@ def main():
     bq = np.percentile(done, QS)
     displaced = int(np.asarray(out.proto["displaced"]).sum())
     rcv = int(np.asarray(out.msg_received).sum())
+    # the fast path's commit over landing rows: rounds run (summed over
+    # ticks and replicas) and the most rows that landed in one tick
+    rounds = int(np.asarray(out.proto["commit_rounds"]).sum())
+    landing_peak = int(np.asarray(out.proto["landing_peak"]).max())
     print(json.dumps({
         "depth": args.depth,
         "replicas": args.replicas,
@@ -79,6 +84,8 @@ def main():
         "displaced_per_replica": round(displaced / args.replicas, 1),
         "received_total": rcv,
         "displaced_over_received": round(displaced / max(rcv, 1), 4),
+        "commit_rounds_per_replica": round(rounds / args.replicas, 1),
+        "landing_peak": landing_peak,
         "batched_s": round(dt, 1),
     }))
 

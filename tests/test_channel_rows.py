@@ -1,16 +1,22 @@
-"""The channel send path's row sets (PR 32): whole-run pins taken on the
-parent of the change that cut the commit scatters to each bucket's own
-rows, and the lowered programs' scatter update counts.
+"""The channel send path's row sets (PR 32, PR 38): whole-run pins taken
+on the parent of the change that cut the commit scatters to each bucket's
+own rows, the lowered programs' scatter update counts, and the
+sender-rows entry's commit over the rows that land against the whole-M
+commit of the same send.
 
-The pins are a position-weighted 32-bit checksum of EVERY state leaf
-after 300 simulated ms at 256 nodes (the benchmark's fingerprint, in
-numpy): the level-axis entry of `_send_stacked` only drops updates that
-were addressed to the dropped row, so no leaf may move by a bit.
+The pins are a position-weighted 32-bit checksum of EVERY state leaf the
+parent has after 300 simulated ms at 256 nodes (the benchmark's
+fingerprint, in numpy): the level-axis entry of `_send_stacked` only
+drops updates that were addressed to the dropped row, and the
+sender-rows entry (Handel's fast path) commits the landing rows alone,
+so no leaf may move by a bit.  The two counters PR 38 put beside
+`displaced` are left out by name.
 """
 
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
@@ -23,11 +29,20 @@ from wittgenstein_tpu.protocols.handel_batched import make_handel
 N = 256
 
 
+LANDING_COUNTERS = ("commit_rounds", "landing_peak")  # PR 38's, Handel's alone
+
+
 def checksum(tree) -> int:
     """One number for a whole state: per leaf the sum of its words times
-    a position weight (mod 2^32), the leaves' sums weighted again."""
+    a position weight (mod 2^32), the leaves' sums weighted again; over
+    the leaves the pins' parent had."""
     total = 0
-    for j, leaf in enumerate(jax.tree_util.tree_leaves(tree)):
+    leaves = [
+        leaf
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        if not any(name in jax.tree_util.keystr(path) for name in LANDING_COUNTERS)
+    ]
+    for j, leaf in enumerate(leaves):
         x = np.asarray(leaf)
         if x.dtype == np.bool_:
             x = x.astype(np.uint8)
@@ -101,6 +116,11 @@ def test_whole_run_checksum_is_the_parents(name):
     out = net.run_ms(state, 300)
     assert int(np.asarray(out.msg_received).sum()) > 0  # traffic ran
     assert checksum(out) == want, (name, checksum(out))
+    # the fast path ran its rounds over landing rows, but for the node
+    # mesh, whose exchange keeps all M rows
+    ran = name != "handel_node_mesh2"
+    for counter in LANDING_COUNTERS if name.startswith("handel") else ():
+        assert (int(out.proto[counter]) > 0) == ran, (name, counter)
 
 
 # -- what the lowered programs scatter --------------------------------------
@@ -136,17 +156,29 @@ def plane_updates(text, a, state):
     return found
 
 
-def commit_updates(n: int, k: int, level_axis: bool) -> int:
-    """Word updates of one send's two commit passes, from shapes alone:
-    every bucket over all M = N x (L-1) x k rows at its w_pad, or over its
-    own levels' rows."""
+def _geometry(n: int):
     from wittgenstein_tpu.protocols._agg_batched import BitsetAggBase
 
     a = BitsetAggBase.__new__(BitsetAggBase)
     a._init_geometry(n)
+    return a
+
+
+def commit_updates(n: int, k: int, level_axis: bool) -> int:
+    """Word updates of one send's two commit passes, from shapes alone:
+    every bucket over all M = N x (L-1) x k rows at its w_pad, or over its
+    own levels' rows."""
+    a = _geometry(n)
     levels = a.n_levels - 1
     per_row = [(b.nl if level_axis else levels) * b.w_pad for b in a.buckets]
     return 2 * n * k * sum(per_row)
+
+
+def round_updates(n: int, rows: int) -> int:
+    """Word updates of ONE round of a sender-rows send's two commit passes:
+    every bucket over `rows` rows at its w_pad, the whole M before PR 38
+    and the landing capacity since."""
+    return 2 * rows * sum(b.w_pad for b in _geometry(n).buckets)
 
 
 @pytest.mark.parametrize(
@@ -159,10 +191,23 @@ def commit_updates(n: int, k: int, level_axis: bool) -> int:
 )
 def test_commit_update_counts_from_shapes(send, n, k, before, after):
     """The benchmark's sends, word updates a send (both passes), before
-    PR 32 and since: 10.2x, 10.2x and 11.5x fewer.  Handel's fast path
-    (every tick, level is data) stays at 2 x 20,480 x 127 = 5,201,920."""
+    PR 32 and since: 10.2x, 10.2x and 11.5x fewer."""
     assert commit_updates(n, k, level_axis=False) == before, send
     assert commit_updates(n, k, level_axis=True) == after, send
+
+
+def test_fast_path_update_counts_from_shapes():
+    """Handel-4096's fast path, every tick, level a per-node register:
+    2 x 20,480 x 127 = 5,201,920 word updates a tick until PR 38; since,
+    2 x 1,536 x 127 = 390,144 a round, 13.3x fewer, and as many rounds as
+    the landing rows take (one on every tick of the measured runs, none
+    where nothing lands)."""
+    from wittgenstein_tpu.protocols._agg_batched import landing_capacity
+
+    m = 4096 * 5
+    assert round_updates(4096, m) == 5_201_920
+    assert landing_capacity(m) == 1536
+    assert round_updates(4096, landing_capacity(m)) == 390_144
 
 
 def _lowered(net, state, hook):
@@ -194,16 +239,212 @@ def test_a_static_level_send_scatters_only_its_buckets_rows(name, build, hook, k
         assert gathers <= PARENT_GSF_TICK_GATHERS
 
 
-def test_the_fast_path_keeps_whole_rows():
-    """Handel's every-tick send: its level is a per-node register, so
-    each bucket still carries all N x ceil(fast_path / 2) rows (ROADMAP
-    A1 (b), what is left)."""
+def test_the_fast_path_scatters_one_round_of_landing_rows():
+    """Handel's every-tick send (PR 38, ROADMAP A1 (b)(1)): its level is
+    a per-node register, so the claim's winners are compacted and every
+    scatter into an in_sig plane carries C = landing_capacity(M) rows at
+    the bucket's w_pad, inside the rounds' loop; none carries the
+    M = N x ceil(fast_path / 2) rows of the send."""
+    from wittgenstein_tpu.protocols._agg_batched import landing_capacity
+
     net, state = _handel_fused()
     a = net.protocol
-    found = plane_updates(_lowered(net, state, "tick"), a, state)
-    rows = N * ((net.protocol.params.fast_path + 1) // 2)
+    text = _lowered(net, state, "tick")
+    found = plane_updates(text, a, state)
+    m = N * ((a.params.fast_path + 1) // 2)
+    c = landing_capacity(m)
+    assert c < m / 8
     for i, b in enumerate(a.buckets):
-        assert found[i] == [rows * b.w_pad] * 2, (i, found)
+        assert found[i] == [c * b.w_pad] * 2, (i, found)
+    assert sum(sum(v) for v in found.values()) == round_updates(N, c)
+    assert "stablehlo.while" in text  # the rounds: a trip count that is data
+    for _operand, updates in _SCATTER.findall(text):
+        assert _dims(updates)[0] != m or len(_dims(updates)) == 1, updates
+
+
+# -- the commit over the rows that land (PR 38) ------------------------------
+# The sender-rows entry against the whole-M commit of the same send: the
+# flat entry, which every bucket still carries all M rows through, takes
+# the rows one by one with their low blocks cut beforehand.
+
+
+def _sender_rows_send(a, rng, r, density, crowd):
+    """A send as Handel's fast path makes it: every node offers its low
+    block of ITS level to r peers of that level, or (crowd) every node of
+    a half-block to ONE receiver, so that slots are contested and few
+    rows land."""
+    n = a.n_nodes
+    ids = np.arange(n, dtype=np.int32)
+    level = rng.integers(1, a.n_levels, size=n).astype(np.int32)
+    bs = a.lv_bs[level - 1][:, None]
+    if crowd:
+        off = np.broadcast_to(ids[:, None] & (bs - 1), (n, r))
+    else:
+        off = rng.integers(0, 1 << 30, size=(n, r)) & (bs - 1)
+    rel = (bs + off).astype(np.int32)
+    mask = rng.random((n, r)) < density
+    words = rng.integers(0, 2**32, size=(n, a.n_words), dtype=np.uint32)
+    return (
+        jnp.asarray(mask), jnp.asarray(ids[:, None]), jnp.asarray(ids[:, None] ^ rel),
+        jnp.asarray(level), jnp.asarray(words),
+    )
+
+
+def _flat(a, mask, frm, to, level, words, aux):
+    """The same send a row each, as the flat entry takes it."""
+    n, r = mask.shape
+    flat = lambda x: jnp.broadcast_to(x, (n, r)).reshape(-1)  # noqa: E731
+    content = [jnp.repeat(a._dyn_low(words, level, b), r, axis=0) for b in a.buckets]
+    return (
+        flat(mask), flat(frm), flat(to), flat(level[:, None]), content,
+        None if aux is None else flat(aux),
+    )
+
+
+def _gsf_small():
+    return make_gsf(gsf_params(node_count=64, threshold=32))
+
+
+LANDED_BUILDS = {"honest": _handel_fused, "byz51": _handel_byz, "gsf-aux": _gsf_small}
+# rows a round: 1 and 3 take many rounds, None is the send's own C, "M" one
+# round over every row; the spread send lands hundreds of rows, more than
+# its C = 128 (several rounds there too), the crowded ones few, with
+# displacements and evictions
+LANDED_CASES = [
+    (build, cap, traffic)
+    for build in sorted(LANDED_BUILDS)
+    for cap in (1, 3, None, "M")
+    for traffic in ("spread", "crowd", "none")
+    if cap in (None, "M") or traffic != "spread"  # hundreds of rounds of 1 or 3
+]
+
+
+def _patched_capacity(monkeypatch, a, cap, r=5):
+    """Rows a round for the case: `cap` to the internal helper through the
+    one function it asks, which reads the send's shape and nothing else."""
+    from wittgenstein_tpu.protocols import _agg_batched
+
+    m = a.n_nodes * r
+    own = _agg_batched.landing_capacity(m)
+    capacity = {None: own, "M": m}.get(cap, cap)
+    monkeypatch.setattr(_agg_batched, "landing_capacity", lambda rows: capacity)
+    return own, capacity
+
+
+def _send_both_ways(build, cap, traffic, monkeypatch):
+    net, state = LANDED_BUILDS[build]()
+    a = net.protocol
+    _patched_capacity(monkeypatch, a, cap)
+    rng = np.random.default_rng(0)
+    has_aux = "in_aux" in state.proto
+    landed = whole = state
+    sends = 3 if traffic == "crowd" else 1
+    density = {"spread": 0.6, "crowd": 0.9, "none": 0.0}[traffic]
+    for j in range(sends):
+        args = _sender_rows_send(a, rng, 5, density, traffic == "crowd")
+        aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1)), jnp.int32) if has_aux else None
+        # later sends leave earlier: their arrivals evict pending occupants
+        at = jnp.int32(2 * (sends - 1 - j))
+        landed = a._send_stacked(net, landed._replace(time=at), *args, aux=aux)
+        *flat, x = _flat(a, *args, aux)
+        whole = a._send_stacked(net, whole._replace(time=at), *flat, aux=x)
+    return a, landed, whole
+
+
+@pytest.mark.parametrize("build, cap, traffic", LANDED_CASES)
+def test_the_landing_rows_commit_equals_the_whole_send(build, cap, traffic, monkeypatch):
+    a, landed, whole = _send_both_ways(build, cap, traffic, monkeypatch)
+    names = ["in_key", "displaced"] + [f"in_sig{i}" for i in range(len(a.buckets))]
+    names += ["in_aux"] if "in_aux" in whole.proto else []
+    paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(whole)]
+    for name in names:
+        assert any(name in p for p in paths), name
+    for (path, x), y in zip(
+        jax.tree_util.tree_leaves_with_path(landed), jax.tree_util.tree_leaves(whole)
+    ):
+        path = jax.tree_util.keystr(path)
+        if not any(name in path for name in LANDING_COUNTERS):
+            assert (np.asarray(x) == np.asarray(y)).all(), (build, cap, traffic, path)
+    moved = int(np.asarray(whole.msg_received).sum())
+    assert (moved > 0) == (traffic != "none")
+    if traffic != "none":
+        assert any(np.asarray(whole.proto[f"in_sig{i}"]).any() for i in range(len(a.buckets)))
+    if traffic == "crowd":
+        assert int(whole.proto["displaced"]) > 0
+    if "in_aux" in whole.proto and traffic != "none":
+        assert np.asarray(whole.proto["in_aux"]).any()
+
+
+def _landing(monkeypatch, a, args) -> int:
+    """Rows of a send that the claim lets land: `winner | fresh_win` as
+    `_send_stacked` hands them to the commit."""
+    from wittgenstein_tpu.protocols._agg_batched import BitsetAggBase
+
+    seen = []
+    real = BitsetAggBase._commit_landed
+
+    def spy(self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, *rest):
+        seen.append(int(np.asarray(winner | fresh_win).sum()))
+        return real(self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, *rest)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(BitsetAggBase, "_commit_landed", spy)
+        a._send_stacked(*args)
+    (landing,) = seen
+    return landing
+
+
+@pytest.mark.parametrize("build", ["honest", "byz51"])
+@pytest.mark.parametrize("cap", [1, 3, None, "M"])
+@pytest.mark.parametrize("traffic", ["dense", "none"])
+def test_the_counters_read_the_rounds_and_the_landing_rows(build, cap, traffic, monkeypatch):
+    """One send from a fresh state: `commit_rounds` reads ceil(landing /
+    capacity) and `landing_peak` the landing count, which the dense send
+    puts above the send's own C (many rounds at every capacity but M)."""
+    net, state = LANDED_BUILDS[build]()
+    a = net.protocol
+    own, capacity = _patched_capacity(monkeypatch, a, cap)
+    density = {"dense": 0.9, "none": 0.0}[traffic]
+    args = _sender_rows_send(a, np.random.default_rng(1), 5, density, False)
+    landing = _landing(monkeypatch, a, (net, state, *args))
+    out = a._send_stacked(net, state, *args)
+    assert int(out.proto["landing_peak"]) == landing
+    assert int(out.proto["commit_rounds"]) == -(-landing // capacity)
+    if traffic == "dense":
+        assert landing > own  # a tick built so that more than C rows land
+        assert (int(out.proto["commit_rounds"]) > 1) == (cap != "M")
+    else:
+        assert landing == 0 and int(out.proto["commit_rounds"]) == 0
+    # a second, empty send adds no round and keeps the high-water mark
+    none = _sender_rows_send(a, np.random.default_rng(2), 5, 0.0, False)
+    again = a._send_stacked(net, out._replace(time=jnp.int32(3)), *none)
+    for counter in LANDING_COUNTERS:
+        assert int(again.proto[counter]) == int(out.proto[counter])
+
+
+@pytest.mark.parametrize("build", ["honest", "byz51"])
+@pytest.mark.parametrize("cap", [3, None])
+def test_rounds_under_vmap_equal_the_single_runs(build, cap, monkeypatch):
+    """Two rows with different landing counts (one lands nothing at
+    all): the batched loop runs until the slower row is through and each
+    row's planes and counters equal its single run's."""
+    net, state = LANDED_BUILDS[build]()
+    a = net.protocol
+    _patched_capacity(monkeypatch, a, cap)
+    busy = _sender_rows_send(a, np.random.default_rng(3), 5, 0.9, False)
+    quiet = _sender_rows_send(a, np.random.default_rng(4), 5, 0.0, False)
+    few = _sender_rows_send(a, np.random.default_rng(5), 5, 0.01, False)
+    for pair in ((busy, quiet), (few, busy)):
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *pair)
+        states = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), state)
+        out = jax.vmap(lambda s, args: a._send_stacked(net, s, *args))(states, stacked)
+        singles = [a._send_stacked(net, state, *args) for args in pair]
+        assert int(singles[0].proto["commit_rounds"]) != int(singles[1].proto["commit_rounds"])
+        for j, single in enumerate(singles):
+            for (path, x), y in zip(
+                jax.tree_util.tree_leaves_with_path(out), jax.tree_util.tree_leaves(single)
+            ):
+                assert (np.asarray(x[j]) == np.asarray(y)).all(), (j, jax.tree_util.keystr(path))
 
 
 # -- what the candidate merge lowers to (PR 34) ------------------------------
@@ -215,8 +456,13 @@ def test_the_fast_path_keeps_whole_rows():
 
 # stablehlo.gather bodies in the lowered Handel tick at 256 nodes since
 # PR 34 (88 and 100 at the parent, commit e662364, with 2 stablehlo.sort
-# each): a pick respelled as an index read would add to these
-HANDEL_TICK_GATHERS = {"handel_fused": 74, "handel_byz51": 86}
+# each): a pick respelled as an index read would add to these.  74 and
+# 86 until PR 38, which added the seven reads of a commit round under
+# `witt.channel.compact` (the landing rows' receiver, level, slot, rel,
+# two win flags and their senders' words: 14 by this count, which finds
+# the generic form's name twice an op) and took out the six table reads
+# of `_dyn_low` (block size and width are arithmetic on the level now)
+HANDEL_TICK_GATHERS = {"handel_fused": 82, "handel_byz51": 94}
 
 
 def _scoped_primitives(jaxpr, scope: str, inside: bool = False, out=None):
@@ -246,7 +492,10 @@ def test_the_candidate_merge_has_no_sort_and_no_gather(name):
     assert len(prims) > 100, prims  # the scope is live: the merge is under it
     assert not {"sort", "gather", "dynamic_slice", "scatter"} & set(prims), sorted(set(prims))
     text = _lowered(net, state, "tick")
-    assert len(re.findall(r"stablehlo\.sort", text)) == 0
+    # none of the merge's seven argsorts: the one sort left in the tick
+    # puts the landing rows of the fast path's send first (PR 38)
+    assert len(re.findall(r"stablehlo\.sort", text)) == 1
+    assert _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, "witt.channel.compact").count("sort") == 1
     gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
     assert gathers <= HANDEL_TICK_GATHERS[name], gathers
 

@@ -24,7 +24,9 @@ flipped off).
 A protocol that sends through the shared channel send path
 (`_send_stacked` of protocols/_agg_batched.py) must also carry every
 sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
-scripts/scope_profile.py are only as whole as these markers are live.
+scripts/scope_profile.py are only as whole as these markers are live
+(`compact` where the state carries the counters its rounds feed,
+`commit_rounds`: Handel's fast path, the one sender-rows send).
 Every other protocol sends through the generic message store and must
 carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
 carries the view's and the repack's: its step still visits the store).
@@ -134,7 +136,10 @@ def _check_presence(jax, name, net, state, path, line, suppress):
     if hasattr(net.protocol, "_send_stacked"):
         from ..engine.core import CHANNEL_SCOPES
 
-        required.extend(CHANNEL_SCOPES.values())
+        required.extend(
+            scope for name, scope in CHANNEL_SCOPES.items()
+            if name != "compact" or "commit_rounds" in state.proto
+        )
         # the channel replaces the store's insert; every step still
         # gathers the (empty) delivery view and clears it
         required.extend(v for k, v in STORE_SCOPES.items() if k != "insert")

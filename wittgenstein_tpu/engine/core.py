@@ -112,6 +112,7 @@ CHANNEL_SCOPES = {
     "arrivals": "witt.channel.arrivals",  # who arrives when: latency, counters, keys, slot
     "readdress": "witt.channel.readdress",  # content from sender to receiver bit space
     "claim": "witt.channel.claim",  # which offer wins which slot; displacement
+    "compact": "witt.channel.compact",  # a sender-rows send: the landing rows to the front, a round's reads
     "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
 }
 

@@ -35,14 +35,33 @@ this turns ~12 unrolled per-level bodies x 4 phases (plus ~24 per-level
 send calls at ~700 StableHLO lines each) into 7 bucket bodies and 2
 stacked sends, which is what lets the flagship config compile.
 
-The send path (_send_stacked) has two entries and one algorithm, claim
+The send path (_send_stacked) has three entries and one algorithm, claim
 by key then commit by bucket; which rows a bucket's re-addressing and
 its two commit scatters run over is read from the shape of the input:
 
-  * level as DATA — mask/from/to/level [M], content[i] [M, w_pad]
-    (Handel's fast path, whose level is a per-node register): a row may
-    belong to any bucket, so every bucket carries all M rows and routes
-    the rows of other buckets' levels to the dropped row;
+  * a row each — mask/from/to/level [M], content[i] [M, w_pad]: the
+    level is DATA and a row may belong to any bucket, so every bucket
+    carries all M rows and routes the rows of other buckets' levels to
+    the dropped row.  No protocol sends this way any more; it is the
+    form the other two reduce to (under a node mesh, in the tests);
+  * rows by SENDER — mask/to [N, r], level [N], content the senders'
+    full-width vectors [N, W] (Handel's fast path, whose level is a
+    per-node register; r = ceil(fast_path / 2)): the level is data too,
+    but few rows land — a node fires only in the two ticks after it
+    completes a level, and a landing row is in ONE bucket — so after the
+    claim the rows that won a slot are compacted to the front of a row
+    list (_commit_landed) and only they are read, cut to their level's
+    low block (_dyn_low), re-addressed and scattered, C rows a round, in
+    as many rounds as they take (a loop whose trip count is data: none
+    where nothing lands; under vmap, until the batch's slowest row is
+    through).  No row is lost or deferred.  C is landing_capacity(M), a
+    function of the send's shape alone: 7.5% of M up to a multiple of
+    128, 1536 of Handel-4096's M = 20,480, chosen from the landing rows
+    a tick of whole 4096-node runs (PERF.md section 5: at most 1532 over
+    eight honest runs, 156 under the byz20 attack, none on 15% and 72%
+    of the ticks), so that one round covers a tick.  Where the state
+    carries them (Handel's), proto["commit_rounds"] sums the rounds run
+    and proto["landing_peak"] keeps the most rows that landed in a tick;
   * level as an AXIS — mask/from/to [N, L-1, k] and no level, content[i]
     the [N, nl, w_pad] block stack as _lows gives it (the dissemination
     beats, k = 1; GSF's accelerated calls, k = accelerated_calls_count):
@@ -52,10 +71,11 @@ its two commit scatters run over is read from the shape of the input:
     about a tenth of the word updates (tests/test_channel_rows.py).
 
 Arrivals and the claim are scalar per row and run over the flat M rows
-in the same row order in both; the state after a send is bit-identical
-(the level-axis entry only loses updates addressed to the dropped row).
-Under a node mesh a row's level is data again after the all_to_all, so
-the level-axis entry pads its blocks back to [M, w_pad] there.
+in the same row order in all three; the state after a send is
+bit-identical (the level-axis and the sender-rows entries only lose
+updates addressed to the dropped row).  Under a node mesh a row's level
+is data again after the all_to_all, so both pad their rows back to
+[M, w_pad] there.
 
 Keys pack (absolute_arrival << rel_bits) | rel — no per-tick countdown
 (see _advance_channel) — which bounds a sim at 2^(31-rel_bits) ms
@@ -79,6 +99,13 @@ from ..ops.bitops import lowest_set_bit, popcount_words, xor_shuffle
 
 INT32_MAX = np.int32(2**31 - 1)
 MAX_NODES = 1 << 14  # int32 key-packing headroom
+
+
+def landing_capacity(m: int) -> int:
+    """Rows a round of the sender-rows commit carries, from the send's M
+    rows alone: 3/40 of them, up to a multiple of 128 (see the module
+    docstring for the histogram behind it)."""
+    return min(m, -(-3 * m // 40 // 128) * 128)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,7 +193,6 @@ class BitsetAggBase(BatchedProtocol):
         self.buckets = [Bucket(tuple(lv), wp) for lv, wp in buckets]
 
         # static per-level tables (stacked [L-1] vectors, level-1 at index 0)
-        self.lv_w = np.asarray(self.w[1:], np.int32)  # exact widths
         self.lv_bs = np.asarray(self.bs[1:], np.int32)  # block sizes
 
     # -- stacked block views -------------------------------------------------
@@ -241,9 +267,10 @@ class BitsetAggBase(BatchedProtocol):
     def _dyn_low(self, x, level, b: Bucket):
         """Sender-space outgoing content at a DYNAMIC per-node level
         (valid where level is inside bucket b): [N, W], [N] -> [N, w_pad]."""
-        lv = jnp.clip(level, 1, self.n_levels - 1) - 1
-        bs = jnp.asarray(self.lv_bs)[lv]
-        w = jnp.asarray(self.lv_w)[lv]
+        # bs_l = 2^(l-1) and w_l = max(1, bs_l / 32) by arithmetic: a
+        # table read would be a gather a row
+        bs = jnp.int32(1) << (jnp.clip(level, 1, self.n_levels - 1) - 1)
+        w = jnp.maximum(bs >> 5, 1)
         out = x[..., : b.w_pad]
         if b.w_pad == 1 and self.bs[b.lo] < 32:
             # sub-word levels: bits [0, bs) of word 0 (bs may be 32; the
@@ -384,10 +411,17 @@ class BitsetAggBase(BatchedProtocol):
         per-(receiver, level, slot) channel in ONE body: earliest arrival
         wins an arrival slot, the newest offer always takes the fresh slot.
 
-        Level as data: mask/from_idx/to_idx/level [M] (level in [1, L-1]);
+        A row each: mask/from_idx/to_idx/level [M] (level in [1, L-1]);
         content: list aligned with self.buckets of [M, w_pad] SENDER-space
         words (only rows whose level lies in the bucket need valid values);
         aux: optional [M] int32 stored per slot in proto["in_aux"].
+
+        Rows by sender: mask [N, r], from_idx/to_idx/aux anything that
+        broadcasts to it, level [N] (a sender's r rows share its level),
+        content the senders' full-width [N, W] vectors: row [n, c] carries
+        the low block of content[n] at level[n].  The flat row order is
+        the axis order; after the claim only the rows that land are
+        committed (_commit_landed).
 
         Level as an axis: mask [N, L-1, k], from_idx/to_idx/aux anything
         that broadcasts to it, level None: row [n, j, c] is a level-(j+1)
@@ -407,19 +441,33 @@ class BitsetAggBase(BatchedProtocol):
         scope = functools.partial(net._scope, scopes=CHANNEL_SCOPES)
         mesh = getattr(net, "node_mesh", None)
         axis = mask.shape if mask.ndim == 3 else None  # rows on a level axis
-        if axis is not None:
+        words = None  # rows by sender, whose landing rows alone are committed
+        if mask.ndim == 2:
+            if mesh is None:
+                words = content
+            else:
+                # node-sharded, the exchange has its own capacity logic:
+                # a row each, as the flat entry takes them
+                content = [
+                    jnp.repeat(self._dyn_low(content, level, b), mask.shape[1], axis=0)
+                    for b in self.buckets
+                ]
+            level = level[:, None]
+        elif axis is not None:
             if level is not None or axis[1] != self.n_levels - 1:
                 raise ValueError(
                     "a level-axis send is [N, L-1, k] and numbers its own "
                     f"levels: got {axis} with level {level!r}"
                 )
             level = jnp.arange(1, self.n_levels, dtype=jnp.int32)[None, :, None]
+        if mask.ndim > 1:
+            rows = mask.shape
             mask, from_idx, to_idx, level = (
-                jnp.broadcast_to(x, axis).reshape(-1)
+                jnp.broadcast_to(x, rows).reshape(-1)
                 for x in (mask, from_idx, to_idx, level)
             )
             if aux is not None:
-                aux = jnp.broadcast_to(aux, axis).reshape(-1)
+                aux = jnp.broadcast_to(aux, rows).reshape(-1)
         # node-sharded, a row's level is data again after the exchange:
         # every bucket carries all M rows there, whichever the entry
         cut = axis is not None and mesh is None
@@ -479,20 +527,21 @@ class BitsetAggBase(BatchedProtocol):
             # shared by both commit passes; r0 < bs keeps the permutation
             # inside the level block, and rows routed away from the bucket
             # get r0 = 0 so the (dropped) shuffle stays in range
-            if axis is not None:
-                content = [
-                    self._level_rows(c, b, axis, whole=not cut)
-                    for c, b in zip(content, self.buckets)
-                ]
             r0_row = rel & ((jnp.int32(1) << (level - 1)) - 1)  # bs_l = 2^(l-1)
-            rows = [
-                self._bucket_rows(b, level, axis if cut else None)
-                for b in self.buckets
-            ]
-            cnt_list = [
-                xor_shuffle(c.astype(jnp.uint32), own(r0_row, 0))
-                for own, c in zip(rows, content)
-            ]
+            if words is None:
+                if axis is not None:
+                    content = [
+                        self._level_rows(c, b, axis, whole=not cut)
+                        for c, b in zip(content, self.buckets)
+                    ]
+                rows = [
+                    self._bucket_rows(b, level, axis if cut else None)
+                    for b in self.buckets
+                ]
+                cnt_list = [
+                    xor_shuffle(c.astype(jnp.uint32), own(r0_row, 0))
+                    for own, c in zip(rows, content)
+                ]
 
         if mesh is not None:
             # node-axis sharding: the channel commit goes through an
@@ -538,14 +587,27 @@ class BitsetAggBase(BatchedProtocol):
             win_to = jnp.where(winner, to_idx, self.n_nodes)
             fwin_to = jnp.where(fresh_win, to_idx, self.n_nodes)
 
-        with scope("commit"):
-            for i, (b, own) in enumerate(zip(self.buckets, rows)):
-                updates[f"in_sig{i}"] = self._commit_bucket(
-                    updates[f"in_sig{i}"], b,
-                    own(win_to, self.n_nodes), own(fwin_to, self.n_nodes),
-                    own(level) - b.lo, own(slot), cnt_list[i],
-                )
-            if aux is not None:
+        sig_names = [f"in_sig{i}" for i in range(len(self.buckets))]
+        if words is None:
+            with scope("commit"):
+                for name, b, own, cnt in zip(sig_names, self.buckets, rows, cnt_list):
+                    updates[name] = self._commit_bucket(
+                        updates[name], b,
+                        own(win_to, self.n_nodes), own(fwin_to, self.n_nodes),
+                        own(level) - b.lo, own(slot), cnt,
+                    )
+        else:
+            sigs, rounds, landing = self._commit_landed(
+                [updates[name] for name in sig_names], words,
+                to_idx, level, slot, r0_row, winner, fresh_win,
+                landing_capacity(mask.shape[0]), scope,
+            )
+            updates.update(zip(sig_names, sigs))
+            if "commit_rounds" in proto:
+                updates["commit_rounds"] = proto["commit_rounds"] + rounds
+                updates["landing_peak"] = jnp.maximum(proto["landing_peak"], landing)
+        if aux is not None:
+            with scope("commit"):
                 new_aux = proto["in_aux"].at[win_to, col].set(
                     aux.astype(jnp.int32), mode="drop"
                 )
@@ -576,9 +638,10 @@ class BitsetAggBase(BatchedProtocol):
     def _bucket_rows(b: Bucket, level, axis):
         """Which of a send's M rows bucket b's re-addressing and commit run
         over, as own(x, fill): a per-row [M] vector -> the bucket's rows.
-        Level as data (axis None): all M rows, those of other buckets'
-        levels replaced by `fill` where one is given (the dropped row, a
-        zero shift).  Level as an axis [N, L-1, k]: the rows of levels
+        Level as data (axis None: a row each, or the C rows of a round of
+        _commit_landed): all of them, those of other buckets' levels
+        replaced by `fill` where one is given (the dropped row, a zero
+        shift).  Level as an axis [N, L-1, k]: the rows of levels
         b.lo..b.hi alone, cut by reshape and static slice (an index array
         would lower to a gather); nothing is left to route."""
         if axis is None:
@@ -598,6 +661,69 @@ class BitsetAggBase(BatchedProtocol):
         fcols = ((li * ss + ss - 1) * b.w_pad)[:, None] + cw
         sig = sig.at[win_to[:, None], cols].set(cnt, mode="drop")
         return sig.at[fwin_to[:, None], fcols].set(cnt, mode="drop")
+
+    def _commit_landed(
+        self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, capacity, scope
+    ):
+        """The commit of a sender-rows send over the rows that land: the
+        claim's winners (`winner | fresh_win` of the flat [M] rows, each
+        the only one at its plane cell, so their order is free) are
+        compacted to the front of a row list and committed `capacity`
+        rows a round, as many rounds as the landing rows take (none for
+        none): no row is lost or deferred.  A round reads its rows'
+        scalars (`r0` the xor of the re-addressing) and their senders'
+        full-width `words` [N, W] (row m's sender is m // r), cuts and re-addresses each bucket's low block
+        and runs `_commit_bucket` over `capacity` rows; rows of other
+        buckets' levels and the list's tail go to the dropped row.
+        Under vmap the loop runs until the batch's slowest row is
+        through (a finished row's rounds write nothing).  Returns the
+        planes, the rounds this send took and the rows that landed."""
+        m, n = to_idx.shape[0], self.n_nodes
+        r = m // words.shape[0]
+        with scope("compact"):
+            lands = winner | fresh_win
+            landing = jnp.sum(lands.astype(jnp.int32))
+            # landing rows' numbers first, ascending; m marks the rest
+            # and the padding up to whole rounds
+            order = jnp.sort(jnp.where(lands, jnp.arange(m, dtype=jnp.int32), m))
+            order = jnp.concatenate(
+                [order, jnp.full(-m % capacity, m, jnp.int32)]
+            )
+
+        def one_round(carry):
+            k, sigs = carry
+            with scope("compact"):
+                sel = lax.dynamic_slice(order, (k * capacity,), (capacity,))
+                live = sel < m
+                sel = jnp.where(live, sel, 0)
+                to_c, level_c, slot_c, r0_c = (
+                    x[sel] for x in (to_idx, level, slot, r0)
+                )
+                win_to = jnp.where(live & winner[sel], to_c, n)
+                fwin_to = jnp.where(live & fresh_win[sel], to_c, n)
+                words_c = words[sel // r]
+            owns = [self._bucket_rows(b, level_c, None) for b in self.buckets]
+            with scope("readdress"):
+                cnts = [
+                    xor_shuffle(self._dyn_low(words_c, level_c, b), own(r0_c, 0))
+                    for own, b in zip(owns, self.buckets)
+                ]
+            with scope("commit"):
+                sigs = [
+                    self._commit_bucket(
+                        sig, b, own(win_to, n), own(fwin_to, n),
+                        level_c - b.lo, slot_c, cnt,
+                    )
+                    for sig, b, own, cnt in zip(sigs, self.buckets, owns, cnts)
+                ]
+            return k + 1, sigs
+
+        rounds, sigs = lax.while_loop(
+            lambda carry: carry[0] * capacity < landing,
+            one_round,
+            (jnp.int32(0), list(sigs)),
+        )
+        return sigs, rounds, landing
 
     # -- node-sharded channel commit (explicit all_to_all exchange) ----------
     def _channel_commit_sharded(

@@ -290,6 +290,10 @@ class BatchedHandel(BitsetAggBase):
             "in_key": in_key,
             **in_sigs,
             "displaced": jnp.int32(0),
+            # the fast path's commit (_commit_landed): rounds run, summed
+            # over ticks, and the most rows that landed in one tick
+            "commit_rounds": jnp.int32(0),
+            "landing_peak": jnp.int32(0),
             **self._not_ok_init(n),
             # stage 2: candidate buffer (toVerifyAgg)
             "cand_rank": jnp.full((n, (L - 1) * K), INT32_MAX, jnp.int32),
@@ -543,10 +547,6 @@ class BatchedHandel(BitsetAggBase):
                 & (ks < bs_sel[:, None])
             )
             rel_fp = bs_sel[:, None] + ((fp_off[:, None] + ks) & (bs_sel[:, None] - 1))
-            content = [
-                jnp.repeat(self._dyn_low(inc, fp_level, b), r, axis=0)
-                for b in self.buckets
-            ]
             state = state._replace(
                 proto=dict(
                     state.proto,
@@ -555,14 +555,10 @@ class BatchedHandel(BitsetAggBase):
                     fp_off=fp_off,
                 )
             )
+            # r rows a sender at the level of its register: the send cuts
+            # each landing row's low block from `inc` itself
             state = self._send_stacked(
-                net,
-                state,
-                m_rows.reshape(-1),
-                jnp.repeat(ids, r),
-                (ids[:, None] ^ rel_fp).reshape(-1),
-                jnp.repeat(fp_level, r),
-                content,
+                net, state, m_rows, ids[:, None], ids[:, None] ^ rel_fp, fp_level, inc
             )
         return state
 
