@@ -16,7 +16,11 @@ position-weighted 32-bit checksum of every leaf (`benchmark/run.py`
 every backend, so the lines of a CPU run (`JAX_PLATFORMS=cpu`) and of a run
 on the chip are equal leaf for leaf or the chip's program is wrong.
 `--compare` prints the first time and the leaves at which two such files
-differ, and exits 1 if they do.
+differ, and exits 1 if they do.  `--twin` builds the configuration at its
+twin's width (`twin.params` over `params`): a program too slow for the
+sandbox's CPU at full width (Casper-1024: 4.5 min a slot) is compared
+through many steps there and through a few at full width.  Each line also
+has the step's wall seconds, which `--compare` does not read.
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (ROOT, os.path.join(ROOT, "benchmark")):
@@ -57,6 +62,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=7001)
     ap.add_argument("--step-ms", type=int, default=200)
     ap.add_argument("--until-ms", type=int, default=2400)
+    ap.add_argument("--twin", action="store_true", help="at the width of the configuration's twin")
     ap.add_argument("--out", help="file for the lines (they are printed too)")
     args = ap.parse_args(argv)
     if args.compare:
@@ -73,7 +79,8 @@ def main(argv=None) -> int:
 
     with open(args.config) as f:
         config = json.load(f)
-    params = cells.build_params(config, config["params_class"])
+    params = cells.build_params(config, config["params_class"],
+                                config["twin"]["params"] if args.twin else None)
     net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
     states = replicate_state(state, args.rows, seeds=twin.row_seeds(args.seed, args.rows))
     paths = [jax.tree_util.keystr(path) for path, _ in jax.tree_util.tree_leaves_with_path(states)]
@@ -84,9 +91,11 @@ def main(argv=None) -> int:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         out = open(args.out, "w")
     for t in range(args.step_ms, args.until_ms + 1, args.step_ms):
+        t0 = time.perf_counter()
         states, _stats = sharded_run_stats(net, states, args.step_ms)
         sums = np.asarray(fingerprint(states)).tolist()
         line = json.dumps({"time_ms": t, "device": device, "rows": args.rows, "seed": args.seed,
+                           "seconds": round(time.perf_counter() - t0, 3),
                            "dropped": int(np.asarray(states.dropped).max()),
                            "leaves": dict(zip(paths, sums))})
         print(line, flush=True)

@@ -2,13 +2,17 @@
 """Device time by `witt.*` scope, for one benchmark configuration's program.
 
     python3 scripts/scope_profile.py --config benchmark/configs/handel-4096.json --replicas 8 --chunks 2
+    python3 scripts/scope_profile.py --config benchmark/configs/casper-1024.json --replicas 1 --chunks 1 --chunk-ms 8000
 
 Builds the program by the configuration's own factory and parameters,
-compiles and warms it through `sharded_run_stats` (one 10-ms chunk),
+compiles and warms it through `sharded_run_stats` (one chunk of
+`--chunk-ms`, 10 unless given: a jump-loop protocol's chunk is its slot,
+so Casper's warm-up is the empty slot 0, its untraced chunk slot 1 and
+its traced chunk slot 2, the first with every committee's wave),
 runs `--chunks` chunks untraced and `--chunks` under a profiler trace,
 reads the trace with the benchmark's reader (`benchmark/xplane.py`
 `read_trace`: leaf ops, self time) and joins every op event's leading
-instruction name with `run_cache_op_scopes(net, 10)`, the table from
+instruction name with `run_cache_op_scopes(net, chunk_ms)`, the table from
 instruction to scope that this one compiled program itself carries.
 
 Printed, as one JSON line: per innermost scope and per scope chain
@@ -52,7 +56,7 @@ for _p in (ROOT, os.path.join(ROOT, "benchmark")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-CHUNK_MS = 10  # the benchmark's chunk: one dissemination period
+CHUNK_MS = 10  # the aggregation cells' chunk: one dissemination period
 HOST_PREFIX = "witt.host."
 HEAVIEST = 3
 
@@ -114,6 +118,7 @@ def main(argv=None) -> int:
     ap.add_argument("--config", required=True, help="benchmark/configs/<name>.json")
     ap.add_argument("--replicas", type=int, required=True)
     ap.add_argument("--chunks", type=int, default=2)
+    ap.add_argument("--chunk-ms", type=int, default=CHUNK_MS)
     ap.add_argument("--nodes", type=int,
                     help="rehearsal, with or without a TPU: node count instead of the configuration's; exit 4")
     ap.add_argument("--out", help="directory for the document and the rows")
@@ -162,7 +167,7 @@ def main(argv=None) -> int:
         span = jax.profiler.TraceAnnotation if annotate else (lambda name: contextlib.nullcontext())
         t0 = time.perf_counter()
         with span("bench.dispatch"):
-            out, stats = sharded_run_stats(net, states, CHUNK_MS)
+            out, stats = sharded_run_stats(net, states, args.chunk_ms)
         with span("bench.block"):
             jax.block_until_ready((out, stats))
         return out, time.perf_counter() - t0
@@ -197,15 +202,15 @@ def main(argv=None) -> int:
 
     path = xplane.find_xplane(trace_dir)
     tr = xplane.read_trace(path, allow_host_ops=not on_tpu)
-    (op_scopes,) = run_cache_op_scopes(net, CHUNK_MS).values()  # one R, one placement: one program
+    (op_scopes,) = run_cache_op_scopes(net, args.chunk_ms).values()  # one R, one placement: one program
     events = [(o.name, o.self_ns) for plane in tr.ops.values() for o in plane]
     times = scope_self_times(events, op_scopes)
-    ticks = args.chunks * CHUNK_MS
+    ticks = args.chunks * args.chunk_ms
     bench_spans = span_totals(("bench." + span, end - start) for span, start, end in tr.spans)
 
     doc = {
         "config": name, "nodes": params.node_count, "replicas": args.replicas,
-        "chunks": args.chunks, "chunk_ms": CHUNK_MS, "ticks_traced": ticks,
+        "chunks": args.chunks, "chunk_ms": args.chunk_ms, "ticks_traced": ticks,
         "device": {"platform": device.platform, "kind": device.device_kind},
         "rehearsal": bool(args.nodes),
         "compile_cache_dir": cache_dir,

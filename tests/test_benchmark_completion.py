@@ -45,3 +45,32 @@ def test_parts_one_to_three_are_loaded_and_part_four_is_not():
     assert "test_rows_are_replaced_at_the_horizon_and_the_next_chunk_is_marked_first" in loaded
     assert "test_the_drafts_rehearsal_runs_to_its_end_with_its_checks_passed" not in loaded
     assert len(loaded) >= 16  # fifteen functions of theirs (34 cases) and this one
+
+
+def test_the_reading_of_casper_1024_resolves_on_both_sides():
+    """`twin.completion.reading` of the real configuration, on a rehearsal
+    build: `proto.head_score` (the head's height and the fork choice's
+    count for it, one integer) is a per-node leaf of the program's state
+    and `head_score` an attribute of every node of the reference, so
+    `twin.check_stated` passes in set-up; a path or an attribute that is
+    not there stops the run there (PR 36)."""
+    import copy
+
+    import pytest
+
+    import cells
+    import twin
+    from wittgenstein_tpu.engine import replicate_state
+
+    config = cells.load_cell("casper-1024.single-r1-s8000").config
+    assert twin.reading(config) == {"program": "proto.head_score", "reference": "head_score"}
+    params = cells.build_params(config, config["params_class"], {"node_count": 64})
+    net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+    rows = replicate_state(state, 2, seeds=[1, 2])
+    twin.check_stated(config, rows)
+    assert twin.program_reading(config, rows).shape == (2, 1 + 2 + 64)
+    for side, wrong in (("program", "proto.blk_parent"), ("reference", "head_skore")):
+        broken = copy.deepcopy(config)
+        broken["twin"]["completion"]["reading"][side] = wrong
+        with pytest.raises(cells.BenchmarkFileError, match=wrong.split(".")[-1]):
+            twin.check_stated(broken, rows)

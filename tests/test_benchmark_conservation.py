@@ -9,8 +9,19 @@ program's `proto.sent_not_ok` and `sanfermin-4096` (PR 37) the message
 store's occupancy.  That case is replaced here by the rule it stood for:
 a configuration names a per-node leaf exactly where its network is built
 with nodes down and the store's planes exactly where its protocol sends
-through the generic message store, and every leaf it names is one the
-program places.
+through the generic message store's time wheel, and every leaf it names
+is one the program places.
+
+A FLAT network (`casper-1024`, PR 39: the overflow lane is the whole
+store) names no leaf, and not because its store is empty: the lane also
+holds the protocol's periodic tasks, one size-0 self-message a scheduled
+node, re-armed for ever and counted neither as sent nor as received, so
+`sent == received + sum(ovf_valid)` is false at every time.  The law
+closes where the rows are read, at slot boundaries: there nothing but
+the tasks is in the lane and `sent == received` exactly, which the last
+case here shows on a built rehearsal network.  Inside a slot it would take
+a `received_minus` key or a count of sized messages in the store
+(PERF.md section 7, for a `benchmark` issue).
 """
 
 import importlib.util
@@ -38,9 +49,11 @@ globals().update(
 def test_a_configuration_names_a_leaf_exactly_where_nodes_are_down():
     """`per_node` leaves exactly where nodes are down (the program's count
     of sends that were not ok), `whole` leaves exactly where the factory's
-    network is on the generic message store (`net.flat` false: a message
-    is counted at delivery, so the store's occupancy stands beside the
-    received total), none otherwise."""
+    network is on the generic message store's wheel (`net.flat` false: a
+    message is counted at delivery, so the store's occupancy stands beside
+    the received total), none otherwise: the channel protocols count at
+    the send, and a flat network's lane holds task rows beside the
+    messages (the module's docstring; the next case)."""
     import cells
     import timed_rows
 
@@ -72,3 +85,37 @@ def test_a_configuration_names_a_leaf_exactly_where_nodes_are_down():
             else:  # the store's planes, whatever the t=1 wave put there
                 assert path in ("msg_valid", "ovf_valid") and leaf.dtype == bool
     assert seen == {(False, False), (True, False), (False, True)}
+
+
+def test_a_flat_networks_lane_holds_its_tasks_while_sent_equals_received():
+    """`casper-1024` at a rehearsal's 64 validators, as `run.py` builds it:
+    the network is flat, the configuration names no leaf, and at t=0 and at
+    the first two slot boundaries the lane holds the scheduled nodes' tasks
+    (2 producers and 64 attesters; not the observer) while every message
+    sent has been counted for its receiver: 0 == 0 after the empty slot 0,
+    1139 == 1139 after slot 1 (one block and one committee of 16 to 67
+    nodes each).  So `sum(ovf_valid)` can stand on neither side of the law."""
+    import numpy as np
+
+    import cells
+    import timed_rows
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.parallel.replica_shard import sharded_run_stats
+
+    config = cells.load_cell("casper-1024.single-r1-s8000").config
+    assert "conservation" not in config["timed_rows"]
+    params = cells.build_params(config, config["params_class"], {"node_count": 64})
+    net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+    assert net.flat and config["factory_kwargs"]["capacity"] is None
+    assert net.capacity == 16384  # the factory's own rule at 64 validators (524,288 at 1024)
+    states = replicate_state(state, 1, seeds=[7001])
+    scheduled, totals = 2 + 64, []
+    for _slot in range(3):
+        counts = timed_rows.program_counts(states, config)
+        assert counts["received_plus"] == {}
+        assert counts["sent_total"] == counts["received_total"]
+        assert int(np.asarray(states.ovf_valid).sum()) == scheduled
+        assert int(np.asarray(states.dropped).max()) == 0
+        totals.append(counts["sent_total"][0])
+        states, _stats = sharded_run_stats(net, states, 8000)
+    assert totals == [0, 0, (1 + 16) * 67]

@@ -161,7 +161,13 @@ class TestWatchdogWorker:
         at most ONE extra thread while running and zero afterwards (the
         old per-chunk spawn churned a thread per chunk and left the last
         one unjoined)."""
-        baseline = threading.active_count()
+        # threads that earlier tests of this worker left winding down must
+        # not be counted as the baseline: wait until the count stands still
+        baseline, since = threading.active_count(), time.monotonic()
+        while time.monotonic() - since < 0.5:
+            time.sleep(0.02)
+            if threading.active_count() != baseline:
+                baseline, since = threading.active_count(), time.monotonic()
         during = []
 
         rep = Supervisor(

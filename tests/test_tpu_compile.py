@@ -223,3 +223,49 @@ def test_sanfermin_chunk_program_compiles_for_one_chip(topo, no_compile_cache, s
         assert scope in text, scope
     assert " sort(" in text and "scatter" in text
     assert "tpu_custom_call" not in text
+
+
+@pytest.fixture(scope="module")
+def casper1024():
+    """`casper-1024` as the benchmark builds it (`make_casper` with its
+    parameters and `factory_kwargs`, nothing scaled), one row: the first
+    program on the FLAT store under the jump loop, at its full width
+    (1027 nodes, a 524,288-row lane, a 262,912-row ATT emission)."""
+    import json
+    import os
+
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.protocols.casper import CasperParameters
+    from wittgenstein_tpu.protocols.casper_batched import make_casper
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "casper-1024.json")) as f:
+        config = json.load(f)
+    net, state = make_casper(CasperParameters(**config["params"]), **config["factory_kwargs"])
+    assert net.flat and net.protocol.TICK_INTERVAL is None
+    assert (net.protocol.n_nodes, net.protocol.apr, net.capacity) == (1027, 256, 524288)
+    return net, replicate_state(state, 1)
+
+
+def test_casper_chunk_program_lowers_for_one_chip(topo, no_compile_cache, casper1024):
+    """The 8000-ms chunk program of `sharded_run_stats` for the cell
+    `casper-1024.single-r1-s8000`, lowered for one described v5e chip at
+    the cell's own width: the jump loop, the flat lane's planes, the
+    committee's emission and the fork choice's product, each under its
+    scope.  Lowered, not compiled: XLA:TPU takes 69 s over this program
+    (sandbox, PR 39), which the tier-1 run does not have; what the
+    compiler makes of it is PERF.md section 5's."""
+    from wittgenstein_tpu.engine.core import ENGINE_PHASE_SCOPES, STORE_SCOPES
+    from wittgenstein_tpu.protocols.casper_batched import CHAIN_SCOPES
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+
+    net, states = casper1024
+    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
+    text = _run_and_reduce(net, 8000)._jit_for(shapes).lower(shapes).as_text(debug_info=True)
+    for scope in (*CHAIN_SCOPES.values(), *STORE_SCOPES.values(), ENGINE_PHASE_SCOPES["jump"]):
+        assert scope in text, scope
+    assert net.due_view_rows == 4096  # the factory's rule: 1/128 of the lane
+    assert "524288" in text and "524289" in text  # the lane, and the view over all of it (a step with more due than fits)
+    assert "4097" in text  # the due view: what every other step hands to deliver
+    assert "262912" in text  # the ATT emission at its static [apr x N]
+    assert "1027x6144" in text  # rec_att, and the fork choice's product

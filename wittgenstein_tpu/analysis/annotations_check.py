@@ -32,7 +32,10 @@ carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
 carries the view's and the repack's: its step still visits the store).
 A Handel built with an attack (`track_bad`) must carry the sub-scopes of
 engine.core.ATTACK_SCOPES that its attack runs, and every Handel the
-candidate merge's engine.core.DELIVER_SCOPES.
+candidate merge's engine.core.DELIVER_SCOPES.  A protocol that keeps a
+scope table of its own names the scopes its step must carry in a
+`REQUIRED_SCOPES` attribute (Casper's `CHAIN_SCOPES`: the fork choice,
+the block build, the committee's vote).
 
 If this jax version exposes no `name_stack` on source_info, the
 presence half is skipped (API drift guard) — neutrality still runs.
@@ -160,6 +163,8 @@ def _check_presence(jax, name, net, state, path, line, suppress):
         from ..engine.core import DELIVER_SCOPES
 
         required.extend(DELIVER_SCOPES.values())
+    # a protocol's own scope table, kept beside the protocol
+    required.extend(getattr(net.protocol, "REQUIRED_SCOPES", ()))
     for want in required:
         if not any(want in s for s in scopes):
             f = _mk("SL601", path, line,

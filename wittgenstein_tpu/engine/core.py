@@ -146,6 +146,18 @@ DELIVER_SCOPES = {
 }
 
 
+# what a send into the FLAT store writes (`latency_arrivals`, `_insert_rows`):
+# the leaves `apply_emissions` carries through its branch under a due view
+_SEND_FIELDS = (
+    "send_ctr", "msg_sent", "bytes_sent", "ovf_valid", "ovf_arrival", "ovf_from",
+    "ovf_to", "ovf_type", "ovf_payload", "msg_head", "dropped", "faults",
+)
+
+
+# and the lane's planes that a delivery reads and never writes
+_LANE_READ_FIELDS = ("ovf_arrival", "ovf_from", "ovf_to", "ovf_type", "ovf_payload")
+
+
 class SimState(NamedTuple):
     """Per-replica simulation state; every field is a jnp array so the whole
     thing is a pytree (checkpointable for free — an upgrade over the
@@ -226,6 +238,41 @@ class Emission:
     arrival: Optional[jnp.ndarray] = None  # explicit arrival times [K]
 
 
+_EMISSION_ARRAYS = ("mask", "from_idx", "to_idx", "mtype", "payload", "send_time", "arrival")
+
+
+def _emission_leaves(emissions):
+    """A step's emissions as (static part, arrays) so that they can leave
+    a `lax.cond`: per emission the names of its array fields and its
+    static `mtype`, and the arrays themselves."""
+    shape, leaves = [], []
+    for em in emissions:
+        arrays = {
+            f: getattr(em, f)
+            for f in _EMISSION_ARRAYS
+            if getattr(em, f) is not None and not isinstance(getattr(em, f), int)
+        }
+        shape.append((tuple(arrays), em.mtype if isinstance(em.mtype, int) else None))
+        leaves.append(tuple(arrays.values()))
+    return shape, tuple(leaves)
+
+
+def _kept(new, old, fields) -> None:
+    """Trace-time check that `new` holds `old`'s very arrays in `fields`:
+    what a due view's branch leaves out of its carry, nothing inside it
+    may have written."""
+    moved = [f for f in fields if getattr(new, f) is not getattr(old, f)]
+    if moved:
+        raise AssertionError(f"written inside a due view's branch: {moved}")
+
+
+def _emissions_of(shape, leaves):
+    return [
+        Emission(**{"mtype": mtype, **dict(zip(names, arrays))})
+        for (names, mtype), arrays in zip(shape, leaves)
+    ]
+
+
 class BatchedNetwork:
     """The engine: binds a latency model + protocol to compiled step/run
     functions.  One instance is reusable across replica counts (everything
@@ -258,6 +305,7 @@ class BatchedNetwork:
         fuse_step: bool = False,
         narrow_lanes: Optional[bool] = None,
         batched_jumps: bool = False,
+        due_view_rows: Optional[int] = None,
     ):
         self.protocol = protocol
         self.latency = latency
@@ -289,6 +337,15 @@ class BatchedNetwork:
         # at exactly its own singleton tick set); default-off pending the
         # paired A/B in BENCH_FLOOR.json (profiling.md lever ledger)
         self.batched_jumps = bool(batched_jumps)
+        # STATIC switch for the FLAT store's due view (`_deliver_and_clear`,
+        # `apply_emissions`, docs/batched_blockchain_design.md): None hands
+        # protocol.deliver the whole lane on every executed step; an int K
+        # hands it the step's DUE rows, compacted in lane order to K rows
+        # (the whole lane again on a step with more than K due), and skips
+        # a step's emissions when every mask is empty.  Bit-identical to
+        # None by construction (tests/test_casper_batched.py); what it buys
+        # is a step that costs what is due, not what the lane holds
+        self.due_view_rows = None if due_view_rows is None else int(due_view_rows)
         # STATIC switch: None compiles the exact pre-telemetry program
         # (state.tele is an empty pytree); a TelemetryConfig threads the
         # counter side-car through every send/deliver/jump site below
@@ -320,7 +377,16 @@ class BatchedNetwork:
             self.overflow_capacity = (
                 capacity if overflow_capacity is None else overflow_capacity
             )
+            if self.due_view_rows is not None and not (
+                0 < self.due_view_rows < self.overflow_capacity
+            ):
+                raise ValueError(
+                    f"due_view_rows={self.due_view_rows} must lie inside the "
+                    f"lane's {self.overflow_capacity} rows"
+                )
         else:
+            if self.due_view_rows is not None:
+                raise ValueError("due_view_rows is the FLAT store's (wheel_rows=0)")
             if wheel_rows % 32:
                 raise ValueError(
                     f"wheel_rows={wheel_rows} must be a multiple of 32 "
@@ -440,6 +506,7 @@ class BatchedNetwork:
             self.annotate,
             self.fuse_step,
             self.batched_jumps,
+            self.due_view_rows,
             self.lanes.key(),
             # the bitset-kernel backend is read from the environment at
             # trace time (WITT_BITOPS) — fold it in so a flipped override
@@ -472,6 +539,7 @@ class BatchedNetwork:
             self.annotate,
             self.fuse_step,
             self.batched_jumps,
+            self.due_view_rows,
             self.lanes.key(),
             bitops_backend(),
         )
@@ -857,9 +925,38 @@ class BatchedNetwork:
         return state, to_ovf & ~ofits
 
     def apply_emissions(self, state: SimState, emissions) -> SimState:
-        for em in emissions:
-            state = self.apply_emission(state, em)
-        return state
+        if self.due_view_rows is None or self.telemetry is not None or not emissions:
+            for em in emissions:
+                state = self.apply_emission(state, em)
+            return state
+        # the due view's other half: a step whose every mask is empty (all
+        # but a handful a slot, for a protocol that acts on timers) skips
+        # the sampling of every row and the passes over the lane.  An empty
+        # emission still ticks the per-event counter (`latency_arrivals`),
+        # and nothing else: its rows add 0 to every counter and scatter out
+        # of bounds.  Only the fields a send writes ride through the branch
+        fields = _SEND_FIELDS
+        sampled = sum(em.arrival is None for em in emissions)
+        any_send = functools.reduce(
+            jnp.logical_or, [jnp.any(em.mask) for em in emissions]
+        )
+
+        def send(vals):
+            s = state._replace(**dict(zip(fields, vals)))
+            for em in emissions:
+                s = self.apply_emission(s, em)
+            _kept(s, state, [f for f in SimState._fields if f not in fields])
+            return tuple(getattr(s, f) for f in fields)
+
+        def skip(vals):
+            s = state._replace(**dict(zip(fields, vals)))
+            s = s._replace(send_ctr=s.send_ctr + sampled)
+            return tuple(getattr(s, f) for f in fields)
+
+        vals = lax.cond(
+            any_send, send, skip, tuple(getattr(state, f) for f in fields)
+        )
+        return state._replace(**dict(zip(fields, vals)))
 
     # -- delivery ------------------------------------------------------------
     def _window(self) -> int:
@@ -876,7 +973,7 @@ class BatchedNetwork:
             )
         return q
 
-    def delivery_view(self, state: SimState):
+    def delivery_view(self, state: SimState, ovf_due=None):
         """Build the flat delivery VIEW protocol.deliver sees: msg_* columns
         are `[D]` gathers of the due wheel window rows + the overflow lane
         (see the module docstring).  Returns (vstate, due, deliver, ctx):
@@ -884,10 +981,34 @@ class BatchedNetwork:
         delivery-time down/partition discards, and `ctx` carries the wheel
         internals `_deliver_and_clear` needs for the post-deliver repack.
         Exposed as API so the static checker (wittgenstein_tpu.analysis)
-        can trace `deliver` against the exact view contract."""
+        can trace `deliver` against the exact view contract.
+
+        `ovf_due` (bool[V], the lane's due rows; `due_view_rows` alone)
+        puts those rows in place of the lane, compacted in lane order to
+        `due_view_rows` rows: the caller has seen that they fit.  What
+        is not due is not in this view at all; a protocol's `deliver`
+        reads nothing but what its mask delivers, so it computes the same."""
         t = state.time
         w, b = self.wheel_rows, self.wheel_slots
         q = self._window()
+        if ovf_due is not None:
+            with self._scope("view", STORE_SCOPES):
+                v = self.overflow_capacity
+                lane = jnp.arange(v, dtype=jnp.int32)
+                # one sort: the due rows' numbers first and ascending, then
+                # numbers past the lane's end, which every gather below fills
+                at = lax.sort(
+                    jnp.where(ovf_due, lane, lane + v), is_stable=False
+                )[: self.due_view_rows]
+                take = lambda a, fill: a.at[at].get(mode="fill", fill_value=fill)
+                state = state._replace(
+                    ovf_valid=take(state.ovf_valid, False),
+                    ovf_arrival=take(state.ovf_arrival, INT_MAX),
+                    ovf_from=take(state.ovf_from, 0),
+                    ovf_to=take(state.ovf_to, 0),
+                    ovf_type=take(state.ovf_type, 0),
+                    ovf_payload=take(state.ovf_payload, 0),
+                )
         with self._scope("view", STORE_SCOPES):
             rows = jnp.remainder(
                 t - q + 1 + jnp.arange(q, dtype=jnp.int32), jnp.int32(w)
@@ -959,7 +1080,31 @@ class BatchedNetwork:
             return self._deliver_and_clear_impl(state)
 
     def _deliver_and_clear_impl(self, state: SimState):
-        vview, due, deliver, ctx = self.delivery_view(state)
+        if self.due_view_rows is None:
+            return self._deliver_view_and_clear(state, None)
+        # the FLAT store's due view: the step's due rows alone where they
+        # fit, the whole lane where they do not (the same rows either way,
+        # so the same step: a wave's fullest ms decides the size).  The
+        # lane's planes that a delivery only reads stay out of the branch
+        ovf_due = state.ovf_valid & (state.ovf_arrival <= state.time)
+        fits = jnp.sum(ovf_due.astype(jnp.int32)) <= self.due_view_rows
+        carried = [f for f in SimState._fields if f not in _LANE_READ_FIELDS]
+        shape = []  # the emissions' static part, the same in both branches
+
+        def through(view_due):
+            def run(s):
+                out, emissions = self._deliver_view_and_clear(s, view_due)
+                _kept(out, s, _LANE_READ_FIELDS)
+                shape[:], leaves = _emission_leaves(emissions)
+                return tuple(getattr(out, f) for f in carried), leaves
+
+            return run
+
+        vals, leaves = lax.cond(fits, through(ovf_due), through(None), state)
+        return state._replace(**dict(zip(carried, vals))), _emissions_of(shape, leaves)
+
+    def _deliver_view_and_clear(self, state: SimState, ovf_due):
+        vview, due, deliver, ctx = self.delivery_view(state, ovf_due)
         rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp = ctx
         view_to = vview.msg_to
         view_type = vview.msg_type
@@ -1017,19 +1162,19 @@ class BatchedNetwork:
         with self._scope("protocol_deliver"):
             pstate, emissions = self.protocol.deliver(self, vstate, deliver)
 
-        state = self._clear_visited_rows(pstate, state, ctx, due)
+        state = self._clear_visited_rows(pstate, state, ctx, due, ovf_due)
         return state, emissions
 
-    def _clear_visited_rows(self, pstate, state, ctx, due) -> SimState:
+    def _clear_visited_rows(self, pstate, state, ctx, due, ovf_due=None) -> SimState:
         """Clear due entries from the visited window rows + overflow lane;
         surviving entries (a row visited early by a quantum window) repack
         to the slot prefix so whl_fill stays the next-free-slot index.
         `pstate` carries the protocol's post-deliver columns; the wheel
         fields are taken from the pre-view `state`."""
         with self._scope("repack", STORE_SCOPES):
-            return self._clear_visited_rows_impl(pstate, state, ctx, due)
+            return self._clear_visited_rows_impl(pstate, state, ctx, due, ovf_due)
 
-    def _clear_visited_rows_impl(self, pstate, state, ctx, due) -> SimState:
+    def _clear_visited_rows_impl(self, pstate, state, ctx, due, ovf_due=None) -> SimState:
         rows, wv, wa, wf, wt, wk, wp, q, b, _ = ctx
         keep = wv & ~due[: q * b].reshape(q, b)
         pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
@@ -1050,7 +1195,10 @@ class BatchedNetwork:
             whl_fill=state.whl_fill.at[rows].set(
                 jnp.sum(keep.astype(jnp.int32), axis=1)
             ),
-            ovf_valid=state.ovf_valid & ~due[q * b :],
+            # under a due view the view's rows are not the lane's: the
+            # lane's own due mask says which leave
+            ovf_valid=state.ovf_valid
+            & ~(due[q * b :] if ovf_due is None else ovf_due),
         )
         if self.payload_width:
             np_ = jnp.zeros_like(wp).at[ii, tgt].set(wp, mode="drop")
@@ -1393,6 +1541,13 @@ class BatchedNetwork:
         self, states: SimState, ms: int, stop_when_done: bool
     ) -> SimState:
         proto = self.protocol
+        batch = jnp.shape(states.time)  # static
+        if self.due_view_rows is not None and batch == (1,):
+            # one row is no batch: under `vmap` the due view's branches are
+            # selects that run both sides, so the row runs as it is
+            one = jax.tree_util.tree_map(lambda a: a[0], states)
+            one = self._run_ms_impl(one, ms, stop_when_done)
+            return jax.tree_util.tree_map(lambda a: a[None], one)
         if self.batched_jumps and proto.TICK_INTERVAL is None:
             return self._run_ms_batched_jumps(states, ms, stop_when_done)
         period, residues = proto.BEAT_PERIOD, proto.BEAT_RESIDUES

@@ -65,11 +65,21 @@ from ..engine import BatchedNetwork, BatchedProtocol, Emission
 from ..engine.rng import hash32
 from .casper import SLOT_DURATION, Attester, BlockProducer, CasperIMD, CasperParameters
 
+# what this family's deliver does beside the store (the global block table
+# by height, the dense ancestry matrix, the attestation planes), nested
+# under the phase that delivers and switched by the network's `annotate`
+CHAIN_SCOPES = {
+    "forkchoice": "witt.chain.forkchoice",  # best / countAttestations: the [N, mH] x [mH, mA] product, the rec_att reads
+    "build": "witt.chain.build",  # buildBlock: the included-attestation product, the block table and ancestry writes, the BLOCK rows
+    "attest": "witt.chain.attest",  # a committee's vote: the attestation table's writes and the [apr x N] ATT rows
+}
+
 
 class BatchedCasper(BatchedProtocol):
     MSG_TYPES = ["BLOCK", "ATT", "TBP", "TATT", "TWF", "TWFB", "TBYZ"]
     PAYLOAD_WIDTH = 2
     TICK_INTERVAL = None  # all timing is explicit-arrival self-messages
+    REQUIRED_SCOPES = tuple(CHAIN_SCOPES.values())  # simlint SL601 holds them live
 
     def __init__(
         self,
@@ -124,6 +134,8 @@ class BatchedCasper(BatchedProtocol):
             "att_head": jnp.zeros(ma, jnp.int32),
             # per-node state
             "head": jnp.zeros(n, jnp.int32),
+            # (head, countAttestations for it) as one integer: `_head_score`
+            "head_score": jnp.zeros(n, jnp.int32),
             "seen": seen,
             "rec_att": jnp.zeros((n, ma), bool),
             "reeval": jnp.zeros((n, mh), bool),
@@ -159,6 +171,26 @@ class BatchedCasper(BatchedProtocol):
             & (hcn[:, None] >= proto["att_head"][None, :] - self.cl)
         )
         return jnp.sum(att_ok & (from_blocks | from_recv), axis=1).astype(jnp.int32)
+
+    def _head_score(self, proto):
+        """(head height, head votes) as one integer, the height first
+        (the oracle's `CasperNode.head_score`).  The votes are `_count`
+        for the head against its ancestor `cycle_length` blocks back
+        (genesis where the chain is shorter): what `_best` would weigh for
+        this head in a fork at that ancestor.  No fork choice reads it;
+        with one block a slot `_best` is decided by the direct link, and
+        this leaf is where the count's value can be held against the
+        reference."""
+        head = proto["head"]
+        hr = jnp.arange(self.mh, dtype=jnp.int32)
+        anc = proto["anc"][head]  # [N, mH]: the head's strict ancestors
+        from_top = jnp.cumsum(anc[:, ::-1], axis=1)[:, ::-1]  # ancestors at this height or above
+        back = jnp.min(
+            jnp.where(anc & (from_top <= self.cl), hr[None, :], self.mh), axis=1
+        )
+        hcn = jnp.where(back == self.mh, 0, back).astype(jnp.int32)  # genesis has none
+        votes = self._count(proto, proto["rec_att"], head, hcn)
+        return head * (self.apr * self.cl + 1) + votes
 
     def _best(self, state, proto, rec_att, o1, o2, mask):
         """Vectorized pairwise best(o1, o2) (CasperIMD.java:204-257)."""
@@ -199,12 +231,55 @@ class BatchedCasper(BatchedProtocol):
             )
             return head, reeval
 
-        head, _ = lax.fori_loop(1, self.mh, body, (proto["head"], proto["reeval"]))
-        reeval = jnp.where(nodes_mask[:, None], False, proto["reeval"])
+        def fold():
+            head, _ = lax.fori_loop(1, self.mh, body, (proto["head"], proto["reeval"]))
+            return head, jnp.where(nodes_mask[:, None], False, proto["reeval"])
+
+        # nobody acts on most executed steps (arrivals alone): the fold
+        # would change no head and clear no candidate, so it is not run
+        head, reeval = lax.cond(
+            jnp.any(nodes_mask), fold, lambda: (proto["head"], proto["reeval"])
+        )
         return dict(proto, head=head, reeval=reeval)
 
     # -- block building (buildBlock, :383-428) -------------------------------
+    _BUILD_WRITES = (
+        "blk_exists", "blk_parent", "blk_time", "anc", "blk_att", "head", "seen",
+    )
+
     def _build_blocks(self, state, proto, mask, base, height):
+        """`_build` where a producer of `mask` fires, and nothing where
+        none does (all but two or three executed steps a slot): the tables
+        as they are and BLOCK rows that are all masked out, which is what
+        `_build` returns for an empty mask."""
+
+        def build():
+            new, em = self._build(state, proto, mask, base, height)
+            return {k: new[k] for k in self._BUILD_WRITES}, (em.mask, em.payload)
+
+        def skip():
+            kp = self.prod_ids.shape[0] * self.n_nodes
+            return {k: proto[k] for k in self._BUILD_WRITES}, (
+                jnp.zeros(kp, bool), jnp.zeros((kp, 2), jnp.int32))
+
+        writes, (em_mask, em_payload) = lax.cond(jnp.any(mask), build, skip)
+        return dict(proto, **writes), self._block_rows(state, em_mask, em_payload)
+
+    def _block_rows(self, state, mask, payload):
+        """BLOCK broadcast rows restricted to the (few, static) producer ids."""
+        kp = self.prod_ids.shape[0] * self.n_nodes
+        return Emission(
+            mask=mask,
+            from_idx=jnp.repeat(self.prod_ids, self.n_nodes),
+            to_idx=jnp.tile(self.all_ids, self.prod_ids.shape[0]),
+            mtype=self.mtype("BLOCK"),
+            payload=payload,
+            send_time=jnp.broadcast_to(
+                state.time + self.params.block_construction_time, (kp,)
+            ).astype(jnp.int32),
+        )
+
+    def _build(self, state, proto, mask, base, height):
         """Producers in `mask` create block `height[n]` on parent `base[n]`:
         include every received attestation on the parent chain (within the
         cycle window) not already included in it."""
@@ -249,23 +324,17 @@ class BatchedCasper(BatchedProtocol):
         proto["head"] = jnp.where(mask, height, proto["head"])
         proto["seen"] = proto["seen"].at[self.all_ids, w_h].set(True, mode="drop")
 
-        # broadcast rows restricted to the (few, static) producer ids
         kp = self.prod_ids.shape[0] * self.n_nodes
-        em = Emission(
-            mask=jnp.repeat(mask[self.prod_ids], self.n_nodes),
-            from_idx=jnp.repeat(self.prod_ids, self.n_nodes),
-            to_idx=jnp.tile(self.all_ids, self.prod_ids.shape[0]),
-            mtype=self.mtype("BLOCK"),
-            payload=jnp.stack(
+        em = self._block_rows(
+            state,
+            jnp.repeat(mask[self.prod_ids], self.n_nodes),
+            jnp.stack(
                 [
                     jnp.repeat(height[self.prod_ids], self.n_nodes),
                     jnp.zeros(kp, jnp.int32),
                 ],
                 axis=1,
             ),
-            send_time=jnp.broadcast_to(
-                t + self.params.block_construction_time, (kp,)
-            ).astype(jnp.int32),
         )
         return proto, em
 
@@ -370,9 +439,15 @@ class BatchedCasper(BatchedProtocol):
             jax.nn.one_hot(proto["head"], mh, dtype=bool) & got_blk[:, None]
         )
         proto["reeval"] = proto["reeval"] | new_blk
-        proto["head"] = self._best(
-            state, proto, proto["rec_att"], proto["head"], best_new, got_blk
-        )
+        with net._scope("forkchoice", CHAIN_SCOPES):
+            # a step on which no block arrives (the wave's) has no pair to weigh
+            proto["head"] = lax.cond(
+                jnp.any(got_blk),
+                lambda: self._best(
+                    state, proto, proto["rec_att"], proto["head"], best_new, got_blk
+                ),
+                lambda: proto["head"],
+            )
 
         if self.byz_variant == "wf":
             # WF producer response (:660-676): fires when the awaited parent
@@ -454,24 +529,27 @@ class BatchedCasper(BatchedProtocol):
 
         # one reevaluation pass for every node acting this tick
         acting = tbp | tatt | twf | tbyz
-        proto = self._reevaluate(state, proto, acting)
+        with net._scope("forkchoice", CHAIN_SCOPES):
+            proto = self._reevaluate(state, proto, acting)
 
         # honest production: height = slot index (:370-377)
         produce = tbp & (slot_now < mh)
-        proto, em_b = self._build_blocks(
-            state, proto, produce, proto["head"], jnp.broadcast_to(slot_now, (n,))
-        )
+        with net._scope("build", CHAIN_SCOPES):
+            proto, em_b = self._build_blocks(
+                state, proto, produce, proto["head"], jnp.broadcast_to(slot_now, (n,))
+            )
         emissions.append(em_b)
 
         if self.byz_variant == "wf":
             # WF kick-off build: block 1 on genesis (reevaluateH at genesis)
-            proto, em_k = self._build_blocks(
-                state,
-                proto,
-                wf_kick,
-                jnp.zeros(n, jnp.int32),
-                jnp.ones(n, jnp.int32),
-            )
+            with net._scope("build", CHAIN_SCOPES):
+                proto, em_k = self._build_blocks(
+                    state,
+                    proto,
+                    wf_kick,
+                    jnp.zeros(n, jnp.int32),
+                    jnp.ones(n, jnp.int32),
+                )
             emissions.append(em_k)
 
             # ---- 6. WF scheduled build lands (r(), :663-668) --------------
@@ -482,9 +560,10 @@ class BatchedCasper(BatchedProtocol):
             wf_th = jnp.zeros(n, jnp.int32).at[to].max(
                 jnp.where(is_twfb, pay1, 0), mode="drop"
             )
-            proto, em_w = self._build_blocks(
-                state, proto, twfb & (wf_th < mh), wf_base, wf_th
-            )
+            with net._scope("build", CHAIN_SCOPES):
+                proto, em_w = self._build_blocks(
+                    state, proto, twfb & (wf_th < mh), wf_base, wf_th
+                )
             emissions.append(em_w)
         else:
             # ---- 6'. byz producer fires (reevaluateH + variant head tweak
@@ -527,9 +606,10 @@ class BatchedCasper(BatchedProtocol):
                 proto["byz_older"] = proto["byz_older"] + (tbyz & ~direct).astype(
                     jnp.int32
                 )
-            proto, em_z = self._build_blocks(
-                state, proto, tbyz & (th < mh), base, th
-            )
+            with net._scope("build", CHAIN_SCOPES):
+                proto, em_z = self._build_blocks(
+                    state, proto, tbyz & (th < mh), base, th
+                )
             emissions.append(em_z)
             proto["wf_to_send"] = jnp.where(tbyz, th + self.bpc, proto["wf_to_send"])
             emissions.append(
@@ -546,40 +626,44 @@ class BatchedCasper(BatchedProtocol):
             )
 
         # attester votes: create the attestation and broadcast it ------------
-        vote_h = slot_now
-        can_vote = tatt & (vote_h >= 1) & (vote_h < mh)
-        att_slot = jnp.clip(
-            (vote_h - 1) * self.apr + jnp.where(self.is_att, self._att_j(), 0),
-            0,
-            ma - 1,
-        )
-        w_a = jnp.where(can_vote, att_slot, ma)
-        proto["att_exists"] = proto["att_exists"].at[w_a].set(True, mode="drop")
-        proto["att_head"] = proto["att_head"].at[w_a].set(proto["head"], mode="drop")
-        # the attester holds its own attestation from the start
-        proto["rec_att"] = proto["rec_att"].at[ids, w_a].set(True, mode="drop")
-        # committee of this slot shares the tick: [apr x N] rows
-        cm = self.committee[jnp.clip((vote_h - 1) % self.cl, 0, self.cl - 1)]
-        cm_mask = can_vote[cm]  # [apr]
-        emissions.append(
-            Emission(
-                mask=jnp.repeat(cm_mask, n),
-                from_idx=jnp.repeat(cm, n),
-                to_idx=jnp.tile(ids, self.apr),
-                mtype=self.mtype("ATT"),
-                payload=jnp.stack(
-                    [
-                        jnp.repeat(att_slot[cm], n),
-                        jnp.zeros(self.apr * n, jnp.int32),
-                    ],
-                    axis=1,
-                ),
-                send_time=jnp.broadcast_to(
-                    t + p.attestation_construction_time, (self.apr * n,)
-                ).astype(jnp.int32),
+        with net._scope("attest", CHAIN_SCOPES):
+            vote_h = slot_now
+            can_vote = tatt & (vote_h >= 1) & (vote_h < mh)
+            att_slot = jnp.clip(
+                (vote_h - 1) * self.apr + jnp.where(self.is_att, self._att_j(), 0),
+                0,
+                ma - 1,
             )
-        )
+            w_a = jnp.where(can_vote, att_slot, ma)
+            proto["att_exists"] = proto["att_exists"].at[w_a].set(True, mode="drop")
+            proto["att_head"] = proto["att_head"].at[w_a].set(proto["head"], mode="drop")
+            # the voter counts its own attestation when its own copy of the
+            # broadcast arrives, like every other node (the oracle's sendAll)
 
+            # committee of this slot shares the tick: [apr x N] rows
+            cm = self.committee[jnp.clip((vote_h - 1) % self.cl, 0, self.cl - 1)]
+            cm_mask = can_vote[cm]  # [apr]
+            emissions.append(
+                Emission(
+                    mask=jnp.repeat(cm_mask, n),
+                    from_idx=jnp.repeat(cm, n),
+                    to_idx=jnp.tile(ids, self.apr),
+                    mtype=self.mtype("ATT"),
+                    payload=jnp.stack(
+                        [
+                            jnp.repeat(att_slot[cm], n),
+                            jnp.zeros(self.apr * n, jnp.int32),
+                        ],
+                        axis=1,
+                    ),
+                    send_time=jnp.broadcast_to(
+                        t + p.attestation_construction_time, (self.apr * n,)
+                    ).astype(jnp.int32),
+                )
+            )
+
+        with net._scope("forkchoice", CHAIN_SCOPES):
+            proto["head_score"] = self._head_score(proto)
         return state._replace(proto=proto), emissions
 
     def _att_j(self):
@@ -601,6 +685,7 @@ def make_casper(
     seed: int = 0,
     byz_variant: str = "wf",
     byz_delay: int = 0,
+    due_view_rows: Optional[int] = None,
 ):
     """Host-side construction from the oracle's init (observer + the chosen
     Byzantine producer variant + honest producers + attesters, same RNG).
@@ -655,13 +740,23 @@ def make_casper(
     if capacity is None:
         # the peak in-flight load is one committee's attestation broadcast
         # ([apr x N] messages, all delivered well inside the 8 s slot) plus
-        # scheduled self-messages; a full ring DROPS new sends, so auto-size
-        # to 1.5 waves (the default 20x4 config keeps the old 1<<14)
+        # scheduled self-messages; a full lane DROPS new sends (the store
+        # is the flat overflow lane, below), so auto-size it to 1.5 waves
+        # (the default 20x4 config keeps the old 1<<14)
         wave = apr * n + 4 * n
         capacity = max(1 << 14, 1 << int(np.ceil(np.log2(1.5 * wave))))
     # flat mode (wheel_rows=0): Casper's scheduling is dominated by
     # explicit-arrival self-messages whole 8 s slots ahead — far beyond any
     # useful wheel horizon, so the exact overflow-lane scan IS the store
-    net = BatchedNetwork(proto, latency, n, capacity=capacity, wheel_rows=0)
+    if due_view_rows is None:
+        # a step delivers what is due at one ms, a wave spread over the
+        # latency model's couple of hundred: 1/128 of the lane is a fifth
+        # over the fullest ms read at 256 x 1027 (3457 rows, sandbox); a
+        # fuller ms is viewed in the whole lane, exactly.  0: always that
+        due_view_rows = max(256, capacity // 128)
+    net = BatchedNetwork(
+        proto, latency, n, capacity=capacity, wheel_rows=0,
+        due_view_rows=due_view_rows or None,
+    )
     state = net.init_state(cols, seed=seed, proto=proto.proto_init(n))
     return net, state
