@@ -3,6 +3,11 @@
 1-of-K `take_along_axis` picks of Handel's `_select`.  Exact equality:
 the payload of a slot the caller masks is still written to the state, so
 the order among tied keys (the many -1 among them) is part of the result.
+
+And the message store's same-row rank (PR 40) against the search it
+replaced: `run_rank` against `iota - searchsorted(keys, keys, "left")`,
+`same_key_rank` against `_insert_rows`' lines as they stood, kept here as
+the plain reference.
 """
 
 import re
@@ -15,6 +20,9 @@ import pytest
 
 from wittgenstein_tpu.ops.select import (
     descending_positions,
+    run_rank,
+    same_key_rank,
+    sort_with_order,
     take_slot,
     top_k_merge,
 )
@@ -136,3 +144,73 @@ def test_the_helper_lowers_to_no_sort_and_no_gather():
     idx = jnp.zeros((N, 6), jnp.int32)
     text = jax.jit(take_slot).lower(payloads[0], idx).as_text()
     assert not re.findall(r"stablehlo\.(?:sort|(?:dynamic_)?gather)", text)
+
+
+ROWS = 4  # the replica axis the store's insert runs under
+
+
+def rank_keys(kind: str, k: int, buckets: int):
+    """`[ROWS, k]` keys as `_insert_rows` makes them: a wheel row in
+    `[0, buckets)` for a candidate, `buckets` for the rest."""
+    rng = np.random.default_rng(zlib.crc32(f"{kind}-{k}-{buckets}".encode()))
+    if kind == "all_equal":
+        return np.full((ROWS, k), buckets, np.int32)
+    if kind == "all_distinct":
+        return np.stack([rng.permutation(k) for _ in range(ROWS)]).astype(np.int32)
+    assert kind == "ties"
+    return rng.integers(0, buckets + 1, (ROWS, k)).astype(np.int32)
+
+
+def rank_by_search(rkey):
+    """`engine/core.py` `_insert_rows`, the same-row rank as it stood
+    before PR 40: the plain reference."""
+    k = rkey.shape[0]
+    order = jnp.argsort(rkey)
+    rsort = rkey[order]
+    pos_sorted = jnp.arange(k, dtype=jnp.int32) - jnp.searchsorted(
+        rsort, rsort, side="left"
+    ).astype(jnp.int32)
+    return jnp.zeros(k, jnp.int32).at[order].set(pos_sorted)
+
+
+RANK_CASES = [
+    (kind, k, buckets)
+    for kind in ("ties", "all_equal", "all_distinct")
+    for k, buckets in ((1, 5), (7, 3), (1280, 512), (8192, 512))
+]
+
+
+@pytest.mark.parametrize("whole", [False, True], ids=["run_rank", "same_key_rank"])
+@pytest.mark.parametrize("kind,k,buckets", RANK_CASES)
+def test_rank_among_equal_keys_is_the_search_it_replaced(kind, k, buckets, whole):
+    key = jnp.asarray(rank_keys(kind, k, buckets))
+    if whole:  # sort, scan and write-back, in row order
+        got = jax.jit(jax.vmap(same_key_rank))(key)
+        want = jax.vmap(rank_by_search)(key)
+    else:  # the scan alone, over sorted keys
+        key = jnp.sort(key, axis=-1)
+        got = jax.jit(jax.vmap(run_rank))(key)
+        want = jnp.arange(k, dtype=jnp.int32) - jax.vmap(
+            lambda s: jnp.searchsorted(s, s, side="left")
+        )(key).astype(jnp.int32)
+        # a leading axis is one more: the same without the vmap
+        np.testing.assert_array_equal(np.asarray(run_rank(key)), np.asarray(want))
+    assert got.dtype == want.dtype == jnp.int32 and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_sort_with_order_is_the_stable_argsort():
+    key = jnp.asarray(rank_keys("ties", 1280, 512)[0])
+    skey, order = jax.jit(sort_with_order)(key)
+    want = jnp.argsort(key)  # stable
+    np.testing.assert_array_equal(np.asarray(order), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(skey), np.asarray(key[want]))
+
+
+def test_the_rank_lowers_to_no_search():
+    """No loop, no gather and no scatter: two sorts, a comparison with the
+    shifted keys and a cumulative max."""
+    key = jnp.asarray(rank_keys("ties", 8192, 512))
+    text = jax.jit(jax.vmap(same_key_rank)).lower(key).as_text()
+    assert "stablehlo.sort" in text
+    assert not re.findall(r"stablehlo\.(?:while|(?:dynamic_)?gather|scatter)|searchsorted", text)

@@ -168,6 +168,52 @@ class TestWheelMechanics:
         assert int(state.proto["max_delay"]) == 0
         assert int(state.dropped) == 0
 
+    def test_one_emission_ranks_its_rows_slot_for_slot(self):
+        """A burst of more than `wheel_slots` rows to one wheel row and a
+        handful to two others in ONE emission: rows of one wheel row take
+        consecutive slots after what the row held, in row order; the spill
+        and a beyond-horizon row go to the lane's free slots in row order;
+        a masked row takes nothing.  Every value is written out."""
+        net, state = _probe_net(n=16, wheel_slots=4, overflow_capacity=16)
+
+        def emit(state, senders, arrivals, mask=None):
+            k = len(senders)
+            return net.apply_emission(state, Emission(
+                mask=jnp.ones(k, bool) if mask is None else jnp.asarray(mask),
+                from_idx=jnp.asarray(senders, jnp.int32),
+                to_idx=jnp.arange(k, dtype=jnp.int32) % net.n_nodes,
+                mtype=0,
+                arrival=jnp.asarray(arrivals, jnp.int32),
+            ))
+
+        state = emit(state, [15], [10])  # wheel row 10 holds one row already
+        senders = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
+        arrivals = [10, 500, 10, 10, 9, 10, 7, 10, 10, 9, 10, 7, 10]
+        mask = [True] * 13
+        mask[3] = False  # sender 4 sends nothing
+        state = emit(state, senders, arrivals, mask)
+
+        fill = np.zeros(64, np.int32)
+        fill[[7, 9, 10]] = [2, 2, 4]
+        np.testing.assert_array_equal(np.asarray(state.whl_fill), fill)
+        valid = np.zeros((64, 4), bool)
+        valid[7, :2] = valid[9, :2] = valid[10, :] = True
+        np.testing.assert_array_equal(np.asarray(state.msg_valid), valid)
+        wheel_from = {7: [7, 12], 9: [5, 10], 10: [15, 1, 3, 6]}  # row 10: three of its seven fit
+        for row, want in wheel_from.items():
+            assert np.asarray(state.msg_from)[row, : len(want)].tolist() == want, row
+            assert np.asarray(state.msg_arrival)[row, : len(want)].tolist() == [row] * len(want)
+        # the lane, in row order: the far arrival, then row 10's spill
+        assert np.asarray(state.ovf_valid).tolist() == [True] * 5 + [False] * 11
+        assert np.asarray(state.ovf_from)[:5].tolist() == [2, 8, 9, 11, 13]
+        assert np.asarray(state.ovf_arrival)[:5].tolist() == [500, 10, 10, 10, 10]
+        assert int(state.dropped) == 0 and int(state.msg_head) == 13
+
+        state = net.run_ms(state, 600)
+        assert int(state.proto["delivered"]) == 13
+        assert int(state.proto["max_delay"]) == 0
+        assert int(state.dropped) == 0
+
     def test_genuine_overflow_counts_dropped(self):
         net, state = _probe_net(wheel_slots=2, overflow_capacity=4)
         state = _schedule(net, state, [10] * 9)

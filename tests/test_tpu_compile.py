@@ -12,6 +12,8 @@ libtpu, so the topology is described inside a module fixture (never at
 import) and everything compiles in the test's own process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -212,7 +214,9 @@ def test_sanfermin_chunk_program_compiles_for_one_chip(topo, no_compile_cache, s
     """The chunk program of `sharded_run_stats` on the message store, for
     one described v5e chip: the time wheel's scatters, the insert ranks'
     sorts and the delivery view's gathers under the replica axis, each
-    under its `witt.store.*` scope; no Mosaic call (ROADMAP B10)."""
+    under its `witt.store.*` scope; no Mosaic call (ROADMAP B10).  The
+    insert's same-row rank is a scan over the sorted keys (PR 40): no
+    `searchsorted`, and no loop under the insert at all."""
     from wittgenstein_tpu.engine.core import STORE_SCOPES
     from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
 
@@ -223,6 +227,8 @@ def test_sanfermin_chunk_program_compiles_for_one_chip(topo, no_compile_cache, s
         assert scope in text, scope
     assert " sort(" in text and "scatter" in text
     assert "tpu_custom_call" not in text
+    assert "searchsorted" not in text
+    assert not re.search(r'op_name="[^"]*witt\.store\.insert[^"]*/while', text)
 
 
 @pytest.fixture(scope="module")

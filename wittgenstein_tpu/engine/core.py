@@ -64,6 +64,7 @@ from ..ops.bitops import (
     pack_bool_words,
     popcount_words,
 )
+from ..ops.select import same_key_rank
 from ..telemetry.state import (
     TelemetryConfig,
     count_by_type,
@@ -829,7 +830,6 @@ class BatchedNetwork:
     ):
         """One emission's ok-rows into the wheel or the overflow lane.
         Returns the state and the rows a full store dropped."""
-        k = ok.shape[0]
         n_ok = jnp.sum(ok.astype(jnp.int32))
         t = state.time
         w, b, v = self.wheel_rows, self.wheel_slots, self.overflow_capacity
@@ -844,14 +844,9 @@ class BatchedNetwork:
             eff = jnp.maximum(arrival, t + 1)
             cand = ok & (eff <= t + w)
             row = jnp.remainder(eff, w)
-            # same-row rank via sort (ties broadcast to distinct slots)
+            # same-row rank (ties take consecutive slots in row order)
             rkey = jnp.where(cand, row, w)
-            order = jnp.argsort(rkey)
-            rsort = rkey[order]
-            pos_sorted = jnp.arange(k, dtype=jnp.int32) - jnp.searchsorted(
-                rsort, rsort, side="left"
-            ).astype(jnp.int32)
-            rank = jnp.zeros(k, jnp.int32).at[order].set(pos_sorted)
+            rank = same_key_rank(rkey)
             slot = state.whl_fill[jnp.where(cand, row, 0)] + rank
             fits = cand & (slot < b)
             w_row = jnp.where(fits, row, w)  # OOB -> dropped scatter

@@ -9,11 +9,20 @@ are plain elementwise work: no `sort`, no `gather`, no index array.  Every
 size (K, the candidate count, trailing word widths) is read from the
 shapes of the inputs, and every function maps over leading axes, so a
 `vmap` over replicas is one more of them.
+
+The same holds for a rank among equal keys over a long axis (the message
+store's slot inside a wheel row, PR 40): a binary search of sorted keys
+among themselves is a loop of whole-array gathers on a TPU (84 of a
+235-ms tick of `sanfermin-4096` at R=64, PERF.md section 6), where the
+sort that precedes it has already put every run of equal keys side by
+side: `run_rank` reads the ranks off with one comparison and one
+cumulative max.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 
 def one_hot_take(x, sel):
@@ -56,3 +65,41 @@ def top_k_merge(key, k: int, payloads):
     pos = descending_positions(key)
     sel = pos[..., None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]  # [..., k, C]
     return one_hot_take(key, sel), [one_hot_take(x, sel) for x in payloads]
+
+
+def sort_with_order(key):
+    """A stable ascending sort of a 1-D `key` and the permutation that
+    made it, `(key[order], order)` for `order = argsort(key)`, as one
+    two-operand sort: the sorted keys come out of the sort itself and
+    not out of a gather through `order`."""
+    iota = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return lax.sort((key, iota), num_keys=1, is_stable=True)
+
+
+def run_rank(keys):
+    """For `keys` sorted ascending along the last axis, the place of
+    every entry inside its run of equal keys: `iota - first`, where
+    `first[i]` is the least j with `keys[j] == keys[i]`, the index a
+    left-sided binary search of the keys among themselves returns,
+    without the search.  A run starts where a key differs from the one
+    before it (position 0 starts one), and the start of the run an entry
+    lies in is the running maximum of the run starts up to it: one
+    comparison with the shifted keys and one cumulative max, no gather
+    and no loop."""
+    axis = keys.ndim - 1
+    iota = lax.broadcasted_iota(jnp.int32, keys.shape, axis)
+    first = jnp.concatenate(
+        [jnp.ones_like(keys[..., :1], bool), keys[..., 1:] != keys[..., :-1]], axis
+    )
+    return iota - lax.cummax(jnp.where(first, iota, 0), axis=axis)
+
+
+def same_key_rank(key):
+    """The place of every entry of a 1-D `key` among the entries of equal
+    key, in index order: entry i gets the count of j < i with `key[j] ==
+    key[i]`.  One stable sort brings equal keys together in index order,
+    `run_rank` numbers each run, and a second sort keyed on the
+    permutation (no two keys equal, so it need not be stable) carries
+    the numbers back to the entries' own places."""
+    skey, order = sort_with_order(key)
+    return lax.sort((order, run_rank(skey)), num_keys=1, is_stable=False)[1]

@@ -96,6 +96,7 @@ from jax import lax
 from ..engine import BatchedProtocol
 from ..engine.core import CHANNEL_SCOPES
 from ..ops.bitops import lowest_set_bit, popcount_words, xor_shuffle
+from ..ops.select import run_rank, sort_with_order
 
 INT32_MAX = np.int32(2**31 - 1)
 MAX_NODES = 1 << 14  # int32 key-packing headroom
@@ -810,11 +811,8 @@ class BitsetAggBase(BatchedProtocol):
             # dropped by the scatter; beyond-capacity rows too, counted
             # below as displaced)
             dest = jnp.where(meta_l[:, 5] > 0, meta_l[:, 0] // n_loc, p_sz)
-            order = jnp.argsort(dest)
-            dsort = dest[order]
-            pos = jnp.arange(m_loc, dtype=jnp.int32) - jnp.searchsorted(
-                dsort, dsort, side="left"
-            ).astype(jnp.int32)
+            dsort, order = sort_with_order(dest)
+            pos = run_rank(dsort)  # place inside the destination's bucket
             overflow = jnp.sum(
                 ((pos >= bucket_cap) & (dsort < p_sz)).astype(jnp.int32)
             )
