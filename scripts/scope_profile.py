@@ -35,6 +35,11 @@ of the configuration's, host op events standing in for the device's.
 The document then says `"rehearsal": true` and the exit code is 4, as
 `benchmark/run.py --rehearse` ends: the join is real, the times are the
 host's and mean nothing.
+`--host-tracer-level N` (1 unless given, as the benchmark traces) raises
+the profiler's host tracer level: at 2 or 3 the runtime's own events
+(argument checks, buffer allocation, the executable's launch) are on the
+host planes, and the document gains `inside_enqueue`: what lies inside
+the `witt.host.enqueue` spans, by event name.
 `--out DIR` also writes the document (indented) and, gzipped, every op
 event's text with its self time beside the whole instruction table, for
 a second look without a second run.
@@ -84,6 +89,30 @@ def host_span_totals(path: str) -> dict:
     )
 
 
+def events_inside(path: str, span: str, heaviest: int = 25) -> dict:
+    """What fills a host span: the host planes' events that lie inside an
+    event named `span` on the same plane, by name, the `heaviest` by
+    seconds ({"count", "seconds"}; an event that encloses another holds
+    its time too).  Told apart from the span's own time by `span_totals`."""
+    from jax.profiler import ProfileData
+
+    inside = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        events = [
+            (e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+            for line in plane.lines for e in line.events
+        ]
+        spans = sorted((s, t) for name, s, t in events if name == span)
+        for name, start, end in events:
+            if name != span and any(s <= start and end <= t for s, t in spans):
+                inside.append((name, end - start))
+    totals = span_totals(inside)
+    top = sorted(totals.items(), key=lambda kv: -kv[1]["seconds"])[:heaviest]
+    return dict(top)
+
+
 def profile_rows(times: dict, ticks: int) -> dict:
     """`scope_self_times` as printable rows: ms per tick, share of the
     summed self time, the heaviest instructions of each row."""
@@ -121,6 +150,8 @@ def main(argv=None) -> int:
     ap.add_argument("--chunk-ms", type=int, default=CHUNK_MS)
     ap.add_argument("--nodes", type=int,
                     help="rehearsal, with or without a TPU: node count instead of the configuration's; exit 4")
+    ap.add_argument("--host-tracer-level", type=int, default=1,
+                    help="the profiler's host tracer level (2, 3: the runtime's own events, and `inside_enqueue`)")
     ap.add_argument("--out", help="directory for the document and the rows")
     args = ap.parse_args(argv)
 
@@ -188,7 +219,7 @@ def main(argv=None) -> int:
     trace_dir = tempfile.mkdtemp(prefix="scope-profile-")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
-    options.host_tracer_level = 1
+    options.host_tracer_level = args.host_tracer_level
     traced = []
     c2 = run_cache_info()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
@@ -225,6 +256,8 @@ def main(argv=None) -> int:
         **profile_rows(times, ticks),
         "host_spans": {**bench_spans, **host_span_totals(path)},
     }
+    if args.host_tracer_level > 1:
+        doc["inside_enqueue"] = events_inside(path, HOST_PREFIX + "enqueue")
     print(json.dumps(doc), flush=True)
     if args.out:
         os.makedirs(args.out, exist_ok=True)

@@ -442,9 +442,11 @@ class TestAgainstTheBenchmarksReference:
 
 
 def _leaves_differ(a, b):
+    """Every leaf but the work census's (PR 41): it counts the view's
+    overflows, which the whole lane has none of."""
     import jax
 
-    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    la, lb = (jax.tree_util.tree_leaves(x._replace(census=())) for x in (a, b))
     assert len(la) == len(lb)
     return [i for i, (x, y) in enumerate(zip(la, lb)) if not np.array_equal(np.asarray(x), np.asarray(y))]
 
@@ -461,11 +463,29 @@ def whole_lane_rows():
     return one, two
 
 
+@pytest.fixture(scope="module")
+def due_rows_a_step():
+    """The lane rows due at each executed step of that one row, counted
+    from outside: the whole-lane program stepped one jump at a time."""
+    import jax
+
+    net, state = make_casper(CasperParameters(node_count=64), max_heights=24, due_view_rows=0)
+    state, end = state._replace(seed=state.seed + 7001), 3 * SLOT_MS
+    jump = jax.jit(lambda s: net._step_jump(s, end))
+    due = []
+    while int(state.time) < end:
+        due.append(int(np.sum(np.asarray(state.ovf_valid) & (np.asarray(state.ovf_arrival) <= int(state.time)))))
+        state = jump(state)
+    return due
+
+
 @pytest.mark.parametrize("rows, fits, batched", [
     (None, "always", False), (8, "on the block's steps alone", False), (1, "never", False),
     (8, "on the block's steps alone", True),
 ])
-def test_the_due_view_computes_what_the_whole_lane_does(whole_lane_rows, rows, fits, batched):
+def test_the_due_view_computes_what_the_whole_lane_does(
+    whole_lane_rows, due_rows_a_step, rows, fits, batched
+):
     """The factory's own view (256 rows of a 16,384-row lane: every step
     of a 1072-message wave fits), one that the wave's steps overflow (so
     both branches run in one simulation) and one that only an empty step
@@ -482,6 +502,15 @@ def test_the_due_view_computes_what_the_whole_lane_does(whole_lane_rows, rows, f
         got = net.run_ms_batched(replicate_state(state, 1, seeds=[7001]), 3 * SLOT_MS)
         assert np.asarray(got.time).shape == (1,)
         assert _leaves_differ(jax_first(got), one) == []
+        # the work census counts the steps that took the whole-lane branch:
+        # those with more rows due than the view holds, as counted outside
+        census = {k: int(v[0]) for k, v in got.census._asdict().items()}
+        over = sum(d > net.due_view_rows for d in due_rows_a_step)
+        assert census["steps"] == len(due_rows_a_step) == int(one.census.steps)
+        assert census["view_overflow_steps"] == over
+        assert census["due_rows_peak"] == max(due_rows_a_step)
+        assert {"always": over == 0, "never": over > len(due_rows_a_step) // 2}.get(fits, over > 0)
+        assert int(one.census.view_overflow_steps) == int(one.census.due_rows_peak) == 0
     assert int(np.asarray(got.msg_sent).sum()) > 0 and int(np.asarray(got.dropped).max()) == 0
 
 

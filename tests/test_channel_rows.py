@@ -30,6 +30,9 @@ N = 256
 
 
 LANDING_COUNTERS = ("commit_rounds", "landing_peak")  # PR 38's, Handel's alone
+# leaves the pins' parents lacked: those, and the work census (PR 41,
+# engine.core.Census: `.census.steps` and its siblings)
+UNPINNED = LANDING_COUNTERS + ("census",)
 
 
 def checksum(tree) -> int:
@@ -40,7 +43,7 @@ def checksum(tree) -> int:
     leaves = [
         leaf
         for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
-        if not any(name in jax.tree_util.keystr(path) for name in LANDING_COUNTERS)
+        if not any(name in jax.tree_util.keystr(path) for name in UNPINNED)
     ]
     for j, leaf in enumerate(leaves):
         x = np.asarray(leaf)
@@ -363,7 +366,7 @@ def test_the_landing_rows_commit_equals_the_whole_send(build, cap, traffic, monk
         jax.tree_util.tree_leaves_with_path(landed), jax.tree_util.tree_leaves(whole)
     ):
         path = jax.tree_util.keystr(path)
-        if not any(name in path for name in LANDING_COUNTERS):
+        if not any(name in path for name in UNPINNED):
             assert (np.asarray(x) == np.asarray(y)).all(), (build, cap, traffic, path)
     moved = int(np.asarray(whole.msg_received).sum())
     assert (moved > 0) == (traffic != "none")
@@ -445,6 +448,55 @@ def test_rounds_under_vmap_equal_the_single_runs(build, cap, monkeypatch):
                 jax.tree_util.tree_leaves_with_path(out), jax.tree_util.tree_leaves(single)
             ):
                 assert (np.asarray(x[j]) == np.asarray(y)).all(), (j, jax.tree_util.keystr(path))
+
+
+# -- the work census of the sender-rows send (PR 41) -------------------------
+# `census.landed_rows` and `census.extra_commit_rounds` (engine.core.Census)
+# are written where `landing_peak` and `commit_rounds` are, and reach
+# `run_cache_info()` through the run cache (tests/test_work_census.py).
+
+
+@pytest.mark.parametrize("build", ["honest", "byz51"])
+@pytest.mark.parametrize("cap", [3, None])
+def test_the_census_sums_the_landing_rows_and_the_rounds_past_the_first(build, cap, monkeypatch):
+    """Three sends in a row, the landing rows of each counted from outside
+    (`winner | fresh_win` as the commit is handed them): the census holds
+    their sum, and of the rounds those past each send's first: some with
+    3 rows a round, none at the send's own capacity."""
+    net, state = LANDED_BUILDS[build]()
+    a = net.protocol
+    own, capacity = _patched_capacity(monkeypatch, a, cap)
+    landings = []
+    for j, density in enumerate((0.05, 0.0, 0.03)):
+        args = _sender_rows_send(a, np.random.default_rng(10 + j), 5, density, False)
+        state = state._replace(time=jnp.int32(2 * j))
+        landings.append(_landing(monkeypatch, a, (net, state, *args)))
+        state = a._send_stacked(net, state, *args)
+    assert landings[0] > 3 and landings[1] == 0 and max(landings) <= own
+    assert int(state.census.landed_rows) == sum(landings)
+    extra = sum(max(-(-n // capacity) - 1, 0) for n in landings)
+    assert int(state.census.extra_commit_rounds) == extra
+    assert (extra > 0) == (cap == 3)
+    assert int(state.proto["commit_rounds"]) == sum(-(-n // capacity) for n in landings)
+    assert a.census_limits() == {"landing_peak": own}
+
+
+def test_a_whole_handel_run_counts_a_step_a_tick_and_what_landed_on_it():
+    """256 nodes tick by tick: a step a millisecond, the landing rows of a
+    tick (the census's growth) are what `landing_peak` keeps the most of,
+    a tick on which rows land takes its one round, and none takes two."""
+    net, state = _handel_fused()
+    landed, rounds = [], []
+    for _ in range(120):
+        new = net.run_ms(state, 1)
+        landed.append(int(new.census.landed_rows) - int(state.census.landed_rows))
+        rounds.append(int(new.proto["commit_rounds"]) - int(state.proto["commit_rounds"]))
+        state = new
+    assert int(state.census.steps) == 120
+    assert sum(landed) > 0 and max(landed) == int(state.proto["landing_peak"])
+    assert rounds == [int(n > 0) for n in landed]
+    assert int(state.census.extra_commit_rounds) == 0
+    assert max(landed) <= net.census_limits()["landing_peak"] == net.protocol.census_limits()["landing_peak"]
 
 
 # -- what the candidate merge lowers to (PR 34) ------------------------------

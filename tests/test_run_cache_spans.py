@@ -161,9 +161,12 @@ def test_compile_ticks_each_new_counter_once(traced):
 def test_second_call_adds_to_the_dispatch_counters_only(traced):
     _, c1, c2 = traced["counters"]
     second = _delta(c1, c2)
-    assert set(second) == {
+    # beside them only what the chunk did (the work census, PR 41) and
+    # what the collector did meanwhile
+    assert {k for k in second if not k.startswith(("census_", "gc_"))} == {
         "hits", "calls", "lookup_seconds_total", "execute_seconds_total"
     }
+    assert second["census_steps_total"] == CHUNK_MS and second["census_seconds_total"] > 0
     assert second["calls"] == 1 and second["hits"] == 1
     assert second["lookup_seconds_total"] > 0 and second["execute_seconds_total"] > 0
 

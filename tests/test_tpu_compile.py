@@ -147,7 +147,9 @@ def test_handel256_replica_sharded_program_compiles_for_four_chips(mosaic, hande
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert " all-gather(" not in text and " all-gather-start(" not in text
-    out_shapes, _stats = compiled.output_shardings
+    # the states, `stats`, and the chunk's census vector (PR 41), reduced
+    # after the map as `stats` is
+    out_shapes, _stats, _census = compiled.output_shardings
     assert out_shapes.done_at.spec == P("replicas")
 
 

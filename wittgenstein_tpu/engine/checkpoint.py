@@ -60,6 +60,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from .core import Census
+
 
 def _path_str(path) -> str:
     parts = []
@@ -96,11 +98,13 @@ ENGINE_LAYOUT = "timewheel-v3"
 COMPAT_LAYOUTS = ("timewheel-v1", "timewheel-v2")
 MANIFEST_FORMAT = 2
 
-# SimState leaves that a checkpoint may legitimately omit (none today:
-# every leaf participates in the bit-identity contract).  simlint SL501
+# SimState leaves that a checkpoint may legitimately omit.  simlint SL501
 # asserts save/restore completeness against this set — a new SimState
 # field must either checkpoint bitwise or be declared here with a reason.
-EPHEMERAL_LEAVES: frozenset = frozenset()
+# The work census (engine.core.Census, PR 41) is saved like any leaf, but
+# the dynamics read none of it: a checkpoint written before it existed
+# resumes with the template's counts and every other leaf bit for bit.
+EPHEMERAL_LEAVES: frozenset = frozenset(f"census/{name}" for name in Census._fields)
 
 
 class CheckpointError(Exception):

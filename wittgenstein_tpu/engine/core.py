@@ -159,6 +159,85 @@ _SEND_FIELDS = (
 _LANE_READ_FIELDS = ("ovf_arrival", "ovf_from", "ovf_to", "ovf_type", "ovf_payload")
 
 
+class Census(NamedTuple):
+    """The work census of a row: int32 scalars counted on the device where
+    the work happens, always on (PERF.md section 3, docs/observability.md).
+    Never read by the dynamics, no RNG is drawn for them and `send_ctr` is
+    untouched, so every other leaf is what it is without them.  The run
+    cache reduces the rows' census to one vector a chunk (`chunk_census`)
+    and folds it into `run_cache_info()`; a mechanism a protocol lacks
+    stays 0.  A new capacity takes a slot here, not a leaf of its own.
+
+    Sums grow by what a step did; peaks keep the most seen since the row
+    began, each against the static limit `BatchedNetwork.census_limits`
+    names.  Rows accepted into the store are `SimState.msg_head`'s growth
+    and Handel's landing peak is `proto["landing_peak"]`: no slot here."""
+
+    steps: jnp.ndarray  # executed steps (loop trips that ran `step`)
+    view_overflow_steps: jnp.ndarray  # steps whose due rows passed `due_view_rows`: the whole lane
+    landed_rows: jnp.ndarray  # rows a sender-rows send's claim let land (`_send_stacked`)
+    extra_commit_rounds: jnp.ndarray  # commit rounds beyond a send's first (`landing_capacity` passed)
+    due_rows_peak: jnp.ndarray  # most lane rows due in a step, against `due_view_rows`
+    wheel_fill_peak: jnp.ndarray  # fullest wheel row after a step's inserts, against `wheel_slots`
+    lane_live_peak: jnp.ndarray  # most live lane rows after a step's inserts, against `overflow_capacity`
+
+
+CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak")
+_CENSUS_ROW_SUMS = ("view_overflow_steps", "landed_rows", "extra_commit_rounds")
+
+# what a chunk's census vector holds, in order (`chunk_census`): the sums
+# first (a chunk's own, from the rows' growth), then the peaks
+CENSUS_VECTOR = (
+    "steps", "store_rows", "view_overflow_steps", "landed_rows", "extra_commit_rounds",
+    "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "landing_peak",
+)
+CENSUS_VECTOR_PEAKS = CENSUS_PEAKS + ("landing_peak",)
+
+
+def census_add(state, **amounts):
+    """`state` with `amounts` in its census: added to a sum, the larger
+    kept of a peak.  A state that carries no census (built without
+    `init_state`) is returned as it is."""
+    census = state.census
+    if not isinstance(census, Census):
+        return state
+    new = {}
+    for name, amount in amounts.items():
+        old = getattr(census, name)
+        amount = jnp.asarray(amount).astype(jnp.int32)
+        new[name] = jnp.maximum(old, amount) if name in CENSUS_PEAKS else old + amount
+    return state._replace(census=census._replace(**new))
+
+
+def chunk_census(before, after) -> jnp.ndarray:
+    """One int32 vector (`CENSUS_VECTOR`) for the chunk that took the
+    rows `before` to `after`: sums as the rows' growth (steps by max, the
+    rows being in lockstep; the others summed over rows), peaks as the
+    most any row has seen."""
+    zero = jnp.int32(0)
+    old, new, proto = before.census, after.census, after.proto
+    have = isinstance(old, Census) and isinstance(new, Census)
+
+    def grown(name, reduce=jnp.sum):
+        return reduce(getattr(new, name) - getattr(old, name)) if have else zero
+
+    def peak(name):
+        return jnp.max(getattr(new, name)) if have else zero
+
+    values = {
+        "steps": grown("steps", jnp.max),
+        "store_rows": jnp.sum(after.msg_head - before.msg_head),
+        **{name: grown(name) for name in _CENSUS_ROW_SUMS},
+        **{name: peak(name) for name in CENSUS_PEAKS},
+        "landing_peak": (
+            jnp.max(proto["landing_peak"])
+            if isinstance(proto, dict) and "landing_peak" in proto
+            else zero
+        ),
+    }
+    return jnp.stack([values[name].astype(jnp.int32) for name in CENSUS_VECTOR])
+
+
 class SimState(NamedTuple):
     """Per-replica simulation state; every field is a jnp array so the whole
     thing is a pytree (checkpointable for free — an upgrade over the
@@ -216,6 +295,9 @@ class SimState(NamedTuple):
     # makes every fault predicate constant-false, so a fault-enabled run
     # on neutral_fault_state is bit-identical too (simlint SL406)
     faults: Any = ()
+    # the work census (`Census`): counters beside the work, read by nothing
+    # in the dynamics; () on a state built without `init_state`
+    census: Any = ()
 
 
 @dataclasses.dataclass
@@ -475,10 +557,37 @@ class BatchedNetwork:
                 if self.faults is not None
                 else ()
             ),
+            census=Census(*(jnp.int32(0) for _ in Census._fields)),
         )
         for em in self.protocol.initial_emissions(self, state):
             state = self.apply_emission(state, em)
-        return state
+        return census_add(state, **self._store_fill(state))
+
+    def census_limits(self) -> dict:
+        """The static limit each peak of the census is read against
+        (`CENSUS_VECTOR_PEAKS`): the store's here, the protocol's own
+        from its `census_limits`; 0 where the mechanism is lacking."""
+        return {
+            "due_rows_peak": self.due_view_rows or 0,
+            "wheel_fill_peak": 0 if self.flat else self.wheel_slots,
+            "lane_live_peak": self.overflow_capacity,
+            "landing_peak": 0,
+            **self.protocol.census_limits(),
+        }
+
+    def _store_fill(self, state: SimState) -> dict:
+        """The store's fill as the census's peaks take it, after a step's
+        inserts: occupancy only grows there, and a step inserts after it
+        has cleared."""
+        fill = {"lane_live_peak": jnp.sum(state.ovf_valid.astype(jnp.int32))}
+        if not self.flat:
+            fill["wheel_fill_peak"] = jnp.max(state.whl_fill)
+        return fill
+
+    def _census_step(self, state: SimState) -> SimState:
+        """What one executed step adds to the census (called before the
+        time advance, from every loop)."""
+        return census_add(state, steps=1, **self._store_fill(state))
 
     def cache_key(self) -> tuple:
         """Explicit identity for compiled-program caches (parallel
@@ -1082,7 +1191,9 @@ class BatchedNetwork:
         # so the same step: a wave's fullest ms decides the size).  The
         # lane's planes that a delivery only reads stay out of the branch
         ovf_due = state.ovf_valid & (state.ovf_arrival <= state.time)
-        fits = jnp.sum(ovf_due.astype(jnp.int32)) <= self.due_view_rows
+        n_due = jnp.sum(ovf_due.astype(jnp.int32))
+        fits = n_due <= self.due_view_rows
+        state = census_add(state, view_overflow_steps=~fits, due_rows_peak=n_due)
         carried = [f for f in SimState._fields if f not in _LANE_READ_FIELDS]
         shape = []  # the emissions' static part, the same in both branches
 
@@ -1332,7 +1443,7 @@ class BatchedNetwork:
             state = self.protocol.tick_beat(self, state)
         with self._scope("post"):
             state = self.protocol.tick_post(self, state)
-        state = self._tele_tick(state)
+        state = self._census_step(self._tele_tick(state))
         return state._replace(time=state.time + 1)
 
     # -- occupancy summaries --------------------------------------------------
@@ -1590,6 +1701,7 @@ class BatchedNetwork:
             s = post_v(s)
             if self.telemetry is not None:
                 s = jax.vmap(self._tele_tick)(s)
+            s = jax.vmap(self._census_step)(s)
             return s._replace(time=s.time + 1)
 
         if not stop_when_done:

@@ -46,12 +46,15 @@ ENGINE_OWNED_FIELDS = (
     "dropped",
     "tele",
     "faults",
+    "census",
 )
 
 # Hooks traced under jit (tracer-safety rules apply) vs host-side
 # construction hooks (plain Python allowed).
 KERNEL_HOOKS = ("deliver", "tick", "tick_beat", "tick_post", "all_done")
-HOST_HOOKS = ("proto_init", "initial_emissions", "msg_size", "n_msg_types", "mtype")
+HOST_HOOKS = (
+    "proto_init", "initial_emissions", "msg_size", "n_msg_types", "mtype", "census_limits",
+)
 
 
 class BatchedProtocol:
@@ -152,6 +155,12 @@ class BatchedProtocol:
     def proto_init(self, n_nodes: int) -> Any:
         """Protocol-state pytree for a fresh replica (Protocol.init)."""
         return ()
+
+    def census_limits(self) -> dict:
+        """The static limits of the protocol's OWN capacities, by the name
+        of the peak the work census reads against each (engine.core
+        `CENSUS_VECTOR_PEAKS`; the engine adds its store's): none here."""
+        return {}
 
     def initial_emissions(self, net, state) -> List:
         """Messages injected at t=0 (the protocol's init() sends)."""

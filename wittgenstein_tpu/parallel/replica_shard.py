@@ -29,15 +29,19 @@ tick "store_hits" instead.
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import threading
-from collections import OrderedDict
+import time
+from collections import OrderedDict, deque
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.core import CENSUS_VECTOR, CENSUS_VECTOR_PEAKS, chunk_census
 from ..profiling.xla_cost import compiled_cost_summary, hlo_op_scopes
 from ..runtime.locks import make_lock, yield_point
 from ..tools.profiling import host_span
@@ -115,7 +119,42 @@ _COUNTERS = {
     # published to the cross-process store (runtime.compile_store)
     "store_hits": 0,
     "store_puts": 0,
+    # the work census (engine.core.Census), counted on the device inside
+    # the chunk program and folded here a call later (`_harvest`): what
+    # the chunks did, as counts (PERF.md section 3, docs/observability.md)
+    **{
+        f"census_{name}_total": 0
+        for name in CENSUS_VECTOR
+        if name not in CENSUS_VECTOR_PEAKS
+    },
+    # its peaks: gauges since process start, each beside the static limit
+    # of the program that set it, so headroom is one subtraction
+    **{f"census_{name}": 0 for name in CENSUS_VECTOR_PEAKS},
+    **{f"census_{name}_limit": 0 for name in CENSUS_VECTOR_PEAKS},
+    # the host's part of the census: the copy's start and the folds
+    "census_seconds_total": 0.0,
 }
+
+# chunk census vectors whose copy to the host is under way, oldest first,
+# each with its program's limits: folded by a later call once ready
+_PENDING_CENSUS: "deque[tuple]" = deque()
+_CENSUS_LOCK = make_lock("runcache.census")
+
+# the collector's pauses, for the stalled chunks PERF.md section 7 puts
+# down to a cold run's garbage.  Written by the hook alone (collections
+# do not nest), and without `_COUNTER_LOCK`: a collection can start
+# inside it
+_GC = {"gc_pause_seconds_total": 0.0, "gc_collections_total": 0}
+_GC_STARTED = [None]
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_STARTED[0] = time.perf_counter()
+    elif _GC_STARTED[0] is not None:
+        _GC["gc_pause_seconds_total"] += time.perf_counter() - _GC_STARTED[0]
+        _GC["gc_collections_total"] += 1
+        _GC_STARTED[0] = None
 
 
 def _count(key: str, amount=1) -> None:
@@ -128,6 +167,41 @@ def _span(name: str, key: "str | None" = None) -> host_span:
     seconds added to `_COUNTERS[key]` (PERF.md §3 names each span's
     reader)."""
     return host_span(name, _COUNTERS if key else None, key, lock=_COUNTER_LOCK)
+
+
+def _fold_census(vector, limits: dict) -> None:
+    """One chunk's census into `_COUNTERS`: sums added, of a peak the
+    larger kept, with the limit of the program that reached it (or of
+    the first program that has the mechanism, while the peak is 0)."""
+    values = dict(zip(CENSUS_VECTOR, np.asarray(vector).tolist()))
+    with _COUNTER_LOCK:
+        for name, value in values.items():
+            key = f"census_{name}"
+            if name not in CENSUS_VECTOR_PEAKS:
+                _COUNTERS[key + "_total"] += value
+            elif value > _COUNTERS[key] or not _COUNTERS[key + "_limit"]:
+                _COUNTERS[key] = max(value, _COUNTERS[key])
+                _COUNTERS[key + "_limit"] = limits[name]
+
+
+def _harvest(vector=None, limits=None, wait: bool = False) -> None:
+    """The census's host side.  With a chunk's `vector`: start its copy
+    to the host and queue it.  Then fold the queued vectors that have
+    arrived, oldest first, each exactly once (whoever takes one off the
+    queue folds it); `wait` takes them all and blocks on them outside the
+    queue's lock (`run_cache_info`, outside any window).  The chunk just
+    enqueued is still running and is never waited for here."""
+    with _span("census", "census_seconds_total"):
+        if vector is not None:
+            vector.copy_to_host_async()
+        arrived = []
+        with _CENSUS_LOCK:
+            if vector is not None:
+                _PENDING_CENSUS.append((vector, limits))
+            while _PENDING_CENSUS and (wait or _PENDING_CENSUS[0][0].is_ready()):
+                arrived.append(_PENDING_CENSUS.popleft())
+        for item in arrived:
+            _fold_census(*item)
 
 
 class _CachedRun:
@@ -159,13 +233,18 @@ class _CachedRun:
         self.stable_key = (
             "run/"
             + hashlib.blake2b(
-                repr((stable(), self.sim_ms, geometry)).encode(),
+                # the program's outputs are part of its identity: one
+                # stored before the census vector is never adopted
+                repr((stable(), self.sim_ms, geometry, "census-1")).encode(),
                 digest_size=12,
             ).hexdigest()
             if callable(stable)
             else None
         )
 
+        self.census_limits = net.census_limits()
+        if _gc_hook not in gc.callbacks:  # once a process
+            gc.callbacks.append(_gc_hook)
         self._programs: "OrderedDict[tuple, object]" = OrderedDict()
         self._summaries: "OrderedDict[tuple, dict]" = OrderedDict()
         # XLA compiles release the GIL, so two threads calling with the
@@ -211,7 +290,8 @@ class _CachedRun:
                 / n_live,
                 "all_done": jnp.all(jnp.where(live, out.done_at > 0, True)),
             }
-            return out, stats
+            # beside `stats`, not in it: its keys are the callers'
+            return out, stats, chunk_census(s, out)
 
         return fn
 
@@ -315,9 +395,10 @@ class _CachedRun:
                                 _count("store_puts")
                     self._programs[sig] = compiled
         with _span("enqueue", "execute_seconds_total"):
-            out = compiled(states)
+            out, stats, census = compiled(states)
         _count("calls")
-        return out
+        _harvest(census, self.census_limits)
+        return out, stats
 
     def summaries(self) -> list:
         return list(self._summaries.values())
@@ -344,11 +425,12 @@ def clear_run_cache() -> None:
 def _counters() -> dict:
     """`_COUNTERS` as exported: with `compile_seconds_total`, what
     `.lower(states).compile()` took, as the sum of its two parts."""
+    _harvest(wait=True)
     out = dict(_COUNTERS)
     out["compile_seconds_total"] = (
         out["lower_seconds_total"] + out["backend_compile_seconds_total"]
     )
-    return out
+    return {**out, **_GC}
 
 
 def run_cache_info() -> dict:

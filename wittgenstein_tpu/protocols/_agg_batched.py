@@ -94,7 +94,7 @@ import numpy as np
 from jax import lax
 
 from ..engine import BatchedProtocol
-from ..engine.core import CHANNEL_SCOPES
+from ..engine.core import CHANNEL_SCOPES, census_add
 from ..ops.bitops import lowest_set_bit, popcount_words, xor_shuffle
 from ..ops.select import run_rank, sort_with_order
 
@@ -607,6 +607,11 @@ class BitsetAggBase(BatchedProtocol):
             if "commit_rounds" in proto:
                 updates["commit_rounds"] = proto["commit_rounds"] + rounds
                 updates["landing_peak"] = jnp.maximum(proto["landing_peak"], landing)
+            # the work census: what landed of the M rows this send carried,
+            # and the rounds a landing count past the capacity added
+            state = census_add(
+                state, landed_rows=landing, extra_commit_rounds=jnp.maximum(rounds - 1, 0)
+            )
         if aux is not None:
             with scope("commit"):
                 new_aux = proto["in_aux"].at[win_to, col].set(

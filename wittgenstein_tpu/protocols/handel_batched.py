@@ -120,7 +120,7 @@ from ..engine.rng import hash32
 from ..ops.bitops import popcount_words, xor_shuffle
 from ..ops.select import take_slot, top_k_merge
 from ..utils.javarand import JavaRandom
-from ._agg_batched import INT32_MAX, BitsetAggBase
+from ._agg_batched import INT32_MAX, BitsetAggBase, landing_capacity
 from .handel import HandelParameters
 
 
@@ -217,6 +217,16 @@ class BatchedHandel(BitsetAggBase):
             if dt.itemsize < 4:
                 leaves.append(NarrowLeaf(name, dt.name, bound, sentinel))
         return tuple(leaves)
+
+    def census_limits(self) -> dict:
+        """`landing_peak` is read against the rows a round of the fast
+        path's commit carries: `landing_capacity` of the N x
+        ceil(fast_path / 2) rows its send has; 0 without a fast path."""
+        p, n = self.params, self.n_nodes
+        if not (p.fast_path > 0 and self.n_levels > 1):
+            return {"landing_peak": 0}
+        fp = min(p.fast_path, max(1, n // 2))
+        return {"landing_peak": landing_capacity(n * ((fp + 1) // 2))}
 
     def msg_size(self, mtype: int) -> int:
         # Size = level + bit field + the signatures included + our own sig

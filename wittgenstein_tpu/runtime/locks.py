@@ -126,6 +126,11 @@ LOCK_HIERARCHY: Tuple[LockSpec, ...] = (
         doc="per-entry compile serialization (the PR-11 guard)",
     ),
     LockSpec(
+        "runcache.census", ("parallel/replica_shard.py::GLOBAL._CENSUS_LOCK",),
+        doc="the queue of chunk census vectors on their way to the host "
+            "(a leaf: folds and waits happen outside it)",
+    ),
+    LockSpec(
         "runcache.counters", ("parallel/replica_shard.py::GLOBAL._COUNTER_LOCK",),
         doc="the adds to the run cache's monotonic counters (a leaf: "
             "taken on leaving a span, under the compile lock or none)",

@@ -244,6 +244,41 @@ class Server:
                   "(call to return, not device time)", "counter")
             p.add("run_cache_calls_total", info["calls"],
                   "compiled run programs enqueued", "counter")
+            # the work census (engine.core.Census): what the chunks did,
+            # counted on the device; each peak beside the static limit
+            # it sizes (docs/observability.md names the knob)
+            for name, what in (
+                ("steps", "loop trips that executed a step"),
+                ("store_rows", "rows accepted into the wheel or the lane"),
+                ("view_overflow_steps",
+                 "steps whose due rows passed due_view_rows (whole-lane branch)"),
+                ("landed_rows", "rows a sender-rows send's claim let land"),
+                ("extra_commit_rounds",
+                 "commit rounds beyond a send's first (landing_capacity passed)"),
+            ):
+                p.add(f"run_cache_census_{name}_total",
+                      info[f"census_{name}_total"], what, "counter")
+            for name, knob in (
+                ("due_rows_peak", "due_view_rows"),
+                ("wheel_fill_peak", "wheel_slots"),
+                ("lane_live_peak", "overflow_capacity"),
+                ("landing_peak", "landing_capacity(M)"),
+            ):
+                p.add(f"run_cache_census_{name}", info[f"census_{name}"],
+                      f"most seen since process start, against {knob}", "gauge")
+                p.add(f"run_cache_census_{name}_limit",
+                      info[f"census_{name}_limit"],
+                      f"{knob} of the program that reached the peak", "gauge")
+            p.add("run_cache_census_seconds_total",
+                  round(info["census_seconds_total"], 6),
+                  "wall-clock spent starting the census copies and "
+                  "folding them", "counter")
+            p.add("run_cache_gc_pause_seconds_total",
+                  round(info["gc_pause_seconds_total"], 6),
+                  "wall-clock inside Python's cyclic collector", "counter")
+            p.add("run_cache_gc_collections_total",
+                  info["gc_collections_total"],
+                  "collections of Python's cyclic collector", "counter")
         except Exception:
             pass
         try:
