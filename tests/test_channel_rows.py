@@ -11,6 +11,13 @@ drops updates that were addressed to the dropped row, and the
 sender-rows entry (Handel's fast path) commits the landing rows alone,
 so no leaf may move by a bit.  The two counters PR 38 put beside
 `displaced` are left out by name.
+
+Since PR 42 arrivals and the claim of the two every-tick sends (Handel's
+fast path, GSF's accelerated calls) run over the rows that FIRE,
+`firing_capacity` of them a round (`_send_fired`): held here against the
+whole-M body the flat entry keeps, state for state, with the capacity
+patched small so that a send takes many rounds, and in the lowered
+programs' scatter update counts.
 """
 
 import re
@@ -33,6 +40,15 @@ LANDING_COUNTERS = ("commit_rounds", "landing_peak")  # PR 38's, Handel's alone
 # leaves the pins' parents lacked: those, and the work census (PR 41,
 # engine.core.Census: `.census.steps` and its siblings)
 UNPINNED = LANDING_COUNTERS + ("census",)
+
+
+def _assert_same_state(got, want, note, but=UNPINNED):
+    for (path, x), y in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
+    ):
+        path = jax.tree_util.keystr(path)
+        if not any(name in path for name in but):
+            assert (np.asarray(x) == np.asarray(y)).all(), (note, path)
 
 
 def checksum(tree) -> int:
@@ -137,10 +153,13 @@ _SCATTER = re.compile(
     r"\(tensor<([^>]+)>, tensor<[^>]+>, tensor<([^>]+)>\) ->",
     re.S,
 )
-# stablehlo.gather ops in the lowered GSF tick of the parent (commit
-# 913a72b, PR 31) at 256 nodes: the cut is by reshape and slice, an index
-# array would add to these
-PARENT_GSF_TICK_GATHERS = 82
+# stablehlo.gather ops in the lowered GSF tick at 256 nodes: 82 at the
+# parent of PR 32 (commit 913a72b, PR 31; the cut is by reshape and slice,
+# an index array would add to these) and since PR 42 the reads of the
+# firing rows besides (sender, receiver and level of a round's rows in
+# `arrive`, receiver, level and aux in `claim`: 10 by this count, which
+# finds the generic form's name twice an op)
+GSF_TICK_GATHERS = 92
 
 
 def _dims(tensor: str):
@@ -239,7 +258,7 @@ def test_a_static_level_send_scatters_only_its_buckets_rows(name, build, hook, k
     assert 2 * N * k * widths == commit_updates(N, k, level_axis=True)
     if name == "gsf tick":
         gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
-        assert gathers <= PARENT_GSF_TICK_GATHERS
+        assert gathers <= GSF_TICK_GATHERS
 
 
 def test_the_fast_path_scatters_one_round_of_landing_rows():
@@ -362,12 +381,7 @@ def test_the_landing_rows_commit_equals_the_whole_send(build, cap, traffic, monk
     paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(whole)]
     for name in names:
         assert any(name in p for p in paths), name
-    for (path, x), y in zip(
-        jax.tree_util.tree_leaves_with_path(landed), jax.tree_util.tree_leaves(whole)
-    ):
-        path = jax.tree_util.keystr(path)
-        if not any(name in path for name in UNPINNED):
-            assert (np.asarray(x) == np.asarray(y)).all(), (build, cap, traffic, path)
+    _assert_same_state(landed, whole, (build, cap, traffic))
     moved = int(np.asarray(whole.msg_received).sum())
     assert (moved > 0) == (traffic != "none")
     if traffic != "none":
@@ -379,16 +393,20 @@ def test_the_landing_rows_commit_equals_the_whole_send(build, cap, traffic, monk
 
 
 def _landing(monkeypatch, a, args) -> int:
-    """Rows of a send that the claim lets land: `winner | fresh_win` as
-    `_send_stacked` hands them to the commit."""
+    """Rows of a send that the claim lets land: the row numbers
+    `_send_fired` lists for the commit (below M; M marks the list's
+    tail), which are as many as it counted."""
     from wittgenstein_tpu.protocols._agg_batched import BitsetAggBase
 
     seen = []
     real = BitsetAggBase._commit_landed
 
-    def spy(self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, *rest):
-        seen.append(int(np.asarray(winner | fresh_win).sum()))
-        return real(self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, *rest)
+    def spy(self, sigs, words, frm, to_idx, level, land_rows, land_info, landing, *rest):
+        listed = np.asarray(land_rows)
+        listed = listed[listed < to_idx.shape[0]]
+        assert len(set(listed.tolist())) == listed.size == int(landing)
+        seen.append(listed.size)
+        return real(self, sigs, words, frm, to_idx, level, land_rows, land_info, landing, *rest)
 
     with monkeypatch.context() as patch:
         patch.setattr(BitsetAggBase, "_commit_landed", spy)
@@ -444,10 +462,7 @@ def test_rounds_under_vmap_equal_the_single_runs(build, cap, monkeypatch):
         singles = [a._send_stacked(net, state, *args) for args in pair]
         assert int(singles[0].proto["commit_rounds"]) != int(singles[1].proto["commit_rounds"])
         for j, single in enumerate(singles):
-            for (path, x), y in zip(
-                jax.tree_util.tree_leaves_with_path(out), jax.tree_util.tree_leaves(single)
-            ):
-                assert (np.asarray(x[j]) == np.asarray(y)).all(), (j, jax.tree_util.keystr(path))
+            _assert_same_state(jax.tree_util.tree_map(lambda x: x[j], out), single, j, but=())
 
 
 # -- the work census of the sender-rows send (PR 41) -------------------------
@@ -478,7 +493,7 @@ def test_the_census_sums_the_landing_rows_and_the_rounds_past_the_first(build, c
     assert int(state.census.extra_commit_rounds) == extra
     assert (extra > 0) == (cap == 3)
     assert int(state.proto["commit_rounds"]) == sum(-(-n // capacity) for n in landings)
-    assert a.census_limits() == {"landing_peak": own}
+    assert a.census_limits()["landing_peak"] == own
 
 
 def test_a_whole_handel_run_counts_a_step_a_tick_and_what_landed_on_it():
@@ -499,6 +514,170 @@ def test_a_whole_handel_run_counts_a_step_a_tick_and_what_landed_on_it():
     assert max(landed) <= net.census_limits()["landing_peak"] == net.protocol.census_limits()["landing_peak"]
 
 
+# -- arrivals and the claim over the rows that fire (PR 42) -------------------
+# The two every-tick entries (rows by sender; the level axis with k > 1)
+# number their firing rows to the front and run `_arrive`, `_claim_keys`
+# and `_claim_winners` over `firing_capacity(rows)` of them a round; the
+# flat entry keeps the whole-M body, so it is the reference, as above.
+
+
+# build, and the entry its every-tick send takes
+FIRED_BUILDS = {
+    "honest": (_handel_fused, "sender"), "byz51": (_handel_byz, "sender"), "gsf": (_gsf_small, "axis"),
+}
+
+
+def _fired_send(a, entry, rng, density, crowd, has_aux):
+    """(the entry's own arguments, the same send a row each)."""
+    from test_agg_buckets import _flat_send_args, _random_send  # the level-axis send and its flat form
+
+    if entry == "sender":
+        args = _sender_rows_send(a, rng, 5, density, crowd)
+        aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1)), jnp.int32) if has_aux else None
+        *flat, x = _flat(a, *args, aux)
+    else:
+        mask, frm, to, level, blocks = _random_send(
+            a, rng, a.params.accelerated_calls_count, density, crowd)
+        args = (mask, frm, to, None, blocks)  # the axis numbers its own levels
+        aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1, 1)), jnp.int32) if has_aux else None
+        *flat, x = _flat_send_args(a, mask, frm, to, level, blocks, aux)
+    return (args, aux), (flat, x)
+
+
+def _patched_firing(monkeypatch, cap):
+    """Rows a round of arrivals and the claim for the case, through the
+    one function `_send_stacked` asks: None is the send's own F, "M" one
+    round over every row."""
+    from wittgenstein_tpu.protocols import _agg_batched
+
+    real = _agg_batched.firing_capacity
+    patched = {None: real, "M": lambda rows: int(np.prod(rows))}.get(cap, lambda rows: cap)
+    monkeypatch.setattr(_agg_batched, "firing_capacity", patched)
+    return real
+
+
+# rows a round: 1 and 7 take a round a row or nearly (a key of a later
+# round beats a row that held its slot after its own), 50 a few rounds of
+# a spread send
+FIRED_CASES = [
+    (build, cap, traffic)
+    for build in sorted(FIRED_BUILDS)
+    for cap, traffic in (
+        (1, "crowd"), (7, "crowd"), (7, "none"), (50, "spread"), (None, "spread"), ("M", "crowd"),
+    )
+]
+
+
+@pytest.mark.parametrize("build, cap, traffic", FIRED_CASES)
+def test_the_firing_rows_send_equals_the_whole_send(build, cap, traffic, monkeypatch):
+    """State for state, `displaced` too: three crowded sends in a row (the
+    later ones leave earlier, so winners evict pending occupants and rows
+    lose both slots), a spread one, an empty one; at every capacity but
+    the send's own and M the firing rows take many rounds."""
+    factory, entry = FIRED_BUILDS[build]
+    net, state = factory()
+    a = net.protocol
+    real = _patched_firing(monkeypatch, cap)
+    rng = np.random.default_rng(0)
+    has_aux = "in_aux" in state.proto
+    fired = whole = state
+    sends = 3 if traffic == "crowd" else 1
+    density = {"spread": 0.6, "crowd": 0.9, "none": 0.0}[traffic]
+    overflows = 0
+    for j in range(sends):
+        (args, aux), (flat, x) = _fired_send(a, entry, rng, density, traffic == "crowd", has_aux)
+        at = jnp.int32(2 * (sends - 1 - j))
+        fired = a._send_stacked(net, fired._replace(time=at), *args, aux=aux)
+        whole = a._send_stacked(net, whole._replace(time=at), *flat, aux=x)
+        rows = args[0].shape
+        capacity = {None: real(rows), "M": int(np.prod(rows))}.get(cap, cap)
+        overflows += int(np.asarray(args[0]).sum()) > capacity
+    _assert_same_state(fired, whole, (build, cap, traffic))
+    assert int(whole.census.fired_rows) == 0  # the flat entry is the whole-M body
+    assert (int(np.asarray(whole.msg_received).sum()) > 0) == (traffic != "none")
+    if traffic == "crowd":
+        assert int(whole.proto["displaced"]) > 0
+    if has_aux and traffic != "none":
+        assert np.asarray(whole.proto["in_aux"]).any()
+    assert int(fired.census.firing_overflows) == overflows
+    assert (overflows > 0) == (traffic != "none" and cap not in ("M", None) or (cap is None and density > 0.5))
+
+
+@pytest.mark.parametrize("build", sorted(FIRED_BUILDS))
+def test_firing_rounds_under_vmap_equal_the_single_runs(build, monkeypatch):
+    """Two rows, one that fires more than a round carries and one that
+    fires less (and, the other way round, one that fires nothing): each
+    batched loop runs until the slower row is through, and every leaf of
+    each row, the counters and the census among them, equals its single
+    run's."""
+    factory, entry = FIRED_BUILDS[build]
+    net, state = factory()
+    a = net.protocol
+    _patched_firing(monkeypatch, 64)
+    has_aux = "in_aux" in state.proto
+    send = lambda seed, density: _fired_send(  # noqa: E731
+        a, entry, np.random.default_rng(seed), density, False, has_aux)[0]
+    busy, few, quiet = send(3, 0.5), send(4, 0.01), send(5, 0.0)
+    for pair in ((busy, few), (quiet, busy)):
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *pair)
+        states = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), state)
+        out = jax.vmap(lambda s, args, aux: a._send_stacked(net, s, *args, aux=aux))(states, *stacked)
+        singles = [a._send_stacked(net, state, *args, aux=aux) for args, aux in pair]
+        over = [int(np.asarray(args[0]).sum()) > 64 for args, _ in pair]
+        assert sorted(over) == [False, True]
+        assert [int(s.census.firing_overflows) for s in singles] == [int(o) for o in over]
+        for j, single in enumerate(singles):
+            _assert_same_state(jax.tree_util.tree_map(lambda x: x[j], out), single, (build, j), but=())
+
+
+def _scatter_rows(text, operand_dims, dtype="i32"):
+    """Update rows of every scatter into an operand of that shape."""
+    return [
+        _dims(updates)[0]
+        for operand, updates in _SCATTER.findall(text)
+        if operand.endswith(dtype) and _dims(operand) == tuple(operand_dims)
+    ]
+
+
+@pytest.mark.parametrize(
+    "name, build, rows",
+    [
+        ("handel tick", _handel_fused, (N, 5)),
+        ("gsf tick", _gsf, (N, 8, 10)),
+    ],
+)
+def test_the_every_tick_sends_scatter_a_round_of_firing_rows(name, build, rows):
+    """The counter that says the firing compaction engaged is static too:
+    in the lowered tick every scatter into `in_key` (the claim's min and
+    max; `in_aux` beside it) and into a node column (the traffic counters of `_arrive` and of
+    `latency_arrivals`) carries F = firing_capacity(rows) update rows,
+    inside the rounds' loops, and none the M rows of the send; the beat's
+    send, whose rows mostly fire, still carries its M."""
+    from wittgenstein_tpu.protocols._agg_batched import firing_capacity
+
+    net, state = build()
+    a = net.protocol
+    m, f = int(np.prod(rows)), firing_capacity(rows)
+    assert f <= m / 5 and net.census_limits()["firing_peak"] == f
+    text = _lowered(net, state, "tick")
+    # the claim's min and max into `in_key` and, where the sends carry an aux
+    # word (GSF), the two writes of the `in_aux` plane, of the same shape
+    planes = 4 if "in_aux" in state.proto else 2
+    keys = _scatter_rows(text, state.proto["in_key"].shape)
+    assert keys == [f] * planes, (name, keys)
+    counters = _scatter_rows(text, (N,))
+    # msg_sent, bytes_sent, msg_received, bytes_received (and sent_not_ok
+    # where a node is down) of the send; the tick's other [N] scatters
+    # (a node a row) are not the send's
+    assert counters.count(f) >= 4 and m not in counters, (name, counters)
+    assert text.count("stablehlo.while") >= 2  # arrive's rounds, claim's rounds
+    beat = _lowered(net, state, "tick_beat")
+    m_beat = N * (a.n_levels - 1)
+    assert _scatter_rows(beat, state.proto["in_key"].shape) == [m_beat] * planes
+    assert _scatter_rows(beat, (N,)).count(m_beat) >= 4
+    assert "stablehlo.while" not in beat
+
+
 # -- what the candidate merge lowers to (PR 34) ------------------------------
 # The merge engages on every (node, level) of every tick, so its counter
 # is static too: no sort and no gather under `witt.deliver.merge`, and
@@ -513,8 +692,12 @@ def test_a_whole_handel_run_counts_a_step_a_tick_and_what_landed_on_it():
 # `witt.channel.compact` (the landing rows' receiver, level, slot, rel,
 # two win flags and their senders' words: 14 by this count, which finds
 # the generic form's name twice an op) and took out the six table reads
-# of `_dyn_low` (block size and width are arithmetic on the level now)
-HANDEL_TICK_GATHERS = {"handel_fused": 82, "handel_byz51": 94}
+# of `_dyn_low` (block size and width are arithmetic on the level now).
+# 82 and 94 until PR 42, whose rounds of firing rows read a row's sender,
+# receiver and level in `arrive` and its receiver and level in `claim`
+# (10 by this count), where a commit round reads four columns for seven
+# (its rows' slot and win bits come with the landing list: 6 fewer)
+HANDEL_TICK_GATHERS = {"handel_fused": 86, "handel_byz51": 98}
 
 
 def _scoped_primitives(jaxpr, scope: str, inside: bool = False, out=None):
@@ -544,10 +727,11 @@ def test_the_candidate_merge_has_no_sort_and_no_gather(name):
     assert len(prims) > 100, prims  # the scope is live: the merge is under it
     assert not {"sort", "gather", "dynamic_slice", "scatter"} & set(prims), sorted(set(prims))
     text = _lowered(net, state, "tick")
-    # none of the merge's seven argsorts: the one sort left in the tick
-    # puts the landing rows of the fast path's send first (PR 38)
-    assert len(re.findall(r"stablehlo\.sort", text)) == 1
-    assert _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, "witt.channel.compact").count("sort") == 1
+    # none of the merge's seven argsorts: the two sorts left in the tick
+    # put the firing rows of the fast path's send first (PR 42) and, of a
+    # round of them, the landing rows (PR 38)
+    assert len(re.findall(r"stablehlo\.sort", text)) == 2
+    assert _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, "witt.channel.compact").count("sort") == 2
     gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
     assert gathers <= HANDEL_TICK_GATHERS[name], gathers
 

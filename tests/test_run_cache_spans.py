@@ -106,12 +106,9 @@ def traced(request, cold_compiles, tmp_path_factory):
     (op_scopes,) = rs.run_cache_op_scopes(net, CHUNK_MS).values()
     (program,) = rs._RUN_CACHE[rs._entry_key(net, CHUNK_MS, None)]._programs.values()
     return {
-        # `compact` is the sender-rows send's (Handel's fast path): GSF's
-        # sends all lie on a level axis
-        "channel_scopes": {
-            scope for name, scope in CHANNEL_SCOPES.items()
-            if name != "compact" or request.param == "handel"
-        },
+        # `compact` is the every-tick sends' (Handel's fast path, and
+        # since PR 42 GSF's accelerated calls: the firing rows to the front)
+        "channel_scopes": set(CHANNEL_SCOPES.values()),
         "counters": (c0, c1, c2),
         "trace_path": path,
         "data": ProfileData.from_file(path),

@@ -41,6 +41,8 @@ NEW_METRICS = {
     "extra_commit_rounds_in_window": "census_extra_commit_rounds_total",
     "census_s_in_window": "census_seconds_total",
     "gc_pause_s_in_window": "gc_pause_seconds_total",
+    "fired_rows_in_window": "census_fired_rows_total",
+    "firing_overflows_in_window": "census_firing_overflows_total",
 }
 
 
@@ -134,7 +136,7 @@ def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanf
     assert int(np.asarray(out.census.wheel_fill_peak).max()) <= net.census_limits()["wheel_fill_peak"]
     assert net.census_limits() == {
         "due_rows_peak": 0, "wheel_fill_peak": net.wheel_slots,
-        "lane_live_peak": net.overflow_capacity, "landing_peak": 0}
+        "lane_live_peak": net.overflow_capacity, "firing_peak": 0, "landing_peak": 0}
 
 
 # -- the harvest -----------------------------------------------------------------
@@ -211,8 +213,9 @@ def test_rows_without_a_census_harvest_zeros_and_the_stores_rows():
 def test_a_peak_keeps_the_larger_and_the_limit_of_the_program_that_reached_it(monkeypatch):
     monkeypatch.setattr(rs, "_COUNTERS", dict(rs._COUNTERS, **{
         k: 0 for k in rs._COUNTERS if k.startswith("census_") and "seconds" not in k}))
-    limits = {"due_rows_peak": 8, "wheel_fill_peak": 64, "lane_live_peak": 128, "landing_peak": 0}
-    vector = dict(zip(CENSUS_VECTOR, range(1, 10)))
+    limits = {"due_rows_peak": 8, "wheel_fill_peak": 64, "lane_live_peak": 128,
+              "firing_peak": 0, "landing_peak": 0}
+    vector = dict(zip(CENSUS_VECTOR, range(1, len(CENSUS_VECTOR) + 1)))
     rs._fold_census(np.asarray([vector[n] for n in CENSUS_VECTOR], np.int32), limits)
     rs._fold_census(np.asarray([vector[n] for n in CENSUS_VECTOR], np.int32), limits)
     info = rs._COUNTERS
@@ -220,7 +223,8 @@ def test_a_peak_keeps_the_larger_and_the_limit_of_the_program_that_reached_it(mo
     assert info["census_wheel_fill_peak"] == vector["wheel_fill_peak"]  # a peak does not
     assert info["census_wheel_fill_peak_limit"] == 64
     # a program without the mechanism reads 0 and leaves the limit alone
-    other = {"due_rows_peak": 0, "wheel_fill_peak": 0, "lane_live_peak": 16, "landing_peak": 0}
+    other = {"due_rows_peak": 0, "wheel_fill_peak": 0, "lane_live_peak": 16,
+             "firing_peak": 0, "landing_peak": 0}
     rs._fold_census(np.zeros(len(CENSUS_VECTOR), np.int32), other)
     assert info["census_wheel_fill_peak_limit"] == 64 and info["census_due_rows_peak_limit"] == 8
     # and a higher peak brings its own program's limit
@@ -241,6 +245,91 @@ def test_the_collectors_pauses_are_counted():
     assert after["gc_pause_seconds_total"] > before["gc_pause_seconds_total"]
 
 
+# -- the firing rows of the every-tick channel sends (PR 42) --------------------
+# `_agg_batched.py` `_send_fired`: `fired_rows` the send's `mask.sum()`,
+# `firing_overflows` the sends whose count passed `firing_capacity(rows)`,
+# `firing_peak` the most rows one send fired, against that capacity.
+
+
+def _channel(name):
+    return registry_batched_protocols.get(name).factory()
+
+
+def _channel256(name):
+    """The benchmark's two channel deployments at 256 nodes."""
+    import test_channel_rows
+
+    return {"handel": test_channel_rows._handel_fused, "gsf": test_channel_rows._gsf}[name]()
+
+
+@pytest.mark.parametrize("name", ["handel", "gsf"])
+def test_fired_rows_are_the_masked_rows_of_a_run(name):
+    """256 nodes tick by tick from t=0.  A masked row ticks its sender's
+    `msg_sent` once, and only the beat sends besides: on every tick but
+    the beat's (one in `period`) the growth of the summed `msg_sent` IS
+    the send's `mask.sum()`, counted from outside; the beat's rows are
+    not the census's.  No tick passes the shipped capacity."""
+    net, state = _channel256(name)
+    params = net.protocol.params
+    period = getattr(params, "dissemination_period_ms", None) or params.period_duration_ms
+    limit = net.census_limits()["firing_peak"]
+    assert limit >= 256
+    fired, sent = [], []
+    for _ in range(300):
+        new = net.run_ms(state, 1)
+        fired.append(int(new.census.fired_rows) - int(state.census.fired_rows))
+        sent.append(int(np.asarray(new.msg_sent).sum()) - int(np.asarray(state.msg_sent).sum()))
+        state = new
+    beats = {t % period for t, (f, s) in enumerate(zip(fired, sent)) if s != f}
+    assert len(beats) == 1, beats  # the beat's phase, and no other tick
+    assert all(s >= f for f, s in zip(fired, sent))
+    assert sum(fired) == int(state.census.fired_rows) > 0
+    assert int(state.census.firing_peak) == max(fired) <= limit
+    assert int(state.census.firing_overflows) == 0
+
+
+@pytest.mark.parametrize("name", ["handel", "gsf"])
+def test_forced_overflows_are_counted_and_cost_nothing_else(name, monkeypatch):
+    """The capacity patched to 3 rows a round: the ticks that fire more
+    are the overflows the census counts, and every other leaf (the new
+    `fired_rows` and `firing_peak` among them) is the shipped capacity's."""
+    import test_channel_rows
+    from wittgenstein_tpu.protocols import _agg_batched
+
+    net, state = _channel(name)
+    shipped = net.run_ms(state, 100)
+    monkeypatch.setattr(_agg_batched, "firing_capacity", lambda rows: 3)
+    net, state = _channel(name)  # a fresh program: the capacity is read at trace time
+    fired = []
+    for _ in range(100):
+        new = net.run_ms(state, 1)
+        fired.append(int(new.census.fired_rows) - int(state.census.fired_rows))
+        state = new
+    overflows = sum(f > 3 for f in fired)
+    assert int(state.census.firing_overflows) == overflows > 0
+    assert int(shipped.census.firing_overflows) == 0
+    test_channel_rows._assert_same_state(state, shipped, name, but=("firing_overflows",))
+
+
+def test_the_run_cache_carries_the_firing_counts():
+    net, state = _channel("handel")
+    states = replicate_state(state, 2, seeds=[5, 6])
+    before = rs.run_cache_info()
+    out, _ = rs.sharded_run_stats(net, states, 40)
+    out, _ = rs.sharded_run_stats(net, out, 40)
+    after = rs.run_cache_info()
+    got = _delta(before, after)
+    assert got["census_fired_rows_total"] == int(np.asarray(out.census.fired_rows).sum()) > 0
+    assert got["census_fired_rows_total"] >= got["census_landed_rows_total"] > 0
+    assert got["census_firing_overflows_total"] == 0
+    limit = net.census_limits()["firing_peak"]
+    assert limit == net.protocol.census_limits()["firing_peak"] > 0
+    assert after["census_firing_peak"] >= int(np.asarray(out.census.firing_peak).max()) > 0
+    assert after["census_firing_peak_limit"] > 0
+    if after["census_firing_peak"] == int(np.asarray(out.census.firing_peak).max()):
+        assert after["census_firing_peak_limit"] == limit
+
+
 # -- the files that read the counters ------------------------------------------
 
 
@@ -258,6 +347,8 @@ def test_the_new_metric_files_name_counters_the_program_has():
         assert m.get("workloads") == entries[name].get("workloads")
     handel = {"handel-4096.sweep-r8", "handel-4096.single-r1", "handel-4096-byz20.single-r1-c20"}
     assert set(files["landed_rows_in_window"]["workloads"]) == handel
+    for name in ("fired_rows_in_window", "firing_overflows_in_window"):  # the channel cells
+        assert set(files[name]["workloads"]) == handel | {"gsf-2048.single-r1"}
     assert files["view_overflow_steps_in_window"]["workloads"] == ["casper-1024.single-r1-s8000"]
     for name in ("steps_in_window", "census_s_in_window", "gc_pause_s_in_window"):
         assert "workloads" not in files[name]  # every cell
@@ -277,5 +368,7 @@ def test_the_server_renders_the_census():
         "witt_run_cache_census_due_rows_peak", "witt_run_cache_census_due_rows_peak_limit",
         "witt_run_cache_census_wheel_fill_peak_limit", "witt_run_cache_census_lane_live_peak",
         "witt_run_cache_census_landing_peak_limit", "witt_run_cache_gc_pause_seconds_total",
+        "witt_run_cache_census_fired_rows_total", "witt_run_cache_census_firing_overflows_total",
+        "witt_run_cache_census_firing_peak", "witt_run_cache_census_firing_peak_limit",
     ):
         assert family in text, family

@@ -25,8 +25,9 @@ A protocol that sends through the shared channel send path
 (`_send_stacked` of protocols/_agg_batched.py) must also carry every
 sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
 scripts/scope_profile.py are only as whole as these markers are live
-(`compact` where the state carries the counters its rounds feed,
-`commit_rounds`: Handel's fast path, the one sender-rows send).
+(`compact` where the protocol makes an every-tick send, whose firing
+rows it brings to the front: one that states a `firing_peak` limit,
+Handel with a fast path and GSF with accelerated calls).
 Every other protocol sends through the generic message store and must
 carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
 carries the view's and the repack's: its step still visits the store).
@@ -141,7 +142,7 @@ def _check_presence(jax, name, net, state, path, line, suppress):
 
         required.extend(
             scope for name, scope in CHANNEL_SCOPES.items()
-            if name != "compact" or "commit_rounds" in state.proto
+            if name != "compact" or net.census_limits()["firing_peak"]
         )
         # the channel replaces the store's insert; every step still
         # gathers the (empty) delivery view and clears it

@@ -113,7 +113,7 @@ CHANNEL_SCOPES = {
     "arrivals": "witt.channel.arrivals",  # who arrives when: latency, counters, keys, slot
     "readdress": "witt.channel.readdress",  # content from sender to receiver bit space
     "claim": "witt.channel.claim",  # which offer wins which slot; displacement
-    "compact": "witt.channel.compact",  # a sender-rows send: the landing rows to the front, a round's reads
+    "compact": "witt.channel.compact",  # an every-tick send: the firing rows (then the landing ones) to the front, a round's reads
     "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
 }
 
@@ -177,19 +177,25 @@ class Census(NamedTuple):
     view_overflow_steps: jnp.ndarray  # steps whose due rows passed `due_view_rows`: the whole lane
     landed_rows: jnp.ndarray  # rows a sender-rows send's claim let land (`_send_stacked`)
     extra_commit_rounds: jnp.ndarray  # commit rounds beyond a send's first (`landing_capacity` passed)
+    fired_rows: jnp.ndarray  # rows an every-tick channel send carried with their mask set (`_send_fired`)
+    firing_overflows: jnp.ndarray  # such sends whose fired rows passed `firing_capacity`: a second round
     due_rows_peak: jnp.ndarray  # most lane rows due in a step, against `due_view_rows`
     wheel_fill_peak: jnp.ndarray  # fullest wheel row after a step's inserts, against `wheel_slots`
     lane_live_peak: jnp.ndarray  # most live lane rows after a step's inserts, against `overflow_capacity`
+    firing_peak: jnp.ndarray  # most rows one every-tick channel send fired, against `firing_capacity`
 
 
-CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak")
-_CENSUS_ROW_SUMS = ("view_overflow_steps", "landed_rows", "extra_commit_rounds")
+CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak")
+_CENSUS_ROW_SUMS = (
+    "view_overflow_steps", "landed_rows", "extra_commit_rounds", "fired_rows", "firing_overflows",
+)
 
 # what a chunk's census vector holds, in order (`chunk_census`): the sums
 # first (a chunk's own, from the rows' growth), then the peaks
 CENSUS_VECTOR = (
     "steps", "store_rows", "view_overflow_steps", "landed_rows", "extra_commit_rounds",
-    "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "landing_peak",
+    "fired_rows", "firing_overflows",
+    "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "landing_peak",
 )
 CENSUS_VECTOR_PEAKS = CENSUS_PEAKS + ("landing_peak",)
 
@@ -571,6 +577,7 @@ class BatchedNetwork:
             "due_rows_peak": self.due_view_rows or 0,
             "wheel_fill_peak": 0 if self.flat else self.wheel_slots,
             "lane_live_peak": self.overflow_capacity,
+            "firing_peak": 0,
             "landing_peak": 0,
             **self.protocol.census_limits(),
         }

@@ -35,47 +35,75 @@ this turns ~12 unrolled per-level bodies x 4 phases (plus ~24 per-level
 send calls at ~700 StableHLO lines each) into 7 bucket bodies and 2
 stacked sends, which is what lets the flagship config compile.
 
-The send path (_send_stacked) has three entries and one algorithm, claim
-by key then commit by bucket; which rows a bucket's re-addressing and
-its two commit scatters run over is read from the shape of the input:
+The send path (_send_stacked) has three entries and one algorithm,
+arrive, claim by key, then commit by bucket; which rows each of the
+three runs over is read from the shape of the input:
 
   * a row each — mask/from/to/level [M], content[i] [M, w_pad]: the
     level is DATA and a row may belong to any bucket, so every bucket
     carries all M rows and routes the rows of other buckets' levels to
-    the dropped row.  No protocol sends this way any more; it is the
-    form the other two reduce to (under a node mesh, in the tests);
+    the dropped row, and arrivals and the claim run over all M rows.  No
+    protocol sends this way any more; it is the whole-M body the other
+    two are held against (in the tests) and reduce to under a node mesh;
   * rows by SENDER — mask/to [N, r], level [N], content the senders'
-    full-width vectors [N, W] (Handel's fast path, whose level is a
-    per-node register; r = ceil(fast_path / 2)): the level is data too,
-    but few rows land — a node fires only in the two ticks after it
-    completes a level, and a landing row is in ONE bucket — so after the
-    claim the rows that won a slot are compacted to the front of a row
-    list (_commit_landed) and only they are read, cut to their level's
-    low block (_dyn_low), re-addressed and scattered, C rows a round, in
-    as many rounds as they take (a loop whose trip count is data: none
-    where nothing lands; under vmap, until the batch's slowest row is
-    through).  No row is lost or deferred.  C is landing_capacity(M), a
-    function of the send's shape alone: 7.5% of M up to a multiple of
-    128, 1536 of Handel-4096's M = 20,480, chosen from the landing rows
-    a tick of whole 4096-node runs (PERF.md section 5: at most 1532 over
-    eight honest runs, 156 under the byz20 attack, none on 15% and 72%
-    of the ticks), so that one round covers a tick.  Where the state
-    carries them (Handel's), proto["commit_rounds"] sums the rounds run
-    and proto["landing_peak"] keeps the most rows that landed in a tick;
+    full-width vectors [N, W] (Handel's fast path, every tick, whose
+    level is a per-node register; r = ceil(fast_path / 2)): few rows
+    FIRE — a node sends only in the two ticks after it completes a
+    level — so arrivals and the claim run over the firing rows alone
+    (_send_fired, below), and of those the rows that won a slot, each in
+    ONE bucket, are listed in their turn and only they are read, cut to
+    their level's low block (_dyn_low), re-addressed and scattered
+    (_commit_landed), C rows a round, in as many rounds as they take (a
+    loop whose trip count is data: none where nothing lands; under vmap,
+    until the batch's slowest row is through).  No row is lost or
+    deferred.  C is landing_capacity(M), a function of the send's shape
+    alone: 7.5% of M up to a multiple of 128, 1536 of Handel-4096's
+    M = 20,480, chosen from the landing rows a tick of whole 4096-node
+    runs (PERF.md section 5: at most 1532 over eight honest runs, 156
+    under the byz20 attack, none on 15% and 72% of the ticks), so that
+    one round covers a tick.  Where the state carries them (Handel's),
+    proto["commit_rounds"] sums the rounds run and proto["landing_peak"]
+    keeps the most rows that landed in a tick;
   * level as an AXIS — mask/from/to [N, L-1, k] and no level, content[i]
-    the [N, nl, w_pad] block stack as _lows gives it (the dissemination
-    beats, k = 1; GSF's accelerated calls, k = accelerated_calls_count):
-    a row's position on axis 1 IS its level, so bucket i's rows are cut
-    from that axis by reshape and static slice and only they are
-    re-addressed and scattered — M_i = N x nl x k rows at w_pad words,
-    about a tenth of the word updates (tests/test_channel_rows.py).
+    the [N, nl, w_pad] block stack as _lows gives it: a row's position
+    on axis 1 IS its level, so bucket i's rows are cut from that axis by
+    reshape and static slice and only they are re-addressed and
+    scattered — M_i = N x nl x k rows at w_pad words, about a tenth of
+    the word updates (tests/test_channel_rows.py).  The dissemination
+    beats (k = 1, one tick in a period, most rows firing) run arrivals
+    and the claim over all M rows; GSF's accelerated calls (k =
+    accelerated_calls_count > 1, every tick, a node firing only on the
+    tick its verified prefix improved) over the firing rows alone
+    (_send_fired), whose winners are expanded back onto the M rows for
+    the buckets' cuts.
 
-Arrivals and the claim are scalar per row and run over the flat M rows
-in the same row order in all three; the state after a send is
-bit-identical (the level-axis and the sender-rows entries only lose
-updates addressed to the dropped row).  Under a node mesh a row's level
-is data again after the all_to_all, so both pad their rows back to
-[M, w_pad] there.
+The firing rows (_send_fired; the two every-tick entries, not under a
+node mesh): the rows whose mask is set are numbered to the front of a
+row list by one sort of M row numbers and taken F rows a round through
+the latency draw, the traffic counters, the keys and the in_key
+min-max scatters, then, once every round's keys are in, through the
+winners' reads — two loops whose trip count is data (none where no row
+fires, 76% of the ticks under the byz20 attack).  The draw is keyed on
+(seed, send time, sender, level, send counter, receiver), never on a
+row's place, and a masked row adds nothing to any counter, key or
+claim, so the state is the whole-M send's bit for bit, `displaced`
+included, however many rounds a tick takes.  F is firing_capacity(rows),
+a function of the mask's shape alone, chosen from mask.sum() tick by
+tick over whole runs from t=0 (PERF.md section 5) as the smallest
+multiple of 128 that one round covers on every tick seen: rows by
+sender 2/25 of M (1664 of Handel-4096's 20,480: at most 1530-1635 fire
+over eight honest runs, 186-208 under byz20); level axis 1/20 of N x k
+(1024 of GSF-2048's 225,280 rows: at most 900-970 over eight runs, a
+node bursts at a level or two of the eleven); 256 at least (at 256
+nodes 135 and 198 fire at most).  The work census counts the rows that
+fired, the sends that passed F and the most one send fired
+(engine.core.Census `fired_rows`, `firing_overflows`, `firing_peak`).
+
+Arrivals and the claim are scalar per row; the state after a send is
+bit-identical in all three entries (the level-axis and the sender-rows
+entries only lose updates addressed to the dropped row).  Under a node
+mesh a row's level is data again after the all_to_all, so both pad
+their rows back to [M, w_pad] there and keep the whole-M body.
 
 Keys pack (absolute_arrival << rel_bits) | rel — no per-tick countdown
 (see _advance_channel) — which bounds a sim at 2^(31-rel_bits) ms
@@ -107,6 +135,19 @@ def landing_capacity(m: int) -> int:
     rows alone: 3/40 of them, up to a multiple of 128 (see the module
     docstring for the histogram behind it)."""
     return min(m, -(-3 * m // 40 // 128) * 128)
+
+
+def firing_capacity(rows: tuple) -> int:
+    """Rows a round of an every-tick send's arrivals and claim carries
+    (_send_fired), from the shape of the send's mask alone.  Rows by
+    sender [N, r]: 2/25 of the M rows; level axis [N, L-1, k]: 1/20 of
+    the N x k rows a level has (a node fires at a level or two, whatever
+    their number); each up to a multiple of 128 and 256 at least (a small
+    network's share swings more).  See the module docstring for the
+    histograms behind them."""
+    m = int(np.prod(rows))
+    share = -(-2 * m // 25) if len(rows) == 2 else -(-rows[0] * rows[2] // 20)
+    return min(m, max(256, -(-share // 128) * 128))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -421,8 +462,9 @@ class BitsetAggBase(BatchedProtocol):
         broadcasts to it, level [N] (a sender's r rows share its level),
         content the senders' full-width [N, W] vectors: row [n, c] carries
         the low block of content[n] at level[n].  The flat row order is
-        the axis order; after the claim only the rows that land are
-        committed (_commit_landed).
+        the axis order; arrivals and the claim run over the rows that
+        fire (_send_fired) and the commit over those of them that land
+        (_commit_landed).
 
         Level as an axis: mask [N, L-1, k], from_idx/to_idx/aux anything
         that broadcasts to it, level None: row [n, j, c] is a level-(j+1)
@@ -430,15 +472,14 @@ class BitsetAggBase(BatchedProtocol):
         content[i] [N, nl, w_pad], the bucket's block stack as _lows
         gives it, shared by a node's k rows of a level.  The flat row
         order is the axis order, so arrivals and the claim are the ones
-        the flattened send would get.
+        the flattened send would get; with k > 1 they run over the rows
+        that fire (_send_fired).
 
         Content is re-addressed into the receiver's block-local space
         here, at send time.  See the module docstring for which rows each
-        bucket's re-addressing and commit run over.
+        entry's arrivals, claim, re-addressing and commit run over.
         """
         proto = state.proto
-        d = self.CHANNEL_DEPTH
-        ss = d + 1
         scope = functools.partial(net._scope, scopes=CHANNEL_SCOPES)
         mesh = getattr(net, "node_mesh", None)
         axis = mask.shape if mask.ndim == 3 else None  # rows on a level axis
@@ -461,8 +502,8 @@ class BitsetAggBase(BatchedProtocol):
                     f"levels: got {axis} with level {level!r}"
                 )
             level = jnp.arange(1, self.n_levels, dtype=jnp.int32)[None, :, None]
+        rows = mask.shape
         if mask.ndim > 1:
-            rows = mask.shape
             mask, from_idx, to_idx, level = (
                 jnp.broadcast_to(x, rows).reshape(-1)
                 for x in (mask, from_idx, to_idx, level)
@@ -472,55 +513,22 @@ class BitsetAggBase(BatchedProtocol):
         # node-sharded, a row's level is data again after the exchange:
         # every bucket carries all M rows there, whichever the entry
         cut = axis is not None and mesh is None
-        with scope("arrivals"):
-            # masked rows may carry junk levels; clamp so every computed
-            # index is in range (their scatters are dropped via the
-            # n_nodes row)
-            level = jnp.clip(level.astype(jnp.int32), 1, self.n_levels - 1)
-            state, ok, arrival = net.latency_arrivals(
-                state, mask, from_idx, to_idx, state.time + 1, level
+        # masked rows may carry junk levels; clamp so every computed index
+        # is in range (their scatters are dropped via the n_nodes row)
+        level = jnp.clip(level.astype(jnp.int32), 1, self.n_levels - 1)
+        # the two every-tick entries, of whose rows few fire
+        fired = mesh is None and (words is not None or (cut and axis[2] > 1))
+        if fired:
+            state, claimed = self._send_fired(
+                net, state, firing_capacity(rows), mask, from_idx, to_idx, level, aux, scope,
+                landing=words is not None,
             )
-            # receiver traffic counters tick here, at send time: every ok
-            # send is delivered by the oracle (Network.java:611-612), but
-            # the channel may displace it — counting at send keeps
-            # end-of-run totals exact at the cost of counters leading
-            # arrivals by the latency
-            okc = ok.astype(jnp.int32)
-            sizes = jnp.asarray(self._size_table(), jnp.int32)[level]
-            state = state._replace(
-                msg_received=state.msg_received.at[to_idx].add(okc, mode="drop"),
-                bytes_received=state.bytes_received.at[to_idx].add(
-                    okc * sizes, mode="drop"
-                ),
-            )
-            if "sent_not_ok" in proto:
-                # the other side of the same ledger, by sender (see
-                # _not_ok_init): before fits_t, because a time_overflow
-                # send was counted for its receiver just above
-                proto = dict(
-                    proto,
-                    sent_not_ok=proto["sent_not_ok"]
-                    .at[from_idx]
-                    .add((mask & ~ok).astype(jnp.int32)),
+        else:
+            with scope("arrivals"):
+                state, ok, key, slot, time_overflow = self._arrive(
+                    net, state, mask, from_idx, to_idx, level
                 )
-                state = state._replace(proto=proto)
-            rel = (to_idx ^ from_idx).astype(jnp.int32)
-            # ABSOLUTE arrival packing (no per-tick countdown — see
-            # _advance_channel).  Sims running past the int32 packing
-            # horizon (2^(31-rel_bits) ms: 524 s at 4096 nodes, 128 s at
-            # the 16384 cap) would overflow the shift; such sends are
-            # dropped and counted in proto["displaced"] so a too-long sim
-            # fails loudly in the displacement stats rather than
-            # corrupting arrival order.
-            # strictly below the last in-horizon ms: at the boundary
-            # arrival, a max-rel send would pack to exactly INT32_MAX —
-            # the empty-slot sentinel — and vanish uncounted
-            fits_t = arrival < (jnp.int32(1) << (31 - self.rel_bits)) - 1
-            time_overflow = jnp.sum((ok & ~fits_t).astype(jnp.int32))
-            ok = ok & fits_t
-            key = jnp.where(ok, (arrival << self.rel_bits) | rel, INT32_MAX)
-
-            slot = lax.rem(arrival, jnp.int32(d))
+        proto = state.proto
 
         with scope("readdress"):
             # re-address sender-space content into the receiver's
@@ -528,20 +536,20 @@ class BitsetAggBase(BatchedProtocol):
             # shared by both commit passes; r0 < bs keeps the permutation
             # inside the level block, and rows routed away from the bucket
             # get r0 = 0 so the (dropped) shuffle stays in range
-            r0_row = rel & ((jnp.int32(1) << (level - 1)) - 1)  # bs_l = 2^(l-1)
             if words is None:
+                r0_row = self._r0(from_idx, to_idx, level)
                 if axis is not None:
                     content = [
                         self._level_rows(c, b, axis, whole=not cut)
                         for c, b in zip(content, self.buckets)
                     ]
-                rows = [
+                bucket_rows = [
                     self._bucket_rows(b, level, axis if cut else None)
                     for b in self.buckets
                 ]
                 cnt_list = [
                     xor_shuffle(c.astype(jnp.uint32), own(r0_row, 0))
-                    for own, c in zip(rows, content)
+                    for own, c in zip(bucket_rows, content)
                 ]
 
         if mesh is not None:
@@ -555,52 +563,36 @@ class BitsetAggBase(BatchedProtocol):
                 time_overflow=time_overflow, scope=scope,
             )
 
-        with scope("claim"):
-            col = (level - 1) * ss + slot
-            safe_to = jnp.where(ok, to_idx, self.n_nodes)
-            prev = proto["in_key"].at[to_idx, col].get(
-                mode="fill", fill_value=INT32_MAX
-            )
-            new_key = proto["in_key"].at[safe_to, col].min(key, mode="drop")
-            winner = ok & (new_key[to_idx, col] == key)
-
-            # freshest-offer backstop (empty at -1 so any real key wins
-            # the max)
-            fcol = (level - 1) * ss + d
-            new_key = new_key.at[safe_to, fcol].max(
-                jnp.where(ok, key, -1), mode="drop"
-            )
-            fresh_win = ok & (new_key[to_idx, fcol] == key)
-
-            # displacement accounting (the channel's SimState.dropped
-            # analog): an ok send that won neither slot, or a winner that
-            # evicted a still-pending occupant with a later arrival
-            lost_entry = ok & ~winner & ~fresh_win
-            evicted = winner & (prev != INT32_MAX) & (prev > key)
-            displaced = (
-                jnp.sum((lost_entry | evicted).astype(jnp.int32)) + time_overflow
-            )
-
-            updates = dict(
-                proto, in_key=new_key, displaced=proto["displaced"] + displaced
-            )
-
-            win_to = jnp.where(winner, to_idx, self.n_nodes)
-            fwin_to = jnp.where(fresh_win, to_idx, self.n_nodes)
+        updates = dict(proto)
+        if not fired:
+            with scope("claim"):
+                updates["in_key"] = self._claim_keys(proto["in_key"], ok, to_idx, level, key, slot)
+                win_to, fwin_to, displaced = self._claim_winners(
+                    proto["in_key"], updates["in_key"], ok, to_idx, level, key, slot
+                )
+                updates["displaced"] = proto["displaced"] + displaced + time_overflow
+                claimed = (win_to, fwin_to, slot)
+            if aux is not None:
+                with scope("commit"):
+                    updates["in_aux"] = self._commit_aux(
+                        proto["in_aux"], win_to, fwin_to, level, slot, aux
+                    )
 
         sig_names = [f"in_sig{i}" for i in range(len(self.buckets))]
         if words is None:
+            win_to, fwin_to, slot = claimed
             with scope("commit"):
-                for name, b, own, cnt in zip(sig_names, self.buckets, rows, cnt_list):
+                for name, b, own, cnt in zip(sig_names, self.buckets, bucket_rows, cnt_list):
                     updates[name] = self._commit_bucket(
                         updates[name], b,
                         own(win_to, self.n_nodes), own(fwin_to, self.n_nodes),
                         own(level) - b.lo, own(slot), cnt,
                     )
         else:
-            sigs, rounds, landing = self._commit_landed(
+            land_rows, land_info, landing = claimed
+            sigs, rounds = self._commit_landed(
                 [updates[name] for name in sig_names], words,
-                to_idx, level, slot, r0_row, winner, fresh_win,
+                from_idx, to_idx, level, land_rows, land_info, landing,
                 landing_capacity(mask.shape[0]), scope,
             )
             updates.update(zip(sig_names, sigs))
@@ -612,16 +604,229 @@ class BitsetAggBase(BatchedProtocol):
             state = census_add(
                 state, landed_rows=landing, extra_commit_rounds=jnp.maximum(rounds - 1, 0)
             )
-        if aux is not None:
-            with scope("commit"):
-                new_aux = proto["in_aux"].at[win_to, col].set(
-                    aux.astype(jnp.int32), mode="drop"
-                )
-                new_aux = new_aux.at[fwin_to, fcol].set(
-                    aux.astype(jnp.int32), mode="drop"
-                )
-                updates["in_aux"] = new_aux
         return state._replace(proto=updates)
+
+    def _r0(self, from_idx, to_idx, level):
+        """The xor of a row's re-addressing: the sender relative to the
+        receiver inside the level's block, bs_l = 2^(l-1)."""
+        rel = (to_idx ^ from_idx).astype(jnp.int32)
+        return rel & ((jnp.int32(1) << (level - 1)) - 1)
+
+    def _arrive(self, net, state, mask, from_idx, to_idx, level):
+        """Who arrives when, for the rows handed over (all M of a send,
+        or a round of its firing rows): the latency draw, the traffic
+        counters, and a row's packed key and arrival slot.  Returns
+        (state, ok, key, slot, sends lost to the packing horizon)."""
+        proto = state.proto
+        state, ok, arrival = net.latency_arrivals(
+            state, mask, from_idx, to_idx, state.time + 1, level
+        )
+        # receiver traffic counters tick here, at send time: every ok
+        # send is delivered by the oracle (Network.java:611-612), but
+        # the channel may displace it — counting at send keeps
+        # end-of-run totals exact at the cost of counters leading
+        # arrivals by the latency
+        okc = ok.astype(jnp.int32)
+        sizes = jnp.asarray(self._size_table(), jnp.int32)[level]
+        state = state._replace(
+            msg_received=state.msg_received.at[to_idx].add(okc, mode="drop"),
+            bytes_received=state.bytes_received.at[to_idx].add(
+                okc * sizes, mode="drop"
+            ),
+        )
+        if "sent_not_ok" in proto:
+            # the other side of the same ledger, by sender (see
+            # _not_ok_init): before fits_t, because a time_overflow
+            # send was counted for its receiver just above
+            proto = dict(
+                proto,
+                sent_not_ok=proto["sent_not_ok"]
+                .at[from_idx]
+                .add((mask & ~ok).astype(jnp.int32)),
+            )
+            state = state._replace(proto=proto)
+        rel = (to_idx ^ from_idx).astype(jnp.int32)
+        # ABSOLUTE arrival packing (no per-tick countdown — see
+        # _advance_channel).  Sims running past the int32 packing
+        # horizon (2^(31-rel_bits) ms: 524 s at 4096 nodes, 128 s at
+        # the 16384 cap) would overflow the shift; such sends are
+        # dropped and counted in proto["displaced"] so a too-long sim
+        # fails loudly in the displacement stats rather than
+        # corrupting arrival order.
+        # strictly below the last in-horizon ms: at the boundary
+        # arrival, a max-rel send would pack to exactly INT32_MAX —
+        # the empty-slot sentinel — and vanish uncounted
+        fits_t = arrival < (jnp.int32(1) << (31 - self.rel_bits)) - 1
+        time_overflow = jnp.sum((ok & ~fits_t).astype(jnp.int32))
+        ok = ok & fits_t
+        key = jnp.where(ok, (arrival << self.rel_bits) | rel, INT32_MAX)
+        slot = lax.rem(arrival, jnp.int32(self.CHANNEL_DEPTH))
+        return state, ok, key, slot, time_overflow
+
+    def _cols(self, level, slot):
+        """A row's two in_key / in_aux columns: its level's arrival slot
+        and its level's fresh slot."""
+        ss = self.CHANNEL_DEPTH + 1
+        return (level - 1) * ss + slot, (level - 1) * ss + ss - 1
+
+    def _claim_keys(self, in_key, ok, to_idx, level, key, slot):
+        """The claim's writes: every ok row's key min-scattered into its
+        arrival slot and max-scattered into its level's fresh slot
+        (empty at -1 so any real key wins the max)."""
+        col, fcol = self._cols(level, slot)
+        safe_to = jnp.where(ok, to_idx, self.n_nodes)
+        in_key = in_key.at[safe_to, col].min(key, mode="drop")
+        return in_key.at[safe_to, fcol].max(jnp.where(ok, key, -1), mode="drop")
+
+    def _claim_winners(self, before, after, ok, to_idx, level, key, slot):
+        """The claim's reads, once every key of the send is in `after`:
+        a row's receiver where it holds its arrival slot (`win_to`) and
+        where it holds the fresh slot (`fwin_to`), the dropped row
+        n_nodes where it does not, and the displacement count (the
+        channel's SimState.dropped analog): an ok send that won neither
+        slot, or a winner that evicted a still-pending occupant of
+        `before` with a later arrival."""
+        col, fcol = self._cols(level, slot)
+        prev = before.at[to_idx, col].get(mode="fill", fill_value=INT32_MAX)
+        winner = ok & (after[to_idx, col] == key)
+        fresh_win = ok & (after[to_idx, fcol] == key)
+        lost_entry = ok & ~winner & ~fresh_win
+        evicted = winner & (prev != INT32_MAX) & (prev > key)
+        return (
+            jnp.where(winner, to_idx, self.n_nodes),
+            jnp.where(fresh_win, to_idx, self.n_nodes),
+            jnp.sum((lost_entry | evicted).astype(jnp.int32)),
+        )
+
+    def _commit_aux(self, in_aux, win_to, fwin_to, level, slot, aux):
+        """A row's aux word beside its key, in the slots it won."""
+        col, fcol = self._cols(level, slot)
+        aux = aux.astype(jnp.int32)
+        return in_aux.at[win_to, col].set(aux, mode="drop").at[fwin_to, fcol].set(aux, mode="drop")
+
+    def _send_fired(
+        self, net, state, capacity, mask, from_idx, to_idx, level, aux, scope, *, landing
+    ):
+        """Arrivals and the claim of an every-tick send over the rows
+        that FIRE: the flat [M] rows whose mask is set, a few in a
+        hundred, are numbered to the front of a row list and taken
+        `capacity` rows a round, as many rounds as they take (none for
+        none; one on all but a handful of ticks, see firing_capacity).
+        The latency draw is keyed on a row's sender, receiver, level and
+        the send's counter, never on its place, and a masked row adds
+        nothing to any counter, key or claim, so the state is the whole-M
+        send's bit for bit.  Two loops: the first draws a round's
+        arrivals and scatters its keys, the second reads the winners once
+        EVERY round's keys are in (a row that holds a slot after its own
+        round may lose it to a later round's).  Under vmap each runs
+        until the batch's slowest row is through.
+
+        Returns the state (counters, in_key, in_aux, displaced written;
+        the census's fired rows) and what the commit needs: with
+        `landing` (rows by sender) the row numbers of the rows that won a
+        slot, compacted in their turn, their slot and win bits beside
+        them, and their count; without it (level axis) win_to, fwin_to
+        and slot expanded back onto the M rows, for the buckets' cuts."""
+        proto = state.proto
+        m, n, d = mask.shape[0], self.n_nodes, self.CHANNEL_DEPTH
+        with scope("compact"):
+            fired = jnp.sum(mask.astype(jnp.int32))
+            # firing rows' numbers first, ascending; m marks the rest and
+            # the padding up to whole rounds (equal keys are all m: no
+            # stable sort's second operand)
+            order = lax.sort(jnp.where(mask, jnp.arange(m, dtype=jnp.int32), m), is_stable=False)
+            order = jnp.concatenate([order, jnp.full(-m % capacity, m, jnp.int32)])
+
+        def round_rows(k, *columns):
+            with scope("compact"):
+                sel = lax.dynamic_slice(order, (k * capacity,), (capacity,))
+                live = sel < m
+                return (sel, live) + tuple(x[jnp.where(live, sel, 0)] for x in columns)
+
+        more = lambda carry: carry[0] * capacity < fired  # noqa: E731
+        # what the arrivals write is small: the node columns and two
+        # leaves of proto; the planes stay outside the loops
+        slim = state._replace(
+            proto={k: proto[k] for k in ("in_key", "sent_not_ok") if k in proto}
+        )
+        ctr = state.send_ctr  # one send, one draw counter, however many rounds
+
+        def arrive(carry):
+            k, slim, keys, time_overflow = carry
+            _sel, live, frm, to, lvl = round_rows(k, from_idx, to_idx, level)
+            with scope("arrivals"):
+                slim, ok, key, slot, over = self._arrive(
+                    net, slim._replace(send_ctr=ctr), live, frm, to, lvl
+                )
+            with scope("claim"):
+                in_key = self._claim_keys(slim.proto["in_key"], ok, to, lvl, key, slot)
+            slim = slim._replace(proto=dict(slim.proto, in_key=in_key))
+            keys = lax.dynamic_update_slice(keys, key, (k * capacity,))
+            return k + 1, slim, keys, time_overflow + over
+
+        _, slim, keys, time_overflow = lax.while_loop(
+            more, arrive,
+            (jnp.int32(0), slim, jnp.full(order.shape, INT32_MAX, jnp.int32), jnp.int32(0)),
+        )
+        in_key = slim.proto["in_key"]
+        state = slim._replace(send_ctr=ctr + 1, proto=dict(proto, **slim.proto))
+
+        if landing:
+            out = (jnp.full(order.shape, m, jnp.int32), jnp.zeros(order.shape, jnp.int32), jnp.int32(0))
+        else:
+            out = (jnp.full(m, n, jnp.int32), jnp.full(m, n, jnp.int32), jnp.zeros(m, jnp.int32))
+        in_aux = proto["in_aux"] if aux is not None else ()
+
+        def claim(carry):
+            k, displaced, in_aux, out = carry
+            sel, live, to, lvl, *aux_c = round_rows(
+                k, to_idx, level, *(() if aux is None else (aux,))
+            )
+            with scope("claim"):
+                key = lax.dynamic_slice(keys, (k * capacity,), (capacity,))
+                ok = live & (key != INT32_MAX)  # an ok row's key is below it (_arrive)
+                slot = lax.rem(key >> self.rel_bits, jnp.int32(d))
+                win_to, fwin_to, lost = self._claim_winners(
+                    proto["in_key"], in_key, ok, to, lvl, key, slot
+                )
+            if aux is not None:
+                with scope("commit"):
+                    in_aux = self._commit_aux(in_aux, win_to, fwin_to, lvl, slot, *aux_c)
+            with scope("compact"):
+                if landing:
+                    # the round's landing rows behind those of the rounds
+                    # before: row number, and slot and win bits beside it
+                    land_rows, land_info, landed = out
+                    winner, fresh_win = win_to < n, fwin_to < n
+                    lands = winner | fresh_win
+                    info = slot * 4 + winner.astype(jnp.int32) * 2 + fresh_win.astype(jnp.int32)
+                    first, info = lax.sort(
+                        (jnp.where(lands, sel, m), info), num_keys=1, is_stable=False
+                    )
+                    out = (
+                        lax.dynamic_update_slice(land_rows, first, (landed,)),
+                        lax.dynamic_update_slice(land_info, info, (landed,)),
+                        landed + jnp.sum(lands.astype(jnp.int32)),
+                    )
+                else:
+                    at = jnp.where(live, sel, m)
+                    out = tuple(
+                        x.at[at].set(y, mode="drop")
+                        for x, y in zip(out, (win_to, fwin_to, slot))
+                    )
+            return k + 1, displaced + lost, in_aux, out
+
+        _, displaced, in_aux, out = lax.while_loop(
+            more, claim, (jnp.int32(0), time_overflow, in_aux, out)
+        )
+        updates = dict(state.proto, displaced=proto["displaced"] + displaced)
+        if aux is not None:
+            updates["in_aux"] = in_aux
+        state = census_add(
+            state._replace(proto=updates),
+            fired_rows=fired, firing_overflows=fired > capacity, firing_peak=fired,
+        )
+        return state, out
 
     def _level_rows(self, blocks, b: Bucket, axis, whole: bool):
         """Bucket b's content rows of a level-axis send: its [N, nl, w_pad]
@@ -669,47 +874,46 @@ class BitsetAggBase(BatchedProtocol):
         return sig.at[fwin_to[:, None], fcols].set(cnt, mode="drop")
 
     def _commit_landed(
-        self, sigs, words, to_idx, level, slot, r0, winner, fresh_win, capacity, scope
+        self, sigs, words, from_idx, to_idx, level, land_rows, land_info, landing,
+        capacity, scope,
     ):
         """The commit of a sender-rows send over the rows that land: the
-        claim's winners (`winner | fresh_win` of the flat [M] rows, each
-        the only one at its plane cell, so their order is free) are
-        compacted to the front of a row list and committed `capacity`
+        claim's winners (each the only one at its plane cell, so their
+        order is free), as _send_fired lists them (`land_rows` the flat
+        row numbers of the `landing` rows that won a slot, m behind them;
+        `land_info` a row's slot and win bits), committed `capacity`
         rows a round, as many rounds as the landing rows take (none for
         none): no row is lost or deferred.  A round reads its rows'
-        scalars (`r0` the xor of the re-addressing) and their senders'
-        full-width `words` [N, W] (row m's sender is m // r), cuts and re-addresses each bucket's low block
-        and runs `_commit_bucket` over `capacity` rows; rows of other
-        buckets' levels and the list's tail go to the dropped row.
-        Under vmap the loop runs until the batch's slowest row is
-        through (a finished row's rounds write nothing).  Returns the
-        planes, the rounds this send took and the rows that landed."""
+        sender, receiver and level from the send's flat [M] columns and
+        their senders' full-width `words` [N, W] (row m's sender is
+        m // r), cuts and re-addresses each bucket's low block and runs
+        `_commit_bucket` over `capacity` rows; rows of other buckets'
+        levels and the list's tail go to the dropped row.  Under vmap
+        the loop runs until the batch's slowest row is through (a
+        finished row's rounds write nothing).  Returns the planes and
+        the rounds this send took."""
         m, n = to_idx.shape[0], self.n_nodes
         r = m // words.shape[0]
         with scope("compact"):
-            lands = winner | fresh_win
-            landing = jnp.sum(lands.astype(jnp.int32))
-            # landing rows' numbers first, ascending; m marks the rest
-            # and the padding up to whole rounds
-            order = jnp.sort(jnp.where(lands, jnp.arange(m, dtype=jnp.int32), m))
-            order = jnp.concatenate(
-                [order, jnp.full(-m % capacity, m, jnp.int32)]
-            )
+            pad = -land_rows.shape[0] % capacity  # up to whole rounds
+            land_rows = jnp.concatenate([land_rows, jnp.full(pad, m, jnp.int32)])
+            land_info = jnp.concatenate([land_info, jnp.zeros(pad, jnp.int32)])
 
         def one_round(carry):
             k, sigs = carry
             with scope("compact"):
-                sel = lax.dynamic_slice(order, (k * capacity,), (capacity,))
+                sel = lax.dynamic_slice(land_rows, (k * capacity,), (capacity,))
+                info = lax.dynamic_slice(land_info, (k * capacity,), (capacity,))
                 live = sel < m
                 sel = jnp.where(live, sel, 0)
-                to_c, level_c, slot_c, r0_c = (
-                    x[sel] for x in (to_idx, level, slot, r0)
-                )
-                win_to = jnp.where(live & winner[sel], to_c, n)
-                fwin_to = jnp.where(live & fresh_win[sel], to_c, n)
+                from_c, to_c, level_c = (x[sel] for x in (from_idx, to_idx, level))
+                slot_c = info >> 2
+                win_to = jnp.where(live & ((info & 2) > 0), to_c, n)
+                fwin_to = jnp.where(live & ((info & 1) > 0), to_c, n)
                 words_c = words[sel // r]
             owns = [self._bucket_rows(b, level_c, None) for b in self.buckets]
             with scope("readdress"):
+                r0_c = self._r0(from_c, to_c, level_c)
                 cnts = [
                     xor_shuffle(self._dyn_low(words_c, level_c, b), own(r0_c, 0))
                     for own, b in zip(owns, self.buckets)
@@ -729,7 +933,7 @@ class BitsetAggBase(BatchedProtocol):
             one_round,
             (jnp.int32(0), list(sigs)),
         )
-        return sigs, rounds, landing
+        return sigs, rounds
 
     # -- node-sharded channel commit (explicit all_to_all exchange) ----------
     def _channel_commit_sharded(

@@ -67,7 +67,7 @@ from ..ops.bitops import block_mask
 # (ROADMAP B0), and not a choice ops.bitops could make for both.
 from ..ops.bitops import _popcount_words_lax as popcount_words
 from ..utils.javarand import JavaRandom
-from ._agg_batched import INT32_MAX, BitsetAggBase
+from ._agg_batched import INT32_MAX, BitsetAggBase, firing_capacity
 from .gsf import GSFSignatureParameters
 
 
@@ -81,6 +81,16 @@ class BatchedGSF(BitsetAggBase):
         self.pref_masks = np.stack(
             [block_mask(0, 1 << k, self.n_words) for k in range(self.n_levels)]
         )
+
+    def census_limits(self) -> dict:
+        """`firing_peak` is read against the rows a round of the
+        accelerated calls' arrivals and claim carries: `firing_capacity`
+        of their [N, L-1, accelerated_calls_count] send; 0 where no such
+        send is made (no calls, or a single one: the whole-M body)."""
+        k = self.params.accelerated_calls_count
+        if not (k > 1 and self.n_levels > 2):
+            return {"firing_peak": 0}
+        return {"firing_peak": firing_capacity((self.n_nodes, self.n_levels - 1, k))}
 
     def msg_size(self, mtype: int) -> int:
         # Size = level byte + bit field + the aggregated sig + our own sig

@@ -120,7 +120,7 @@ from ..engine.rng import hash32
 from ..ops.bitops import popcount_words, xor_shuffle
 from ..ops.select import take_slot, top_k_merge
 from ..utils.javarand import JavaRandom
-from ._agg_batched import INT32_MAX, BitsetAggBase, landing_capacity
+from ._agg_batched import INT32_MAX, BitsetAggBase, firing_capacity, landing_capacity
 from .handel import HandelParameters
 
 
@@ -219,14 +219,19 @@ class BatchedHandel(BitsetAggBase):
         return tuple(leaves)
 
     def census_limits(self) -> dict:
-        """`landing_peak` is read against the rows a round of the fast
-        path's commit carries: `landing_capacity` of the N x
-        ceil(fast_path / 2) rows its send has; 0 without a fast path."""
+        """The fast path's send has N x ceil(fast_path / 2) rows:
+        `firing_peak` is read against the rows a round of its arrivals
+        and claim carries (`firing_capacity`), `landing_peak` against
+        those a round of its commit does (`landing_capacity`); 0 without
+        a fast path."""
         p, n = self.params, self.n_nodes
         if not (p.fast_path > 0 and self.n_levels > 1):
-            return {"landing_peak": 0}
-        fp = min(p.fast_path, max(1, n // 2))
-        return {"landing_peak": landing_capacity(n * ((fp + 1) // 2))}
+            return {"firing_peak": 0, "landing_peak": 0}
+        r = (min(p.fast_path, max(1, n // 2)) + 1) // 2
+        return {
+            "firing_peak": firing_capacity((n, r)),
+            "landing_peak": landing_capacity(n * r),
+        }
 
     def msg_size(self, mtype: int) -> int:
         # Size = level + bit field + the signatures included + our own sig

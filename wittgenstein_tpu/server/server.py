@@ -255,6 +255,10 @@ class Server:
                 ("landed_rows", "rows a sender-rows send's claim let land"),
                 ("extra_commit_rounds",
                  "commit rounds beyond a send's first (landing_capacity passed)"),
+                ("fired_rows",
+                 "rows the every-tick channel sends carried with their mask set"),
+                ("firing_overflows",
+                 "every-tick channel sends whose fired rows passed firing_capacity"),
             ):
                 p.add(f"run_cache_census_{name}_total",
                       info[f"census_{name}_total"], what, "counter")
@@ -262,6 +266,7 @@ class Server:
                 ("due_rows_peak", "due_view_rows"),
                 ("wheel_fill_peak", "wheel_slots"),
                 ("lane_live_peak", "overflow_capacity"),
+                ("firing_peak", "firing_capacity(rows)"),
                 ("landing_peak", "landing_capacity(M)"),
             ):
                 p.add(f"run_cache_census_{name}", info[f"census_{name}"],
