@@ -5,6 +5,8 @@ different on the chip" (ROADMAP B0, PERF.md section 6).
 
     python3 scripts/leaf_checksums.py --config benchmark/configs/sanfermin-4096.json \\
         --rows 2 --seed 7001 --step-ms 200 --until-ms 2400 --out chiprun_out/leaves-tpu.jsonl
+    python3 scripts/leaf_checksums.py --config benchmark/configs/dfinity-4096.json \
+        --rows 1 --step-ms 6000 --until-ms 6000 --out chiprun_out/leaves-tpu.jsonl
     python3 scripts/leaf_checksums.py --compare leaves-cpu.jsonl chiprun_out/leaves-tpu.jsonl
 
 Builds the program by the configuration's own factory, parameters and
@@ -19,7 +21,9 @@ on the chip are equal leaf for leaf or the chip's program is wrong.
 differ, and exits 1 if they do.  `--twin` builds the configuration at its
 twin's width (`twin.params` over `params`): a program too slow for the
 sandbox's CPU at full width (Casper-1024: 4.5 min a slot) is compared
-through many steps there and through a few at full width.  Each line also
+through many steps there and through a few at full width (Dfinity-4096's
+first 6000-ms chunk, one block of 1.84 million messages, takes 20 s there
+with its compile and every later chunk a minute).  Each line also
 has the step's wall seconds, which `--compare` does not read.
 """
 

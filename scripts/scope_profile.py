@@ -3,12 +3,17 @@
 
     python3 scripts/scope_profile.py --config benchmark/configs/handel-4096.json --replicas 8 --chunks 2
     python3 scripts/scope_profile.py --config benchmark/configs/casper-1024.json --replicas 1 --chunks 1 --chunk-ms 8000
+    python3 scripts/scope_profile.py --config benchmark/configs/dfinity-4096.json --replicas 1 --chunks 1 --chunk-ms 6000
 
 Builds the program by the configuration's own factory and parameters,
 compiles and warms it through `sharded_run_stats` (one chunk of
 `--chunk-ms`, 10 unless given: a jump-loop protocol's chunk is its slot,
 so Casper's warm-up is the empty slot 0, its untraced chunk slot 1 and
-its traced chunk slot 2, the first with every committee's wave),
+its traced chunk slot 2, the first with every committee's wave;
+Dfinity's chunk is its beacon's 6000-ms cycle, the warm-up the row's
+first block, the untraced and the traced chunk two blocks each, with the
+role scopes `witt.chain.propose`, `.notarize`, `.beacon` and the
+fan-out's `witt.store.fanout` around `witt.store.insert`),
 runs `--chunks` chunks untraced and `--chunks` under a profiler trace,
 reads the trace with the benchmark's reader (`benchmark/xplane.py`
 `read_trace`: leaf ops, self time) and joins every op event's leading

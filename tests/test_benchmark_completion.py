@@ -74,3 +74,37 @@ def test_the_reading_of_casper_1024_resolves_on_both_sides():
         broken["twin"]["completion"]["reading"][side] = wrong
         with pytest.raises(cells.BenchmarkFileError, match=wrong.split(".")[-1]):
             twin.check_stated(broken, rows)
+
+
+def test_the_reading_of_dfinity_4096_resolves_on_both_sides():
+    """`twin.completion.reading` of `dfinity-4096`, on a rehearsal build:
+    `proto.chain_score` (the head's height and the most votes the node
+    has counted for one block, one integer) is a per-node leaf of the
+    program's state and `chain_score` an attribute of every node of the
+    reference's copy, whatever its role, so `twin.check_stated` passes in
+    set-up; a path or an attribute that is not there stops the run there."""
+    import copy
+
+    import pytest
+
+    import cells
+    import twin
+    from wittgenstein_tpu.engine import replicate_state
+
+    config = cells.load_cell("dfinity-4096.single-r1-c6000-h18000").config
+    assert twin.reading(config) == {"program": "proto.chain_score", "reference": "chain_score"}
+    params = cells.build_params(config, config["params_class"], config["rehearsal"]["params"])
+    net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+    rows = replicate_state(state, 2, seeds=[1, 2])
+    twin.check_stated(config, rows)
+    assert twin.program_reading(config, rows).shape == (2, 1 + 64 + 10 + 16)
+    reference = cells.build_reference(config, config["twin"]["params"])
+    reference.init()
+    roles = {type(node).__name__ for node in reference.network().all_nodes}
+    assert roles == {"_ObserverNode", "AttesterNode", "BlockProducerNode", "RandomBeaconNode"}
+    assert reference.params.node_count == 256 and len(reference.network().all_nodes) == 283
+    for side, wrong in (("program", "proto.blk_parent"), ("reference", "chain_skore")):
+        broken = copy.deepcopy(config)
+        broken["twin"]["completion"]["reading"][side] = wrong
+        with pytest.raises(cells.BenchmarkFileError, match=wrong.split(".")[-1]):
+            twin.check_stated(broken, rows)

@@ -119,3 +119,40 @@ def test_a_flat_networks_lane_holds_its_tasks_while_sent_equals_received():
         totals.append(counts["sent_total"][0])
         states, _stats = sharded_run_stats(net, states, 8000)
     assert totals == [0, 0, (1 + 16) * 67]
+
+
+def test_a_wheel_store_holds_far_future_messages_at_a_chunks_end():
+    """`dfinity-4096` at its rehearsal's 64 attesters in committees of 16,
+    as `run.py` builds it, through the traffic file's three 6000-ms chunks:
+    every chunk ends in the quiet part of a beacon cycle, with the wheel
+    empty and the NEXT height's exchange in the lane (16 x 16 real
+    messages, sent up to two rounds ahead, counted as sent when they were
+    scheduled): `sent == received + sum(msg_valid) + sum(ovf_valid)` with
+    256 rows in flight each time, and `sent == received` would be off by
+    exactly those (PR 43: the first cell whose far-future rows are messages
+    and not tasks)."""
+    import numpy as np
+
+    import cells
+    import timed_rows
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.parallel.replica_shard import sharded_run_stats
+
+    cell = cells.load_cell("dfinity-4096.single-r1-c6000-h18000")
+    config, chunk_ms = cell.config, cell.traffic["chunk_ms"]
+    assert config["timed_rows"]["conservation"]["received_plus"] == {"whole": ["msg_valid", "ovf_valid"]}
+    assert (chunk_ms, cell.traffic["horizon_ms"]) == (6000, 18000)
+    params = cells.build_params(config, config["params_class"], config["rehearsal"]["params"])
+    net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+    assert not net.flat and config["factory_kwargs"]["capacity"] is None
+    states = replicate_state(state, 1, seeds=[7001])
+    heads = []
+    for _chunk in range(3):
+        states, _stats = sharded_run_stats(net, states, chunk_ms)
+        counts = timed_rows.program_counts(states, config)
+        assert counts["received_plus"] == {"msg_valid": [0], "ovf_valid": [16 * 16]}
+        assert counts["sent_total"][0] == counts["received_total"][0] + 16 * 16
+        assert timed_rows.compare(counts, counts["sent_mean"][0], 0.0)["sent_minus_received"] == [0]
+        assert int(np.asarray(states.dropped).max()) == 0
+        heads.append(np.unique(np.asarray(states.proto["chain_score"]) // (16 + 1)).tolist())
+    assert heads == [[1], [3], [5]]  # one block, then two a cycle

@@ -533,11 +533,14 @@ def test_a_step_without_a_send_still_ticks_the_event_counter():
 
 
 @pytest.mark.parametrize("kwargs, message", [
-    ({"wheel_rows": 32, "due_view_rows": 8}, "FLAT store"),
+    # since PR 43 a store with a wheel takes a due view too, of its due row's leading slots
+    ({"wheel_rows": 32, "due_view_rows": 1 << 20}, "inside a wheel row"),
+    ({"wheel_rows": 32, "due_view_rows": (64, 8)}, "must ascend"),
     ({"wheel_rows": 0, "due_view_rows": 1 << 14}, "inside the lane"),
     ({"wheel_rows": 0, "due_view_rows": 0}, "inside the lane"),
+    ({"wheel_rows": 0, "due_view_rows": (8, 64)}, "inside the lane"),
 ])
-def test_a_due_view_is_the_flat_stores_and_smaller_than_its_lane(kwargs, message):
+def test_a_due_view_lies_inside_its_lane_or_its_wheel_row(kwargs, message):
     from wittgenstein_tpu.engine import BatchedNetwork
 
     net, _state = make_casper(CasperParameters(node_count=64), max_heights=24)

@@ -1,44 +1,61 @@
 """Batched Dfinity: chain-progress parity with the oracle, role behavior,
 determinism.  The protocol is open-ended (no doneAt), so the observables
-are head heights and traffic, like the reference's printStat."""
+are head heights and traffic, like the reference's printStat.
 
+Since PR 43 every broadcast is a `FanOut` (engine/core.py): its rows are
+made for the senders that fire.  The plain spelling it replaces (every
+sender that could fire, masked: `FanOut.dense()`, the `Emission`s the
+protocol built itself until then) stays in the engine behind
+`BatchedNetwork(dense_fanout=True)`, and the fan-out is held against it
+here leaf for leaf."""
+
+import dataclasses
+
+import jax
 import numpy as np
+import pytest
 
 from wittgenstein_tpu.engine import replicate_state
 from wittgenstein_tpu.oracle.blockchain import Block
 from wittgenstein_tpu.protocols.dfinity import Dfinity, DfinityParameters
-from wittgenstein_tpu.protocols.dfinity_batched import make_dfinity
+from wittgenstein_tpu.protocols.dfinity_batched import (
+    BatchedDfinity,
+    make_dfinity,
+    store_plan,
+)
 
 RUN_MS = 15000
+IC3 = "IC3NetworkLatency"  # the oracle Network's default, which upstream's Dfinity runs under
 
 
-def oracle_run(run_ms=RUN_MS):
+def oracle_run(run_ms=RUN_MS, params=None, seed=None):
     Block.reset_block_ids()
-    o = Dfinity(DfinityParameters())
+    o = Dfinity(params or DfinityParameters())
+    if seed is not None:
+        o.network().rd.set_seed(seed)
     o.init()
     o.network().run_ms(run_ms)
-    heights = np.array([n.head.height for n in o.network().all_nodes])
-    msgs = sum(n.msg_received for n in o.network().all_nodes)
-    return heights, msgs
+    return o.network().all_nodes
 
 
 class TestBatchedDfinity:
     def test_oracle_parity(self):
-        """All nodes converge to the same head height as the oracle run
-        (the notarized chain advances in lockstep rounds); traffic within
-        5%."""
-        oh, om = oracle_run()
-        net, state = make_dfinity(DfinityParameters(), max_heights=64)
+        """ONE latency model on both sides (the oracle's default IC3,
+        which the program is told by name; until PR 43 a jittered program
+        was held against the IC3 oracle by an 8% bound).  IC3 draws
+        nothing, so with the same population the two are the same
+        deterministic system but for same-ms ties: the heads agree on
+        every node and the traffic read 0.36% apart (5535 received against
+        5555: 31 nodes, 15000 ms), under a bound of 1%."""
+        nodes = oracle_run()
+        oh = np.array([n.head.height for n in nodes])
+        om = sum(n.msg_received for n in nodes)
+        net, state = make_dfinity(DfinityParameters(), max_heights=64, latency_name=IC3)
         out = net.run_ms(state, RUN_MS)
         bh = np.asarray(net.protocol.head_height(out))
-        assert bh.min() == bh.max(), "chain must be in sync across nodes"
-        assert abs(int(bh.max()) - int(oh.max())) <= 1, (oh.max(), bh.max())
+        assert (bh == oh).all(), (oh, bh)
         bm = int(np.asarray(out.msg_received).sum())
-        # single-seed traffic comparison: 8% bound (was 5% on the r5 draw
-        # stream; r6 keys per-row latency draws by destination id instead
-        # of emission-row position — layout-invariant for the time-wheel
-        # store — which re-rolls every jittered draw; measured 5.8%)
-        assert abs(bm - om) / om <= 0.08, (om, bm)
+        assert abs(bm - om) / om <= 0.01, (om, bm)
         assert int(out.dropped) == 0
 
     def test_chain_grows_with_time(self):
@@ -82,6 +99,242 @@ class TestBatchedDfinity:
 
 
 def jnp_max_heights(net, states):
-    import jax
-
     return jax.vmap(lambda s: net.protocol.head_height(s).max())(states)
+
+
+# -- the parameters' node_count (PR 43) -----------------------------------------
+
+
+def test_node_count_states_the_attesters():
+    p = DfinityParameters(node_count=64, attesters_per_round=16)
+    assert (p.attesters_count, p.attesters_round, p.random_beacon_count, p.majority) == (64, 4, 16, 9)
+    assert DfinityParameters(node_count=64, attesters_count=64, attesters_per_round=16).attesters_count == 64
+
+
+def test_node_count_absent_is_upstreams_ten():
+    p = DfinityParameters()
+    assert p.node_count is None and (p.attesters_count, p.attesters_per_round, p.majority) == (10, 10, 6)
+    assert DfinityParameters(attesters_count=40).attesters_count == 40
+
+
+def test_node_count_contradicted_is_an_error():
+    with pytest.raises(ValueError, match="attesters_count says 32"):
+        DfinityParameters(node_count=64, attesters_count=32)
+    with pytest.raises(ValueError, match="attesters_count says 64"):  # replace of one of the two
+        dataclasses.replace(DfinityParameters(node_count=64, attesters_per_round=16), node_count=128)
+    with pytest.raises(ValueError, match="not positive"):
+        DfinityParameters(node_count=0)
+
+
+# -- the store's sizes by rule (PR 43), asserted without a run --------------------
+
+
+def test_store_plan_at_4096_attesters():
+    """benchmark/configs/dfinity-4096.json `assumed.store` has these."""
+    assert store_plan(1 + 4096 + 10 + 64, 64) == {
+        "wheel_rows": 256,
+        "wheel_slots": 262144,  # 3/4 of a wave of 64 x 4171 = 266,944 rows, up to a power of two
+        "overflow_capacity": 8192,  # twice the 64 x 64 exchange
+        "due_view_rows": (4096, 32768),
+    }
+    assert store_plan(1 + 256 + 10 + 16, 16) == {
+        "wheel_rows": 256, "wheel_slots": 4096, "overflow_capacity": 512,
+        "due_view_rows": (64, 512),
+    }
+    p = DfinityParameters(node_count=4096, attesters_per_round=64)
+    roles = {k: np.zeros(1, np.int32) for k in
+             ("is_att", "is_bp", "is_bcn", "my_round", "bp_local", "att_ids", "bp_ids", "bcn_ids")}
+    proto = BatchedDfinity(p, roles, 8)
+    assert (proto.n_nodes, proto.max_b) == (4171, 80)
+    assert (proto.vote_capacity, proto.block_capacity, proto.proposal_capacity) == (128, 64, 5)
+    assert proto.census_limits() == {"fanout_peak": 128}
+
+
+# -- the fan-out against the plain spelling it replaces (PR 43) -------------------
+
+SMALL = dict(node_count=64, attesters_per_round=16)  # 4 committees of 16, 91 nodes
+
+
+def _small(latency_name=IC3, **kwargs):
+    return make_dfinity(DfinityParameters(**SMALL), max_heights=8, latency_name=latency_name, **kwargs)
+
+
+def _with_capacity(net, capacity):
+    """Every fan-out of the protocol at one capacity (the shipped ones are
+    the committee sizes: 32 pairs for the votes, 16, 5)."""
+    proto = net.protocol
+    proto.vote_capacity = proto.block_capacity = proto.proposal_capacity = capacity
+    proto.beacon_capacity = capacity
+    return net
+
+
+def _differing(a, b, but=("census",)):
+    a, b = (s._replace(**{f: () for f in but}) for s in (a, b))
+    return [
+        jax.tree_util.keystr(path)
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a), jax.tree_util.tree_leaves(b))
+        if not np.array_equal(np.asarray(x), np.asarray(y))
+    ]
+
+
+@pytest.fixture(scope="module")
+def dense_run():
+    """The plain spelling through 13,000 ms (heads 5 under IC3: five
+    blocks, the far-future exchange of the sixth in the lane)."""
+    net, state = _small(dense_fanout=True)
+    out = net.run_ms(state, 13000)
+    assert int(out.census.fanout_senders) == 0  # nothing went through the fan-out
+    assert np.unique(np.asarray(net.protocol.head_height(out))).tolist() == [5]
+    return out
+
+
+@pytest.mark.parametrize("capacity", [1, 3, 16, None])
+def test_fanout_equals_the_dense_form_leaf_for_leaf(dense_run, capacity):
+    """Every leaf but the census, at capacities 1, 3 and the committee's
+    size and at the shipped ones: the firing senders of a step take as
+    many rounds as the capacity makes of them, the rows reach the store
+    in the dense form's order with the dense form's draws."""
+    net, state = _small()
+    if capacity is not None:
+        _with_capacity(net, capacity)
+    out = net.run_ms(state, 13000)
+    assert _differing(out, dense_run) == []
+    assert int(out.dropped) == 0
+    c = out.census
+    assert int(c.fanout_senders) == 632  # every sender of every broadcast, once
+    assert int(c.fanout_peak) == 22  # (slot, attester) pairs of one step's votes
+    if capacity is None:
+        assert int(c.fanout_overflows) == 0 and net.census_limits()["fanout_peak"] == 32
+    else:
+        assert (int(c.fanout_overflows) > 0) == (capacity < 22)
+
+
+def test_fanout_equals_the_dense_form_where_wheel_rows_spill(monkeypatch):
+    """A wheel row of 64 slots under waves of hundreds: the fullest ms
+    spills into the lane (the fan-out's round takes its lane branch
+    there, and not elsewhere), nothing is dropped, and the stored rows
+    are the dense form's, slot for slot."""
+    import wittgenstein_tpu.protocols.dfinity_batched as module
+
+    plan = module.store_plan
+    monkeypatch.setattr(module, "store_plan", lambda n, c: {
+        **plan(n, c), "wheel_slots": 64, "overflow_capacity": 4096, "due_view_rows": (8, 32)})
+    runs = []
+    for dense in (True, False):
+        net, state = _small(dense_fanout=dense)
+        assert (net.wheel_slots, net.overflow_capacity) == (64, 4096)
+        runs.append(net.run_ms(state, 7000))
+    assert _differing(*runs) == []
+    assert int(runs[1].dropped) == 0
+    assert int(runs[1].census.wheel_fill_peak) == 64 and int(runs[1].census.lane_live_peak) > 256
+
+
+def test_fanout_equals_the_dense_form_with_a_telemetry_side_car():
+    """With a side-car (telemetry here; faults and a throughput model
+    alike) a round's rows are spelled out and take the plain send path,
+    each with its own event's counter, where without one they are
+    computed on the [senders, receivers] grid: the same leaves again, the
+    side-car's counts among them, and the store invariant closes."""
+    from wittgenstein_tpu.telemetry.state import TelemetryConfig
+
+    runs = []
+    for dense in (True, False):
+        net, state = _small(latency_name=None, dense_fanout=dense)
+        net, state = net.with_telemetry(state, TelemetryConfig())
+        runs.append(_with_capacity(net, 3).run_ms(state, 4000))
+    assert _differing(*runs) == []
+    tele = runs[1].tele
+    pending = int(runs[1].msg_valid.sum()) + int(runs[1].ovf_valid.sum())
+    assert int(tele.sent.sum()) == int(tele.delivered.sum() + tele.discarded.sum() + tele.dropped.sum()) + pending > 0
+
+
+def test_fanout_equals_the_dense_form_under_jitter():
+    """The same under a model that draws (the factory's default,
+    NetworkLatencyByDistanceWJitter): the draws are keyed by destination
+    id and by the send event's own counter, not by a row's place."""
+    runs = []
+    for dense in (True, False):
+        net, state = _small(latency_name=None, dense_fanout=dense)
+        runs.append(_with_capacity(net, 3).run_ms(state, 7000))
+    assert _differing(*runs) == []
+    assert int(runs[1].census.fanout_overflows) > 0
+
+
+def test_fanout_under_vmap_one_row_over_its_capacity_and_one_under():
+    """Two rows of one batch under a model that draws, at a capacity that
+    one row's fullest step passes and the other's does not: each row is
+    what it is alone, census and all, and the overflow is counted."""
+    import jax.numpy as jnp
+
+    net, state = _small(latency_name=None)
+    # the run's own peak: the 16 beacon results of t=0 are every row's
+    state = state._replace(census=state.census._replace(fanout_peak=jnp.int32(0)))
+    row = lambda seed: state._replace(seed=jnp.int32(seed))
+    peaks = {seed: int(net.run_ms(row(seed), 4000).census.fanout_peak) for seed in range(1, 9)}
+    under, over = min(peaks, key=peaks.get), max(peaks, key=peaks.get)
+    assert peaks[under] < peaks[over], peaks
+    net, _ = _small(latency_name=None)
+    _with_capacity(net, peaks[under])
+    batch = net.run_ms_batched(replicate_state(state, 2, seeds=[under, over]), 4000)
+    for k, seed in enumerate((under, over)):
+        one = jax.tree_util.tree_map(lambda a: a[k], batch)
+        assert _differing(one, net.run_ms(row(seed), 4000), but=()) == []
+    overflows = np.asarray(batch.census.fanout_overflows).tolist()
+    assert overflows[0] == 0 and overflows[1] > 0, overflows
+    assert np.asarray(batch.census.fanout_peak).tolist() == [peaks[under], peaks[over]]
+
+
+def test_due_view_of_the_wheel_row_changes_no_leaf():
+    """The wheel's due view (`store_plan`'s two sizes, the whole row
+    above them) against the engine's plain delivery, which gathers the
+    whole row and repacks it: every leaf but the census."""
+    import wittgenstein_tpu.protocols.dfinity_batched as module
+
+    net, state = _small()
+    assert net.due_view_rows == (32, 256) and net.wheel_slots == 2048
+    viewed = net.run_ms(state, 9000)
+    assert int(viewed.census.view_overflow_steps) > 0  # some steps took the whole row
+    assert 256 < int(viewed.census.due_rows_peak) <= 2048
+    plan = module.store_plan
+    try:
+        module.store_plan = lambda n, c: {**plan(n, c), "due_view_rows": None}
+        net, state = _small()
+    finally:
+        module.store_plan = plan
+    assert net.due_view_rows is None
+    assert _differing(viewed, net.run_ms(state, 9000)) == []
+
+
+# -- oracle parity at 256 attesters in committees of 16 (PR 43) -------------------
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_oracle_parity_256_attesters(seed):
+    """Heads, traffic and the twin's reading against the oracle from the
+    same population (`population_seed`), 283 nodes, through three blocks
+    (9000 ms, the quiet part of the second cycle).  Under IC3 nothing is
+    drawn, so what can part the two is a same-ms tie alone; read: heads
+    equal on every node and the traffic equal to the message on all four
+    seeds; chain_score equal in its quantiles, and apart on 12 to 20 of
+    283 nodes, where one ms brings a committee member several votes and
+    the program counts them all while the oracle stops at the majority."""
+    params = DfinityParameters(node_count=256, attesters_per_round=16)
+    nodes = oracle_run(9000, params, seed)
+    net, state = make_dfinity(
+        DfinityParameters(node_count=256, attesters_per_round=16), max_heights=8,
+        latency_name=IC3, population_seed=seed,
+    )
+    out = net.run_ms(state, 9000)
+    assert int(out.dropped) == 0 and int(out.census.fanout_overflows) == 0
+    heads = np.asarray(net.protocol.head_height(out))
+    assert (heads == [n.head.height for n in nodes]).all() and set(heads.tolist()) == {3}
+    sent = [n.msg_sent for n in nodes]
+    assert int(out.msg_sent.sum()) == sum(sent)
+    score = np.asarray(out.proto["chain_score"])
+    want = np.array([n.chain_score for n in nodes])
+    assert np.percentile(score, [10, 50, 90]).tolist() == np.percentile(want, [10, 50, 90]).tolist()
+    assert (score != want).sum() <= 24 and (score >= want).all()
+    # conservation with the store's occupancy: the exchanges in flight included
+    in_store = int(out.msg_valid.sum()) + int(out.ovf_valid.sum())
+    assert in_store == 16 * 16  # the fourth height's exchange, sent two rounds ahead
+    assert int(out.msg_sent.sum()) == int(out.msg_received.sum()) + in_store

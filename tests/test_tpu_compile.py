@@ -277,3 +277,58 @@ def test_casper_chunk_program_lowers_for_one_chip(topo, no_compile_cache, casper
     assert "4097" in text  # the due view: what every other step hands to deliver
     assert "262912" in text  # the ATT emission at its static [apr x N]
     assert "1027x6144" in text  # rec_att, and the fork choice's product
+
+
+@pytest.fixture(scope="module")
+def dfinity4096():
+    """`dfinity-4096` as the benchmark builds it (`make_dfinity` with its
+    parameters and `factory_kwargs`, nothing scaled), one row: the first
+    program whose broadcasts are fan-outs, on the wheel under the jump
+    loop and the wheel's due view, at its full width (4171 nodes, 256
+    wheel rows of 262,144 slots, an 8192-row lane)."""
+    import json
+    import os
+
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.protocols.dfinity import DfinityParameters
+    from wittgenstein_tpu.protocols.dfinity_batched import make_dfinity
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "dfinity-4096.json")) as f:
+        config = json.load(f)
+    net, state = make_dfinity(DfinityParameters(**config["params"]), **config["factory_kwargs"])
+    assert not net.flat and net.protocol.TICK_INTERVAL is None
+    assert (net.protocol.n_nodes, net.protocol.n_bcn, net.protocol.n_bp) == (4171, 64, 10)
+    assert (net.wheel_rows, net.wheel_slots, net.overflow_capacity) == (256, 262144, 8192)
+    assert net.protocol.vote_capacity == 128
+    # the one-word payload is what the configuration expects of the program: the parent's has two
+    assert net.protocol.PAYLOAD_WIDTH == config["expect"]["protocol_attrs"]["PAYLOAD_WIDTH"] == 1
+    return net, replicate_state(state, 1)
+
+
+def test_dfinity_chunk_program_lowers_for_one_chip(topo, no_compile_cache, dfinity4096):
+    """The 6000-ms chunk program of `sharded_run_stats` for the cell
+    `dfinity-4096.single-r1-c6000-h18000`, lowered for one described v5e
+    chip at the cell's own width: the jump loop, the wheel's planes, the
+    three views of the due row, the five fan-outs' rounds and the three
+    roles, each under its scope.  Lowered, not compiled: XLA:TPU takes
+    150 s over this program (sandbox, PR 43), which the tier-1 run does
+    not have; what the compiler makes of it is PERF.md section 5's."""
+    from wittgenstein_tpu.engine.core import ENGINE_PHASE_SCOPES, FANOUT_SCOPES, STORE_SCOPES
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+    from wittgenstein_tpu.protocols.dfinity_batched import ROLE_SCOPES
+
+    net, states = dfinity4096
+    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
+    text = _run_and_reduce(net, 6000)._jit_for(shapes).lower(shapes).as_text(debug_info=True)
+    for scope in (*ROLE_SCOPES.values(), *FANOUT_SCOPES.values(), *STORE_SCOPES.values(),
+                  ENGINE_PHASE_SCOPES["jump"]):
+        assert scope in text, scope
+    assert net.due_view_rows == (4096, 32768)  # the factory's rule: 1/64 and 1/8 of a wheel row
+    assert "256x262144" in text  # the wheel's planes
+    for view in (4096 + 8192, 32768 + 8192, 262144 + 8192):  # a step's view: the row's leading slots and the lane
+        assert f"tensor<{view}x" in text, view
+    assert f"tensor<{64 * 4171}x" in text  # a round of the beacon's or the notarised block's fan-out
+    assert f"tensor<{128 * 4096}x" in text  # a round of the votes': 128 (slot, attester) pairs
+    assert f"tensor<{10 * 4096 * 4096}x" not in text  # and never the votes' static form
+    assert "4171x80" in text  # the per-node planes by block slot

@@ -43,6 +43,8 @@ NEW_METRICS = {
     "gc_pause_s_in_window": "gc_pause_seconds_total",
     "fired_rows_in_window": "census_fired_rows_total",
     "firing_overflows_in_window": "census_firing_overflows_total",
+    "fanout_senders_in_window": "census_fanout_senders_total",
+    "fanout_overflows_in_window": "census_fanout_overflows_total",
 }
 
 
@@ -136,7 +138,8 @@ def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanf
     assert int(np.asarray(out.census.wheel_fill_peak).max()) <= net.census_limits()["wheel_fill_peak"]
     assert net.census_limits() == {
         "due_rows_peak": 0, "wheel_fill_peak": net.wheel_slots,
-        "lane_live_peak": net.overflow_capacity, "firing_peak": 0, "landing_peak": 0}
+        "lane_live_peak": net.overflow_capacity, "firing_peak": 0, "fanout_peak": 0,
+        "landing_peak": 0}
 
 
 # -- the harvest -----------------------------------------------------------------
@@ -214,7 +217,7 @@ def test_a_peak_keeps_the_larger_and_the_limit_of_the_program_that_reached_it(mo
     monkeypatch.setattr(rs, "_COUNTERS", dict(rs._COUNTERS, **{
         k: 0 for k in rs._COUNTERS if k.startswith("census_") and "seconds" not in k}))
     limits = {"due_rows_peak": 8, "wheel_fill_peak": 64, "lane_live_peak": 128,
-              "firing_peak": 0, "landing_peak": 0}
+              "firing_peak": 0, "fanout_peak": 0, "landing_peak": 0}
     vector = dict(zip(CENSUS_VECTOR, range(1, len(CENSUS_VECTOR) + 1)))
     rs._fold_census(np.asarray([vector[n] for n in CENSUS_VECTOR], np.int32), limits)
     rs._fold_census(np.asarray([vector[n] for n in CENSUS_VECTOR], np.int32), limits)
@@ -224,7 +227,7 @@ def test_a_peak_keeps_the_larger_and_the_limit_of_the_program_that_reached_it(mo
     assert info["census_wheel_fill_peak_limit"] == 64
     # a program without the mechanism reads 0 and leaves the limit alone
     other = {"due_rows_peak": 0, "wheel_fill_peak": 0, "lane_live_peak": 16,
-             "firing_peak": 0, "landing_peak": 0}
+             "firing_peak": 0, "fanout_peak": 0, "landing_peak": 0}
     rs._fold_census(np.zeros(len(CENSUS_VECTOR), np.int32), other)
     assert info["census_wheel_fill_peak_limit"] == 64 and info["census_due_rows_peak_limit"] == 8
     # and a higher peak brings its own program's limit
@@ -350,6 +353,8 @@ def test_the_new_metric_files_name_counters_the_program_has():
     for name in ("fired_rows_in_window", "firing_overflows_in_window"):  # the channel cells
         assert set(files[name]["workloads"]) == handel | {"gsf-2048.single-r1"}
     assert files["view_overflow_steps_in_window"]["workloads"] == ["casper-1024.single-r1-s8000"]
+    for name in ("fanout_senders_in_window", "fanout_overflows_in_window"):  # the fan-out's one cell
+        assert files[name]["workloads"] == ["dfinity-4096.single-r1-c6000-h18000"]
     for name in ("steps_in_window", "census_s_in_window", "gc_pause_s_in_window"):
         assert "workloads" not in files[name]  # every cell
     # the peaks and their limits are beside the sums
@@ -370,5 +375,7 @@ def test_the_server_renders_the_census():
         "witt_run_cache_census_landing_peak_limit", "witt_run_cache_gc_pause_seconds_total",
         "witt_run_cache_census_fired_rows_total", "witt_run_cache_census_firing_overflows_total",
         "witt_run_cache_census_firing_peak", "witt_run_cache_census_firing_peak_limit",
+        "witt_run_cache_census_fanout_senders_total", "witt_run_cache_census_fanout_overflows_total",
+        "witt_run_cache_census_fanout_peak", "witt_run_cache_census_fanout_peak_limit",
     ):
         assert family in text, family

@@ -16,7 +16,7 @@ from .capacity import (
     sized_overrides,
     validate_table,
 )
-from .core import BatchedNetwork, Emission, SimState, replicate_state, stack_states
+from .core import BatchedNetwork, Emission, FanOut, SimState, replicate_state, stack_states
 from .density import LanePlan, NarrowLeaf, lane_plan, narrowest_int
 from .protocol import (
     ENGINE_OWNED_FIELDS,
@@ -31,6 +31,7 @@ __all__ = [
     "BatchedProtocol",
     "CapacityEntry",
     "Emission",
+    "FanOut",
     "LanePlan",
     "NarrowLeaf",
     "SimState",

@@ -138,6 +138,15 @@ STORE_SCOPES = {
     "repack": "witt.store.repack",  # due entries cleared, visited rows dense again
 }
 
+# the store's fan-out form of a broadcast (`FanOut`, `apply_fanout`): the
+# firing senders to the front, a round's `capacity x receivers` rows made
+# and inserted (the insert's own scope nests inside), nested under
+# witt.send and switched by the same `annotate`.  Required of the
+# protocols that emit a `FanOut` (their `REQUIRED_SCOPES`).
+FANOUT_SCOPES = {
+    "expand": "witt.store.fanout",  # a broadcast's rows, for the senders that fire
+}
+
 # the deliver phase's candidate merge of an aggregation protocol
 # (ops/select.py `top_k_merge`, called by protocols/handel_batched.py
 # `_channel_deliver` on every (node, level) of every tick), nested under
@@ -152,6 +161,13 @@ DELIVER_SCOPES = {
 _SEND_FIELDS = (
     "send_ctr", "msg_sent", "bytes_sent", "ovf_valid", "ovf_arrival", "ovf_from",
     "ovf_to", "ovf_type", "ovf_payload", "msg_head", "dropped", "faults",
+)
+
+# the wheel's planes: written by a send into a store with a wheel, read and
+# never written by a delivery under the wheel's due view (the due row is
+# emptied after the branch)
+_WHEEL_FIELDS = (
+    "msg_valid", "msg_arrival", "msg_from", "msg_to", "msg_type", "msg_payload", "whl_fill",
 )
 
 
@@ -179,23 +195,28 @@ class Census(NamedTuple):
     extra_commit_rounds: jnp.ndarray  # commit rounds beyond a send's first (`landing_capacity` passed)
     fired_rows: jnp.ndarray  # rows an every-tick channel send carried with their mask set (`_send_fired`)
     firing_overflows: jnp.ndarray  # such sends whose fired rows passed `firing_capacity`: a second round
-    due_rows_peak: jnp.ndarray  # most lane rows due in a step, against `due_view_rows`
+    fanout_senders: jnp.ndarray  # senders a fan-out expanded into their rows (`apply_fanout`)
+    fanout_overflows: jnp.ndarray  # fan-outs whose firing senders passed their capacity: another round
+    due_rows_peak: jnp.ndarray  # most rows due in a step (the lane's, or the wheel row's), against `due_view_rows`
     wheel_fill_peak: jnp.ndarray  # fullest wheel row after a step's inserts, against `wheel_slots`
     lane_live_peak: jnp.ndarray  # most live lane rows after a step's inserts, against `overflow_capacity`
     firing_peak: jnp.ndarray  # most rows one every-tick channel send fired, against `firing_capacity`
+    fanout_peak: jnp.ndarray  # most senders one fan-out fired, against the protocol's largest `FanOut.capacity`
 
 
-CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak")
+CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "fanout_peak")
 _CENSUS_ROW_SUMS = (
     "view_overflow_steps", "landed_rows", "extra_commit_rounds", "fired_rows", "firing_overflows",
+    "fanout_senders", "fanout_overflows",
 )
 
 # what a chunk's census vector holds, in order (`chunk_census`): the sums
 # first (a chunk's own, from the rows' growth), then the peaks
 CENSUS_VECTOR = (
     "steps", "store_rows", "view_overflow_steps", "landed_rows", "extra_commit_rounds",
-    "fired_rows", "firing_overflows",
-    "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "landing_peak",
+    "fired_rows", "firing_overflows", "fanout_senders", "fanout_overflows",
+    "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "fanout_peak",
+    "landing_peak",
 )
 CENSUS_VECTOR_PEAKS = CENSUS_PEAKS + ("landing_peak",)
 
@@ -327,21 +348,74 @@ class Emission:
     arrival: Optional[jnp.ndarray] = None  # explicit arrival times [K]
 
 
+@dataclasses.dataclass
+class FanOut:
+    """A step's broadcasts of one message type, by their SENDERS: entry e
+    of the S, where `mask[e]`, is one message from `from_idx[e]` to every
+    node of the static list `receivers`, with the entry's payload row and
+    send time.  What `Emission` spells as S x len(receivers) rows, most of
+    them masked out, the store makes for the entries that fire
+    (`BatchedNetwork.apply_fanout`): `capacity` of them a round, read
+    from the deployment's shape, and another round where more fire,
+    counted by the census.  The stored rows, the counters and the latency
+    draws (keyed by destination id, not by row) are those of `dense()`,
+    bit for bit.
+
+    `events` > 1: the entries lie in that many equal groups, each a send
+    event of its own (one per-event counter each, as one `Emission` each
+    would tick): Dfinity's votes, one group a producer's slot, compacted
+    over (slot, sender) pairs."""
+
+    mask: jnp.ndarray  # bool[S]
+    from_idx: jnp.ndarray  # int[S]
+    receivers: Any  # int[R], static: the same list for every entry
+    mtype: int
+    capacity: int  # entries expanded a round (static)
+    payload: Optional[jnp.ndarray] = None  # [S, P]
+    send_time: Optional[jnp.ndarray] = None  # [S]; default: state.time + 1
+    events: int = 1
+
+    def dense(self) -> "list[Emission]":
+        """The plain spelling: one `Emission` of S/events x R rows an event."""
+        r = len(self.receivers)
+        per = self.mask.shape[0] // self.events
+        out = []
+        for j in range(self.events):
+            at = slice(j * per, (j + 1) * per)
+            out.append(
+                Emission(
+                    mask=jnp.repeat(self.mask[at], r),
+                    from_idx=jnp.repeat(self.from_idx[at], r),
+                    to_idx=jnp.tile(jnp.asarray(self.receivers), per),
+                    mtype=self.mtype,
+                    payload=None if self.payload is None else jnp.repeat(self.payload[at], r, axis=0),
+                    send_time=None if self.send_time is None else jnp.repeat(self.send_time[at], r),
+                )
+            )
+        return out
+
+
 _EMISSION_ARRAYS = ("mask", "from_idx", "to_idx", "mtype", "payload", "send_time", "arrival")
+_FANOUT_STATICS = ("receivers", "mtype", "capacity", "events")
 
 
 def _emission_leaves(emissions):
     """A step's emissions as (static part, arrays) so that they can leave
-    a `lax.cond`: per emission the names of its array fields and its
-    static `mtype`, and the arrays themselves."""
+    a `lax.cond`: per emission its class, the names of its array fields
+    and its static fields (an `Emission`'s static `mtype`, a `FanOut`'s
+    receivers, type, capacity and events), and the arrays themselves."""
     shape, leaves = [], []
     for em in emissions:
+        if isinstance(em, FanOut):
+            static = {f: getattr(em, f) for f in _FANOUT_STATICS}
+        else:
+            static = {"mtype": em.mtype} if isinstance(em.mtype, int) else {}
         arrays = {
             f: getattr(em, f)
             for f in _EMISSION_ARRAYS
-            if getattr(em, f) is not None and not isinstance(getattr(em, f), int)
+            if f not in static and getattr(em, f, None) is not None
         }
-        shape.append((tuple(arrays), em.mtype if isinstance(em.mtype, int) else None))
+        shape.append((type(em), tuple(arrays), static))
         leaves.append(tuple(arrays.values()))
     return shape, tuple(leaves)
 
@@ -357,8 +431,8 @@ def _kept(new, old, fields) -> None:
 
 def _emissions_of(shape, leaves):
     return [
-        Emission(**{"mtype": mtype, **dict(zip(names, arrays))})
-        for (names, mtype), arrays in zip(shape, leaves)
+        cls(**static, **dict(zip(names, arrays)))
+        for (cls, names, static), arrays in zip(shape, leaves)
     ]
 
 
@@ -394,7 +468,8 @@ class BatchedNetwork:
         fuse_step: bool = False,
         narrow_lanes: Optional[bool] = None,
         batched_jumps: bool = False,
-        due_view_rows: Optional[int] = None,
+        due_view_rows: "Optional[int | tuple]" = None,
+        dense_fanout: bool = False,
     ):
         self.protocol = protocol
         self.latency = latency
@@ -433,8 +508,21 @@ class BatchedNetwork:
         # (the whole lane again on a step with more than K due), and skips
         # a step's emissions when every mask is empty.  Bit-identical to
         # None by construction (tests/test_casper_batched.py); what it buys
-        # is a step that costs what is due, not what the lane holds
-        self.due_view_rows = None if due_view_rows is None else int(due_view_rows)
+        # is a step that costs what is due, not what the lane holds.  On a
+        # store WITH a wheel the view is of the due wheel row (a dense
+        # prefix of `whl_fill[row]` entries, every one of them due): a tuple
+        # of ascending sizes, a step taking the smallest its row fits, the
+        # whole row's `wheel_slots` where it fits none (`_deliver_row_view`)
+        if due_view_rows is None:
+            self.due_view_rows = None
+        elif isinstance(due_view_rows, (tuple, list)):
+            self.due_view_rows = tuple(int(k) for k in due_view_rows)
+        else:
+            self.due_view_rows = int(due_view_rows)
+        # STATIC switch: True stores a `FanOut` as its plain spelling
+        # (`FanOut.dense()`: every sender that could fire, masked), the form
+        # the fan-out is held against leaf for leaf (tests/test_dfinity_batched.py)
+        self.dense_fanout = bool(dense_fanout)
         # STATIC switch: None compiles the exact pre-telemetry program
         # (state.tele is an empty pytree); a TelemetryConfig threads the
         # counter side-car through every send/deliver/jump site below
@@ -467,15 +555,14 @@ class BatchedNetwork:
                 capacity if overflow_capacity is None else overflow_capacity
             )
             if self.due_view_rows is not None and not (
-                0 < self.due_view_rows < self.overflow_capacity
+                isinstance(self.due_view_rows, int)
+                and 0 < self.due_view_rows < self.overflow_capacity
             ):
                 raise ValueError(
                     f"due_view_rows={self.due_view_rows} must lie inside the "
                     f"lane's {self.overflow_capacity} rows"
                 )
         else:
-            if self.due_view_rows is not None:
-                raise ValueError("due_view_rows is the FLAT store's (wheel_rows=0)")
             if wheel_rows % 32:
                 raise ValueError(
                     f"wheel_rows={wheel_rows} must be a multiple of 32 "
@@ -495,6 +582,23 @@ class BatchedNetwork:
                 if overflow_capacity is None
                 else overflow_capacity
             )
+            if self.due_view_rows is not None:
+                if isinstance(self.due_view_rows, int):
+                    self.due_view_rows = (self.due_view_rows,)
+                tiers = self.due_view_rows
+                if (
+                    not tiers
+                    or list(tiers) != sorted(set(tiers))
+                    or not 0 < tiers[0] <= tiers[-1] < self.wheel_slots
+                ):
+                    raise ValueError(
+                        f"due_view_rows={tiers} must ascend inside a wheel row's "
+                        f"{self.wheel_slots} slots"
+                    )
+                if self._window() != 1:
+                    raise ValueError(
+                        "the wheel's due view needs a row visited once (TIME_QUANTUM 1)"
+                    )
 
     # -- state construction (host-side) -------------------------------------
     def init_state(self, cols: dict, seed: int, proto: Any, down=None) -> SimState:
@@ -565,8 +669,8 @@ class BatchedNetwork:
             ),
             census=Census(*(jnp.int32(0) for _ in Census._fields)),
         )
-        for em in self.protocol.initial_emissions(self, state):
-            state = self.apply_emission(state, em)
+        for em in self._spelled(self.protocol.initial_emissions(self, state)):
+            state = self._apply_one(state, em)
         return census_add(state, **self._store_fill(state))
 
     def census_limits(self) -> dict:
@@ -574,7 +678,9 @@ class BatchedNetwork:
         (`CENSUS_VECTOR_PEAKS`): the store's here, the protocol's own
         from its `census_limits`; 0 where the mechanism is lacking."""
         return {
-            "due_rows_peak": self.due_view_rows or 0,
+            # a wheel's due view is a tuple of sizes: the largest
+            "due_rows_peak": max(np.atleast_1d(self.due_view_rows or 0).tolist()),
+            "fanout_peak": 0,
             "wheel_fill_peak": 0 if self.flat else self.wheel_slots,
             "lane_live_peak": self.overflow_capacity,
             "firing_peak": 0,
@@ -624,6 +730,7 @@ class BatchedNetwork:
             self.fuse_step,
             self.batched_jumps,
             self.due_view_rows,
+            self.dense_fanout,
             self.lanes.key(),
             # the bitset-kernel backend is read from the environment at
             # trace time (WITT_BITOPS) — fold it in so a flipped override
@@ -657,6 +764,7 @@ class BatchedNetwork:
             self.fuse_step,
             self.batched_jumps,
             self.due_view_rows,
+            self.dense_fanout,
             self.lanes.key(),
             bitops_backend(),
         )
@@ -769,12 +877,18 @@ class BatchedNetwork:
         ).astype(jnp.int32)
 
     # -- the send path (createMessageArrival, Network.java:469-487) ----------
-    def latency_arrivals(self, state, mask, from_idx, to_idx, send_time, mtype):
+    def latency_arrivals(
+        self, state, mask, from_idx, to_idx, send_time, mtype, event_ctr=None
+    ):
         """The createMessageArrival kernel shared by the generic ring and
         protocol-specific message channels: ticks sender counters (even for
         dropped sends, Network.java:476-477), samples the latency model via
         the counter RNG, applies partition/down/discard filters.  Returns
-        (state, ok, arrival)."""
+        (state, ok, arrival).
+
+        `event_ctr` (int32[K], a fan-out's rounds alone) is the per-event
+        counter of each row's own send event: the caller has taken the
+        events' ticks, and `send_ctr` is left as it is."""
         k = mask.shape[0]
         from_idx = from_idx.astype(jnp.int32)
         to_idx = to_idx.astype(jnp.int32)
@@ -785,8 +899,10 @@ class BatchedNetwork:
             bytes_sent=state.bytes_sent.at[from_idx].add(
                 mask.astype(jnp.int32) * size
             ),
-            send_ctr=state.send_ctr + 1,
+            send_ctr=state.send_ctr + (1 if event_ctr is None else 0),
         )
+        if event_ctr is None:
+            event_ctr = state.send_ctr
         # per-event seed: the batched analog of rd.nextInt() per send;
         # send_ctr decorrelates same-tick emissions, to_idx the rows of
         # one emission.  The destination id — NOT the row position — is
@@ -802,7 +918,7 @@ class BatchedNetwork:
             send_time,
             from_idx,
             mtype,
-            state.send_ctr,
+            event_ctr,
             to_idx,
         )
         delta = pseudo_delta(to_idx, seed)
@@ -842,7 +958,7 @@ class BatchedNetwork:
                 )
                 supp = send_suppress(
                     self.faults, fs, state.time, from_idx, to_idx, mrows,
-                    state.seed, state.send_ctr, send_time,
+                    state.seed, event_ctr, send_time,
                 )
                 ok_f = (
                     mask
@@ -892,7 +1008,7 @@ class BatchedNetwork:
         with self._scope("send"):
             return self._apply_emission_impl(state, em)
 
-    def _apply_emission_impl(self, state: SimState, em: Emission) -> SimState:
+    def _apply_emission_impl(self, state: SimState, em: Emission, event_ctr=None) -> SimState:
         k = em.mask.shape[0]
         send_time = em.send_time if em.send_time is not None else state.time + 1
         mask = em.mask
@@ -908,7 +1024,7 @@ class BatchedNetwork:
             ok = mask
         else:
             state, ok, arrival = self.latency_arrivals(
-                state, mask, from_idx, to_idx, send_time, mtype
+                state, mask, from_idx, to_idx, send_time, mtype, event_ctr
             )
 
         payload = em.payload
@@ -942,10 +1058,17 @@ class BatchedNetwork:
         return state
 
     def _insert_rows(
-        self, state: SimState, ok, arrival, from_idx, to_idx, mtype_rows, payload
+        self, state: SimState, ok, arrival, from_idx, to_idx, mtype_rows, payload,
+        wide: bool = False,
     ):
         """One emission's ok-rows into the wheel or the overflow lane.
-        Returns the state and the rows a full store dropped."""
+        Returns the state and the rows a full store dropped.
+
+        `wide` (a fan-out round's 10^5 rows, `_store_grid`): the same
+        slots and counts, with the wheel rows' fill read and written as
+        reductions of a [rows, wheel_rows] comparison (an indexed read or
+        an add into 256 bins costs the chip tens of ns a row), and the
+        lane's half under a branch that runs where a row goes there."""
         n_ok = jnp.sum(ok.astype(jnp.int32))
         t = state.time
         w, b, v = self.wheel_rows, self.wheel_slots, self.overflow_capacity
@@ -963,7 +1086,15 @@ class BatchedNetwork:
             # same-row rank (ties take consecutive slots in row order)
             rkey = jnp.where(cand, row, w)
             rank = same_key_rank(rkey)
-            slot = state.whl_fill[jnp.where(cand, row, 0)] + rank
+            if wide:
+                wheel = jnp.arange(w, dtype=jnp.int32)[None, :]
+                fill = jnp.sum(
+                    jnp.where(jnp.where(cand, row, 0)[:, None] == wheel, state.whl_fill[None, :], 0),
+                    axis=1,
+                )
+            else:
+                fill = state.whl_fill[jnp.where(cand, row, 0)]
+            slot = fill + rank
             fits = cand & (slot < b)
             w_row = jnp.where(fits, row, w)  # OOB -> dropped scatter
             w_slot = jnp.where(fits, slot, 0)
@@ -981,8 +1112,10 @@ class BatchedNetwork:
                 msg_type=state.msg_type.at[w_row, w_slot].set(
                     mtype_rows.astype(self.lanes.mtype), mode="drop"
                 ),
-                whl_fill=state.whl_fill.at[w_row].add(
-                    fits.astype(jnp.int32), mode="drop"
+                whl_fill=(
+                    state.whl_fill + jnp.sum((w_row[:, None] == wheel).astype(jnp.int32), axis=0)
+                    if wide
+                    else state.whl_fill.at[w_row].add(fits.astype(jnp.int32), mode="drop")
                 ),
             )
             if self.payload_width:
@@ -993,6 +1126,30 @@ class BatchedNetwork:
                 )
             to_ovf = ok & ~fits  # beyond horizon, or full-row spill
 
+        state = state._replace(msg_head=state.msg_head + n_ok)
+        if not wide:
+            return self._insert_lane(state, to_ovf, arrival, from_idx, to_idx, mtype_rows, payload)
+        # a wave that fits its wheel rows sends nothing to the lane: its
+        # ranks and six scatters of every row run where a row goes there
+        lane = ("ovf_valid", "ovf_arrival", "ovf_from", "ovf_to", "ovf_type", "ovf_payload", "dropped")
+
+        def spill(vals):
+            st, lost = self._insert_lane(
+                state._replace(**dict(zip(lane, vals))), to_ovf, arrival, from_idx, to_idx,
+                mtype_rows, payload,
+            )
+            return tuple(getattr(st, f) for f in lane), lost
+
+        vals, lost = lax.cond(
+            jnp.any(to_ovf), spill, lambda vals: (vals, jnp.zeros_like(to_ovf)),
+            tuple(getattr(state, f) for f in lane),
+        )
+        return state._replace(**dict(zip(lane, vals))), lost
+
+    def _insert_lane(self, state, to_ovf, arrival, from_idx, to_idx, mtype_rows, payload):
+        """The rows `to_ovf` into the overflow lane.  Returns the state
+        and the rows a full lane dropped."""
+        v = self.overflow_capacity
         # overflow lane: pack into FREE slots, k-th ok row takes the k-th
         # invalid slot (a head cursor would clobber still-pending long-lived
         # messages — ENR's wakes, Casper's slot calendar — once cumulative
@@ -1024,9 +1181,6 @@ class BatchedNetwork:
             ovf_type=state.ovf_type.at[pos].set(
                 mtype_rows.astype(self.lanes.mtype), mode="drop"
             ),
-            # head is not an allocator; kept as a monotone sent-message
-            # counter for observability
-            msg_head=state.msg_head + n_ok,
             dropped=state.dropped + overwritten,
         )
         if self.payload_width:
@@ -1035,10 +1189,149 @@ class BatchedNetwork:
             )
         return state, to_ovf & ~ofits
 
+    def _send_fields(self) -> tuple:
+        """The leaves a send into this store writes."""
+        fields = _SEND_FIELDS if self.flat else _SEND_FIELDS + _WHEEL_FIELDS
+        return fields + ("tele",) if self.telemetry is not None else fields
+
+    def apply_fanout(self, state: SimState, fo: FanOut) -> SimState:
+        """Store a `FanOut`: the entries that fire to the front in entry
+        order, then `capacity` of them a round, each round the plain
+        `capacity x receivers` rows through `_apply_emission_impl`, until
+        all are stored (no round where none fires).  Rows reach the store
+        in the order `fo.dense()`'s do, with the per-event counter their
+        own event would have drawn, so every leaf but the census is the
+        dense form's.  A fan-out with more firing entries than its
+        capacity is counted (`fanout_overflows`), never cut."""
+        with self._scope("send"), self._scope("expand", FANOUT_SCOPES):
+            return self._apply_fanout_impl(state, fo)
+
+    def _apply_fanout_impl(self, state: SimState, fo: FanOut) -> SimState:
+        s = fo.mask.shape[0]
+        per = s // fo.events
+        k = min(int(fo.capacity), s)
+        receivers = jnp.asarray(fo.receivers, jnp.int32)
+        r = receivers.shape[0]
+        entries = jnp.arange(s, dtype=jnp.int32)
+        fired = jnp.sum(fo.mask.astype(jnp.int32))
+        if k < s:
+            # one sort: the firing entries' numbers first and ascending, then
+            # numbers past the end; k more of those so that a round's slice fits
+            order = lax.sort(jnp.where(fo.mask, entries, entries + s), is_stable=False)
+            order = jnp.concatenate([order, jnp.full(k, 2 * s, jnp.int32)])
+        base = state.send_ctr  # event j of the fan-out draws with base + 1 + j
+        send_time = (
+            jnp.broadcast_to(state.time + 1, (s,)) if fo.send_time is None else fo.send_time
+        ).astype(jnp.int32)
+        fields = self._send_fields()
+        # with no side-car and no throughput model a round's arrivals are
+        # computed on the [capacity, receivers] grid; else as plain rows
+        plain = self.faults is None and self.telemetry is None and self.throughput is None
+
+        def one_round(cursor, vals):
+            st = state._replace(**dict(zip(fields, vals)))
+            if k < s:
+                live = cursor + jnp.arange(k, dtype=jnp.int32) < fired
+                at = jnp.where(live, lax.dynamic_slice(order, (cursor,), (k,)), 0)
+            else:
+                live, at = fo.mask, entries
+            senders = fo.from_idx.astype(jnp.int32)[at]
+            payload = None if fo.payload is None else jnp.repeat(fo.payload[at], r, axis=0)
+            if plain:
+                st = self._store_grid(
+                    st, live, senders, receivers, send_time[at], fo.mtype,
+                    base + 1 + at // per, payload,
+                )
+            else:
+                rows = Emission(
+                    mask=jnp.repeat(live, r),
+                    from_idx=jnp.repeat(senders, r),
+                    to_idx=jnp.tile(receivers, k),
+                    mtype=fo.mtype,
+                    payload=payload,
+                    send_time=jnp.repeat(send_time[at], r),
+                )
+                st = self._apply_emission_impl(
+                    st, rows, event_ctr=jnp.repeat(base + 1 + at // per, r)
+                )
+            _kept(st, state, [f for f in SimState._fields if f not in fields])
+            return cursor + k, tuple(getattr(st, f) for f in fields)
+
+        _, vals = lax.while_loop(
+            lambda c: c[0] < fired,
+            lambda c: one_round(*c),
+            (jnp.int32(0), tuple(getattr(state, f) for f in fields)),
+        )
+        state = state._replace(**dict(zip(fields, vals)))
+        state = state._replace(send_ctr=base + fo.events)
+        return census_add(
+            state, fanout_senders=fired, fanout_overflows=fired > k, fanout_peak=fired
+        )
+
+    def _store_grid(self, state, live, senders, receivers, send_time, mtype, event_ctr, payload):
+        """A fan-out round's `len(senders) x len(receivers)` rows into the
+        store: what `_apply_emission_impl` does with the same rows spelled
+        out (sender-major), to the bit, with everything that depends on one
+        end alone read once an end and not once a row: a row's position,
+        extra latency, down flag and partition are those of its sender or
+        of its receiver, so they are K + R reads broadcast over the grid
+        where the rows' own `x[from_idx]` are K x R gathers, and the
+        sender counters grow by R a live sender (an indexed read costs the
+        chip tens of ns a row: ten of them were two thirds of a round)."""
+        k, r = senders.shape[0], receivers.shape[0]
+        f, t = senders[:, None], receivers[None, :]
+        size = jnp.asarray(self._msg_sizes, jnp.int32)[mtype]
+        sent = live.astype(jnp.int32) * r
+        state = state._replace(
+            msg_sent=state.msg_sent.at[senders].add(sent),
+            bytes_sent=state.bytes_sent.at[senders].add(sent * size),
+        )
+        seed = hash32(state.seed, send_time[:, None], f, mtype, event_ctr[:, None], t)
+        delta = pseudo_delta(t, seed)
+        static = LatencyStatic(state.x, state.y, state.extra_latency, state.city_idx)
+        lat = vec_latency(self.latency, static, f, t, delta)
+        arrival = send_time[:, None] + lat
+        pid_f = self.partition_id(state, state.x[senders])
+        pid_t = self.partition_id(state, state.x[receivers])
+        ok = (
+            (live & ~state.down[senders])[:, None]
+            & ~state.down[receivers][None, :]
+            & (pid_f[:, None] == pid_t[None, :])
+            & (lat < self.msg_discard_time)
+        )
+        rows = lambda a: jnp.broadcast_to(a, (k, r)).reshape(k * r)
+        if self.payload_width and payload is None:
+            payload = jnp.zeros((k * r, self.payload_width), dtype=jnp.int32)
+        with self._scope("insert", STORE_SCOPES):
+            state, _lost = self._insert_rows(
+                state, rows(ok), rows(arrival), rows(f), rows(t),
+                jnp.full(k * r, mtype, jnp.int32), payload, wide=not self.flat,
+            )
+        return state
+
+    def _spelled(self, emissions) -> list:
+        """A step's emissions as this store takes them: under
+        `dense_fanout` every `FanOut` in its plain spelling."""
+        if not self.dense_fanout:
+            return list(emissions)
+        return [em for fo in emissions for em in (fo.dense() if isinstance(fo, FanOut) else [fo])]
+
+    def _apply_one(self, state: SimState, em) -> SimState:
+        if isinstance(em, FanOut):
+            return self.apply_fanout(state, em)
+        return self.apply_emission(state, em)
+
     def apply_emissions(self, state: SimState, emissions) -> SimState:
-        if self.due_view_rows is None or self.telemetry is not None or not emissions:
+        emissions = self._spelled(emissions)
+        if (
+            self.due_view_rows is None
+            or self.telemetry is not None
+            or not emissions
+            # a fan-out stores nothing where nothing fires, of itself
+            or any(isinstance(em, FanOut) for em in emissions)
+        ):
             for em in emissions:
-                state = self.apply_emission(state, em)
+                state = self._apply_one(state, em)
             return state
         # the due view's other half: a step whose every mask is empty (all
         # but a handful a slot, for a protocol that acts on timers) skips
@@ -1046,7 +1339,7 @@ class BatchedNetwork:
         # emission still ticks the per-event counter (`latency_arrivals`),
         # and nothing else: its rows add 0 to every counter and scatter out
         # of bounds.  Only the fields a send writes ride through the branch
-        fields = _SEND_FIELDS
+        fields = self._send_fields()
         sampled = sum(em.arrival is None for em in emissions)
         any_send = functools.reduce(
             jnp.logical_or, [jnp.any(em.mask) for em in emissions]
@@ -1084,7 +1377,7 @@ class BatchedNetwork:
             )
         return q
 
-    def delivery_view(self, state: SimState, ovf_due=None):
+    def delivery_view(self, state: SimState, ovf_due=None, row_slots=None):
         """Build the flat delivery VIEW protocol.deliver sees: msg_* columns
         are `[D]` gathers of the due wheel window rows + the overflow lane
         (see the module docstring).  Returns (vstate, due, deliver, ctx):
@@ -1098,9 +1391,13 @@ class BatchedNetwork:
         puts those rows in place of the lane, compacted in lane order to
         `due_view_rows` rows: the caller has seen that they fit.  What
         is not due is not in this view at all; a protocol's `deliver`
-        reads nothing but what its mask delivers, so it computes the same."""
+        reads nothing but what its mask delivers, so it computes the same.
+
+        `row_slots` (the wheel's due view alone) views that many leading
+        slots of the due wheel row in place of all `wheel_slots`: the
+        caller has seen that the row's dense prefix fits."""
         t = state.time
-        w, b = self.wheel_rows, self.wheel_slots
+        w, b = self.wheel_rows, self.wheel_slots if row_slots is None else row_slots
         q = self._window()
         if ovf_due is not None:
             with self._scope("view", STORE_SCOPES):
@@ -1124,12 +1421,24 @@ class BatchedNetwork:
             rows = jnp.remainder(
                 t - q + 1 + jnp.arange(q, dtype=jnp.int32), jnp.int32(w)
             )  # [q] distinct rows covering ticks (t-q, t]
-            wv = state.msg_valid[rows]  # [q, B]
-            wa = state.msg_arrival[rows]
-            wf = state.msg_from[rows]
-            wt = state.msg_to[rows]
-            wk = state.msg_type[rows]
-            wp = state.msg_payload[rows]  # [q, B, P]
+            if row_slots is None:
+                take = lambda a: a[rows]
+            else:  # q == 1: a slice of the one row, no gather; the payload
+                # plane sliced as [rows, slots x P], as flat as the others
+                # (XLA:TPU changed the layout of all of a 3-D plane first)
+                def take(a):
+                    width = int(np.prod(a.shape[2:], dtype=np.int64))
+                    flat = a.reshape(a.shape[0], a.shape[1] * width)
+                    cut = lax.dynamic_slice(flat, (rows[0], 0), (1, b * width))
+                    if a.ndim > 2:  # or the reshapes fold into a 3-D slice again
+                        cut = lax.optimization_barrier(cut)
+                    return cut.reshape((1, b) + a.shape[2:])
+            wv = take(state.msg_valid)  # [q, B]
+            wa = take(state.msg_arrival)
+            wf = take(state.msg_from)
+            wt = take(state.msg_to)
+            wk = take(state.msg_type)
+            wp = take(state.msg_payload)  # [q, B, P]
 
             view_valid = jnp.concatenate([wv.reshape(-1), state.ovf_valid])
             view_arrival = jnp.concatenate([wa.reshape(-1), state.ovf_arrival])
@@ -1150,11 +1459,24 @@ class BatchedNetwork:
             )
 
         due = view_valid & (view_arrival <= t)
+
         # delivery-time checks: down destination or cross-partition messages
         # are discarded on arrival (Network.java:606, :518-520)
-        pid_f = self.partition_id(state, state.x[view_from])
-        pid_t = self.partition_id(state, state.x[view_to])
-        deliver = due & ~state.down[view_to] & (pid_f == pid_t)
+        def checked():
+            pid_f = self.partition_id(state, state.x[view_from])
+            pid_t = self.partition_id(state, state.x[view_to])
+            return due & ~state.down[view_to] & (pid_f == pid_t)
+
+        if row_slots is None:
+            deliver = checked()
+        else:
+            # a view of 10^5 rows: with no node down and no partition line
+            # every due row is delivered, and the three indexed reads a view
+            # row (tens of ns each on the chip) are not made
+            deliver = lax.cond(
+                jnp.any(state.down) | jnp.any(state.partition_x != INT_MAX),
+                checked, lambda: due,
+            )
         if self.faults is not None:
             # fault choke point 2 (arrival): suppress delivery to
             # fault-crashed destinations and across an active group
@@ -1193,6 +1515,8 @@ class BatchedNetwork:
     def _deliver_and_clear_impl(self, state: SimState):
         if self.due_view_rows is None:
             return self._deliver_view_and_clear(state, None)
+        if not self.flat:
+            return self._deliver_row_view(state)
         # the FLAT store's due view: the step's due rows alone where they
         # fit, the whole lane where they do not (the same rows either way,
         # so the same step: a wave's fullest ms decides the size).  The
@@ -1201,30 +1525,78 @@ class BatchedNetwork:
         n_due = jnp.sum(ovf_due.astype(jnp.int32))
         fits = n_due <= self.due_view_rows
         state = census_add(state, view_overflow_steps=~fits, due_rows_peak=n_due)
-        carried = [f for f in SimState._fields if f not in _LANE_READ_FIELDS]
-        shape = []  # the emissions' static part, the same in both branches
+        through, carried, shape = self._view_branches(_LANE_READ_FIELDS)
+        vals, leaves = lax.cond(fits, through(ovf_due), through(None), state)
+        return state._replace(**dict(zip(carried, vals))), _emissions_of(shape, leaves)
 
-        def through(view_due):
+    def _view_branches(self, read):
+        """What a due view's branches share: `through(ovf_due, row_slots)`
+        makes the branch that delivers from that view and returns the
+        fields it may write (`carried`: all but `read`, which it must
+        leave as they are) and its emissions' arrays; `shape` takes the
+        emissions' static part, the same in every branch."""
+        carried = [f for f in SimState._fields if f not in read]
+        shape = []
+
+        def through(ovf_due=None, row_slots=None):
             def run(s):
-                out, emissions = self._deliver_view_and_clear(s, view_due)
-                _kept(out, s, _LANE_READ_FIELDS)
+                out, emissions = self._deliver_view_and_clear(s, ovf_due, row_slots)
+                _kept(out, s, read)
                 shape[:], leaves = _emission_leaves(emissions)
                 return tuple(getattr(out, f) for f in carried), leaves
 
             return run
 
-        vals, leaves = lax.cond(fits, through(ovf_due), through(None), state)
-        return state._replace(**dict(zip(carried, vals))), _emissions_of(shape, leaves)
+        return through, carried, shape
 
-    def _deliver_view_and_clear(self, state: SimState, ovf_due):
-        vview, due, deliver, ctx = self.delivery_view(state, ovf_due)
+    def _deliver_row_view(self, state: SimState):
+        """A step's delivery under the WHEEL's due view.  The due row is a
+        dense prefix of `whl_fill[row]` entries and, visited once, every
+        one of them is due (`_step_core_fused` has the argument), so the
+        step views the smallest of `due_view_rows` leading slots that the
+        prefix fits, all `wheel_slots` where it fits none: the same rows
+        in the same order either way.  The wheel's planes are read in the
+        branch and never written; the row is emptied whole after it."""
+        tiers = self.due_view_rows
+        w, b = self.wheel_rows, self.wheel_slots
+        row = jnp.remainder(state.time, jnp.int32(w))
+        n_due = state.whl_fill[row]
+        tier = functools.reduce(jnp.add, [(n_due > k).astype(jnp.int32) for k in tiers])
+        state = census_add(
+            state, view_overflow_steps=tier == len(tiers), due_rows_peak=n_due
+        )
+        through, carried, shape = self._view_branches(_WHEEL_FIELDS + _LANE_READ_FIELDS)
+        vals, leaves = lax.switch(tier, [through(row_slots=k) for k in (*tiers, b)], state)
+        state = state._replace(**dict(zip(carried, vals)))
+        with self._scope("repack", STORE_SCOPES):
+            empty = lambda a, fill: lax.dynamic_update_slice(
+                a, jnp.full((1,) + a.shape[1:], fill, a.dtype), (row,) + (0,) * (a.ndim - 1)
+            )
+            state = state._replace(
+                msg_valid=empty(state.msg_valid, False),
+                msg_arrival=empty(state.msg_arrival, INT_MAX),
+                msg_from=empty(state.msg_from, 0),
+                msg_to=empty(state.msg_to, 0),
+                msg_type=empty(state.msg_type, 0),
+                msg_payload=(
+                    empty(state.msg_payload, 0) if self.payload_width else state.msg_payload
+                ),
+                whl_fill=state.whl_fill.at[row].set(0),
+            )
+        return state, _emissions_of(shape, leaves)
+
+    def _deliver_view_and_clear(self, state: SimState, ovf_due, row_slots=None):
+        vview, due, deliver, ctx = self.delivery_view(state, ovf_due, row_slots)
         rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp = ctx
         view_to = vview.msg_to
         view_type = vview.msg_type
 
         # receiver counters skip size-0 (task-style) types, mirroring the
         # Task exemption at Network.java:522-526
-        sizes = jnp.asarray(self._msg_sizes, jnp.int32)[view_type]
+        if row_slots is not None and len(set(self._msg_sizes.tolist())) == 1:
+            sizes = jnp.full(view_type.shape, int(self._msg_sizes[0]), jnp.int32)  # no read a row
+        else:
+            sizes = jnp.asarray(self._msg_sizes, jnp.int32)[view_type]
         dm = (deliver & (sizes > 0)).astype(jnp.int32)
         state = state._replace(
             msg_received=state.msg_received.at[view_to].add(dm, mode="drop"),
@@ -1275,6 +1647,15 @@ class BatchedNetwork:
         with self._scope("protocol_deliver"):
             pstate, emissions = self.protocol.deliver(self, vstate, deliver)
 
+        if row_slots is not None:
+            # the wheel's due view: the lane's due entries leave here, the
+            # wheel's row is emptied by the caller, outside its branch
+            with self._scope("repack", STORE_SCOPES):
+                q, b = ctx[7], ctx[8]
+                return pstate._replace(
+                    **{f: getattr(state, f) for f in _WHEEL_FIELDS},
+                    ovf_valid=state.ovf_valid & ~due[q * b :],
+                ), emissions
         state = self._clear_visited_rows(pstate, state, ctx, due, ovf_due)
         return state, emissions
 

@@ -259,6 +259,10 @@ class Server:
                  "rows the every-tick channel sends carried with their mask set"),
                 ("firing_overflows",
                  "every-tick channel sends whose fired rows passed firing_capacity"),
+                ("fanout_senders",
+                 "senders a fan-out expanded into their rows (apply_fanout)"),
+                ("fanout_overflows",
+                 "fan-outs whose firing senders passed their capacity (another round)"),
             ):
                 p.add(f"run_cache_census_{name}_total",
                       info[f"census_{name}_total"], what, "counter")
@@ -267,6 +271,7 @@ class Server:
                 ("wheel_fill_peak", "wheel_slots"),
                 ("lane_live_peak", "overflow_capacity"),
                 ("firing_peak", "firing_capacity(rows)"),
+                ("fanout_peak", "the protocol's largest FanOut.capacity"),
                 ("landing_peak", "landing_capacity(M)"),
             ):
                 p.add(f"run_cache_census_{name}", info[f"census_{name}"],
