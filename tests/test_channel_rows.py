@@ -31,7 +31,7 @@ from jax.sharding import Mesh
 from wittgenstein_tpu.protocols.gsf import GSFSignatureParameters
 from wittgenstein_tpu.protocols.gsf_batched import make_gsf
 from wittgenstein_tpu.protocols.handel import HandelParameters
-from wittgenstein_tpu.protocols.handel_batched import make_handel
+from wittgenstein_tpu.protocols.handel_batched import BatchedHandel, make_handel
 
 N = 256
 
@@ -696,8 +696,12 @@ def test_the_every_tick_sends_scatter_a_round_of_firing_rows(name, build, rows):
 # 82 and 94 until PR 42, whose rounds of firing rows read a row's sender,
 # receiver and level in `arrive` and its receiver and level in `claim`
 # (10 by this count), where a commit round reads four columns for seven
-# (its rows' slot and win bits come with the landing list: 6 fewer)
-HANDEL_TICK_GATHERS = {"handel_fused": 86, "handel_byz51": 98}
+# (its rows' slot and win bits come with the landing list: 6 fewer).
+# 86 and 98 until PR 44, which took out the deliver phase's read of the
+# verified-sender bit (`_getbit`'s take_along_axis into `ind`: 2) and the
+# table read of a level's block size in `_rank` (2 a call: one call in
+# the honest tick, four under the attack)
+HANDEL_TICK_GATHERS = {"handel_fused": 82, "handel_byz51": 88}
 
 
 def _scoped_primitives(jaxpr, scope: str, inside: bool = False, out=None):
@@ -734,6 +738,55 @@ def test_the_candidate_merge_has_no_sort_and_no_gather(name):
     assert _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, "witt.channel.compact").count("sort") == 2
     gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
     assert gathers <= HANDEL_TICK_GATHERS[name], gathers
+
+
+@pytest.mark.parametrize("name", sorted(HANDEL_TICK_GATHERS))
+def test_the_due_candidates_rank_has_no_gather(name):
+    """The rank and the verified-sender demotion of the two due candidates
+    (PR 44): the sender's bit of `ind` comes from the level's block view
+    and a one-hot mask, so nothing under `witt.deliver.rank` is an indexed
+    read.  On a v5e the gather it replaced was 2.33 ms of an 8.87-ms tick
+    at 4096 nodes (PERF.md section 6, PR 44)."""
+    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+
+    net, state = PINS[name][0]()
+    tick = lambda s: net.protocol.tick(net, s)  # noqa: E731
+    prims = _scoped_primitives(jax.make_jaxpr(tick)(state).jaxpr, DELIVER_SCOPES["rank"])
+    assert len(prims) > 100, prims  # the scope is live: the rank is under it
+    assert not {"sort", "gather", "dynamic_slice", "scatter"} & set(prims), sorted(set(prims))
+
+
+def _bit_by_hand(plane, rel):
+    """Bit `rel` of each row's packed plane, in numpy: [N, W], [N, ...]."""
+    rows = np.arange(plane.shape[0]).reshape((-1,) + (1,) * (rel.ndim - 1))
+    return ((plane[rows, rel >> 5] >> (rel & 31).astype(np.uint32)) & 1).astype(bool)
+
+
+@pytest.mark.parametrize("n", [64, 256, 4096])
+def test_level_bit_reads_the_verified_senders_bit(n):
+    """`_level_bit` as `_channel_deliver` calls it on `ind`, [N, L-1, 2]:
+    against the plane's bit at the sender's full rel, every level of every
+    bucket (the sub-word ones share word 0).  A slot that is not due
+    carries the junk rel of an empty key (every rel bit set): it reads
+    bit bs - 1 of its own block, inside the plane, and `accept` masks it."""
+    rng = np.random.default_rng(n)
+    proto = BatchedHandel(handel_params(node_count=n, threshold=int(n * 0.99)))
+    L, rows = proto.n_levels, 16
+    ind = np.zeros((n, proto.n_words), np.uint32)
+    ind[:rows] = rng.integers(0, 2**32, (rows, proto.n_words), dtype=np.uint32)
+    ind[0], ind[1] = 0, 0xFFFFFFFF
+    bs = np.asarray(proto.lv_bs)[None, :, None]
+    rel2 = bs + rng.integers(0, 2**30, (n, L - 1, 2)) % bs
+    due2 = rng.random((n, L - 1, 2)) < 0.5
+    junk = (1 << proto.rel_bits) - 1  # INT32_MAX & rel_mask, and -1 & rel_mask
+    rel2 = np.where(due2, rel2, junk).astype(np.int32)
+    got = np.asarray(proto._level_bit(jnp.asarray(ind), jnp.asarray(rel2)))
+    assert got.shape == (n, L - 1, 2)
+    want = _bit_by_hand(ind, rel2)
+    assert (got[due2] == want[due2]).all()
+    in_block = np.broadcast_to(2 * bs - 1, rel2.shape)
+    assert (got[~due2] == _bit_by_hand(ind, in_block)[~due2]).all()
+    assert not got[0].any() and got[1].all()
 
 
 def test_gsf_keeps_its_own_merge_until_it_claims_in_its_own_cell():

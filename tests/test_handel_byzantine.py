@@ -234,22 +234,23 @@ def test_next_unlisted_is_the_cyclic_scan_of_the_reference(n, density):
 
 @pytest.mark.parametrize("n", [64, 256, 4096])
 def test_listed_reads_the_bit_that_getbit_gathers(n):
-    """The gather-free read of the blacklist (a block view and a one-hot
-    mask) against `_getbit` through the peer's full rel, for every level
-    of every bucket."""
+    """The gather-free read of the blacklist (`_block_bit`: a block view
+    and a one-hot mask) against the word a gather would fetch through the
+    peer's full rel (what `_getbit` did until PR 44), for every level of
+    every bucket."""
     rng = np.random.default_rng(n)
     proto = BatchedHandel(byz_params(node_count=n, nodes_down=n // 4, threshold=n // 2))
     rows, k = 16, 10
     bl = np.zeros((n, proto.n_words), np.uint32)
     bl[:rows] = rng.integers(0, 2**32, (rows, proto.n_words), dtype=np.uint32)
     bl[0], bl[1] = 0, 0xFFFFFFFF
-    bl = jnp.asarray(bl)
     for b in proto.buckets:
         bs = np.asarray([proto.bs[l] for l in b.levels])
         rel = np.zeros((n, b.nl, k), np.int32)
         rel[:rows] = bs[None, :, None] + rng.integers(0, 2**30, (rows, b.nl, k)) % bs[None, :, None]
-        got = np.asarray(proto._listed(bl, b, jnp.asarray(rel)))
-        want = np.asarray(proto._getbit(bl, jnp.asarray(rel))) == 1
+        got = np.asarray(proto._block_bit(jnp.asarray(bl), b, jnp.asarray(rel)))
+        word = bl[np.arange(n)[:, None, None], rel >> 5]
+        want = (word >> (rel & 31).astype(np.uint32)) & 1 == 1
         assert (got[:rows] == want[:rows]).all(), b
         assert got[0].sum() == 0 and got[1].all()
 

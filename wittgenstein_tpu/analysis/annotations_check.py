@@ -33,7 +33,8 @@ carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
 carries the view's and the repack's: its step still visits the store).
 A Handel built with an attack (`track_bad`) must carry the sub-scopes of
 engine.core.ATTACK_SCOPES that its attack runs, and every Handel the
-candidate merge's engine.core.DELIVER_SCOPES.  A protocol that keeps a
+deliver phase's engine.core.DELIVER_SCOPES (the due candidates' rank,
+the candidate merge).  A protocol that keeps a
 scope table of its own names the scopes its step must carry in a
 `REQUIRED_SCOPES` attribute (Casper's `CHAIN_SCOPES`: the fork choice,
 the block build, the committee's vote).
@@ -160,7 +161,7 @@ def _check_presence(jax, name, net, state, path, line, suppress):
     from ..protocols.handel_batched import BatchedHandel
 
     if isinstance(net.protocol, BatchedHandel):
-        # the deliver phase's candidate merge (ops/select.py)
+        # the deliver phase's rank and candidate merge (ops/select.py)
         from ..engine.core import DELIVER_SCOPES
 
         required.extend(DELIVER_SCOPES.values())

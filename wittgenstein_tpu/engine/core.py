@@ -147,11 +147,13 @@ FANOUT_SCOPES = {
     "expand": "witt.store.fanout",  # a broadcast's rows, for the senders that fire
 }
 
-# the deliver phase's candidate merge of an aggregation protocol
-# (ops/select.py `top_k_merge`, called by protocols/handel_batched.py
-# `_channel_deliver` on every (node, level) of every tick), nested under
+# the deliver phase of an aggregation protocol (protocols/handel_batched.py
+# `_channel_deliver`, on every (node, level) of every tick): the due
+# candidates' rank (`_rank` and the sender's bit of `ind`, `_level_bit`)
+# and the candidate merge (ops/select.py `top_k_merge`), nested under
 # the phase that delivers and switched by the same `annotate`.
 DELIVER_SCOPES = {
+    "rank": "witt.deliver.rank",  # the 2 due candidates' reception rank and verified-sender demotion
     "merge": "witt.deliver.merge",  # keep the best K of the K resident and the 2 due candidates
 }
 

@@ -341,12 +341,26 @@ class BitsetAggBase(BatchedProtocol):
         Shared with the engine's wheel-occupancy scan (ops.bitops)."""
         return lowest_set_bit(words)
 
-    def _getbit(self, x, pos):
-        """Bit `pos` of full-width [N, W] vectors; pos is [N, ...] int32."""
-        word = jnp.take_along_axis(
-            x, (pos >> 5).reshape(pos.shape[0], -1), axis=1
-        ).reshape(pos.shape)
-        return (word >> (pos & 31).astype(jnp.uint32)) & jnp.uint32(1)
+    def _block_bit(self, plane, b: Bucket, rel):
+        """Bit `rel` of a rel-space plane (Handel's `bl`, `ind`), for the
+        levels of bucket b: a level-l peer has rel in [bs_l, 2 bs_l), so
+        its bit is bit rel & (bs_l - 1) of the level's block.  [N, W],
+        [N, nl, k] -> bool[N, nl, k]; a block view and a one-hot mask, no
+        gather: a gather of one word a candidate from the loop-carried
+        plane cost 27.8 ms of a 111-ms tick at 4096 nodes for `bl`
+        (PERF.md section 6, PR 31) and 2.33 of 8.87 for `ind` (PR 44), and
+        ran faster or slower with the buffer the plane happened to be in."""
+        bs = jnp.asarray([self.bs[l] for l in b.levels], jnp.int32)
+        bit = self._onehot(rel & (bs[None, :, None] - 1), b.w_pad)
+        return jnp.any((self._blocks(plane, b)[:, :, None, :] & bit) != 0, axis=-1)
+
+    def _level_bit(self, plane, rel):
+        """`_block_bit` for every level at once: [N, W], [N, L-1, k] ->
+        bool[N, L-1, k], the buckets' pieces joined on the level axis."""
+        return jnp.concatenate(
+            [self._block_bit(plane, b, rel[:, b.lo - 1 : b.hi, :]) for b in self.buckets],
+            axis=1,
+        )
 
     # -- channel layout ------------------------------------------------------
     # in_key: [N, (L-1)*(D+1)] packed (arrival<<rel_bits | rel);

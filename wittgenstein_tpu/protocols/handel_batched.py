@@ -255,7 +255,9 @@ class BatchedHandel(BitsetAggBase):
         ids/level/rel broadcast together; level may be a static int or a
         stacked [.., L-1, ..] axis."""
         level = jnp.asarray(level, jnp.int32)
-        bs = jnp.asarray(self.lv_bs)[level - 1]
+        # bs_l = 2^(l-1) by arithmetic: a table read at a stacked level
+        # axis would be a gather
+        bs = jnp.int32(1) << (level - 1)
         r0 = rel & (bs - 1)
         # sender's absolute id: level-l peers of receiver i are i ^ j for
         # bit index j in [bs, 2*bs)
@@ -609,20 +611,18 @@ class BatchedHandel(BitsetAggBase):
         accept = due2 & started[:, None, None] & not_done[:, None, None]
         if self.track_bad:
             with net._scope("blacklist", ATTACK_SCOPES):
-                accept = accept & ~jnp.concatenate(
-                    [
-                        self._listed(proto["bl"], b, rel2[:, b.lo - 1 : b.hi, :])
-                        for b in self.buckets
-                    ],
-                    axis=1,
-                )
+                accept = accept & ~self._level_bit(proto["bl"], rel2)
 
-        # rank + verified-sender demotion (receptionRanks += nodeCount)
-        ind_bit = self._getbit(proto["ind"], rel2)
-        rank2 = self._rank(
-            state.seed, ids[:, None, None], lv_all[None, :, None], rel2
-        ) + self.n_nodes * ind_bit.astype(jnp.int32)
-        rank2 = jnp.where(accept, rank2, INT32_MAX)
+        # rank + verified-sender demotion (receptionRanks += nodeCount):
+        # the sender's bit of `ind` through the level's block, as `bl`'s
+        # above (a slot that is not due carries a junk rel, reads some bit
+        # of its block and is masked by `accept`)
+        with net._scope("rank", DELIVER_SCOPES):
+            ind_bit = self._level_bit(proto["ind"], rel2)
+            rank2 = self._rank(
+                state.seed, ids[:, None, None], lv_all[None, :, None], rel2
+            ) + self.n_nodes * ind_bit.astype(jnp.int32)
+            rank2 = jnp.where(accept, rank2, INT32_MAX)
 
         inc, ind = proto["inc"], proto["ind"]
         bl = proto["bl"] if self.track_bad else None
@@ -689,7 +689,7 @@ class BatchedHandel(BitsetAggBase):
             keep = valid & (all_s > cur[:, :, None])
             if self.track_bad:
                 with net._scope("blacklist", ATTACK_SCOPES):
-                    keep = keep & ~self._listed(bl, b, all_rel)
+                    keep = keep & ~self._block_bit(bl, b, all_rel)
 
             # sort key: higher sizeIfIncluded first, then lower rank;
             # bounded (s <= bs <= N/2, rank < 3N) so s*4N + rank fits int32
@@ -800,18 +800,6 @@ class BatchedHandel(BitsetAggBase):
         )
         return state
 
-    def _listed(self, bl, b, rel):
-        """Has the node blacklisted the level-l peer `rel`, for the levels
-        of bucket b: bit rel & (bs - 1) of the level's block of the
-        rel-space blacklist.  [N, W], [N, nl, k] -> bool[N, nl, k]; a block
-        view and a one-hot mask, no gather: `_getbit`'s gather of one word
-        a candidate from the loop-carried plane cost 27.8 ms of a 111-ms
-        tick at 4096 nodes and ran 13% faster or slower with the buffer
-        the plane happened to be in (PERF.md section 6, PR 31)."""
-        bs = jnp.asarray([self.bs[l] for l in b.levels], jnp.int32)
-        bit = self._onehot(rel & (bs[None, :, None] - 1), b.w_pad)
-        return jnp.any((self._blocks(bl, b)[:, :, None, :] & bit) != 0, axis=-1)
-
     def _next_unlisted(self, bl, peer):
         """For every (node, level): the first block-local peer index at or
         cyclically after `peer` whose bit of the rel-space blacklist is
@@ -892,7 +880,7 @@ class BatchedHandel(BitsetAggBase):
             curated = valid & (s > popcount_words(inc_b)[:, :, None])
             if self.track_bad:
                 with net._scope("blacklist", ATTACK_SCOPES):
-                    curated = curated & ~self._listed(bl, b, c_rel)
+                    curated = curated & ~self._block_bit(bl, b, c_rel)
             # permanent removal, like replaceToVerifyAgg (:612-618) —
             # recorded as a condemn mask, applied by ENTRY IDENTITY below
             condemn_pieces.append(valid & ~curated)
