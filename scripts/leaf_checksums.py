@@ -7,6 +7,8 @@ different on the chip" (ROADMAP B0, PERF.md section 6).
         --rows 2 --seed 7001 --step-ms 200 --until-ms 2400 --out chiprun_out/leaves-tpu.jsonl
     python3 scripts/leaf_checksums.py --config benchmark/configs/dfinity-4096.json \
         --rows 1 --step-ms 6000 --until-ms 6000 --out chiprun_out/leaves-tpu.jsonl
+    python3 scripts/leaf_checksums.py --config benchmark/configs/dfinity-4096-part20.json --twin \
+        --rows 1 --step-ms 6000 --until-ms 18000 --out chiprun_out/leaves-tpu.jsonl   # the line set: `partition_x` and `census` are leaves
     python3 scripts/leaf_checksums.py --compare leaves-cpu.jsonl chiprun_out/leaves-tpu.jsonl
 
 Builds the program by the configuration's own factory, parameters and

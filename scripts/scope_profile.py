@@ -4,6 +4,7 @@
     python3 scripts/scope_profile.py --config benchmark/configs/handel-4096.json --replicas 8 --chunks 2
     python3 scripts/scope_profile.py --config benchmark/configs/casper-1024.json --replicas 1 --chunks 1 --chunk-ms 8000
     python3 scripts/scope_profile.py --config benchmark/configs/dfinity-4096.json --replicas 1 --chunks 1 --chunk-ms 6000
+    python3 scripts/scope_profile.py --config benchmark/configs/dfinity-4096-part20.json --replicas 1 --chunks 1 --chunk-ms 6000   # the same executable, the line set: the witt.reach.* rows
 
 Builds the program by the configuration's own factory and parameters,
 compiles and warms it through `sharded_run_stats` (one chunk of

@@ -101,3 +101,27 @@ def _clear_jax_caches_per_module():
     keeps recompiles cheap."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """The persistent compilation cache off for a module that asks for it.
+    tests/test_tpu_compile.py: a compile for a described chip is written to
+    the cache but cannot be read back without one (the next run warns and
+    compiles again).  Every test that runs a batched Dfinity program
+    (tests/test_dfinity_batched.py, tests/test_dfinity_partition.py, the
+    Dfinity cases of tests/test_benchmark_conservation.py and
+    test_benchmark_completion.py): XLA:CPU's executable serialisation
+    crashes on them now and then, once where the cache WRITES an entry
+    (`executable.serialize()`, a cold cache) and three times where it
+    reads one (`deserialize_executable`), each time in a worker that ran
+    tests/test_dfinity_batched.py, whose cases each pass alone (sandbox,
+    PR 45: the first session took the reads for two workers racing on
+    one entry; the cold run's write has no second party)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()

@@ -76,7 +76,7 @@ def test_the_reading_of_casper_1024_resolves_on_both_sides():
             twin.check_stated(broken, rows)
 
 
-def test_the_reading_of_dfinity_4096_resolves_on_both_sides():
+def test_the_reading_of_dfinity_4096_resolves_on_both_sides(no_compile_cache):  # Dfinity's programs stay off the cache: tests/test_dfinity_batched.py
     """`twin.completion.reading` of `dfinity-4096`, on a rehearsal build:
     `proto.chain_score` (the head's height and the most votes the node
     has counted for one block, one integer) is a per-node leaf of the
@@ -104,6 +104,59 @@ def test_the_reading_of_dfinity_4096_resolves_on_both_sides():
     assert roles == {"_ObserverNode", "AttesterNode", "BlockProducerNode", "RandomBeaconNode"}
     assert reference.params.node_count == 256 and len(reference.network().all_nodes) == 283
     for side, wrong in (("program", "proto.blk_parent"), ("reference", "chain_skore")):
+        broken = copy.deepcopy(config)
+        broken["twin"]["completion"]["reading"][side] = wrong
+        with pytest.raises(cells.BenchmarkFileError, match=wrong.split(".")[-1]):
+            twin.check_stated(broken, rows)
+
+
+def test_the_reading_of_dfinity_4096_part20_resolves_on_both_sides(no_compile_cache):  # Dfinity's programs stay off the cache: tests/test_dfinity_batched.py
+    """`twin.completion.reading` of `dfinity-4096-part20`: the beacon
+    height a node has heard, `proto.last_beacon` in the program and
+    `last_random_beacon` on every node of the PARTITIONED reference's copy
+    (`witt_ref.protocols.dfinity_part`, whose `init()` draws the line), and
+    not `chain_score`: behind the line a head stays at genesis and all but
+    a few nodes hold no vote, so chain_score's P10 is 0 on both sides and
+    the twin's relative gap 0/0.  The beacon height is 1 behind the line
+    (the first result, from the beacon nodes on that side) and the
+    chain's on the larger side, so no quantile of the reference is 0.  The
+    twin's controls put a wrong line, or none, in the program's place."""
+    import copy
+
+    import numpy as np
+    import pytest
+
+    import cells
+    import twin
+    from wittgenstein_tpu.engine import replicate_state
+
+    config = cells.load_cell("dfinity-4096-part20.single-r1-c6000-h18000").config
+    assert twin.reading(config) == {"program": "proto.last_beacon", "reference": "last_random_beacon"}
+    params = cells.build_params(config, config["params_class"], config["rehearsal"]["params"])
+    assert params.partition == 0.2 and type(params).__name__ == "PartitionedDfinityParameters"
+    net, state = cells.resolve(config["factory"])(params, **config["factory_kwargs"])
+    rows = replicate_state(state, 2, seeds=[1, 2])
+    twin.check_stated(config, rows)
+    assert twin.program_reading(config, rows).shape == (2, 1 + 64 + 10 + 16)
+    reference = cells.build_reference(config, config["twin"]["params"])
+    assert type(reference).__module__ == "witt_ref.protocols.dfinity_part"
+    reference.init()
+    assert reference.network().partitions_in_x == [400] and reference.network().dropped > 0
+    assert (reference.params.node_count, reference.params.attesters_per_round) == (256, 32)
+    assert len(reference.network().all_nodes) == 1 + 256 + 10 + 32
+    controls = config["twin"]["controls"]
+    # no line; the line elsewhere; one that leaves no side a majority; another population
+    assert all(c in controls for c in ({"partition": 0}, {"partition": 0.4}, {"partition": 0.5}, {"population_seed": 1}))
+    assert config["params"]["population_seed"] == 0  # who is behind the line: stated, the same on both sides
+    unpartitioned = cells.build_reference(config, {**config["twin"]["params"], "partition": 0})
+    unpartitioned.init()
+    assert unpartitioned.network().partitions_in_x == [] and unpartitioned.network().dropped == 0
+    # the reading through 9000 ms of the rehearsal's rows: 1 behind the line, the chain's elsewhere
+    out = net.run_ms_batched(rows, 9000)
+    heard = np.asarray(twin.program_reading(config, out))[0]
+    behind = np.asarray(state.x) < 400
+    assert set(heard[behind].tolist()) == {1} and set(heard[~behind].tolist()) == {3}
+    for side, wrong in (("program", "proto.blk_parent"), ("reference", "last_random_bacon")):
         broken = copy.deepcopy(config)
         broken["twin"]["completion"]["reading"][side] = wrong
         with pytest.raises(cells.BenchmarkFileError, match=wrong.split(".")[-1]):

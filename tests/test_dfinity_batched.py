@@ -7,7 +7,16 @@ made for the senders that fire.  The plain spelling it replaces (every
 sender that could fire, masked: `FanOut.dense()`, the `Emission`s the
 protocol built itself until then) stays in the engine behind
 `BatchedNetwork(dense_fanout=True)`, and the fan-out is held against it
-here leaf for leaf."""
+here leaf for leaf.
+
+Off JAX's persistent compilation cache (`no_compile_cache`,
+tests/conftest.py) since PR 46: XLA:CPU's executable serialisation
+crashed a worker on this file's programs in two whole runs of two, one
+cold and one warm (a segfault in `executable.serialize()` where the
+cache writes `test_due_view_of_the_wheel_row_changes_no_leaf`'s program,
+an abort in `deserialize_executable` where it reads
+`test_oracle_parity_256_attesters[0]`'s; each case passes alone), and one
+lost worker fails the run.  No other protocol's programs have done it."""
 
 import dataclasses
 
@@ -23,6 +32,8 @@ from wittgenstein_tpu.protocols.dfinity_batched import (
     make_dfinity,
     store_plan,
 )
+
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
 
 RUN_MS = 15000
 IC3 = "IC3NetworkLatency"  # the oracle Network's default, which upstream's Dfinity runs under

@@ -47,7 +47,9 @@ class TestWServer:
         status, ps = get(base_url, "/w/protocols")
         assert status == 200
         assert "PingPong" in ps
-        assert len(ps) == 16  # every reference protocol family
+        # every reference protocol family, and Dfinity under its main()'s
+        # partition as a parameter (protocols/dfinity_part.py, PR 46)
+        assert len(ps) == 17 and "PartitionedDfinity" in ps
 
     def test_all_protocols_api_sweep(self, base_url):
         """WServerTest.testBasicAllProtocols (:65-122): for EVERY registered

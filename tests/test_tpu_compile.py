@@ -44,20 +44,6 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.fixture(scope="module")
-def no_compile_cache():
-    """A compile for a described chip is written to the persistent cache
-    but cannot be read back without one (the next run warns and compiles
-    again): keep the cache off around these compiles."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
-
-
 @pytest.fixture
 def mosaic(monkeypatch, topo, no_compile_cache):
     """Trace the kernels for Mosaic (not the interpreter) although the
@@ -212,25 +198,60 @@ def sanfermin256():
     return net, replicate_state(state, 4)
 
 
-def test_sanfermin_chunk_program_compiles_for_one_chip(topo, no_compile_cache, sanfermin256):
-    """The chunk program of `sharded_run_stats` on the message store, for
-    one described v5e chip: the time wheel's scatters, the insert ranks'
-    sorts and the delivery view's gathers under the replica axis, each
-    under its `witt.store.*` scope; no Mosaic call (ROADMAP B10).  The
-    insert's same-row rank is a scan over the sorted keys (PR 40): no
-    `searchsorted`, and no loop under the insert at all."""
-    from wittgenstein_tpu.engine.core import STORE_SCOPES
+@pytest.fixture(scope="module")
+def sanfermin256_compiled(topo, no_compile_cache, sanfermin256):
+    """The chunk program of `sharded_run_stats` on the message store,
+    compiled for one described v5e chip: the chip's own instructions."""
     from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
 
     net, states = sanfermin256
     shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
-    text = _run_and_reduce(net, 20)._jit_for(shapes).lower(shapes).compile().as_text()
+    return _run_and_reduce(net, 20)._jit_for(shapes).lower(shapes).compile().as_text()
+
+
+def test_sanfermin_chunk_program_compiles_for_one_chip(sanfermin256_compiled):
+    """The time wheel's scatters, the insert ranks' sorts and the delivery
+    view's gathers under the replica axis, each under its `witt.store.*`
+    scope; no Mosaic call (ROADMAP B10).  The insert's same-row rank is a
+    scan over the sorted keys (PR 40): no `searchsorted`, and no loop
+    under the insert at all."""
+    from wittgenstein_tpu.engine.core import STORE_SCOPES
+
+    text = sanfermin256_compiled
     for scope in STORE_SCOPES.values():
         assert scope in text, scope
     assert " sort(" in text and "scatter" in text
     assert "tpu_custom_call" not in text
     assert "searchsorted" not in text
     assert not re.search(r'op_name="[^"]*witt\.store\.insert[^"]*/while', text)
+
+
+def test_sanfermin_sends_share_their_reads_with_the_delivery(sanfermin256, sanfermin256_compiled):
+    """SanFermin replies along the delivery's view (`reply_em`: from and
+    to swapped), so that send reads `x` at the view's two ends for its
+    latency and its `ok`, as `delivery_view`'s `checked` does for the
+    partition: XLA makes each read once for both.  The counts are the
+    program's before the partition's census came (PR 44's tree: 39
+    gathers, 8 of them under the send with a result a view row, at this
+    size; 39 and 7 at 4096 nodes x 64 rows), and PR 45's tree had 41 and
+    10 here."""
+    net, states = sanfermin256
+    text = sanfermin256_compiled
+    view_rows = states.time.shape[0] * (net.wheel_slots + net.overflow_capacity)
+    gathers = len(re.findall(r" gather\(", text))
+    over_view = [
+        line for line in text.splitlines()
+        if re.search(rf"= s32\[{view_rows}\]\S* fusion\(", line)
+        and re.search(r'op_name="[^"]*witt\.send[^"]*/gather', line)
+    ]
+    what_a_rise_means = (
+        "a read the send shared with the delivery now stands alone: 8.6 ns a row a tick on "
+        "the chip, PR 45 was refused for two of them (sanfermin-4096.sweep-r64 -1.27%); see "
+        "`delivery_view`'s `checked` in engine/core.py"
+    )
+    assert gathers <= 39, (gathers, what_a_rise_means)
+    assert len(over_view) <= 8, (len(over_view), what_a_rise_means)
+    assert gathers > 30 and len(over_view) > 4, "the counts read nothing: the compiler's spelling moved"
 
 
 @pytest.fixture(scope="module")
@@ -332,3 +353,52 @@ def test_dfinity_chunk_program_lowers_for_one_chip(topo, no_compile_cache, dfini
     assert f"tensor<{128 * 4096}x" in text  # a round of the votes': 128 (slot, attester) pairs
     assert f"tensor<{10 * 4096 * 4096}x" not in text  # and never the votes' static form
     assert "4171x80" in text  # the per-node planes by block slot
+
+
+def test_the_partitioned_cell_runs_dfinity_4096s_program(topo, no_compile_cache, dfinity4096):
+    """`dfinity-4096-part20` as the benchmark builds it (the partitioned
+    parameters, the line in the initial state) lowers, for one described
+    v5e chip, to the program `dfinity-4096` lowers to, text for text: the
+    partition is data in `SimState.partition_x`, no static of the network.
+    So the two cells time one executable on two initial states, and the
+    reach check's scopes are in it under both (`witt.reach.deliver` in the
+    branch a state with a line, or with a node down, takes)."""
+    import json
+    import os
+
+    import numpy as np
+
+    from wittgenstein_tpu.engine import replicate_state
+    from wittgenstein_tpu.engine.core import INT_MAX, REACH_SCOPES
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+    from wittgenstein_tpu.protocols.dfinity_batched import make_dfinity
+    from wittgenstein_tpu.protocols.dfinity_part import PartitionedDfinityParameters
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "dfinity-4096-part20.json")) as f:
+        config = json.load(f)
+    assert config["factory"].endswith("dfinity_batched.make_dfinity")
+    params = PartitionedDfinityParameters(**config["params"])
+    net, state = make_dfinity(params, **config["factory_kwargs"])
+    sound_net, sound = dfinity4096
+    assert np.asarray(state.partition_x).tolist() == [400] + [int(INT_MAX)] * 3
+    assert (np.asarray(sound.partition_x) == INT_MAX).all()
+    behind = np.asarray(state.x) < 400
+    # the population the parameters state (`population_seed` 0), not the caller's: 3 of the
+    # 10 producers and 9 of the 64 beacon nodes behind the line
+    assert behind.sum() == 880 and not np.array_equal(np.asarray(state.x), np.asarray(sound.x)[0])
+    assert behind[net.protocol.bp_ids].sum() == 3 and behind[net.protocol.bcn_ids].sum() == 9
+    # the t=0 beacon results that cross the line are masked in the initial state already
+    crossing = int((behind[net.protocol.bcn_ids][:, None] != behind[None, :]).sum())
+    assert int(state.census.masked_sends) == crossing == 78019
+    assert int(state.msg_head) == 64 * 4171 - crossing and int(sound.msg_head[0]) == 64 * 4171
+    sharding = SingleDeviceSharding(topo.devices[0])
+    lowered = []
+    for n, rows in ((net, replicate_state(state, 1)), (sound_net, sound)):
+        shapes = _described(rows, sharding)
+        lowered.append(_run_and_reduce(n, 6000)._jit_for(shapes).lower(shapes))
+    # the program's text; the locations beside it vary with what was traced before
+    assert lowered[0].as_text() == lowered[1].as_text()
+    named = lowered[0].as_text(debug_info=True)
+    for scope in REACH_SCOPES.values():
+        assert scope in named, scope

@@ -45,6 +45,8 @@ NEW_METRICS = {
     "firing_overflows_in_window": "census_firing_overflows_total",
     "fanout_senders_in_window": "census_fanout_senders_total",
     "fanout_overflows_in_window": "census_fanout_overflows_total",
+    "masked_sends_in_window": "census_masked_sends_total",
+    "discarded_rows_in_window": "census_discarded_rows_total",
 }
 
 
@@ -333,6 +335,44 @@ def test_the_run_cache_carries_the_firing_counts():
         assert after["census_firing_peak_limit"] == limit
 
 
+# -- what the reach check masks and discards (PR 46) ----------------------------
+
+
+def test_the_run_cache_carries_what_a_partition_masks_and_discards():
+    """PingPong at 32 nodes, two rows, the line drawn at 0.5 on the
+    initial state (the witness's pings are in flight under it): the
+    crossing pings are discarded where they are due, and the run cache has
+    that sum, and the masked one, without a sync; per row the store's law
+    closes with them, and a sound row counts neither."""
+    from wittgenstein_tpu.protocols.pingpong_batched import make_pingpong
+
+    net, state = make_pingpong(32, network_latency_name="IC3NetworkLatency")
+    for name in ("masked_sends", "discarded_rows"):
+        assert name in Census._fields and name in CENSUS_VECTOR
+        assert f"census_{name}_total" in SUMS
+    states = replicate_state(state, 2, seeds=[5, 6])
+    before = rs.run_cache_info()
+    sound, _ = rs.sharded_run_stats(net, states, 400)
+    mid = rs.run_cache_info()
+    assert _delta(before, mid)["census_discarded_rows_total"] == 0
+    assert _delta(before, mid)["census_masked_sends_total"] == 0
+    cut, _ = rs.sharded_run_stats(net, net.partition(states, 0.5), 400)
+    got = _delta(mid, rs.run_cache_info())
+    discarded = np.asarray(cut.census.discarded_rows)
+    assert got["census_discarded_rows_total"] == int(discarded.sum()) > 0
+    assert got["census_masked_sends_total"] == int(np.asarray(cut.census.masked_sends).sum()) == 0
+    for out, lost in ((sound, [0, 0]), (cut, discarded.tolist())):
+        in_store = np.asarray(out.msg_valid).sum((1, 2)) + np.asarray(out.ovf_valid).sum(1)
+        law = (np.asarray(out.msg_sent).sum(1) - np.asarray(out.msg_received).sum(1) - in_store
+               - np.asarray(out.census.masked_sends) - np.asarray(out.census.discarded_rows))
+        assert law.tolist() == [0, 0] and np.asarray(out.census.discarded_rows).tolist() == lost
+    # a line from t=0 masks instead: the pings are counted where they are sent
+    early = net.init_state(
+        {k: np.asarray(getattr(state, k)) for k in ("x", "y", "extra_latency", "city_idx")},
+        seed=0, proto=net.protocol.proto_init(32), partition=0.5)
+    assert int(early.census.masked_sends) == int(discarded[0]) and int(early.census.discarded_rows) == 0
+
+
 # -- the files that read the counters ------------------------------------------
 
 
@@ -355,6 +395,8 @@ def test_the_new_metric_files_name_counters_the_program_has():
     assert files["view_overflow_steps_in_window"]["workloads"] == ["casper-1024.single-r1-s8000"]
     for name in ("fanout_senders_in_window", "fanout_overflows_in_window"):  # the fan-out's one cell
         assert files[name]["workloads"] == ["dfinity-4096.single-r1-c6000-h18000"]
+    for name in ("masked_sends_in_window", "discarded_rows_in_window"):  # the partitioned cell's
+        assert files[name]["workloads"] == ["dfinity-4096-part20.single-r1-c6000-h18000"]
     for name in ("steps_in_window", "census_s_in_window", "gc_pause_s_in_window"):
         assert "workloads" not in files[name]  # every cell
     # the peaks and their limits are beside the sums
@@ -377,5 +419,6 @@ def test_the_server_renders_the_census():
         "witt_run_cache_census_firing_peak", "witt_run_cache_census_firing_peak_limit",
         "witt_run_cache_census_fanout_senders_total", "witt_run_cache_census_fanout_overflows_total",
         "witt_run_cache_census_fanout_peak", "witt_run_cache_census_fanout_peak_limit",
+        "witt_run_cache_census_masked_sends_total", "witt_run_cache_census_discarded_rows_total",
     ):
         assert family in text, family

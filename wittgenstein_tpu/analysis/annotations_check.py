@@ -37,7 +37,9 @@ deliver phase's engine.core.DELIVER_SCOPES (the due candidates' rank,
 the candidate merge).  A protocol that keeps a
 scope table of its own names the scopes its step must carry in a
 `REQUIRED_SCOPES` attribute (Casper's `CHAIN_SCOPES`: the fork choice,
-the block build, the committee's vote).
+the block build, the committee's vote).  Every protocol carries
+engine.core.REACH_SCOPES: the reach check where a row is sent and where
+it is due.
 
 If this jax version exposes no `name_stack` on source_info, the
 presence half is skipped (API drift guard) — neutrality still runs.
@@ -136,8 +138,12 @@ def _check_presence(jax, name, net, state, path, line, suppress):
         required.append("witt.protocol_tick")
     if _hook_traces_ops(jax, lambda s: net.protocol.tick_beat(net, s), state):
         required.append("witt.beat")
-    from ..engine.core import STORE_SCOPES
+    from ..engine.core import REACH_SCOPES, STORE_SCOPES
 
+    # "can this row reach its receiver": every send draws its latency
+    # through `latency_arrivals` or a fan-out's grid, every step builds
+    # the delivery view and its re-check
+    required.extend(REACH_SCOPES.values())
     if hasattr(net.protocol, "_send_stacked"):
         from ..engine.core import CHANNEL_SCOPES
 

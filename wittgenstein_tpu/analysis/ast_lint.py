@@ -45,6 +45,9 @@ ENGINE_HOST_METHODS = {
     "run_ms",
     "run_ms_batched",
     "_window",
+    # the oracle's two partition calls: host-side, between runs
+    "partition",
+    "end_partition",
 }
 
 # SimState fields whose attribute access marks an expression as

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..core.geo import MAX_X
 from ..core.latency import LatencyStatic, NetworkLatency, vec_latency
 from ..faults.state import (
     FaultConfig,
@@ -147,6 +148,17 @@ FANOUT_SCOPES = {
     "expand": "witt.store.fanout",  # a broadcast's rows, for the senders that fire
 }
 
+# "can this row reach its receiver": both ends up, on one side of every
+# partition line (`SimState.partition_x`), the latency under the discard
+# time.  Asked where a row is sent (`latency_arrivals` through
+# `_apply_emission_impl`, and a fan-out round's grid, `_store_grid`) and
+# again where it is due (`delivery_view`'s `checked`), nested under the
+# phase that asks and switched by the same `annotate`.
+REACH_SCOPES = {
+    "send": "witt.reach.send",  # the `ok` mask of a send's rows, and the count of what it masks
+    "deliver": "witt.reach.deliver",  # the delivery's re-check of the due rows
+}
+
 # the deliver phase of an aggregation protocol (protocols/handel_batched.py
 # `_channel_deliver`, on every (node, level) of every tick): the due
 # candidates' rank (`_rank` and the sender's bit of `ind`, `_level_bit`)
@@ -199,6 +211,8 @@ class Census(NamedTuple):
     firing_overflows: jnp.ndarray  # such sends whose fired rows passed `firing_capacity`: a second round
     fanout_senders: jnp.ndarray  # senders a fan-out expanded into their rows (`apply_fanout`)
     fanout_overflows: jnp.ndarray  # fan-outs whose firing senders passed their capacity: another round
+    masked_sends: jnp.ndarray  # rows a send's mask set and its `ok` did not: an end down, across a line, past the discard time (the oracle's `dropped`)
+    discarded_rows: jnp.ndarray  # due rows the delivery did not deliver (`due & ~deliver`): an end went down or a line was drawn under them
     due_rows_peak: jnp.ndarray  # most rows due in a step (the lane's, or the wheel row's), against `due_view_rows`
     wheel_fill_peak: jnp.ndarray  # fullest wheel row after a step's inserts, against `wheel_slots`
     lane_live_peak: jnp.ndarray  # most live lane rows after a step's inserts, against `overflow_capacity`
@@ -209,7 +223,7 @@ class Census(NamedTuple):
 CENSUS_PEAKS = ("due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "fanout_peak")
 _CENSUS_ROW_SUMS = (
     "view_overflow_steps", "landed_rows", "extra_commit_rounds", "fired_rows", "firing_overflows",
-    "fanout_senders", "fanout_overflows",
+    "fanout_senders", "fanout_overflows", "masked_sends", "discarded_rows",
 )
 
 # what a chunk's census vector holds, in order (`chunk_census`): the sums
@@ -217,6 +231,7 @@ _CENSUS_ROW_SUMS = (
 CENSUS_VECTOR = (
     "steps", "store_rows", "view_overflow_steps", "landed_rows", "extra_commit_rounds",
     "fired_rows", "firing_overflows", "fanout_senders", "fanout_overflows",
+    "masked_sends", "discarded_rows",
     "due_rows_peak", "wheel_fill_peak", "lane_live_peak", "firing_peak", "fanout_peak",
     "landing_peak",
 )
@@ -603,7 +618,9 @@ class BatchedNetwork:
                     )
 
     # -- state construction (host-side) -------------------------------------
-    def init_state(self, cols: dict, seed: int, proto: Any, down=None) -> SimState:
+    def init_state(
+        self, cols: dict, seed: int, proto: Any, down=None, partition=None
+    ) -> SimState:
         """Build a fresh single-replica state from node columns
         (core.node.build_node_columns output).
 
@@ -620,7 +637,14 @@ class BatchedNetwork:
         addressed to it (Network.java:606); and (c) never reaches
         done_at > 0, so done counts and CDFs exclude it.  Pinned
         cross-protocol by tests/test_faults.py::test_statically_down_nodes.
-        For crash/recovery *during* a run, see wittgenstein_tpu.faults."""
+        For crash/recovery *during* a run, see wittgenstein_tpu.faults.
+
+        `partition` (a share of the x axis, default none) draws that line
+        (`BatchedNetwork.partition`) before the initial emissions too, so
+        that their crossing rows are masked at the send and counted in
+        `census.masked_sends` like every later one; a line drawn on the
+        state this returns finds them in flight, and the delivery
+        discards them where they are due (`census.discarded_rows`)."""
         n, p = self.n_nodes, self.payload_width
         w, b, v = self.wheel_rows, self.wheel_slots, self.overflow_capacity
         zi = lambda shape: jnp.zeros(shape, dtype=jnp.int32)
@@ -671,6 +695,8 @@ class BatchedNetwork:
             ),
             census=Census(*(jnp.int32(0) for _ in Census._fields)),
         )
+        if partition is not None:
+            state = self.partition(state, partition)
         for em in self._spelled(self.protocol.initial_emissions(self, state)):
             state = self._apply_one(state, em)
         return census_add(state, **self._store_fill(state))
@@ -873,10 +899,47 @@ class BatchedNetwork:
     @staticmethod
     def partition_id(state: SimState, x_col) -> jnp.ndarray:
         """pid = number of partition lines at or left of the node
-        (Network.partitionId, Network.java:639-649)."""
+        (Network.partitionId, Network.java:639-649): a node ON a line is
+        right of it.  Two nodes reach each other where their pids are
+        equal; nothing writes `partition_x` but `partition` and
+        `end_partition` below."""
         return jnp.sum(
             state.partition_x[None, :] <= x_col[:, None], axis=-1
         ).astype(jnp.int32)
+
+    @staticmethod
+    def partition(state: SimState, part) -> SimState:
+        """`state` with a vertical line at `int(MAX_X * part)`: the nodes
+        left of it and those on or right of it cannot reach each other
+        (Network.partition, Network.java:693-703; `oracle/network.py`
+        `partition`).  Host-side, between runs: the line is data in
+        `partition_x` (kept sorted, `INT_MAX` unused), so the program a
+        state runs is the one it ran without it.  A batched state takes one
+        `part` for all rows or one a row.  Rows sent before the line was
+        drawn and due after it are discarded where they are due
+        (`census.discarded_rows`); rows sent under it are masked at their
+        send (`census.masked_sends`)."""
+        lines = np.asarray(state.partition_x)
+        parts = np.broadcast_to(np.asarray(part, np.float64), lines.shape[:-1])
+        if ((parts <= 0) | (parts >= 1)).any():
+            raise ValueError("part needs to be a percentage between 0 & 100 excluded")
+        x_point = (MAX_X * parts).astype(np.int64)[..., None]
+        if (lines == x_point).any():
+            raise ValueError("this partition exists already")
+        if (lines != INT_MAX).all(axis=-1).any():
+            raise ValueError(f"a row has {MAX_PARTITIONS} partition lines already")
+        lines = np.sort(np.concatenate([lines, x_point], axis=-1), axis=-1)
+        return state._replace(
+            partition_x=jnp.asarray(lines[..., :MAX_PARTITIONS], jnp.int32)
+        )
+
+    @staticmethod
+    def end_partition(state: SimState) -> SimState:
+        """`state` with no partition line (Network.endPartition,
+        Network.java:705-707).  The protocol's own part of a heal
+        (BlockChainNetwork.endPartition re-broadcasts every head) is not
+        the engine's."""
+        return state._replace(partition_x=jnp.full_like(state.partition_x, INT_MAX))
 
     # -- the send path (createMessageArrival, Network.java:469-487) ----------
     def latency_arrivals(
@@ -934,15 +997,16 @@ class BatchedNetwork:
         else:
             lat = vec_latency(self.latency, static, from_idx, to_idx, delta)
         arrival = jnp.asarray(send_time, jnp.int32) + lat
-        pid_f = self.partition_id(state, state.x[from_idx])
-        pid_t = self.partition_id(state, state.x[to_idx])
-        ok = (
-            mask
-            & ~state.down[from_idx]
-            & ~state.down[to_idx]
-            & (pid_f == pid_t)
-            & (lat < self.msg_discard_time)
-        )
+        with self._scope("send", REACH_SCOPES):
+            pid_f = self.partition_id(state, state.x[from_idx])
+            pid_t = self.partition_id(state, state.x[to_idx])
+            ok = (
+                mask
+                & ~state.down[from_idx]
+                & ~state.down[to_idx]
+                & (pid_f == pid_t)
+                & (lat < self.msg_discard_time)
+            )
         if self.faults is not None:
             # fault choke point 1 (send): crash/partition/silence/drop
             # suppress rows AFTER the counters ticked above (the oracle
@@ -1006,11 +1070,18 @@ class BatchedNetwork:
         rows stay a dense prefix — a row is only ever cleared whole (or
         repacked) at delivery, so the next free slot is whl_fill[row] plus
         this call's same-row rank.  Only a genuinely full store drops, and
-        it drops the NEW rows, counted in `dropped`."""
+        it drops the NEW rows, counted in `dropped`.  The rows whose mask
+        is set and that cannot reach their receiver are not stored and are
+        counted in `census.masked_sends`, as the oracle counts them in its
+        own `dropped`."""
         with self._scope("send"):
-            return self._apply_emission_impl(state, em)
+            state, masked = self._apply_emission_impl(state, em)
+            return census_add(state, masked_sends=masked)
 
-    def _apply_emission_impl(self, state: SimState, em: Emission, event_ctr=None) -> SimState:
+    def _apply_emission_impl(self, state: SimState, em: Emission, event_ctr=None):
+        """(state with the emission's ok rows stored, how many rows the
+        mask set and `ok` did not): the caller adds the count to the
+        census, outside whatever branch or loop it stores in."""
         k = em.mask.shape[0]
         send_time = em.send_time if em.send_time is not None else state.time + 1
         mask = em.mask
@@ -1024,10 +1095,13 @@ class BatchedNetwork:
             # bypasses createMessageArrival's counter ticks)
             arrival = em.arrival.astype(jnp.int32)
             ok = mask
+            masked = jnp.int32(0)
         else:
             state, ok, arrival = self.latency_arrivals(
                 state, mask, from_idx, to_idx, send_time, mtype, event_ctr
             )
+            with self._scope("send", REACH_SCOPES):
+                masked = jnp.sum((mask & ~ok).astype(jnp.int32))
 
         payload = em.payload
         if self.payload_width and payload is None:
@@ -1057,7 +1131,7 @@ class BatchedNetwork:
                         ),
                     )
                 )
-        return state
+        return state, masked
 
     def _insert_rows(
         self, state: SimState, ok, arrival, from_idx, to_idx, mtype_rows, payload,
@@ -1230,7 +1304,7 @@ class BatchedNetwork:
         # computed on the [capacity, receivers] grid; else as plain rows
         plain = self.faults is None and self.telemetry is None and self.throughput is None
 
-        def one_round(cursor, vals):
+        def one_round(cursor, masked, vals):
             st = state._replace(**dict(zip(fields, vals)))
             if k < s:
                 live = cursor + jnp.arange(k, dtype=jnp.int32) < fired
@@ -1240,7 +1314,7 @@ class BatchedNetwork:
             senders = fo.from_idx.astype(jnp.int32)[at]
             payload = None if fo.payload is None else jnp.repeat(fo.payload[at], r, axis=0)
             if plain:
-                st = self._store_grid(
+                st, n = self._store_grid(
                     st, live, senders, receivers, send_time[at], fo.mtype,
                     base + 1 + at // per, payload,
                 )
@@ -1253,21 +1327,22 @@ class BatchedNetwork:
                     payload=payload,
                     send_time=jnp.repeat(send_time[at], r),
                 )
-                st = self._apply_emission_impl(
+                st, n = self._apply_emission_impl(
                     st, rows, event_ctr=jnp.repeat(base + 1 + at // per, r)
                 )
             _kept(st, state, [f for f in SimState._fields if f not in fields])
-            return cursor + k, tuple(getattr(st, f) for f in fields)
+            return cursor + k, masked + n, tuple(getattr(st, f) for f in fields)
 
-        _, vals = lax.while_loop(
+        _, masked, vals = lax.while_loop(
             lambda c: c[0] < fired,
             lambda c: one_round(*c),
-            (jnp.int32(0), tuple(getattr(state, f) for f in fields)),
+            (jnp.int32(0), jnp.int32(0), tuple(getattr(state, f) for f in fields)),
         )
         state = state._replace(**dict(zip(fields, vals)))
         state = state._replace(send_ctr=base + fo.events)
         return census_add(
-            state, fanout_senders=fired, fanout_overflows=fired > k, fanout_peak=fired
+            state, fanout_senders=fired, fanout_overflows=fired > k, fanout_peak=fired,
+            masked_sends=masked,
         )
 
     def _store_grid(self, state, live, senders, receivers, send_time, mtype, event_ctr, payload):
@@ -1279,7 +1354,9 @@ class BatchedNetwork:
         of its receiver, so they are K + R reads broadcast over the grid
         where the rows' own `x[from_idx]` are K x R gathers, and the
         sender counters grow by R a live sender (an indexed read costs the
-        chip tens of ns a row: ten of them were two thirds of a round)."""
+        chip tens of ns a row: ten of them were two thirds of a round).
+        Returns the state and how many of the live senders' rows `ok`
+        masked (`_apply_emission_impl`'s second result)."""
         k, r = senders.shape[0], receivers.shape[0]
         f, t = senders[:, None], receivers[None, :]
         size = jnp.asarray(self._msg_sizes, jnp.int32)[mtype]
@@ -1293,14 +1370,16 @@ class BatchedNetwork:
         static = LatencyStatic(state.x, state.y, state.extra_latency, state.city_idx)
         lat = vec_latency(self.latency, static, f, t, delta)
         arrival = send_time[:, None] + lat
-        pid_f = self.partition_id(state, state.x[senders])
-        pid_t = self.partition_id(state, state.x[receivers])
-        ok = (
-            (live & ~state.down[senders])[:, None]
-            & ~state.down[receivers][None, :]
-            & (pid_f[:, None] == pid_t[None, :])
-            & (lat < self.msg_discard_time)
-        )
+        with self._scope("send", REACH_SCOPES):
+            pid_f = self.partition_id(state, state.x[senders])
+            pid_t = self.partition_id(state, state.x[receivers])
+            ok = (
+                (live & ~state.down[senders])[:, None]
+                & ~state.down[receivers][None, :]
+                & (pid_f[:, None] == pid_t[None, :])
+                & (lat < self.msg_discard_time)
+            )
+            masked = jnp.sum((live[:, None] & ~ok).astype(jnp.int32))
         rows = lambda a: jnp.broadcast_to(a, (k, r)).reshape(k * r)
         if self.payload_width and payload is None:
             payload = jnp.zeros((k * r, self.payload_width), dtype=jnp.int32)
@@ -1309,7 +1388,7 @@ class BatchedNetwork:
                 state, rows(ok), rows(arrival), rows(f), rows(t),
                 jnp.full(k * r, mtype, jnp.int32), payload, wide=not self.flat,
             )
-        return state
+        return state, masked
 
     def _spelled(self, emissions) -> list:
         """A step's emissions as this store takes them: under
@@ -1348,21 +1427,23 @@ class BatchedNetwork:
         )
 
         def send(vals):
-            s = state._replace(**dict(zip(fields, vals)))
+            s, masked = state._replace(**dict(zip(fields, vals))), jnp.int32(0)
             for em in emissions:
-                s = self.apply_emission(s, em)
+                with self._scope("send"):
+                    s, n = self._apply_emission_impl(s, em)
+                masked = masked + n
             _kept(s, state, [f for f in SimState._fields if f not in fields])
-            return tuple(getattr(s, f) for f in fields)
+            return tuple(getattr(s, f) for f in fields), masked
 
         def skip(vals):
             s = state._replace(**dict(zip(fields, vals)))
             s = s._replace(send_ctr=s.send_ctr + sampled)
-            return tuple(getattr(s, f) for f in fields)
+            return tuple(getattr(s, f) for f in fields), jnp.int32(0)
 
-        vals = lax.cond(
+        vals, masked = lax.cond(
             any_send, send, skip, tuple(getattr(state, f) for f in fields)
         )
-        return state._replace(**dict(zip(fields, vals)))
+        return census_add(state._replace(**dict(zip(fields, vals))), masked_sends=masked)
 
     # -- delivery ------------------------------------------------------------
     def _window(self) -> int:
@@ -1463,21 +1544,37 @@ class BatchedNetwork:
         due = view_valid & (view_arrival <= t)
 
         # delivery-time checks: down destination or cross-partition messages
-        # are discarded on arrival (Network.java:606, :518-520)
+        # are discarded on arrival (Network.java:606, :518-520) and counted
+        # (`census.discarded_rows`).  The reads are of `x` at the view's two
+        # ends, as a send's are: where a protocol replies along the view
+        # (SanFermin: 1280 view rows a replica), that send needs `x[from]`
+        # and `x[to]` for its latency and its own `ok`, and the compiler
+        # makes each read once for both.  Read a per-node side here instead
+        # and the send's two reads stand alone: 8.6 ns a row a tick on the
+        # chip (PR 45 was refused for two of them on `sanfermin-4096`;
+        # tests/test_tpu_compile.py holds the count)
         def checked():
-            pid_f = self.partition_id(state, state.x[view_from])
-            pid_t = self.partition_id(state, state.x[view_to])
-            return due & ~state.down[view_to] & (pid_f == pid_t)
+            with self._scope("deliver", REACH_SCOPES):
+                pid_f = self.partition_id(state, state.x[view_from])
+                pid_t = self.partition_id(state, state.x[view_to])
+                deliver = due & ~state.down[view_to] & (pid_f == pid_t)
+                return deliver, jnp.sum((due & ~deliver).astype(jnp.int32))
 
         if row_slots is None:
-            deliver = checked()
+            deliver, discarded = checked()
         else:
             # a view of 10^5 rows: with no node down and no partition line
             # every due row is delivered, and the three indexed reads a view
-            # row (tens of ns each on the chip) are not made
-            deliver = lax.cond(
+            # row are not made.  Where they are (`dfinity-4096-part20`: a
+            # line set, so every executed step) they cost 2.3 ms each over
+            # a whole-row view of 270,336 rows, 8.6 ns a row (the per-node
+            # operand sits in fast memory), 0.025-0.032 ms a simulated ms
+            # of a 0.361-ms one (chip runs of PR 45, which read a per-node
+            # side in place of `x`: an operand of the same 4171 words;
+            # PERF.md section 5)
+            deliver, discarded = lax.cond(
                 jnp.any(state.down) | jnp.any(state.partition_x != INT_MAX),
-                checked, lambda: due,
+                checked, lambda: (due, jnp.int32(0)),
             )
         if self.faults is not None:
             # fault choke point 2 (arrival): suppress delivery to
@@ -1492,6 +1589,7 @@ class BatchedNetwork:
                     self.faults, state.faults, t, view_from, view_to
                 )
                 deliver = deliver & ~fault_supp
+                discarded = jnp.sum((due & ~deliver).astype(jnp.int32))
         else:
             fault_supp = None
 
@@ -1503,7 +1601,7 @@ class BatchedNetwork:
             msg_type=view_type,
             msg_payload=view_payload,
         )
-        ctx = (rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp)
+        ctx = (rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp, discarded)
         return vstate, due, deliver, ctx
 
     def _deliver_and_clear(self, state: SimState):
@@ -1589,7 +1687,8 @@ class BatchedNetwork:
 
     def _deliver_view_and_clear(self, state: SimState, ovf_due, row_slots=None):
         vview, due, deliver, ctx = self.delivery_view(state, ovf_due, row_slots)
-        rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp = ctx
+        rows, wv, wa, wf, wt, wk, wp, q, b, fault_supp, discarded = ctx
+        state = census_add(state, discarded_rows=discarded)
         view_to = vview.msg_to
         view_type = vview.msg_type
 
@@ -1671,7 +1770,7 @@ class BatchedNetwork:
             return self._clear_visited_rows_impl(pstate, state, ctx, due, ovf_due)
 
     def _clear_visited_rows_impl(self, pstate, state, ctx, due, ovf_due=None) -> SimState:
-        rows, wv, wa, wf, wt, wk, wp, q, b, _ = ctx
+        rows, wv, wa, wf, wt, wk, wp, q, b = ctx[:9]
         keep = wv & ~due[: q * b].reshape(q, b)
         pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
         tgt = jnp.where(keep, pos, b)  # OOB -> dropped scatter
@@ -1731,6 +1830,7 @@ class BatchedNetwork:
             vview, due, deliver, ctx = self.delivery_view(state)
             q, b = ctx[7], ctx[8]
             fault_supp = ctx[9]
+            state = census_add(state, discarded_rows=ctx[10])
             view_to = vview.msg_to
             view_type = vview.msg_type
             sizes = jnp.asarray(self._msg_sizes, jnp.int32)[view_type]

@@ -235,7 +235,7 @@ class _CachedRun:
             + hashlib.blake2b(
                 # the program's outputs are part of its identity: one
                 # stored before the census vector is never adopted
-                repr((stable(), self.sim_ms, geometry, "census-3")).encode(),
+                repr((stable(), self.sim_ms, geometry, "census-4")).encode(),
                 digest_size=12,
             ).hexdigest()
             if callable(stable)

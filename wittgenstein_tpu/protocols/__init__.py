@@ -10,6 +10,7 @@ wittgenstein_tpu.core.params.protocol_registry (the API-discovery contract).
 from . import (  # noqa: F401
     casper,
     dfinity,
+    dfinity_part,
     enr_gossiping,
     ethpow,
     gsf,
@@ -29,6 +30,7 @@ from . import (  # noqa: F401
 __all__ = [
     "casper",
     "dfinity",
+    "dfinity_part",
     "enr_gossiping",
     "ethpow",
     "gsf",

@@ -69,6 +69,7 @@ from .dfinity import (
     DfinityParameters,
     RandomBeaconNode,
 )
+from .dfinity_part import PartitionedDfinity, PartitionedDfinityParameters
 
 
 # sub-scopes of Dfinity's deliver, by role (what the ops are FOR), nested
@@ -528,9 +529,24 @@ def make_dfinity(
     view).  `dense_fanout` stores every broadcast in its plain spelling.
     `population_seed` seeds the oracle's generator before it builds the
     nodes, as a caller of the oracle does (`network().rd.set_seed(s)`,
-    then `init()`): the same positions and producer order on both sides."""
+    then `init()`): the same positions and producer order on both sides.
+    Parameters that state a `population_seed` of their own
+    (`PartitionedDfinityParameters`) have their oracle's `init()` seed
+    the generator itself, after this one and in its place.
+
+    `PartitionedDfinityParameters` (protocols/dfinity_part.py: upstream's
+    `main()`, a fifth of the network cut off) builds the population from
+    that oracle and returns the initial state with the line set
+    (`BatchedNetwork.partition`, at `int(MAX_X * params.partition)`; none
+    for a `partition` of 0), drawn as the oracle's `init()` draws it:
+    before the beacon's first results leave, so that they are masked
+    where they are sent like every later message.  The line is data in
+    `state.partition_x`: the network and its compiled program are the
+    ones `DfinityParameters` of the same shape gives, and who is behind
+    the line is the population's."""
     params = params or DfinityParameters()
-    oracle = Dfinity(params)
+    partitioned = isinstance(params, PartitionedDfinityParameters)
+    oracle = PartitionedDfinity(params) if partitioned else Dfinity(params)
     if population_seed is not None:
         oracle.network().rd.set_seed(population_seed)
     oracle.init()
@@ -576,5 +592,6 @@ def make_dfinity(
     net = BatchedNetwork(
         proto, latency, n, capacity=capacity, dense_fanout=dense_fanout, **store
     )
-    state = net.init_state(cols, seed=seed, proto=proto.proto_init(n))
+    line = (params.partition or None) if partitioned else None  # 0, or no such parameter: no line
+    state = net.init_state(cols, seed=seed, proto=proto.proto_init(n), partition=line)
     return net, state

@@ -263,6 +263,11 @@ class Server:
                  "senders a fan-out expanded into their rows (apply_fanout)"),
                 ("fanout_overflows",
                  "fan-outs whose firing senders passed their capacity (another round)"),
+                ("masked_sends",
+                 "rows a send's mask set and its reach check did not store "
+                 "(an end down, across a partition line, past the discard time)"),
+                ("discarded_rows",
+                 "due rows the delivery's reach check did not deliver"),
             ):
                 p.add(f"run_cache_census_{name}_total",
                       info[f"census_{name}_total"], what, "counter")
