@@ -214,44 +214,84 @@ def test_sanfermin_chunk_program_compiles_for_one_chip(sanfermin256_compiled):
     view's gathers under the replica axis, each under its `witt.store.*`
     scope; no Mosaic call (ROADMAP B10).  The insert's same-row rank is a
     scan over the sorted keys (PR 40): no `searchsorted`, and no loop
-    under the insert at all."""
-    from wittgenstein_tpu.engine.core import STORE_SCOPES
+    under the insert at all.  Both emissions state a capacity (PR 47):
+    the firing rows' numbering and a round's reads are under
+    `witt.store.compact`, and the loop of the rounds past the first is
+    around the insert, not under it."""
+    from wittgenstein_tpu.engine.core import EMISSION_SCOPES, STORE_SCOPES
 
     text = sanfermin256_compiled
-    for scope in STORE_SCOPES.values():
+    for scope in (*STORE_SCOPES.values(), *EMISSION_SCOPES.values()):
         assert scope in text, scope
+    assert re.search(r'op_name="[^"]*witt\.send/while/body/witt\.store\.insert', text)
     assert " sort(" in text and "scatter" in text
     assert "tpu_custom_call" not in text
     assert "searchsorted" not in text
     assert not re.search(r'op_name="[^"]*witt\.store\.insert[^"]*/while', text)
 
 
-def test_sanfermin_sends_share_their_reads_with_the_delivery(sanfermin256, sanfermin256_compiled):
-    """SanFermin replies along the delivery's view (`reply_em`: from and
-    to swapped), so that send reads `x` at the view's two ends for its
-    latency and its `ok`, as `delivery_view`'s `checked` does for the
-    partition: XLA makes each read once for both.  The counts are the
-    program's before the partition's census came (PR 44's tree: 39
-    gathers, 8 of them under the send with a result a view row, at this
-    size; 39 and 7 at 4096 nodes x 64 rows), and PR 45's tree had 41 and
-    10 here."""
+def _gather_fusions(text, payload_width):
+    """(rows of the result, op_name) of every gather fusion of a compiled
+    program's text; a read of payload rows has that many rows, not
+    `payload_width` times as many."""
+    out = []
+    for line in text.splitlines():
+        shape = re.search(r"= \w+\[([\d,]*)\]\S* fusion\(", line)
+        name = re.search(r'op_name="([^"]*/gather[^"]*)"', line)
+        if shape and name:
+            dims = [int(d) for d in shape.group(1).split(",") if d]
+            if len(dims) > 1 and dims[-1] == payload_width:
+                dims.pop()
+            out.append((int(np.prod(dims)), name.group(1)))
+    return out
+
+
+def test_sanfermin_sends_read_a_rounds_rows_and_the_delivery_its_own(sanfermin256, sanfermin256_compiled):
+    """Both of SanFermin's emissions state a capacity (PR 47), so a send
+    reads its columns, the latency model's, `down` and `x` at a round's
+    256 rows a replica: no gather under `witt.send` has a result of the
+    emission's K x R rows (the tick's requests) or of the view's x R (the
+    deliver's replies) any more.  Until PR 47 the reply send read `x` at
+    the view's two ends, XLA made each read once for it and for
+    `delivery_view`'s `checked`, and this test held that sharing (PR 45
+    was refused for respelling one of its two users: two more gathers of
+    81,920 rows a tick).  Now the delivery's reads are its own, and what
+    is held is that they do not grow and that nothing under the send
+    reads an emission's rows again.  Counts at this size, parent (PR 46's
+    tree) -> PR 47: `gather` instructions 39 -> 79 (a round's appear twice
+    in the text, the first round and the loop's body, so the issue's "at or
+    under today's" cannot hold for the instruction count and is held for
+    what costs: the result rows of all gather fusions outside the rounds'
+    loop, 85,252 -> 66,052 a tick of the 4 rows); gather fusions
+    under `witt.send` with a K x R or view x R result 11 + 10 -> 0, with
+    a round's 1024 rows 1 -> 64; under `witt.reach.deliver` 4 -> 3; view
+    x R results in the whole program 23 -> 12 (at 4096 nodes x 64 rows:
+    `gather` 39 -> 80, under the send 14 of 524,288 rows and 10 of 81,920
+    -> 0, a round's 16,384 rows 0 -> 60, the delivery's 4 -> 3, 81,920-row
+    results in the whole program 22 -> 11)."""
     net, states = sanfermin256
     text = sanfermin256_compiled
-    view_rows = states.time.shape[0] * (net.wheel_slots + net.overflow_capacity)
-    gathers = len(re.findall(r" gather\(", text))
-    over_view = [
-        line for line in text.splitlines()
-        if re.search(rf"= s32\[{view_rows}\]\S* fusion\(", line)
-        and re.search(r'op_name="[^"]*witt\.send[^"]*/gather', line)
-    ]
+    rows = states.time.shape[0]
+    view = rows * (net.wheel_slots + net.overflow_capacity)
+    whole = {rows * net.n_nodes * 2, view}
+    a_round = rows * 256
+    fusions = _gather_fusions(text, net.payload_width)
+    sends = [size for size, name in fusions if "witt.send" in name]
+    checked = [size for size, name in fusions if "witt.reach.deliver" in name]
+    assert not [size for size in sends if size in whole], "a send reads every row of its emission again"
+    assert sends.count(a_round) > 40 and checked, "the counts read nothing: the compiler's spelling moved"
     what_a_rise_means = (
-        "a read the send shared with the delivery now stands alone: 8.6 ns a row a tick on "
-        "the chip, PR 45 was refused for two of them (sanfermin-4096.sweep-r64 -1.27%); see "
-        "`delivery_view`'s `checked` in engine/core.py"
+        "an indexed read more a tick: 8.6 ns a row on the chip, and PR 45 was refused for two of "
+        "81,920 rows (sanfermin-4096.sweep-r64 -1.27%); see `delivery_view`'s `checked` and "
+        "`_apply_emission_rounds` in engine/core.py"
     )
-    assert gathers <= 39, (gathers, what_a_rise_means)
-    assert len(over_view) <= 8, (len(over_view), what_a_rise_means)
-    assert gathers > 30 and len(over_view) > 4, "the counts read nothing: the compiler's spelling moved"
+    assert len(checked) <= 3, (len(checked), what_a_rise_means)
+    # what a read costs is its rows a tick, not an instruction in the text: a round's reads are
+    # there twice, and the loop's copy runs on no tick seen.  So the rows of every gather outside
+    # the rounds' loop, and the reads of a whole view, at or under PR 47's (under the parent's)
+    every_tick = sum(size for size, name in fusions if "witt.send/while/body" not in name)
+    assert every_tick <= 66052, (every_tick, what_a_rise_means)
+    assert [size for size, _ in fusions].count(view) <= 12, what_a_rise_means
 
 
 @pytest.fixture(scope="module")
@@ -276,7 +316,21 @@ def casper1024():
     return net, replicate_state(state, 1)
 
 
-def test_casper_chunk_program_lowers_for_one_chip(topo, no_compile_cache, casper1024):
+def _lowered(topo, net, states, chunk_ms):
+    """The chunk program of `sharded_run_stats`, lowered for one described
+    v5e chip, as text with the scopes' names."""
+    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
+
+    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
+    return _run_and_reduce(net, chunk_ms)._jit_for(shapes).lower(shapes).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def casper1024_lowered(topo, no_compile_cache, casper1024):
+    return _lowered(topo, *casper1024, 8000)
+
+
+def test_casper_chunk_program_lowers_for_one_chip(casper1024, casper1024_lowered):
     """The 8000-ms chunk program of `sharded_run_stats` for the cell
     `casper-1024.single-r1-s8000`, lowered for one described v5e chip at
     the cell's own width: the jump loop, the flat lane's planes, the
@@ -286,11 +340,9 @@ def test_casper_chunk_program_lowers_for_one_chip(topo, no_compile_cache, casper
     compiler makes of it is PERF.md section 5's."""
     from wittgenstein_tpu.engine.core import ENGINE_PHASE_SCOPES, STORE_SCOPES
     from wittgenstein_tpu.protocols.casper_batched import CHAIN_SCOPES
-    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
 
-    net, states = casper1024
-    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
-    text = _run_and_reduce(net, 8000)._jit_for(shapes).lower(shapes).as_text(debug_info=True)
+    net, _states = casper1024
+    text = casper1024_lowered
     for scope in (*CHAIN_SCOPES.values(), *STORE_SCOPES.values(), ENGINE_PHASE_SCOPES["jump"]):
         assert scope in text, scope
     assert net.due_view_rows == 4096  # the factory's rule: 1/128 of the lane
@@ -327,7 +379,28 @@ def dfinity4096():
     return net, replicate_state(state, 1)
 
 
-def test_dfinity_chunk_program_lowers_for_one_chip(topo, no_compile_cache, dfinity4096):
+@pytest.fixture(scope="module")
+def dfinity4096_lowered(topo, no_compile_cache, dfinity4096):
+    return _lowered(topo, *dfinity4096, 6000)
+
+
+@pytest.mark.parametrize("lowered", ["casper1024_lowered", "dfinity4096_lowered"])
+def test_a_program_that_states_no_capacity_has_no_rounds(request, lowered):
+    """`Emission.capacity` is the protocol's to state (PR 47): Casper's
+    eight emissions and Dfinity's fan-outs state none, so their chunk
+    programs carry nothing of `_apply_emission_rounds` (no sort of an
+    emission's row numbers, no loop of rounds: a 262,912-row ATT emission
+    whose rows all fire on the one step a slot that sends would pay a
+    262,912-key sort and a thousand rounds for nothing)."""
+    from wittgenstein_tpu.engine.core import EMISSION_SCOPES
+
+    text = request.getfixturevalue(lowered)
+    assert "witt.store.insert" in text
+    for scope in EMISSION_SCOPES.values():
+        assert scope not in text, scope
+
+
+def test_dfinity_chunk_program_lowers_for_one_chip(dfinity4096, dfinity4096_lowered):
     """The 6000-ms chunk program of `sharded_run_stats` for the cell
     `dfinity-4096.single-r1-c6000-h18000`, lowered for one described v5e
     chip at the cell's own width: the jump loop, the wheel's planes, the
@@ -336,12 +409,10 @@ def test_dfinity_chunk_program_lowers_for_one_chip(topo, no_compile_cache, dfini
     150 s over this program (sandbox, PR 43), which the tier-1 run does
     not have; what the compiler makes of it is PERF.md section 5's."""
     from wittgenstein_tpu.engine.core import ENGINE_PHASE_SCOPES, FANOUT_SCOPES, STORE_SCOPES
-    from wittgenstein_tpu.parallel.replica_shard import _run_and_reduce
     from wittgenstein_tpu.protocols.dfinity_batched import ROLE_SCOPES
 
-    net, states = dfinity4096
-    shapes = _described(states, SingleDeviceSharding(topo.devices[0]))
-    text = _run_and_reduce(net, 6000)._jit_for(shapes).lower(shapes).as_text(debug_info=True)
+    net, _states = dfinity4096
+    text = dfinity4096_lowered
     for scope in (*ROLE_SCOPES.values(), *FANOUT_SCOPES.values(), *STORE_SCOPES.values(),
                   ENGINE_PHASE_SCOPES["jump"]):
         assert scope in text, scope

@@ -127,6 +127,10 @@ def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanf
     assert got["census_store_rows_total"] == grown > 0
     assert got["census_steps_total"] == 300  # two rows in lockstep count once
     assert got["census_landed_rows_total"] == got["census_view_overflow_steps_total"] == 0
+    # both emissions state a capacity (PR 47): the rows that fired are counted in the channel
+    # sends' slots, and here every one of them reaches its receiver and is stored
+    assert got["census_fired_rows_total"] == grown and got["census_firing_overflows_total"] == 0
+    assert 0 < int(np.asarray(out.census.firing_peak).max()) <= net.census_limits()["firing_peak"]
     # the wheel's fullest row, against the instrumented program's own mark
     # (which samples after each step; the census also holds the t=0 fill)
     for j, seed in enumerate((3, 4)):
@@ -140,8 +144,8 @@ def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanf
     assert int(np.asarray(out.census.wheel_fill_peak).max()) <= net.census_limits()["wheel_fill_peak"]
     assert net.census_limits() == {
         "due_rows_peak": 0, "wheel_fill_peak": net.wheel_slots,
-        "lane_live_peak": net.overflow_capacity, "firing_peak": 0, "fanout_peak": 0,
-        "landing_peak": 0}
+        "lane_live_peak": net.overflow_capacity, "firing_peak": 256, "fanout_peak": 0,
+        "landing_peak": 0}  # 256: `sanfermin_batched.emission_capacity` of the tick's 512 requests
 
 
 # -- the harvest -----------------------------------------------------------------

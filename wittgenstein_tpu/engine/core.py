@@ -148,6 +148,16 @@ FANOUT_SCOPES = {
     "expand": "witt.store.fanout",  # a broadcast's rows, for the senders that fire
 }
 
+# a plain emission that states a `capacity` (`Emission`,
+# `_apply_emission_rounds`): the firing rows numbered to the front and a
+# round's columns read at their numbers, OUTSIDE the insert's own scope
+# (each round's insert nests under witt.send beside it), switched by the
+# same `annotate`.  Required of the protocols whose emissions state one
+# (their `REQUIRED_SCOPES`).
+EMISSION_SCOPES = {
+    "compact": "witt.store.compact",  # the firing rows to the front, a round's reads
+}
+
 # "can this row reach its receiver": both ends up, on one side of every
 # partition line (`SimState.partition_x`), the latency under the discard
 # time.  Asked where a row is sent (`latency_arrivals` through
@@ -207,8 +217,8 @@ class Census(NamedTuple):
     view_overflow_steps: jnp.ndarray  # steps whose due rows passed `due_view_rows`: the whole lane
     landed_rows: jnp.ndarray  # rows a sender-rows send's claim let land (`_send_stacked`)
     extra_commit_rounds: jnp.ndarray  # commit rounds beyond a send's first (`landing_capacity` passed)
-    fired_rows: jnp.ndarray  # rows an every-tick channel send carried with their mask set (`_send_fired`)
-    firing_overflows: jnp.ndarray  # such sends whose fired rows passed `firing_capacity`: a second round
+    fired_rows: jnp.ndarray  # rows with their mask set of the sends that run over the firing rows: an every-tick channel send (`_send_fired`) or a store emission that states a capacity (`_apply_emission_rounds`); no program has both, the channel protocols never insert into the store
+    firing_overflows: jnp.ndarray  # such sends whose fired rows passed their capacity (`firing_capacity`, `Emission.capacity`): a second round
     fanout_senders: jnp.ndarray  # senders a fan-out expanded into their rows (`apply_fanout`)
     fanout_overflows: jnp.ndarray  # fan-outs whose firing senders passed their capacity: another round
     masked_sends: jnp.ndarray  # rows a send's mask set and its `ok` did not: an end down, across a line, past the discard time (the oracle's `dropped`)
@@ -216,7 +226,7 @@ class Census(NamedTuple):
     due_rows_peak: jnp.ndarray  # most rows due in a step (the lane's, or the wheel row's), against `due_view_rows`
     wheel_fill_peak: jnp.ndarray  # fullest wheel row after a step's inserts, against `wheel_slots`
     lane_live_peak: jnp.ndarray  # most live lane rows after a step's inserts, against `overflow_capacity`
-    firing_peak: jnp.ndarray  # most rows one every-tick channel send fired, against `firing_capacity`
+    firing_peak: jnp.ndarray  # most rows one such send fired, against `firing_capacity` or the protocol's largest `Emission.capacity`
     fanout_peak: jnp.ndarray  # most senders one fan-out fired, against the protocol's largest `FanOut.capacity`
 
 
@@ -354,7 +364,16 @@ class Emission:
     [K] array (protocols with per-level message types).  arrival, when
     given, bypasses the latency model AND sender counters (the analog of
     sendArriveAt, Network.java:419-422, used for task-style self-messages);
-    declare such types with msg_size 0 so receiver counters skip them too."""
+    declare such types with msg_size 0 so receiver counters skip them too.
+
+    `capacity` (static, as a `FanOut`'s): stated by a protocol that knows
+    from its own shape that few of the K rows fire on any one step.  The
+    store then numbers the rows whose mask is set to the front and takes
+    them `capacity` a round through the latency draw and the insert, as
+    many rounds as they take (`BatchedNetwork._apply_emission_rounds`):
+    every leaf but the census is what the K rows would have left, bit for
+    bit.  A capacity of K or more is the one pass over all K rows, counted
+    in the census.  None, the default: all K rows in one pass."""
 
     mask: jnp.ndarray
     from_idx: jnp.ndarray
@@ -363,6 +382,7 @@ class Emission:
     payload: Optional[jnp.ndarray] = None
     send_time: Optional[jnp.ndarray] = None  # default: state.time + 1
     arrival: Optional[jnp.ndarray] = None  # explicit arrival times [K]
+    capacity: Optional[int] = None  # rows stored a round (static); None: all K at once
 
 
 @dataclasses.dataclass
@@ -419,14 +439,17 @@ _FANOUT_STATICS = ("receivers", "mtype", "capacity", "events")
 def _emission_leaves(emissions):
     """A step's emissions as (static part, arrays) so that they can leave
     a `lax.cond`: per emission its class, the names of its array fields
-    and its static fields (an `Emission`'s static `mtype`, a `FanOut`'s
-    receivers, type, capacity and events), and the arrays themselves."""
+    and its static fields (an `Emission`'s static `mtype` and `capacity`,
+    a `FanOut`'s receivers, type, capacity and events), and the arrays
+    themselves."""
     shape, leaves = [], []
     for em in emissions:
         if isinstance(em, FanOut):
             static = {f: getattr(em, f) for f in _FANOUT_STATICS}
         else:
             static = {"mtype": em.mtype} if isinstance(em.mtype, int) else {}
+            if em.capacity is not None:
+                static["capacity"] = em.capacity
         arrays = {
             f: getattr(em, f)
             for f in _EMISSION_ARRAYS
@@ -951,9 +974,10 @@ class BatchedNetwork:
         the counter RNG, applies partition/down/discard filters.  Returns
         (state, ok, arrival).
 
-        `event_ctr` (int32[K], a fan-out's rounds alone) is the per-event
-        counter of each row's own send event: the caller has taken the
-        events' ticks, and `send_ctr` is left as it is."""
+        `event_ctr` (int32[K] or a scalar; the rounds of a fan-out and of
+        an emission that states a capacity) is the per-event counter of
+        each row's own send event: the caller has taken the events' ticks,
+        and `send_ctr` is left as it is."""
         k = mask.shape[0]
         from_idx = from_idx.astype(jnp.int32)
         to_idx = to_idx.astype(jnp.int32)
@@ -1075,8 +1099,92 @@ class BatchedNetwork:
         counted in `census.masked_sends`, as the oracle counts them in its
         own `dropped`."""
         with self._scope("send"):
+            if em.capacity is not None and em.capacity < em.mask.shape[0]:
+                return self._apply_emission_rounds(state, em)
             state, masked = self._apply_emission_impl(state, em)
-            return census_add(state, masked_sends=masked)
+            counts = {}
+            if em.capacity is not None:  # a round holds all K rows: the dense pass, counted
+                with self._scope("compact", EMISSION_SCOPES):
+                    fired = jnp.sum(em.mask.astype(jnp.int32))
+                counts = {"fired_rows": fired, "firing_peak": fired}
+            return census_add(state, masked_sends=masked, **counts)
+
+    def _firing_rounds(self, state: SimState, mask, capacity: int, scope, store_round):
+        """The scaffold of a send that runs over the rows that fire: the
+        rows (a fan-out's: entries) whose `mask` is set numbered to the
+        front in row order by one sort, then `capacity` (static, under the
+        mask's K) of them a round through `store_round(state, live, at) ->
+        (state, masked)`, where `live` is bool[capacity] and `at` the live
+        rows' numbers (0 where not live), until all are through.  Returns
+        (state, how many fired, the rounds' `masked` summed).  The first
+        round is straight-line code and runs on every call; the rounds past
+        it are a `lax.while_loop` that takes no trip where the firing rows
+        fit one round, carries only what a send writes (`_send_fields`;
+        `_kept` holds a round to it) and, under vmap, runs until the
+        batch's slowest row is through.  `scope` names the numbering and a
+        round's slice; `store_round` names its own reads."""
+        k, c = mask.shape[0], capacity
+        with scope():
+            fired = jnp.sum(mask.astype(jnp.int32))
+            # one sort: the firing rows' numbers first and ascending; k marks
+            # the rest, and c more so that a round's slice fits
+            order = lax.sort(jnp.where(mask, jnp.arange(k, dtype=jnp.int32), k), is_stable=False)
+            order = jnp.concatenate([order, jnp.full(c, k, jnp.int32)])
+        fields = self._send_fields()
+        rest = [f for f in SimState._fields if f not in fields]
+
+        def one_round(st, cursor):
+            with scope():
+                sel = lax.dynamic_slice(order, (cursor,), (c,))
+                live = sel < k
+                at = jnp.where(live, sel, 0)
+            new, masked = store_round(st, live, at)
+            _kept(new, st, rest)
+            return new, masked
+
+        first, masked = one_round(state, 0)
+
+        def more(cursor, masked, vals):
+            st, n = one_round(first._replace(**dict(zip(fields, vals))), cursor)
+            return cursor + c, masked + n, tuple(getattr(st, f) for f in fields)
+
+        _, masked, vals = lax.while_loop(
+            lambda carry: carry[0] < fired,
+            lambda carry: more(*carry),
+            (jnp.int32(c), masked, tuple(getattr(first, f) for f in fields)),
+        )
+        return first._replace(**dict(zip(fields, vals))), fired, masked
+
+    def _apply_emission_rounds(self, state: SimState, em: Emission) -> SimState:
+        """Store an emission that states a `capacity` under its K rows: the
+        rows whose mask is set, `capacity` of them a round as a plain
+        emission of that many rows (`_firing_rounds`), until all are
+        stored.  The latency draw is keyed on a row's sender, receiver,
+        type, send time and the send's counter, never on its place; a
+        masked row adds 0 to every counter, takes no rank and scatters out
+        of bounds; and the rounds run in row order, so a wheel row's slots
+        and the lane's free slots are handed out as one pass over all K
+        rows hands them out: every leaf but the census is the dense form's.
+        One emission is one send event, whatever the rounds.  An emission
+        with more firing rows than its capacity is counted
+        (`firing_overflows`), never cut."""
+        scope = functools.partial(self._scope, "compact", EMISSION_SCOPES)
+        # the counter the dense call would have drawn with (`latency_arrivals`)
+        ctr = state.send_ctr + (0 if em.arrival is not None else 1)
+        columns = {f: getattr(em, f) for f in _EMISSION_ARRAYS[1:]}
+
+        def store_round(st, live, at):
+            with scope():
+                # a static or scalar `mtype` and a scalar `send_time` have no rows to read
+                rows = {f: x[at] if getattr(x, "ndim", 0) else x for f, x in columns.items()}
+            return self._apply_emission_impl(st, Emission(mask=live, **rows), event_ctr=ctr)
+
+        state, fired, masked = self._firing_rounds(state, em.mask, em.capacity, scope, store_round)
+        return census_add(
+            state._replace(send_ctr=ctr),
+            fired_rows=fired, firing_overflows=fired > em.capacity, firing_peak=fired,
+            masked_sends=masked,
+        )
 
     def _apply_emission_impl(self, state: SimState, em: Emission, event_ctr=None):
         """(state with the emission's ok rows stored, how many rows the
@@ -1408,8 +1516,9 @@ class BatchedNetwork:
             self.due_view_rows is None
             or self.telemetry is not None
             or not emissions
-            # a fan-out stores nothing where nothing fires, of itself
-            or any(isinstance(em, FanOut) for em in emissions)
+            # a fan-out stores nothing where nothing fires, of itself, and an
+            # emission that states a capacity one round of that many rows
+            or any(isinstance(em, FanOut) or em.capacity is not None for em in emissions)
         ):
             for em in emissions:
                 state = self._apply_one(state, em)

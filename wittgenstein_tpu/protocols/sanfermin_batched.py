@@ -62,10 +62,41 @@ import jax.numpy as jnp
 from ..core.node import build_node_columns
 from ..core.registries import registry_network_latencies
 from ..engine import BatchedNetwork, BatchedProtocol, Emission
+from ..engine.core import EMISSION_SCOPES
 from ..utils.more_math import log2
 from .sanfermin import SanFerminSignature, SanFerminSignatureParameters
 
 INT32_MAX = jnp.int32(2**31 - 1)
+
+
+def emission_capacity(rows: int) -> int:
+    """Rows a round of SanFermin's two every-tick emissions stores
+    (`Emission.capacity`; one number for both, the limit of the census's
+    `firing_peak`), from the K rows of the tick's requests alone
+    (nodes x (1 + candidate_count)): 1/32 of them up to a multiple of
+    128, and 256 at least (a small network's share swings more): 256 of
+    the 8192 requests a tick at 4096 nodes.  The deliver's replies state
+    the same: a reply answers a request that came due this millisecond,
+    so a tick's replies are some earlier ticks' requests spread over the
+    latency's width and peak under them, whatever rows the store's view
+    has (1280 there).
+
+    A node sends when it enters a level, when a reply timeout of its
+    level comes due or a pending partner says NO, and it replies to the
+    requests due this millisecond, so a tick fires a handful in a
+    thousand of its rows.  Rows with their mask set, tick by tick over
+    whole runs from t=0 (sandbox CPU, `sent_req`'s and `msg_sent`'s
+    growth; the program's integers): at 4096 nodes on the deployment's
+    store, 3 seeds x 2400 ticks (PR 47's issue), requests mean 35.8,
+    p99 116, at most 144 / 138 / 150 (at t = 122-196 ms), replies mean
+    26.9, p99 73, at most 84 / 84 / 89; at 256 nodes, 4 seeds x 2400
+    ticks (PR 47), requests mean 1.39, p99 11, at most 16-20, replies
+    mean 0.94, p99 7, at most 9-11.  So one round holds every tick seen
+    with a factor of 1.7 to spare.  The store counts what fired, every
+    emission that took a second round and the most one fired
+    (engine.core.Census `fired_rows`, `firing_overflows`, `firing_peak`);
+    none is cut."""
+    return min(rows, max(256, -(-rows // 32 // 128) * 128))
 
 
 class BatchedSanFermin(BatchedProtocol):
@@ -74,6 +105,7 @@ class BatchedSanFermin(BatchedProtocol):
     TICK_INTERVAL = 1  # timeouts + pairing commits need per-ms ticks
     # slots of the per-node ring of stacked reply timeouts (module docstring)
     TIMEOUT_RING = 4
+    REQUIRED_SCOPES = tuple(EMISSION_SCOPES.values())  # simlint SL601 holds them live
 
     def __init__(self, params: SanFerminSignatureParameters):
         self.params = params
@@ -84,6 +116,14 @@ class BatchedSanFermin(BatchedProtocol):
 
     def msg_size(self, mtype: int) -> int:
         return 4 + self.params.signature_size  # uint32 + sig (both types)
+
+    @property
+    def round_rows(self) -> int:
+        """Both emissions' `capacity` (`emission_capacity` of the tick's requests)."""
+        return emission_capacity(self.n_nodes * (1 + max(1, self.params.candidate_count)))
+
+    def census_limits(self) -> dict:
+        return {"firing_peak": self.round_rows}
 
     def proto_init(self, n_nodes: int, seed: int = 0):
         w = self.w
@@ -209,6 +249,7 @@ class BatchedSanFermin(BatchedProtocol):
                 ],
                 axis=1,
             ),
+            capacity=self.round_rows,
         )
         free = proto["tmo_t"] == 0
         arm = mask[:, None] & free & (jnp.cumsum(free.astype(jnp.int32), axis=1) == 1)
@@ -284,6 +325,7 @@ class BatchedSanFermin(BatchedProtocol):
                 rep_ok, self.mtype("SWAP_REP_OK"), self.mtype("SWAP_REP_NO")
             ),
             payload=jnp.stack([rep_lvl, jnp.where(rep_ok, rep_val, 0)], axis=1),
+            capacity=self.round_rows,  # the requests': `emission_capacity`
         )
 
         # A2 cache store (winner = lowest slot per (node, level))
