@@ -312,13 +312,14 @@ def _random_send(a, rng, k, density, crowd=False):
         off = rng.integers(0, 1 << 30, size=(n, nlv, k)) & (bs - 1)
     rel = (bs + off).astype(np.int32)
     mask = rng.random((n, nlv, k)) < density
-    havings = rng.integers(0, 2**32, size=(n, a.n_words), dtype=np.uint32)
+    havings = jnp.asarray(rng.integers(0, 2**32, size=(n, a.n_words), dtype=np.uint32))
     return (
         jnp.asarray(mask),
         jnp.asarray(ids[:, None, None]),
         jnp.asarray(ids[:, None, None] ^ rel),
         jnp.asarray(np.arange(1, a.n_levels, dtype=np.int32)[None, :, None]),
-        [a._lows(jnp.asarray(havings), b) for b in a.buckets],
+        [a._lows(havings, b) for b in a.buckets],
+        havings,  # the senders' full-width vectors the blocks are cut from
     )
 
 
@@ -352,8 +353,14 @@ SEND_CASES = {
 }
 
 
+@pytest.mark.parametrize("form", ["blocks", "words"])
 @pytest.mark.parametrize("case", sorted(SEND_CASES))
-def test_level_axis_send_equals_the_flattened_send(case):
+def test_level_axis_send_equals_the_flattened_send(case, form):
+    """The level axis takes a bucket's `_lows` blocks (the beats' form:
+    the whole-M_i body, whatever k) or the senders' full-width words
+    [N, W] (GSF's accelerated calls: with k > 1 the firing rows' arrivals
+    and claim and the landing rows' commit, PR 42 and PR 49; with k = 1
+    the blocks are cut from them and the body is the beats')."""
     import jax
 
     factory, k, density, crowd, sends = SEND_CASES[case]
@@ -364,12 +371,13 @@ def test_level_axis_send_equals_the_flattened_send(case):
     rng = np.random.default_rng(len(case))
     by_axis = by_data = state
     for j in range(sends):
-        mask, frm, to, level, blocks = _random_send(a, rng, k, density, crowd)
+        mask, frm, to, level, blocks, words = _random_send(a, rng, k, density, crowd)
         aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1, 1)), jnp.int32) if has_aux else None
         # later sends leave earlier: their arrivals evict pending occupants
         at = jnp.int32(2 * (sends - 1 - j))
         by_axis = a._send_stacked(
-            net, by_axis._replace(time=at), mask, frm, to, None, blocks, aux=aux
+            net, by_axis._replace(time=at), mask, frm, to, None,
+            words if form == "words" else blocks, aux=aux,
         )
         m, f, t, l, content, x = _flat_send_args(a, mask, frm, to, level, blocks, aux)
         by_data = a._send_stacked(net, by_data._replace(time=at), m, f, t, l, content, aux=x)
@@ -380,11 +388,15 @@ def test_level_axis_send_equals_the_flattened_send(case):
     for path, x, y in zip(
         paths, jax.tree_util.tree_leaves(by_axis), jax.tree_util.tree_leaves(by_data)
     ):
-        # the work census counts the rows that fired of a k > 1 send, which
-        # the flat entry (the whole-M body) does not number (PR 42)
+        # the work census counts the rows that fired and landed of a k > 1
+        # send of words, which the flat entry (the whole-M body) does not
+        # number (PR 42, PR 49)
         if ".census" not in path:
-            assert (np.asarray(x) == np.asarray(y)).all(), (case, path)
-    assert (int(by_axis.census.fired_rows) > 0) == (k > 1)
+            assert (np.asarray(x) == np.asarray(y)).all(), (case, form, path)
+    listed = k > 1 and form == "words"
+    assert (int(by_axis.census.fired_rows) > 0) == listed
+    assert (int(by_axis.census.landed_rows) > 0) == listed
+    assert int(by_axis.census.landed_rows) <= int(by_axis.census.fired_rows)
 
     moved = int(np.asarray(by_axis.msg_received).sum())
     assert (moved > 0) == (density > 0)
@@ -406,7 +418,7 @@ def test_level_axis_send_numbers_its_own_levels():
     not the protocol's L-1 levels."""
     net, state = _handel(64, nodes_down=0)
     a = net.protocol
-    mask, frm, to, level, blocks = _random_send(a, np.random.default_rng(0), 1, 0.5)
+    mask, frm, to, level, blocks, _words = _random_send(a, np.random.default_rng(0), 1, 0.5)
     with pytest.raises(ValueError, match="numbers its own levels"):
         a._send_stacked(net, state, mask, frm, to, level, blocks)
     with pytest.raises(ValueError, match="numbers its own levels"):

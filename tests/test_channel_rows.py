@@ -18,6 +18,11 @@ fast path, GSF's accelerated calls) run over the rows that FIRE,
 whole-M body the flat entry keeps, state for state, with the capacity
 patched small so that a send takes many rounds, and in the lowered
 programs' scatter update counts.
+
+Since PR 49 the level-axis every-tick send (GSF's accelerated calls) takes
+the senders' full-width words and commits the rows that land as Handel's
+fast path does, `firing_capacity(rows)` of them a round: the cases of the
+landing commit, its census and its lowered scatters run over both.
 """
 
 import re
@@ -107,13 +112,22 @@ def _gsf():
     return make_gsf(gsf_params())
 
 
-def _handel_node_mesh():
+def _node_mesh(net, state):
     from wittgenstein_tpu.parallel import enable_node_sharding, shard_state_by_node
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("nodes",))
-    net, state = make_handel(handel_params())
     net = enable_node_sharding(net, mesh)
     return net, shard_state_by_node(net, state, mesh)
+
+
+def _handel_node_mesh():
+    return _node_mesh(*make_handel(handel_params()))
+
+
+def _gsf_node_mesh():
+    """The accelerated calls hand the senders' words (PR 49): under a node
+    mesh the send cuts them to block stacks and keeps the whole-M body."""
+    return _node_mesh(*_gsf())
 
 
 # taken on the parent (commit 913a72b, PR 31), before the change; the
@@ -125,6 +139,7 @@ PINS = {
     "handel_byz51": (_handel_byz, 882315166),
     "gsf": (_gsf, 999241418),
     "handel_node_mesh2": (_handel_node_mesh, 3838452459),
+    "gsf_node_mesh2": (_gsf_node_mesh, 999241418),
 }
 
 
@@ -137,7 +152,9 @@ def test_whole_run_checksum_is_the_parents(name):
     assert checksum(out) == want, (name, checksum(out))
     # the fast path ran its rounds over landing rows, but for the node
     # mesh, whose exchange keeps all M rows
-    ran = name != "handel_node_mesh2"
+    ran = "node_mesh" not in name
+    if name.startswith("gsf"):
+        assert (int(out.census.landed_rows) > 0) == ran, name
     for counter in LANDING_COUNTERS if name.startswith("handel") else ():
         assert (int(out.proto[counter]) > 0) == ran, (name, counter)
 
@@ -153,13 +170,21 @@ _SCATTER = re.compile(
     r"\(tensor<([^>]+)>, tensor<[^>]+>, tensor<([^>]+)>\) ->",
     re.S,
 )
+# a scatter's indentation and operand: the ops of a `stablehlo.while`'s
+# regions are printed deeper than the function's body
+_SCATTER_LINE = re.compile(
+    r'^( +)%\S+ = "stablehlo\.scatter"\(.*?\}\) : \(tensor<([^>]+)>', re.S | re.M
+)
 # stablehlo.gather ops in the lowered GSF tick at 256 nodes: 82 at the
 # parent of PR 32 (commit 913a72b, PR 31; the cut is by reshape and slice,
 # an index array would add to these) and since PR 42 the reads of the
 # firing rows besides (sender, receiver and level of a round's rows in
 # `arrive`, receiver, level and aux in `claim`: 10 by this count, which
-# finds the generic form's name twice an op)
-GSF_TICK_GATHERS = 92
+# finds the generic form's name twice an op): 92 until PR 49, whose commit
+# round reads its landing rows' receiver and their senders' full-width
+# words (4 by this count; a row's sender and level are arithmetic on its
+# number, its slot and win bits come with the landing list)
+GSF_TICK_GATHERS = 96
 
 
 def _dims(tensor: str):
@@ -197,25 +222,34 @@ def commit_updates(n: int, k: int, level_axis: bool) -> int:
 
 
 def round_updates(n: int, rows: int) -> int:
-    """Word updates of ONE round of a sender-rows send's two commit passes:
-    every bucket over `rows` rows at its w_pad, the whole M before PR 38
-    and the landing capacity since."""
+    """Word updates of ONE round of an every-tick send's two commit passes:
+    every bucket over `rows` rows at its w_pad (the sender-rows send: the
+    whole M before PR 38 and the landing capacity since; the level-axis
+    one: the firing capacity since PR 49)."""
     return 2 * rows * sum(b.w_pad for b in _geometry(n).buckets)
 
 
 @pytest.mark.parametrize(
-    "send, n, k, before, after",
+    "send, n, k, before, after, a_round",
     [
-        ("gsf-2048 accelerated calls, every tick", 2048, 10, 28_385_280, 2_785_280),
-        ("gsf-2048 dissemination, 1 tick in 10", 2048, 1, 2_838_528, 278_528),
-        ("handel-4096 dissemination, 1 tick in 10", 4096, 1, 12_484_608, 1_081_344),
+        ("gsf-2048 accelerated calls, every tick", 2048, 10, 28_385_280, 2_785_280, 129_024),
+        ("gsf-2048 dissemination, 1 tick in 10", 2048, 1, 2_838_528, 278_528, None),
+        ("handel-4096 dissemination, 1 tick in 10", 4096, 1, 12_484_608, 1_081_344, None),
     ],
 )
-def test_commit_update_counts_from_shapes(send, n, k, before, after):
+def test_commit_update_counts_from_shapes(send, n, k, before, after, a_round):
     """The benchmark's sends, word updates a send (both passes), before
-    PR 32 and since: 10.2x, 10.2x and 11.5x fewer."""
+    PR 32 and since: 10.2x, 10.2x and 11.5x fewer.  The every-tick one
+    commits the rows that land since PR 49: 2 x 1024 x 63 word updates a
+    round, 21.6x fewer again, and as many rounds as the landing rows take
+    (one where any row lands, none where none does)."""
+    from wittgenstein_tpu.protocols._agg_batched import firing_capacity
+
     assert commit_updates(n, k, level_axis=False) == before, send
     assert commit_updates(n, k, level_axis=True) == after, send
+    if a_round is not None:
+        c = firing_capacity((n, _geometry(n).n_levels - 1, k))
+        assert c == 1024 and round_updates(n, c) == a_round, send
 
 
 def test_fast_path_update_counts_from_shapes():
@@ -232,6 +266,32 @@ def test_fast_path_update_counts_from_shapes():
     assert round_updates(4096, landing_capacity(m)) == 390_144
 
 
+@pytest.mark.parametrize("n", [256, 2048])
+def test_a_landing_rows_low_block_is_the_static_cut_of_its_level(n):
+    """What a commit round reads (PR 49): `_dyn_low(words[s], l, b)`, the
+    low block of a row's sender cut at the row's level, against the block
+    stack the level axis carried until then, `_lows(words, b)[s, l - b.lo]`,
+    word for word at every level: the sub-word ones (bits [0, 2^(l-1)) of
+    word 0), level 6's full word, and each wider level's own bucket."""
+    a = _geometry(n)
+    rng = np.random.default_rng(n)
+    words = jnp.asarray(rng.integers(0, 2**32, size=(n, a.n_words), dtype=np.uint32))
+    words = words.at[0].set(0xFFFFFFFF)  # every bit above a block's width is cut
+    senders = jnp.asarray(np.r_[0, rng.integers(0, n, size=63)], jnp.int32)
+    levels_seen = 0
+    for b in a.buckets:
+        stack = np.asarray(a._lows(words, b))  # [N, nl, w_pad]
+        for l in b.levels:
+            level = jnp.full(senders.shape, l, jnp.int32)
+            got = np.asarray(a._dyn_low(words[senders], level, b))
+            want = stack[np.asarray(senders), l - b.lo]
+            assert got.shape == want.shape == (64, b.w_pad)
+            assert (got == want).all(), (n, l, b)
+            assert got[0, 0] == (0xFFFFFFFF if a.bs[l] >= 32 else (1 << a.bs[l]) - 1)
+            levels_seen += 1
+    assert levels_seen == a.n_levels - 1 and a.w[6] == 1 and a.bs[6] == 32
+
+
 def _lowered(net, state, hook):
     fn = getattr(net.protocol, hook)
     return jax.jit(lambda s: fn(net, s)).lower(state).as_text()
@@ -240,12 +300,13 @@ def _lowered(net, state, hook):
 @pytest.mark.parametrize(
     "name, build, hook, k",
     [
-        ("gsf tick", _gsf, "tick", 10),  # the accelerated calls' send
         ("gsf beat", _gsf, "tick_beat", 1),
         ("handel beat", _handel_fused, "tick_beat", 1),
     ],
 )
 def test_a_static_level_send_scatters_only_its_buckets_rows(name, build, hook, k):
+    """The dissemination beats (most rows firing, one tick in a period):
+    two scatters a bucket over its own M_i rows."""
     net, state = build()
     a = net.protocol
     text = _lowered(net, state, hook)
@@ -256,32 +317,55 @@ def test_a_static_level_send_scatters_only_its_buckets_rows(name, build, hook, k
     widths = sum(a.w[1:])
     assert sum(sum(v) for v in found.values()) == 2 * N * k * widths
     assert 2 * N * k * widths == commit_updates(N, k, level_axis=True)
-    if name == "gsf tick":
-        gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
-        assert gathers <= GSF_TICK_GATHERS
 
 
-def test_the_fast_path_scatters_one_round_of_landing_rows():
-    """Handel's every-tick send (PR 38, ROADMAP A1 (b)(1)): its level is
-    a per-node register, so the claim's winners are compacted and every
-    scatter into an in_sig plane carries C = landing_capacity(M) rows at
-    the bucket's w_pad, inside the rounds' loop; none carries the
-    M = N x ceil(fast_path / 2) rows of the send."""
-    from wittgenstein_tpu.protocols._agg_batched import landing_capacity
+@pytest.mark.parametrize("name, build", [("handel tick", _handel_fused), ("gsf tick", _gsf)])
+def test_the_fast_path_scatters_one_round_of_landing_rows(name, build):
+    """The two every-tick sends.  Handel's (PR 38, ROADMAP A1 (b)(1)): its
+    level is a per-node register, so the claim's winners are compacted
+    and every scatter into an in_sig plane carries C = landing_capacity(M)
+    rows at the bucket's w_pad, inside the rounds' loop; none carries the
+    M = N x ceil(fast_path / 2) rows of the send.  GSF's accelerated calls
+    (PR 49), on the [N, L-1, k] level axis: the same list and the same
+    loop at C = firing_capacity(rows); none carries a bucket's
+    M_i = N x nl x k rows, and nothing is scattered over the M rows of the
+    send (the winners are no longer expanded back onto them)."""
+    from wittgenstein_tpu.protocols._agg_batched import firing_capacity, landing_capacity
 
-    net, state = _handel_fused()
+    net, state = build()
     a = net.protocol
     text = _lowered(net, state, "tick")
     found = plane_updates(text, a, state)
-    m = N * ((a.params.fast_path + 1) // 2)
-    c = landing_capacity(m)
+    if name == "handel tick":
+        m = N * ((a.params.fast_path + 1) // 2)
+        c = landing_capacity(m)
+        refused = {m}
+    else:
+        k = a.params.accelerated_calls_count
+        m = N * (a.n_levels - 1) * k
+        c = firing_capacity((N, a.n_levels - 1, k))
+        refused = {m} | {N * b.nl * k for b in a.buckets}
     assert c < m / 8
     for i, b in enumerate(a.buckets):
-        assert found[i] == [c * b.w_pad] * 2, (i, found)
+        assert found[i] == [c * b.w_pad] * 2, (name, i, found)
     assert sum(sum(v) for v in found.values()) == round_updates(N, c)
-    assert "stablehlo.while" in text  # the rounds: a trip count that is data
+    # the rounds: a trip count that is data, and the planes' scatters inside
+    assert text.count("stablehlo.while") >= 3  # arrive's rounds, claim's rounds, the commit's
+    planes = {tuple(state.proto[f"in_sig{i}"].shape) for i in range(len(a.buckets))}
+    inside = [
+        len(indent) > 4  # deeper than the function's body: in a loop's region
+        for indent, operand in _SCATTER_LINE.findall(text)
+        if operand.endswith("ui32") and _dims(operand) in planes
+    ]
+    assert len(inside) == 2 * len(a.buckets) and all(inside), (name, inside)
     for _operand, updates in _SCATTER.findall(text):
-        assert _dims(updates)[0] != m or len(_dims(updates)) == 1, updates
+        if name == "handel tick":
+            assert _dims(updates)[0] != m or len(_dims(updates)) == 1, updates
+        else:
+            assert _dims(updates)[0] not in refused, updates
+    if name == "gsf tick":
+        gathers = len(re.findall(r"stablehlo\.(?:dynamic_)?gather", text))
+        assert gathers <= GSF_TICK_GATHERS
 
 
 # -- the commit over the rows that land (PR 38) ------------------------------
@@ -392,7 +476,7 @@ def test_the_landing_rows_commit_equals_the_whole_send(build, cap, traffic, monk
         assert np.asarray(whole.proto["in_aux"]).any()
 
 
-def _landing(monkeypatch, a, args) -> int:
+def _landing(monkeypatch, a, args, **kw) -> int:
     """Rows of a send that the claim lets land: the row numbers
     `_send_fired` lists for the commit (below M; M marks the list's
     tail), which are as many as it counted."""
@@ -410,7 +494,7 @@ def _landing(monkeypatch, a, args) -> int:
 
     with monkeypatch.context() as patch:
         patch.setattr(BitsetAggBase, "_commit_landed", spy)
-        a._send_stacked(*args)
+        a._send_stacked(*args, **kw)
     (landing,) = seen
     return landing
 
@@ -443,24 +527,54 @@ def test_the_counters_read_the_rounds_and_the_landing_rows(build, cap, traffic, 
         assert int(again.proto[counter]) == int(out.proto[counter])
 
 
-@pytest.mark.parametrize("build", ["honest", "byz51"])
+def _commit_capacity(monkeypatch, a, entry, cap):
+    """Rows a round of the entry's landed commit for the case, patched
+    through the function `_send_stacked` asks: `landing_capacity(M)` for
+    rows by sender, `firing_capacity(rows)` on the level axis (there the
+    firing rounds carry as many).  Returns (the send's own, the case's)."""
+    from wittgenstein_tpu.protocols import _agg_batched
+
+    if entry == "sender":
+        return _patched_capacity(monkeypatch, a, cap)
+    rows = (a.n_nodes, a.n_levels - 1, a.params.accelerated_calls_count)
+    own = _agg_batched.firing_capacity(rows)
+    _patched_firing(monkeypatch, cap)
+    return own, own if cap is None else cap
+
+
+def _every_tick_send(a, entry, seed, density):
+    """(args, aux) of the entry's every-tick send, as the protocol makes it."""
+    has_aux = entry == "axis"  # GSF's sends carry k_new beside the key
+    return _fired_send(a, entry, np.random.default_rng(seed), density, False, has_aux)[0]
+
+
+@pytest.mark.parametrize("build", ["honest", "byz51", "gsf"])
 @pytest.mark.parametrize("cap", [3, None])
 def test_rounds_under_vmap_equal_the_single_runs(build, cap, monkeypatch):
     """Two rows with different landing counts (one lands nothing at
     all): the batched loop runs until the slower row is through and each
     row's planes and counters equal its single run's."""
-    net, state = LANDED_BUILDS[build]()
+    factory, entry = FIRED_BUILDS[build]
+    net, state = factory()
     a = net.protocol
-    _patched_capacity(monkeypatch, a, cap)
-    busy = _sender_rows_send(a, np.random.default_rng(3), 5, 0.9, False)
-    quiet = _sender_rows_send(a, np.random.default_rng(4), 5, 0.0, False)
-    few = _sender_rows_send(a, np.random.default_rng(5), 5, 0.01, False)
+    _commit_capacity(monkeypatch, a, entry, cap)
+    # at 3 rows a round the level axis's firing rounds are 3 rows too: fewer fire
+    busy_share, few_share = (0.02, 0.002) if (cap, entry) == (3, "axis") else (0.9, 0.01)
+    busy = _every_tick_send(a, entry, 3, busy_share)
+    quiet = _every_tick_send(a, entry, 4, 0.0)
+    few = _every_tick_send(a, entry, 5, few_share)
     for pair in ((busy, quiet), (few, busy)):
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *pair)
         states = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), state)
-        out = jax.vmap(lambda s, args: a._send_stacked(net, s, *args))(states, stacked)
-        singles = [a._send_stacked(net, state, *args) for args in pair]
-        assert int(singles[0].proto["commit_rounds"]) != int(singles[1].proto["commit_rounds"])
+        out = jax.vmap(lambda s, args, aux: a._send_stacked(net, s, *args, aux=aux))(states, *stacked)
+        singles = [a._send_stacked(net, state, *args, aux=aux) for args, aux in pair]
+        landed = [int(single.census.landed_rows) for single in singles]
+        assert landed[0] != landed[1] and min(landed) in (0, landed[0])
+        if entry == "sender":
+            assert int(singles[0].proto["commit_rounds"]) != int(singles[1].proto["commit_rounds"])
+        else:
+            extra = [int(single.census.extra_commit_rounds) for single in singles]
+            assert extra[0] != extra[1]
         for j, single in enumerate(singles):
             _assert_same_state(jax.tree_util.tree_map(lambda x: x[j], out), single, j, but=())
 
@@ -471,29 +585,41 @@ def test_rounds_under_vmap_equal_the_single_runs(build, cap, monkeypatch):
 # `run_cache_info()` through the run cache (tests/test_work_census.py).
 
 
-@pytest.mark.parametrize("build", ["honest", "byz51"])
+@pytest.mark.parametrize("build", ["honest", "byz51", "gsf"])
 @pytest.mark.parametrize("cap", [3, None])
 def test_the_census_sums_the_landing_rows_and_the_rounds_past_the_first(build, cap, monkeypatch):
     """Three sends in a row, the landing rows of each counted from outside
     (`winner | fresh_win` as the commit is handed them): the census holds
     their sum, and of the rounds those past each send's first: some with
-    3 rows a round, none at the send's own capacity."""
-    net, state = LANDED_BUILDS[build]()
+    3 rows a round, none at the send's own capacity.  Both every-tick
+    sends write the two slots (PR 49: GSF's accelerated calls too, whose
+    state has no `commit_rounds` leaf: the census slots are its counters)."""
+    factory, entry = FIRED_BUILDS[build]
+    net, state = factory()
     a = net.protocol
-    own, capacity = _patched_capacity(monkeypatch, a, cap)
-    landings = []
-    for j, density in enumerate((0.05, 0.0, 0.03)):
-        args = _sender_rows_send(a, np.random.default_rng(10 + j), 5, density, False)
+    own, capacity = _commit_capacity(monkeypatch, a, entry, cap)
+    landings, fired = [], 0
+    # the level axis has 12 rows where the sender rows have one
+    densities = (0.05, 0.0, 0.03) if entry == "sender" else (0.006, 0.0, 0.004)
+    for j, density in enumerate(densities):
+        args, aux = _every_tick_send(a, entry, 10 + j, density)
+        fired += int(np.asarray(args[0]).sum())
         state = state._replace(time=jnp.int32(2 * j))
-        landings.append(_landing(monkeypatch, a, (net, state, *args)))
-        state = a._send_stacked(net, state, *args)
+        landings.append(_landing(monkeypatch, a, (net, state, *args), aux=aux))
+        state = a._send_stacked(net, state, *args, aux=aux)
     assert landings[0] > 3 and landings[1] == 0 and max(landings) <= own
     assert int(state.census.landed_rows) == sum(landings)
+    # a row lands only if it fired
+    assert sum(landings) <= int(state.census.fired_rows) == fired
     extra = sum(max(-(-n // capacity) - 1, 0) for n in landings)
     assert int(state.census.extra_commit_rounds) == extra
     assert (extra > 0) == (cap == 3)
-    assert int(state.proto["commit_rounds"]) == sum(-(-n // capacity) for n in landings)
-    assert a.census_limits()["landing_peak"] == own
+    if entry == "sender":
+        assert int(state.proto["commit_rounds"]) == sum(-(-n // capacity) for n in landings)
+        assert a.census_limits()["landing_peak"] == own
+    else:
+        assert not set(LANDING_COUNTERS) & set(state.proto)
+        assert a.census_limits()["firing_peak"] == own
 
 
 def test_a_whole_handel_run_counts_a_step_a_tick_and_what_landed_on_it():
@@ -536,9 +662,10 @@ def _fired_send(a, entry, rng, density, crowd, has_aux):
         aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1)), jnp.int32) if has_aux else None
         *flat, x = _flat(a, *args, aux)
     else:
-        mask, frm, to, level, blocks = _random_send(
+        mask, frm, to, level, blocks, words = _random_send(
             a, rng, a.params.accelerated_calls_count, density, crowd)
-        args = (mask, frm, to, None, blocks)  # the axis numbers its own levels
+        # the axis numbers its own levels and cuts its landing rows' blocks
+        args = (mask, frm, to, None, words)
         aux = jnp.asarray(rng.integers(0, 99, size=(a.n_nodes, 1, 1)), jnp.int32) if has_aux else None
         *flat, x = _flat_send_args(a, mask, frm, to, level, blocks, aux)
     return (args, aux), (flat, x)

@@ -47,6 +47,8 @@ NEW_METRICS = {
     "fanout_overflows_in_window": "census_fanout_overflows_total",
     "masked_sends_in_window": "census_masked_sends_total",
     "discarded_rows_in_window": "census_discarded_rows_total",
+    "level_axis_landed_rows_in_window": "census_landed_rows_total",
+    "level_axis_extra_commit_rounds_in_window": "census_extra_commit_rounds_total",
 }
 
 
@@ -317,7 +319,13 @@ def test_forced_overflows_are_counted_and_cost_nothing_else(name, monkeypatch):
     overflows = sum(f > 3 for f in fired)
     assert int(state.census.firing_overflows) == overflows > 0
     assert int(shipped.census.firing_overflows) == 0
-    test_channel_rows._assert_same_state(state, shipped, name, but=("firing_overflows",))
+    # on GSF's level axis a commit round carries `firing_capacity(rows)` too
+    # (PR 49): three rows a round there, and the rounds past the first counted
+    test_channel_rows._assert_same_state(
+        state, shipped, name, but=("firing_overflows", "extra_commit_rounds"))
+    if name == "gsf":
+        assert int(state.census.extra_commit_rounds) > int(shipped.census.extra_commit_rounds) == 0
+        assert 0 < int(state.census.landed_rows) <= int(state.census.fired_rows)
 
 
 def test_the_run_cache_carries_the_firing_counts():
@@ -394,6 +402,9 @@ def test_the_new_metric_files_name_counters_the_program_has():
         assert m.get("workloads") == entries[name].get("workloads")
     handel = {"handel-4096.sweep-r8", "handel-4096.single-r1", "handel-4096-byz20.single-r1-c20"}
     assert set(files["landed_rows_in_window"]["workloads"]) == handel
+    # the same two slots where GSF's accelerated calls write them (PR 49)
+    for name in ("level_axis_landed_rows_in_window", "level_axis_extra_commit_rounds_in_window"):
+        assert files[name]["workloads"] == ["gsf-2048.single-r1"]
     for name in ("fired_rows_in_window", "firing_overflows_in_window"):  # the channel cells
         assert set(files[name]["workloads"]) == handel | {"gsf-2048.single-r1"}
     assert files["view_overflow_steps_in_window"]["workloads"] == ["casper-1024.single-r1-s8000"]

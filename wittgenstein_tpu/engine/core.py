@@ -215,8 +215,8 @@ class Census(NamedTuple):
 
     steps: jnp.ndarray  # executed steps (loop trips that ran `step`)
     view_overflow_steps: jnp.ndarray  # steps whose due rows passed `due_view_rows`: the whole lane
-    landed_rows: jnp.ndarray  # rows a sender-rows send's claim let land (`_send_stacked`)
-    extra_commit_rounds: jnp.ndarray  # commit rounds beyond a send's first (`landing_capacity` passed)
+    landed_rows: jnp.ndarray  # rows the claim let land of the two every-tick channel sends (`_send_stacked`): Handel's sender-rows send and GSF's accelerated calls on the level axis
+    extra_commit_rounds: jnp.ndarray  # their commit rounds beyond a send's first (`landing_capacity(M)` passed by the sender rows, `firing_capacity(rows)` on the level axis)
     fired_rows: jnp.ndarray  # rows with their mask set of the sends that run over the firing rows: an every-tick channel send (`_send_fired`) or a store emission that states a capacity (`_apply_emission_rounds`); no program has both, the channel protocols never insert into the store
     firing_overflows: jnp.ndarray  # such sends whose fired rows passed their capacity (`firing_capacity`, `Emission.capacity`): a second round
     fanout_senders: jnp.ndarray  # senders a fan-out expanded into their rows (`apply_fanout`)

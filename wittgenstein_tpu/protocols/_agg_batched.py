@@ -64,26 +64,36 @@ three runs over is read from the shape of the input:
     one round covers a tick.  Where the state carries them (Handel's),
     proto["commit_rounds"] sums the rounds run and proto["landing_peak"]
     keeps the most rows that landed in a tick;
-  * level as an AXIS — mask/from/to [N, L-1, k] and no level, content[i]
-    the [N, nl, w_pad] block stack as _lows gives it: a row's position
-    on axis 1 IS its level, so bucket i's rows are cut from that axis by
-    reshape and static slice and only they are re-addressed and
-    scattered — M_i = N x nl x k rows at w_pad words, about a tenth of
-    the word updates (tests/test_channel_rows.py).  The dissemination
-    beats (k = 1, one tick in a period, most rows firing) run arrivals
-    and the claim over all M rows; GSF's accelerated calls (k =
-    accelerated_calls_count > 1, every tick, a node firing only on the
-    tick its verified prefix improved) over the firing rows alone
-    (_send_fired), whose winners are expanded back onto the M rows for
-    the buckets' cuts.
+  * level as an AXIS — mask/from/to [N, L-1, k] and no level: a row's
+    position on axis 1 IS its level.  Content is either of two forms.
+    The block stacks, content[i] [N, nl, w_pad] as _lows gives them (the
+    dissemination beats: k = 1, one tick in a period, most rows firing):
+    arrivals and the claim run over all M rows, and bucket i's rows are
+    cut from the level axis by reshape and static slice, so only they
+    are re-addressed and scattered — M_i = N x nl x k rows at w_pad
+    words, about a tenth of the flat entry's word updates
+    (tests/test_channel_rows.py).  Or the senders' full-width vectors
+    [N, W] with k > 1 (GSF's accelerated calls, k =
+    accelerated_calls_count, every tick, a node firing only on the tick
+    its verified prefix improved): arrivals and the claim over the
+    firing rows alone and the commit over those of them that land, as
+    the sender-rows entry's (_send_fired, _commit_landed), a row's
+    sender and level computed from its row number.  A row lands only if
+    it fired, so C is firing_capacity(rows) here, the round that covers
+    a tick's firing rows: 1024 of GSF-2048's M = 225,280, 2 x 1024 x 63
+    word updates a round where the buckets' cuts carried 2,785,280 a
+    tick.  (Vectors with k = 1, or under a node mesh, are cut to their
+    block stacks first and take the beats' body.)
 
 The firing rows (_send_fired; the two every-tick entries, not under a
 node mesh): the rows whose mask is set are numbered to the front of a
 row list by one sort of M row numbers and taken F rows a round through
 the latency draw, the traffic counters, the keys and the in_key
 min-max scatters, then, once every round's keys are in, through the
-winners' reads — two loops whose trip count is data (none where no row
-fires, 76% of the ticks under the byz20 attack).  The draw is keyed on
+winners' reads, which list the round's landing rows behind those of
+the rounds before for the commit — two loops whose trip count is data
+(none where no row fires, 76% of the ticks under the byz20 attack),
+and the commit's third.  The draw is keyed on
 (seed, send time, sender, level, send counter, receiver), never on a
 row's place, and a masked row adds nothing to any counter, key or
 claim, so the state is the whole-M send's bit for bit, `displaced`
@@ -103,7 +113,10 @@ Arrivals and the claim are scalar per row; the state after a send is
 bit-identical in all three entries (the level-axis and the sender-rows
 entries only lose updates addressed to the dropped row).  Under a node
 mesh a row's level is data again after the all_to_all, so both pad
-their rows back to [M, w_pad] there and keep the whole-M body.
+their rows back to [M, w_pad] there and keep the whole-M body.  The
+work census counts what landed of both every-tick sends and the commit
+rounds past a send's first (engine.core.Census `landed_rows`,
+`extra_commit_rounds`).
 
 Keys pack (absolute_arrival << rel_bits) | rel — no per-tick countdown
 (see _advance_channel) — which bounds a sim at 2^(31-rel_bits) ms
@@ -143,8 +156,9 @@ def firing_capacity(rows: tuple) -> int:
     sender [N, r]: 2/25 of the M rows; level axis [N, L-1, k]: 1/20 of
     the N x k rows a level has (a node fires at a level or two, whatever
     their number); each up to a multiple of 128 and 256 at least (a small
-    network's share swings more).  See the module docstring for the
-    histograms behind them."""
+    network's share swings more).  The level axis's commit carries as
+    many a round: a row lands only if it fired.  See the module docstring
+    for the histograms behind them."""
     m = int(np.prod(rows))
     share = -(-2 * m // 25) if len(rows) == 2 else -(-rows[0] * rows[2] // 20)
     return min(m, max(256, -(-share // 128) * 128))
@@ -486,8 +500,12 @@ class BitsetAggBase(BatchedProtocol):
         content[i] [N, nl, w_pad], the bucket's block stack as _lows
         gives it, shared by a node's k rows of a level.  The flat row
         order is the axis order, so arrivals and the claim are the ones
-        the flattened send would get; with k > 1 they run over the rows
-        that fire (_send_fired).
+        the flattened send would get.  Or content the senders'
+        full-width [N, W] vectors, row [n, j, c] being node n's own
+        (from_idx its number n) and carrying the low block of
+        content[n] at level j + 1: with k > 1 arrivals and the claim
+        run over the rows that fire (_send_fired) and the commit over
+        those of them that land (_commit_landed).
 
         Content is re-addressed into the receiver's block-local space
         here, at send time.  See the module docstring for which rows each
@@ -497,7 +515,9 @@ class BitsetAggBase(BatchedProtocol):
         scope = functools.partial(net._scope, scopes=CHANNEL_SCOPES)
         mesh = getattr(net, "node_mesh", None)
         axis = mask.shape if mask.ndim == 3 else None  # rows on a level axis
-        words = None  # rows by sender, whose landing rows alone are committed
+        # the senders' full-width vectors of an every-tick send, whose
+        # landing rows alone are committed
+        words = None
         if mask.ndim == 2:
             if mesh is None:
                 words = content
@@ -516,6 +536,13 @@ class BitsetAggBase(BatchedProtocol):
                     f"levels: got {axis} with level {level!r}"
                 )
             level = jnp.arange(1, self.n_levels, dtype=jnp.int32)[None, :, None]
+            if not isinstance(content, (list, tuple)):
+                if mesh is None and axis[2] > 1:
+                    words = content
+                else:
+                    # a single call a level, or node-sharded: the whole
+                    # rows of the beats' form
+                    content = [self._lows(content, b) for b in self.buckets]
         rows = mask.shape
         if mask.ndim > 1:
             mask, from_idx, to_idx, level = (
@@ -530,12 +557,10 @@ class BitsetAggBase(BatchedProtocol):
         # masked rows may carry junk levels; clamp so every computed index
         # is in range (their scatters are dropped via the n_nodes row)
         level = jnp.clip(level.astype(jnp.int32), 1, self.n_levels - 1)
-        # the two every-tick entries, of whose rows few fire
-        fired = mesh is None and (words is not None or (cut and axis[2] > 1))
-        if fired:
-            state, claimed = self._send_fired(
-                net, state, firing_capacity(rows), mask, from_idx, to_idx, level, aux, scope,
-                landing=words is not None,
+        if words is not None:
+            # the two every-tick entries, of whose rows few fire
+            state, landed = self._send_fired(
+                net, state, firing_capacity(rows), mask, from_idx, to_idx, level, aux, scope
             )
         else:
             with scope("arrivals"):
@@ -544,13 +569,15 @@ class BitsetAggBase(BatchedProtocol):
                 )
         proto = state.proto
 
-        with scope("readdress"):
-            # re-address sender-space content into the receiver's
-            # block-local space (bit j -> j ^ r0) for each bucket's rows,
-            # shared by both commit passes; r0 < bs keeps the permutation
-            # inside the level block, and rows routed away from the bucket
-            # get r0 = 0 so the (dropped) shuffle stays in range
-            if words is None:
+        if words is None:
+            with scope("readdress"):
+                # re-address sender-space content into the receiver's
+                # block-local space (bit j -> j ^ r0) for each bucket's
+                # rows, shared by both commit passes; r0 < bs keeps the
+                # permutation inside the level block, and rows routed away
+                # from the bucket get r0 = 0 so the (dropped) shuffle stays
+                # in range.  (The landing rows of an every-tick send are
+                # re-addressed a round at a time, in _commit_landed.)
                 r0_row = self._r0(from_idx, to_idx, level)
                 if axis is not None:
                     content = [
@@ -578,24 +605,19 @@ class BitsetAggBase(BatchedProtocol):
             )
 
         updates = dict(proto)
-        if not fired:
+        sig_names = [f"in_sig{i}" for i in range(len(self.buckets))]
+        if words is None:
             with scope("claim"):
                 updates["in_key"] = self._claim_keys(proto["in_key"], ok, to_idx, level, key, slot)
                 win_to, fwin_to, displaced = self._claim_winners(
                     proto["in_key"], updates["in_key"], ok, to_idx, level, key, slot
                 )
                 updates["displaced"] = proto["displaced"] + displaced + time_overflow
-                claimed = (win_to, fwin_to, slot)
-            if aux is not None:
-                with scope("commit"):
+            with scope("commit"):
+                if aux is not None:
                     updates["in_aux"] = self._commit_aux(
                         proto["in_aux"], win_to, fwin_to, level, slot, aux
                     )
-
-        sig_names = [f"in_sig{i}" for i in range(len(self.buckets))]
-        if words is None:
-            win_to, fwin_to, slot = claimed
-            with scope("commit"):
                 for name, b, own, cnt in zip(sig_names, self.buckets, bucket_rows, cnt_list):
                     updates[name] = self._commit_bucket(
                         updates[name], b,
@@ -603,11 +625,15 @@ class BitsetAggBase(BatchedProtocol):
                         own(level) - b.lo, own(slot), cnt,
                     )
         else:
-            land_rows, land_info, landing = claimed
+            land_rows, land_info, landing = landed
+            # rows a round, from the send's shape: a level-axis row lands
+            # only if it fired, so the round that covers a tick's firing
+            # rows covers its landing rows
+            capacity = landing_capacity(mask.shape[0]) if axis is None else firing_capacity(rows)
             sigs, rounds = self._commit_landed(
                 [updates[name] for name in sig_names], words,
                 from_idx, to_idx, level, land_rows, land_info, landing,
-                landing_capacity(mask.shape[0]), scope,
+                capacity, scope, axis,
             )
             updates.update(zip(sig_names, sigs))
             if "commit_rounds" in proto:
@@ -718,9 +744,7 @@ class BitsetAggBase(BatchedProtocol):
         aux = aux.astype(jnp.int32)
         return in_aux.at[win_to, col].set(aux, mode="drop").at[fwin_to, fcol].set(aux, mode="drop")
 
-    def _send_fired(
-        self, net, state, capacity, mask, from_idx, to_idx, level, aux, scope, *, landing
-    ):
+    def _send_fired(self, net, state, capacity, mask, from_idx, to_idx, level, aux, scope):
         """Arrivals and the claim of an every-tick send over the rows
         that FIRE: the flat [M] rows whose mask is set, a few in a
         hundred, are numbered to the front of a row list and taken
@@ -736,11 +760,10 @@ class BitsetAggBase(BatchedProtocol):
         until the batch's slowest row is through.
 
         Returns the state (counters, in_key, in_aux, displaced written;
-        the census's fired rows) and what the commit needs: with
-        `landing` (rows by sender) the row numbers of the rows that won a
-        slot, compacted in their turn, their slot and win bits beside
-        them, and their count; without it (level axis) win_to, fwin_to
-        and slot expanded back onto the M rows, for the buckets' cuts."""
+        the census's fired rows) and what the commit needs
+        (_commit_landed): the row numbers of the rows that won a slot,
+        compacted in their turn, their slot and win bits beside them, and
+        their count."""
         proto = state.proto
         m, n, d = mask.shape[0], self.n_nodes, self.CHANNEL_DEPTH
         with scope("compact"):
@@ -785,10 +808,7 @@ class BitsetAggBase(BatchedProtocol):
         in_key = slim.proto["in_key"]
         state = slim._replace(send_ctr=ctr + 1, proto=dict(proto, **slim.proto))
 
-        if landing:
-            out = (jnp.full(order.shape, m, jnp.int32), jnp.zeros(order.shape, jnp.int32), jnp.int32(0))
-        else:
-            out = (jnp.full(m, n, jnp.int32), jnp.full(m, n, jnp.int32), jnp.zeros(m, jnp.int32))
+        out = (jnp.full(order.shape, m, jnp.int32), jnp.zeros(order.shape, jnp.int32), jnp.int32(0))
         in_aux = proto["in_aux"] if aux is not None else ()
 
         def claim(carry):
@@ -807,27 +827,20 @@ class BitsetAggBase(BatchedProtocol):
                 with scope("commit"):
                     in_aux = self._commit_aux(in_aux, win_to, fwin_to, lvl, slot, *aux_c)
             with scope("compact"):
-                if landing:
-                    # the round's landing rows behind those of the rounds
-                    # before: row number, and slot and win bits beside it
-                    land_rows, land_info, landed = out
-                    winner, fresh_win = win_to < n, fwin_to < n
-                    lands = winner | fresh_win
-                    info = slot * 4 + winner.astype(jnp.int32) * 2 + fresh_win.astype(jnp.int32)
-                    first, info = lax.sort(
-                        (jnp.where(lands, sel, m), info), num_keys=1, is_stable=False
-                    )
-                    out = (
-                        lax.dynamic_update_slice(land_rows, first, (landed,)),
-                        lax.dynamic_update_slice(land_info, info, (landed,)),
-                        landed + jnp.sum(lands.astype(jnp.int32)),
-                    )
-                else:
-                    at = jnp.where(live, sel, m)
-                    out = tuple(
-                        x.at[at].set(y, mode="drop")
-                        for x, y in zip(out, (win_to, fwin_to, slot))
-                    )
+                # the round's landing rows behind those of the rounds
+                # before: row number, and slot and win bits beside it
+                land_rows, land_info, landed = out
+                winner, fresh_win = win_to < n, fwin_to < n
+                lands = winner | fresh_win
+                info = slot * 4 + winner.astype(jnp.int32) * 2 + fresh_win.astype(jnp.int32)
+                first, info = lax.sort(
+                    (jnp.where(lands, sel, m), info), num_keys=1, is_stable=False
+                )
+                out = (
+                    lax.dynamic_update_slice(land_rows, first, (landed,)),
+                    lax.dynamic_update_slice(land_info, info, (landed,)),
+                    landed + jnp.sum(lands.astype(jnp.int32)),
+                )
             return k + 1, displaced + lost, in_aux, out
 
         _, displaced, in_aux, out = lax.while_loop(
@@ -889,21 +902,24 @@ class BitsetAggBase(BatchedProtocol):
 
     def _commit_landed(
         self, sigs, words, from_idx, to_idx, level, land_rows, land_info, landing,
-        capacity, scope,
+        capacity, scope, axis=None,
     ):
-        """The commit of a sender-rows send over the rows that land: the
+        """The commit of an every-tick send over the rows that land: the
         claim's winners (each the only one at its plane cell, so their
         order is free), as _send_fired lists them (`land_rows` the flat
         row numbers of the `landing` rows that won a slot, m behind them;
         `land_info` a row's slot and win bits), committed `capacity`
         rows a round, as many rounds as the landing rows take (none for
         none): no row is lost or deferred.  A round reads its rows'
-        sender, receiver and level from the send's flat [M] columns and
-        their senders' full-width `words` [N, W] (row m's sender is
-        m // r), cuts and re-addresses each bucket's low block and runs
+        senders' full-width `words` [N, W] (row m's sender is m // r),
+        cuts and re-addresses each bucket's low block and runs
         `_commit_bucket` over `capacity` rows; rows of other buckets'
-        levels and the list's tail go to the dropped row.  Under vmap
-        the loop runs until the batch's slowest row is through (a
+        levels and the list's tail go to the dropped row.  Rows by sender
+        (`axis` None): a row's sender, receiver and level are read from
+        the send's flat [M] columns.  Level as an axis (`axis` its
+        [N, L-1, k]): row [n, j, c] is node n's at level j + 1, which is
+        arithmetic on its number, and its receiver alone is read.  Under
+        vmap the loop runs until the batch's slowest row is through (a
         finished row's rounds write nothing).  Returns the planes and
         the rounds this send took."""
         m, n = to_idx.shape[0], self.n_nodes
@@ -920,7 +936,11 @@ class BitsetAggBase(BatchedProtocol):
                 info = lax.dynamic_slice(land_info, (k * capacity,), (capacity,))
                 live = sel < m
                 sel = jnp.where(live, sel, 0)
-                from_c, to_c, level_c = (x[sel] for x in (from_idx, to_idx, level))
+                if axis is None:
+                    from_c, to_c, level_c = (x[sel] for x in (from_idx, to_idx, level))
+                else:
+                    from_c, to_c = sel // r, to_idx[sel]
+                    level_c = (sel // axis[2]) % axis[1] + 1
                 slot_c = info >> 2
                 win_to = jnp.where(live & ((info & 2) > 0), to_c, n)
                 fwin_to = jnp.where(live & ((info & 1) > 0), to_c, n)

@@ -314,7 +314,8 @@ class BatchedGSF(BitsetAggBase):
             )  # [N, L-1, acc]
             mask_b = ks[None, None, :] < take[:, :, None]
             # the rows lie on the [N, L-1, acc] level axis: handed over as
-            # they are, with each bucket's blocks (see _send_stacked)
+            # they are, with the senders' full-width vectors, of which the
+            # send cuts each landing row's low block (see _send_stacked)
             state = self._send_stacked(
                 net,
                 state,
@@ -322,7 +323,7 @@ class BatchedGSF(BitsetAggBase):
                 ids[:, None, None],
                 ids[:, None, None] ^ relb,
                 None,
-                [self._lows(havings, b) for b in self.buckets],
+                havings,
                 aux=k_new[:, None, None],
             )
 

@@ -252,9 +252,9 @@ class Server:
                 ("store_rows", "rows accepted into the wheel or the lane"),
                 ("view_overflow_steps",
                  "steps whose due rows passed due_view_rows (whole-lane branch)"),
-                ("landed_rows", "rows a sender-rows send's claim let land"),
+                ("landed_rows", "rows the every-tick channel sends' claim let land"),
                 ("extra_commit_rounds",
-                 "commit rounds beyond a send's first (landing_capacity passed)"),
+                 "commit rounds beyond a send's first (its landing capacity passed)"),
                 ("fired_rows",
                  "rows the every-tick channel sends carried with their mask set"),
                 ("firing_overflows",
