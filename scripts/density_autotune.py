@@ -4,9 +4,11 @@ The density war's sizing probe (engine.capacity is the contract it
 feeds).  Two instruments:
 
   1. Generic message store: every registered generic-engine protocol is
-     run through net.run_ms_occupancy() (plain per-tick steps, no
-     empty-ms jumps, so every tick's occupancy is sampled) and the
-     wheel/overflow high-water marks are recorded.  Sized knobs follow
+     run through net.run_ms() and the wheel/overflow high-water marks
+     are read from the work census it counts on every run
+     (`census.wheel_fill_peak`, `.lane_live_peak`: the store's fill after
+     each executed step's inserts, the only moment occupancy can peak,
+     and the fill the state began with).  Sized knobs follow
      engine.capacity.size_from_hwm (margin + floor + x8 rounding).
      Flat-mode protocols (wheel_rows=0: the Handel family) get only an
      overflow_capacity sizing — their overflow lane IS the store.
@@ -52,7 +54,7 @@ SMOKE_NAMES = ("pingpong", "p2pflood")
 
 
 def probe_store(entry, probe_ms: int):
-    """run_ms_occupancy over one registry entry -> CapacityEntry."""
+    """run_ms over one registry entry, its census's peaks -> CapacityEntry."""
     import jax.numpy as jnp
 
     from wittgenstein_tpu.engine.capacity import (
@@ -64,10 +66,9 @@ def probe_store(entry, probe_ms: int):
     )
 
     net, state = entry.factory()
-    out, hwms = net.run_ms_occupancy(state, probe_ms)
-    jax.block_until_ready(out)
-    fill = int(hwms["wheel_fill_hwm"])
-    ovf = int(hwms["overflow_hwm"])
+    out = jax.block_until_ready(net.run_ms(state, probe_ms))
+    fill = int(out.census.wheel_fill_peak)
+    ovf = int(out.census.lane_live_peak)
     dropped = int(jnp.max(out.dropped))
     sized = {"overflow_capacity": size_from_hwm(ovf, floor=MIN_OVERFLOW)}
     if not net.flat:

@@ -850,7 +850,7 @@ def _scoped_primitives(jaxpr, scope: str, inside: bool = False, out=None):
 
 @pytest.mark.parametrize("name", sorted(HANDEL_TICK_GATHERS))
 def test_the_candidate_merge_has_no_sort_and_no_gather(name):
-    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+    from wittgenstein_tpu.protocols.handel_batched import DELIVER_SCOPES
 
     net, state = PINS[name][0]()
     tick = lambda s: net.protocol.tick(net, s)  # noqa: E731
@@ -874,7 +874,7 @@ def test_the_due_candidates_rank_has_no_gather(name):
     and a one-hot mask, so nothing under `witt.deliver.rank` is an indexed
     read.  On a v5e the gather it replaced was 2.33 ms of an 8.87-ms tick
     at 4096 nodes (PERF.md section 6, PR 44)."""
-    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+    from wittgenstein_tpu.protocols.handel_batched import DELIVER_SCOPES
 
     net, state = PINS[name][0]()
     tick = lambda s: net.protocol.tick(net, s)  # noqa: E731
@@ -920,7 +920,7 @@ def test_gsf_keeps_its_own_merge_until_it_claims_in_its_own_cell():
     """`gsf_batched.py` holds the same algorithm in its own lines, left as
     it is so that `gsf-2048.single-r1` is the cell in which nothing may
     move (ROADMAP A1 (c)): no merge scope in its program."""
-    from wittgenstein_tpu.engine.core import DELIVER_SCOPES
+    from wittgenstein_tpu.protocols.handel_batched import DELIVER_SCOPES
 
     net, state = _gsf()
     text = jax.jit(net.step).lower(state).as_text(debug_info=True)

@@ -205,13 +205,13 @@ def test_sized_capacity_drops_nothing_and_matches():
     assert net_s.overflow_capacity == eng["overflow_capacity"]
 
     ms = int(entry.probe.get("sim_ms", 200))
-    out_s, hwms = net_s.run_ms_occupancy(s_s, ms)
+    out_s = net_s.run_ms(s_s, ms)
     assert int(out_s.dropped) == 0
-    assert int(hwms["wheel_fill_hwm"]) <= eng["wheel_slots"]
-    assert int(hwms["overflow_hwm"]) <= eng["overflow_capacity"]
+    assert int(out_s.census.wheel_fill_peak) <= eng["wheel_slots"]
+    assert int(out_s.census.lane_live_peak) <= eng["overflow_capacity"]
     # observables vs the default-sized wheel: store geometry differs, so
     # compare what the sim reports, not the raw store leaves
-    out_d, _ = net_d.run_ms_occupancy(s_d, ms)
+    out_d = net_d.run_ms(s_d, ms)
     assert np.array_equal(np.asarray(out_d.done_at), np.asarray(out_s.done_at))
     assert np.array_equal(
         np.asarray(out_d.proto["pong"]), np.asarray(out_s.proto["pong"])

@@ -5,9 +5,9 @@ are head heights and traffic, like the reference's printStat.
 Since PR 43 every broadcast is a `FanOut` (engine/core.py): its rows are
 made for the senders that fire.  The plain spelling it replaces (every
 sender that could fire, masked: `FanOut.dense()`, the `Emission`s the
-protocol built itself until then) stays in the engine behind
-`BatchedNetwork(dense_fanout=True)`, and the fan-out is held against it
-here leaf for leaf.
+protocol built itself until then) is the reference: `dense_twin` below
+wraps a network's protocol so that every fan-out reaches the store in
+that spelling, and the fan-out is held against it here leaf for leaf.
 
 Off JAX's persistent compilation cache (`no_compile_cache`,
 tests/conftest.py) since PR 46: XLA:CPU's executable serialisation
@@ -18,13 +18,16 @@ an abort in `deserialize_executable` where it reads
 `test_oracle_parity_256_attesters[0]`'s; each case passes alone), and one
 lost worker fails the run.  No other protocol's programs have done it."""
 
+import copy
 import dataclasses
 
 import jax
 import numpy as np
 import pytest
 
+from wittgenstein_tpu.core.geo import MAX_X
 from wittgenstein_tpu.engine import replicate_state
+from wittgenstein_tpu.engine.core import INT_MAX, FanOut
 from wittgenstein_tpu.oracle.blockchain import Block
 from wittgenstein_tpu.protocols.dfinity import Dfinity, DfinityParameters
 from wittgenstein_tpu.protocols.dfinity_batched import (
@@ -179,6 +182,44 @@ def _with_capacity(net, capacity):
     return net
 
 
+class DenseFanOuts:
+    """The protocol it wraps, every `FanOut` that protocol hands the
+    engine in its plain spelling (`FanOut.dense()`)."""
+
+    def __init__(self, protocol):
+        self._protocol = protocol
+
+    def __getattr__(self, name):
+        return getattr(self._protocol, name)
+
+    @staticmethod
+    def _dense(emissions):
+        return [em for fo in emissions for em in (fo.dense() if isinstance(fo, FanOut) else [fo])]
+
+    def initial_emissions(self, net, state):
+        return self._dense(self._protocol.initial_emissions(net, state))
+
+    def deliver(self, net, state, view):
+        state, emissions = self._protocol.deliver(net, state, view)
+        return state, self._dense(emissions)
+
+
+def dense_twin(net, state):
+    """The reference a fan-out network is held against: a copy of `net`
+    whose protocol is wrapped in `DenseFanOuts`, and the t=0 state that
+    copy makes of `state`'s population, line and protocol leaves."""
+    twin = copy.copy(net)
+    twin.protocol = DenseFanOuts(net.protocol)
+    lines = [int(x) for x in np.asarray(state.partition_x) if x != INT_MAX]
+    fresh = twin.init_state(
+        {f: getattr(state, f) for f in ("x", "y", "extra_latency", "city_idx")},
+        seed=int(state.seed), proto=state.proto, down=state.down,
+        partition=(lines[0] + 0.5) / MAX_X if lines else None,  # `partition` floors MAX_X * part
+    )
+    assert _differing(fresh, state) == []  # the t=0 broadcasts, row for row
+    return twin, fresh
+
+
 def _differing(a, b, but=("census",)):
     a, b = (s._replace(**{f: () for f in but}) for s in (a, b))
     return [
@@ -192,7 +233,7 @@ def _differing(a, b, but=("census",)):
 def dense_run():
     """The plain spelling through 13,000 ms (heads 5 under IC3: five
     blocks, the far-future exchange of the sixth in the lane)."""
-    net, state = _small(dense_fanout=True)
+    net, state = dense_twin(*_small())
     out = net.run_ms(state, 13000)
     assert int(out.census.fanout_senders) == 0  # nothing went through the fan-out
     assert np.unique(np.asarray(net.protocol.head_height(out))).tolist() == [5]
@@ -230,11 +271,9 @@ def test_fanout_equals_the_dense_form_where_wheel_rows_spill(monkeypatch):
     plan = module.store_plan
     monkeypatch.setattr(module, "store_plan", lambda n, c: {
         **plan(n, c), "wheel_slots": 64, "overflow_capacity": 4096, "due_view_rows": (8, 32)})
-    runs = []
-    for dense in (True, False):
-        net, state = _small(dense_fanout=dense)
-        assert (net.wheel_slots, net.overflow_capacity) == (64, 4096)
-        runs.append(net.run_ms(state, 7000))
+    net, state = _small()
+    assert (net.wheel_slots, net.overflow_capacity) == (64, 4096)
+    runs = [n.run_ms(s, 7000) for n, s in (dense_twin(net, state), (net, state))]
     assert _differing(*runs) == []
     assert int(runs[1].dropped) == 0
     assert int(runs[1].census.wheel_fill_peak) == 64 and int(runs[1].census.lane_live_peak) > 256
@@ -248,11 +287,11 @@ def test_fanout_equals_the_dense_form_with_a_telemetry_side_car():
     side-car's counts among them, and the store invariant closes."""
     from wittgenstein_tpu.telemetry.state import TelemetryConfig
 
+    net, state = _small(latency_name=None)
     runs = []
-    for dense in (True, False):
-        net, state = _small(latency_name=None, dense_fanout=dense)
+    for net, state in (dense_twin(net, state), (_with_capacity(net, 3), state)):
         net, state = net.with_telemetry(state, TelemetryConfig())
-        runs.append(_with_capacity(net, 3).run_ms(state, 4000))
+        runs.append(net.run_ms(state, 4000))
     assert _differing(*runs) == []
     tele = runs[1].tele
     pending = int(runs[1].msg_valid.sum()) + int(runs[1].ovf_valid.sum())
@@ -263,10 +302,8 @@ def test_fanout_equals_the_dense_form_under_jitter():
     """The same under a model that draws (the factory's default,
     NetworkLatencyByDistanceWJitter): the draws are keyed by destination
     id and by the send event's own counter, not by a row's place."""
-    runs = []
-    for dense in (True, False):
-        net, state = _small(latency_name=None, dense_fanout=dense)
-        runs.append(_with_capacity(net, 3).run_ms(state, 7000))
+    net, state = _small(latency_name=None)
+    runs = [n.run_ms(s, 7000) for n, s in (dense_twin(net, state), (_with_capacity(net, 3), state))]
     assert _differing(*runs) == []
     assert int(runs[1].census.fanout_overflows) > 0
 

@@ -11,7 +11,7 @@ Dfinity's programs, where the cache reads an entry or writes one)."""
 
 import numpy as np
 import pytest
-from test_dfinity_batched import IC3, _differing, _small  # the sound network's small build and the leaf comparison
+from test_dfinity_batched import IC3, _differing, _small, dense_twin  # the sound network's small build, the leaf comparison, the dense reference
 
 from wittgenstein_tpu.oracle.blockchain import Block
 from wittgenstein_tpu.protocols.dfinity_batched import make_dfinity
@@ -79,10 +79,8 @@ def test_fanout_equals_the_dense_form_under_the_partition():
     """The grid's `ok` (K + R reads broadcast) against the plain rows'
     (`latency_arrivals`, a read a row) with a line set: every leaf, and
     the census's two counts with them."""
-    runs = []
-    for dense in (True, False):
-        net, state = _partitioned(16, node_count=64, dense_fanout=dense)
-        runs.append(net.run_ms(state, 7000))
+    net, state = _partitioned(16, node_count=64)
+    runs = [n.run_ms(s, 7000) for n, s in (dense_twin(net, state), (net, state))]
     assert _differing(*runs) == []
     dense, grid = (r.census for r in runs)
     assert int(grid.masked_sends) == int(dense.masked_sends) > 0

@@ -22,11 +22,15 @@ import pytest
 
 from wittgenstein_tpu.core.registries import builder_name
 from wittgenstein_tpu.engine import replicate_state
-from wittgenstein_tpu.engine.core import ATTACK_SCOPES, DELIVER_SCOPES
 from wittgenstein_tpu.protocols.gsf import GSFSignatureParameters
 from wittgenstein_tpu.protocols.gsf_batched import make_gsf
 from wittgenstein_tpu.protocols.handel import Handel, HandelParameters
-from wittgenstein_tpu.protocols.handel_batched import BatchedHandel, make_handel
+from wittgenstein_tpu.protocols.handel_batched import (
+    ATTACK_SCOPES,
+    DELIVER_SCOPES,
+    BatchedHandel,
+    make_handel,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NL = "NetworkLatencyByDistanceWJitter"
@@ -304,6 +308,22 @@ def test_a_blacklisted_peer_gets_nothing_more_from_the_node_that_listed_it():
 # -- the attack's scopes -------------------------------------------------
 
 
+def _take_out(monkeypatch, dead):
+    """Every engine's `_scope` opens nothing for the scope named `dead`."""
+    import contextlib
+
+    from wittgenstein_tpu.engine.core import BatchedNetwork
+
+    real = BatchedNetwork._scope
+
+    def scope(self, name, scopes=None):
+        if scopes is not None and scopes[name] == dead:
+            return contextlib.nullcontext()
+        return real(self, name) if scopes is None else real(self, name, scopes)
+
+    monkeypatch.setattr(BatchedNetwork, "_scope", scope)
+
+
 def _attack_entry():
     from wittgenstein_tpu.core.registries import BatchedProtocolEntry
 
@@ -327,22 +347,63 @@ def test_sl601_passes_with_the_attack_scopes_live():
     ],
 )
 def test_sl601_detects_a_dead_attack_or_merge_scope(monkeypatch, registry, dead):
-    import contextlib
-
     from wittgenstein_tpu.analysis.annotations_check import check_annotations_entry
-    from wittgenstein_tpu.engine.core import BatchedNetwork
 
-    real = BatchedNetwork._scope
-
-    def scope(self, name, scopes=None):
-        if scopes is registry and name == dead:
-            return contextlib.nullcontext()
-        return real(self, name) if scopes is None else real(self, name, scopes)
-
-    monkeypatch.setattr(BatchedNetwork, "_scope", scope)
+    _take_out(monkeypatch, registry[dead])
     findings = check_annotations_entry(_attack_entry(), root=ROOT)
     assert [f.rule for f in findings] == ["SL601"]
     assert registry[dead] in findings[0].message
+
+
+CHANNEL = ["witt.channel.arrivals", "witt.channel.readdress", "witt.channel.claim", "witt.channel.commit"]
+DELIVER = ["witt.deliver.rank", "witt.deliver.merge"]
+# what the lint required of each build BY NAME until PR 50 (a method called
+# `_send_stacked`, `track_bad`, `isinstance(..., BatchedHandel)`,
+# `params.byzantine_suicide`), spelled out; the last of each is taken out below
+STATED = {
+    "gsf": CHANNEL + ["witt.channel.compact"],
+    "gsf-one-call": CHANNEL,  # no every-tick send on the level axis: no firing rows to the front
+    "handel": CHANNEL + DELIVER + ["witt.channel.compact"],
+    "handel-no-fast-path": DELIVER + CHANNEL,
+    "handel-hidden-byzantine": CHANNEL + ["witt.channel.compact"] + DELIVER
+    + ["witt.attack.emission", "witt.attack.blacklist"],
+    "handel-byzantine-suicide": CHANNEL + ["witt.channel.compact"] + DELIVER
+    + ["witt.attack.blacklist", "witt.attack.emission", "witt.attack.inject"],
+}
+
+
+def _stated_entry(build):
+    from wittgenstein_tpu.core.registries import BatchedProtocolEntry, registry_batched_protocols
+
+    if build in ("gsf", "handel"):  # the registered ones, as simlint runs them
+        return registry_batched_protocols.get(build)
+    factory = {
+        "gsf-one-call": lambda: make_gsf(gsf_params(nodes_down=0, accelerated_calls_count=1)),
+        "handel-no-fast-path": lambda: make_handel(
+            byz_params(nodes_down=0, byzantine_suicide=False, fast_path=0)),
+        "handel-hidden-byzantine": lambda: make_handel(
+            byz_params(byzantine_suicide=False, hidden_byzantine=True)),
+        "handel-byzantine-suicide": lambda: make_handel(byz_params()),
+    }[build]
+    return BatchedProtocolEntry(build, "fixture_batched", factory)
+
+
+@pytest.mark.parametrize("build", sorted(STATED))
+def test_a_channel_protocol_states_its_scopes_and_sl601_holds_it_to_them(monkeypatch, build):
+    """The protocol's own `REQUIRED_SCOPES` is what the lint asks of it (the
+    lint names no protocol): exactly the by-name list, clean as built, and
+    a finding for a scope that its step no longer carries."""
+    from wittgenstein_tpu.analysis.annotations_check import check_annotations_entry
+
+    entry = _stated_entry(build)
+    stated = entry.factory()[0].protocol.REQUIRED_SCOPES
+    assert sorted(stated) == sorted(STATED[build]) and len(set(stated)) == len(stated)
+    assert check_annotations_entry(entry, root=ROOT) == []
+
+    dead = STATED[build][-1]
+    _take_out(monkeypatch, dead)
+    findings = check_annotations_entry(entry, root=ROOT)
+    assert [f.rule for f in findings] == ["SL601"] and dead in findings[0].message
 
 
 def test_an_attack_free_program_carries_no_attack_scope():

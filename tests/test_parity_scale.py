@@ -91,7 +91,7 @@ class TestHandel1024:
 
 class TestHandel4096:
     def test_oracle_quantile_parity_north_star(self):
-        """THE north-star config (BASELINE.md): Handel BLS aggregation at
+        """THE north-star config (BASELINE.json): Handel BLS aggregation at
         4096 nodes.  P10/P50/P90 of time-to-threshold vs the oracle DES,
         plus the displacement-rate pin at full scale."""
         from wittgenstein_tpu.protocols.handel import HandelParameters

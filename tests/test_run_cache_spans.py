@@ -20,7 +20,6 @@ import pytest
 
 from wittgenstein_tpu.core.registries import registry_batched_protocols
 from wittgenstein_tpu.engine import replicate_state
-from wittgenstein_tpu.engine.core import CHANNEL_SCOPES
 from wittgenstein_tpu.parallel import replica_shard as rs
 from wittgenstein_tpu.profiling.xla_cost import (
     _HLO_NAME,
@@ -28,6 +27,7 @@ from wittgenstein_tpu.profiling.xla_cost import (
     scope_chain,
     scope_self_times,
 )
+from wittgenstein_tpu.protocols._agg_batched import CHANNEL_SCOPES
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHUNK_MS = 10

@@ -154,8 +154,7 @@ def test_factories_take_no_switch_of_cache_or_view():
     """The whole signatures: a keyword that picks the cached or the
     uncached program, or the selection's view, would show here."""
     assert list(inspect.signature(make_handel).parameters) == [
-        "params", "capacity", "seed", "wheel_rows", "telemetry", "annotate",
-        "fuse_step",
+        "params", "capacity", "seed", "wheel_rows", "telemetry", "fuse_step",
     ]
     assert list(inspect.signature(make_p2phandel).parameters) == [
         "params", "capacity", "seed",

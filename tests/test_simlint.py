@@ -511,8 +511,8 @@ def test_sl601_clean_on_pingpong():
 
 
 def test_sl601_detects_missing_scope():
-    """An engine whose _scope is a no-op claims annotate=True but emits
-    no markers — the delivery scope must be reported missing."""
+    """An engine whose _scope is a no-op emits no markers — the
+    delivery scope must be reported missing."""
     import contextlib
 
     from wittgenstein_tpu.analysis.annotations_check import (
@@ -533,56 +533,6 @@ def test_sl601_detects_missing_scope():
     )
     assert any(
         f.rule == "SL601" and "witt.delivery" in f.message for f in findings
-    )
-
-
-def test_sl601_detects_annotation_sensitive_kernel():
-    """A kernel that branches on net.annotate computes different bits
-    with annotations off — the neutrality half must fire."""
-    import jax.numpy as jnp
-
-    from wittgenstein_tpu.analysis.annotations_check import (
-        check_annotations_entry,
-    )
-    from wittgenstein_tpu.protocols.pingpong_batched import BatchedPingPong
-
-    class AnnotateSensitive(BatchedPingPong):
-        def tick(self, net, state):
-            state = super().tick(net, state)
-            if net.annotate:  # host flag: branch is trace-time legal
-                state = state._replace(
-                    proto={**state.proto,
-                           "pong": state.proto["pong"] + jnp.int32(1)}
-                )
-            return state
-
-    findings = check_annotations_entry(
-        _entry_with_protocol(AnnotateSensitive), root=str(REPO_ROOT)
-    )
-    assert any(
-        f.rule == "SL601" and "bit-neutral" in f.message for f in findings
-    )
-
-
-def test_sl601_flags_annotate_false_registration():
-    from wittgenstein_tpu.analysis.annotations_check import (
-        check_annotations_entry,
-    )
-    from wittgenstein_tpu.core.registries import BatchedProtocolEntry
-    from wittgenstein_tpu.protocols.pingpong_batched import make_pingpong
-
-    def factory():
-        net, state = make_pingpong(32)
-        net = copy.copy(net)
-        net.annotate = False
-        return net, state
-
-    findings = check_annotations_entry(
-        BatchedProtocolEntry("bad", "fixture_batched", factory),
-        root=str(REPO_ROOT),
-    )
-    assert any(
-        f.rule == "SL601" and "annotate=False" in f.message for f in findings
     )
 
 

@@ -15,6 +15,7 @@ from wittgenstein_tpu.engine import BatchedNetwork, BatchedProtocol, Emission
 from wittgenstein_tpu.engine.core import replicate_state
 from wittgenstein_tpu.core.registries import registry_network_latencies
 from wittgenstein_tpu.protocols.pingpong_batched import make_pingpong
+from wittgenstein_tpu.telemetry.state import TelemetryConfig
 
 
 def _cols(n):
@@ -248,12 +249,12 @@ class TestWheelMechanics:
         state = net.run_ms(state, 600)
         assert int(net.pending_messages(state)) == 0
 
-    def test_run_ms_occupancy_reports_high_water(self):
+    def test_census_peaks_report_high_water(self):
         net, state = _probe_net(wheel_slots=8)
         state = _schedule(net, state, [4, 4, 4, 30, 200])
-        out, occ = net.run_ms_occupancy(state, 50)
-        assert int(occ["wheel_fill_hwm"]) == 3
-        assert int(occ["overflow_hwm"]) == 1  # the 200 sits beyond horizon
+        out = net.run_ms(state, 50)
+        assert int(out.census.wheel_fill_peak) == 3
+        assert int(out.census.lane_live_peak) == 1  # the 200 sits beyond horizon
         assert int(out.proto["delivered"]) == 4
 
     def test_donated_run_matches_undonated(self):
@@ -263,6 +264,43 @@ class TestWheelMechanics:
         out_b = net_b.run_ms(s_b, 400, donate=True)  # s_b consumed
         assert jnp.array_equal(out_a.proto["pong"], out_b.proto["pong"])
         assert jnp.array_equal(out_a.msg_received, out_b.msg_received)
+
+
+class TestCacheKey:
+    """The engine's identity is written once: `stable_cache_key` lists the
+    statics, `cache_key` adds what tells two instances of one process
+    apart."""
+
+    def test_cache_key_is_the_stable_key_and_the_process_ids(self):
+        net, _ = _probe_net()
+        stable = net.stable_cache_key()
+        assert net.cache_key() == stable + (id(net.protocol), id(net.latency), None)
+        net.node_mesh = mesh = object()
+        assert net.cache_key() == stable + (id(net.protocol), id(net.latency), id(mesh))
+        assert not {id(net.protocol), id(net.latency), id(mesh)} & set(stable)
+
+    @pytest.mark.parametrize(
+        "base, static",
+        [
+            pytest.param({}, {"fuse_step": True}, id="fuse_step"),
+            pytest.param({}, {"due_view_rows": (8,)}, id="due_view_rows"),
+            pytest.param({}, {"telemetry": TelemetryConfig()}, id="telemetry"),
+            pytest.param(
+                {"telemetry": TelemetryConfig()}, {"telemetry": TelemetryConfig(snapshots=4)},
+                id="telemetry-config",
+            ),
+        ],
+    )
+    def test_one_static_moves_both_keys(self, base, static):
+        net, _ = _probe_net(**base)
+        # the same protocol and latency objects: the ids agree
+        other = BatchedNetwork(
+            net.protocol, net.latency, net.n_nodes, capacity=256, wheel_rows=64,
+            **{**base, **static},
+        )
+        assert other.stable_cache_key() != net.stable_cache_key()
+        assert other.cache_key()[:-3] == other.stable_cache_key()
+        assert other.cache_key()[-3:] == net.cache_key()[-3:]
 
 
 class TestCheckpointLayout:

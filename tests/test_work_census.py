@@ -116,7 +116,7 @@ def sanfermin():
     return make_sanfermin(SanFerminSignatureParameters(node_count=256, threshold=256))
 
 
-def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanfermin):
+def test_store_rows_are_msg_heads_growth_and_the_peak_is_a_host_loops(sanfermin):
     net, state = sanfermin
     assert not net.flat
     states = replicate_state(state, 2, seeds=[3, 4])
@@ -133,15 +133,19 @@ def test_store_rows_are_msg_heads_growth_and_the_peak_is_the_occupancy_runs(sanf
     # sends' slots, and here every one of them reaches its receiver and is stored
     assert got["census_fired_rows_total"] == grown and got["census_firing_overflows_total"] == 0
     assert 0 < int(np.asarray(out.census.firing_peak).max()) <= net.census_limits()["firing_peak"]
-    # the wheel's fullest row, against the instrumented program's own mark
-    # (which samples after each step; the census also holds the t=0 fill)
+    # the wheel's fullest row and the lane's most live rows, against a host
+    # loop over `net.step` that takes the maxima itself: after every tick's
+    # step (no jump, so every tick is sampled), and of the t=0 fill
+    step = jax.jit(net.step)
     for j, seed in enumerate((3, 4)):
         row = state._replace(seed=state.seed * 0 + seed)
-        _, marks = net.run_ms_occupancy(row, 300)
-        at_start = int(np.asarray(row.whl_fill).max())
-        assert int(out.census.wheel_fill_peak[j]) == max(int(marks["wheel_fill_hwm"]), at_start) > 0
-        assert int(out.census.lane_live_peak[j]) == max(
-            int(marks["overflow_hwm"]), int(np.asarray(row.ovf_valid).sum()))
+        fill, live = int(np.asarray(row.whl_fill).max()), int(np.asarray(row.ovf_valid).sum())
+        for _ in range(300):
+            row = step(row)
+            fill = max(fill, int(np.asarray(row.whl_fill).max()))
+            live = max(live, int(np.asarray(row.ovf_valid).sum()))
+        assert int(out.census.wheel_fill_peak[j]) == fill > 0
+        assert int(out.census.lane_live_peak[j]) == live
     assert after["census_wheel_fill_peak"] >= int(np.asarray(out.census.wheel_fill_peak).max())
     assert int(np.asarray(out.census.wheel_fill_peak).max()) <= net.census_limits()["wheel_fill_peak"]
     assert net.census_limits() == {

@@ -1,48 +1,37 @@
-"""SL601: engine phase annotations — present AND bit-neutral.
+"""SL601: engine phase annotations are present.
 
 The cost-attribution layer (profiling/, scripts/scope_profile.py) only
-works if (a) every engine kernel phase is wrapped in its
-`jax.named_scope` marker (engine.core.ENGINE_PHASE_SCOPES), so jaxprs /
-HLO metadata / device profiles can attribute ops to phases, and (b) the
-markers are trace-time metadata ONLY — flipping `annotate` off must not
-change a single computed bit, or the profile measures a different
-program than production runs.
+works if every kernel phase is wrapped in its `jax.named_scope` marker,
+so jaxprs / HLO metadata / device profiles can attribute ops to phases.
+The markers are trace-time metadata only: `BatchedNetwork._scope` has no
+other arm, so there is no second program to hold them against.
 
 Presence is checked on the real trace: `net.step` is traced to a jaxpr
 and every equation's `source_info.name_stack` is collected, recursing
 into sub-jaxprs (scan/while/cond bodies carry the scopes; the outer
-control-flow equation's own stack is empty).  A phase scope is required
-only when the corresponding protocol hook actually traces equations —
-a trivial `tick_beat` that returns its input adds no ops, so there is
-nothing to attribute and no scope to demand.
+control-flow equation's own stack is empty).  What a step must carry:
 
-Neutrality mirrors SL406's two-level check: abstract (`eval_shape`
-fingerprints of the annotated vs. un-annotated step must match) and
-concrete (one full step must be bitwise identical with `annotate`
-flipped off).
+- `witt.delivery`, always; `witt.protocol_tick` and `witt.beat` only
+  when the corresponding protocol hook actually traces equations — a
+  trivial `tick_beat` that returns its input adds no ops, so there is
+  nothing to attribute and no scope to demand;
+- engine.core.REACH_SCOPES: the reach check where a row is sent and
+  where it is due;
+- engine.core.STORE_SCOPES: the view's and the repack's of every step
+  (a protocol that bypasses the store still visits it), and the
+  insert's where the step sends through the store — which the trace
+  itself shows: the step is traced on a copy of the engine whose
+  `apply_emission`, `apply_emissions` and `apply_fanout` note what
+  they are handed;
+- the protocol's own scopes, which it states beside itself as
+  `REQUIRED_SCOPES` (an attribute or a property): the channel's of the
+  aggregation protocols (protocols/_agg_batched.py `CHANNEL_SCOPES`),
+  Handel's deliver pair and its attack's, Casper's `CHAIN_SCOPES`,
+  Dfinity's `ROLE_SCOPES` and the fan-out's, SanFermin's emission
+  compaction.  A new protocol's scopes need no edit here.
 
-A protocol that sends through the shared channel send path
-(`_send_stacked` of protocols/_agg_batched.py) must also carry every
-sub-scope of engine.core.CHANNEL_SCOPES: the per-scope device times of
-scripts/scope_profile.py are only as whole as these markers are live
-(`compact` where the protocol makes an every-tick send, whose firing
-rows it brings to the front: one that states a `firing_peak` limit,
-Handel with a fast path and GSF with accelerated calls).
-Every other protocol sends through the generic message store and must
-carry every sub-scope of engine.core.STORE_SCOPES (a channel protocol
-carries the view's and the repack's: its step still visits the store).
-A Handel built with an attack (`track_bad`) must carry the sub-scopes of
-engine.core.ATTACK_SCOPES that its attack runs, and every Handel the
-deliver phase's engine.core.DELIVER_SCOPES (the due candidates' rank,
-the candidate merge).  A protocol that keeps a
-scope table of its own names the scopes its step must carry in a
-`REQUIRED_SCOPES` attribute (Casper's `CHAIN_SCOPES`: the fork choice,
-the block build, the committee's vote).  Every protocol carries
-engine.core.REACH_SCOPES: the reach check where a row is sent and where
-it is due.
-
-If this jax version exposes no `name_stack` on source_info, the
-presence half is skipped (API drift guard) — neutrality still runs.
+If this jax version exposes no `name_stack` on source_info, the check
+is skipped (API drift guard).
 """
 
 from __future__ import annotations
@@ -51,14 +40,7 @@ import copy
 import os
 from typing import List, Optional, Set
 
-from .contracts import (
-    _cpu_jax,
-    _diff_fingerprints,
-    _fingerprint,
-    _leaf_paths,
-    _mk,
-    _proto_location,
-)
+from .contracts import _cpu_jax, _mk, _proto_location
 from .findings import Finding
 
 # scopes every annotated step must carry; the rest (telemetry, faults,
@@ -73,7 +55,7 @@ def _sub_jaxprs(params: dict):
     stack = list(params.values())
     while stack:
         x = stack.pop()
-        if isinstance(x, (tuple, list)):
+        if type(x) in (tuple, list):
             stack.extend(x)
         elif hasattr(x, "eqns"):
             yield x
@@ -112,19 +94,33 @@ def _hook_traces_ops(jax, fn, state) -> bool:
     return bool(closed.jaxpr.eqns)
 
 
+def _noting_sends(net):
+    """A copy of `net` whose entry points into the store note what they
+    are handed, and the list they note in: a True for each call that
+    carries an emission (`apply_emissions` is handed a step's list,
+    empty where the protocol sends another way)."""
+    probe, sends = copy.copy(net), []
+
+    def noting(entry):
+        def noted(state, handed):
+            sends.append(bool(handed))
+            return entry(state, handed)
+
+        return noted
+
+    for name in ("apply_emissions", "apply_emission", "apply_fanout"):
+        # the class's own, bound to the copy, behind an attribute of the copy
+        setattr(probe, name, noting(getattr(probe, name)))
+    return probe, sends
+
+
 def _check_presence(jax, name, net, state, path, line, suppress):
     """Every live engine phase appears as a named scope in step()'s
     jaxpr (nested scopes substring-match, per ENGINE_PHASE_SCOPES)."""
     findings = []
-    if not getattr(net, "annotate", True):
-        f = _mk("SL601", path, line,
-                f"[{name}] engine built with annotate=False by its "
-                "registry factory — phase attribution is dark for this "
-                "protocol; construct with annotate=True (the default)",
-                suppress)
-        return [f] if f else []
+    probe, sends = _noting_sends(net)
     try:
-        closed = jax.make_jaxpr(net.step)(state)
+        closed = jax.make_jaxpr(probe.step)(state)
     except Exception as e:
         f = _mk("SL601", path, line,
                 f"[{name}] step() failed tracing for the annotation "
@@ -144,34 +140,13 @@ def _check_presence(jax, name, net, state, path, line, suppress):
     # through `latency_arrivals` or a fan-out's grid, every step builds
     # the delivery view and its re-check
     required.extend(REACH_SCOPES.values())
-    if hasattr(net.protocol, "_send_stacked"):
-        from ..engine.core import CHANNEL_SCOPES
-
-        required.extend(
-            scope for name, scope in CHANNEL_SCOPES.items()
-            if name != "compact" or net.census_limits()["firing_peak"]
-        )
-        # the channel replaces the store's insert; every step still
-        # gathers the (empty) delivery view and clears it
-        required.extend(v for k, v in STORE_SCOPES.items() if k != "insert")
-    else:
-        required.extend(STORE_SCOPES.values())
-    if getattr(net.protocol, "track_bad", False):
-        # Handel built with an attack: what the attack adds to a tick
-        from ..engine.core import ATTACK_SCOPES
-
-        required.extend(
-            scope for name, scope in ATTACK_SCOPES.items()
-            if name != "inject" or net.protocol.params.byzantine_suicide
-        )
-    from ..protocols.handel_batched import BatchedHandel
-
-    if isinstance(net.protocol, BatchedHandel):
-        # the deliver phase's rank and candidate merge (ops/select.py)
-        from ..engine.core import DELIVER_SCOPES
-
-        required.extend(DELIVER_SCOPES.values())
-    # a protocol's own scope table, kept beside the protocol
+    # every step gathers the delivery view (empty, for a protocol that
+    # sends another way) and clears it; the insert is what a send runs
+    required.extend(
+        scope for phase, scope in STORE_SCOPES.items()
+        if phase != "insert" or any(sends)
+    )
+    # the protocol's own scope tables, kept beside the protocol
     required.extend(getattr(net.protocol, "REQUIRED_SCOPES", ()))
     for want in required:
         if not any(want in s for s in scopes):
@@ -182,49 +157,6 @@ def _check_presence(jax, name, net, state, path, line, suppress):
                     "BatchedNetwork._scope(...)", suppress)
             if f:
                 findings.append(f)
-    return findings
-
-
-def _check_neutrality(jax, name, net, state, path, line, suppress):
-    """Annotations must be bit-neutral: the annotate=False twin of the
-    same engine must produce identical avals (abstract) and identical
-    bits after one concrete step (the SL406 pattern)."""
-    import numpy as np
-
-    findings = []
-    net_off = copy.copy(net)
-    net_off.annotate = False
-    try:
-        out_on = jax.eval_shape(net.step, state)
-        out_off = jax.eval_shape(net_off.step, state)
-    except Exception as e:
-        f = _mk("SL601", path, line,
-                f"[{name}] annotate-off step failed abstract "
-                f"evaluation: {type(e).__name__}: {e}", suppress)
-        return [f] if f else []
-    diffs = _diff_fingerprints(_fingerprint(jax, out_on),
-                               _fingerprint(jax, out_off))
-    for d in diffs[:4]:
-        f = _mk("SL601", path, line,
-                f"[{name}] annotations change a leaf aval: {d}", suppress)
-        if f:
-            findings.append(f)
-    if diffs:
-        return findings
-
-    s_on = net.step(state)
-    s_off = net_off.step(state)
-    for (p, a), (_, b) in zip(_leaf_paths(jax, s_on),
-                              _leaf_paths(jax, s_off)):
-        if not np.array_equal(np.asarray(a), np.asarray(b)):
-            f = _mk("SL601", path, line,
-                    f"[{name}] annotations are not bit-neutral: leaf "
-                    f"{p} differs bitwise between annotate=True and "
-                    "annotate=False after one step (a named_scope body "
-                    "must not change computation)", suppress)
-            if f:
-                findings.append(f)
-            break
     return findings
 
 
@@ -243,11 +175,7 @@ def check_annotations_entry(entry, root: str = ".") -> List[Finding]:
         pass
     suppress = set(getattr(net.protocol, "SIMLINT_SUPPRESS", ()) or ())
 
-    findings = _check_presence(jax, entry.name, net, state, path, line,
-                               suppress)
-    findings += _check_neutrality(jax, entry.name, net, state, path, line,
-                                  suppress)
-    return findings
+    return _check_presence(jax, entry.name, net, state, path, line, suppress)
 
 
 def check_annotations(root: str = ".",

@@ -11,7 +11,7 @@ Runs up to ten passes and prints findings as `path:line: RULE [sev] msg`
   6. abstract-eval contract checks              (SL401-SL404)
   7. beat RNG audit                             (SL405)
   8. checkpoint completeness                    (SL501)
-  9. phase-annotation presence + neutrality     (SL601)
+  9. phase-annotation presence                  (SL601)
  10. serve scheduler batching contract          (SL801)
  11. 2D-mesh replicated-leaf audit              (SL1001)
 

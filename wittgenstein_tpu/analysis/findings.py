@@ -71,9 +71,9 @@ RULES: Dict[str, str] = {
     "EPHEMERAL_LEAVES declaration is stale, or save/load does not "
     "roundtrip bitwise",
     # -- phase annotations ----------------------------------------------------
-    "SL601": "engine phase annotations: a live kernel phase is missing its "
-    "named-scope marker in the step jaxpr, or annotations are not "
-    "bit-neutral (annotate=False twin diverges)",
+    "SL601": "engine phase annotations: a live kernel phase, or a scope the "
+    "protocol states in REQUIRED_SCOPES, is missing its named-scope marker "
+    "in the step jaxpr",
     # -- derived-cache consistency --------------------------------------------
     "SL701": "derived-cache consistency: a DERIVED_CACHE_LEAVES leaf is "
     "stale after concrete steps (carried cache differs bitwise from "

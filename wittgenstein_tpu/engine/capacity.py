@@ -7,10 +7,11 @@ no run ever fills.  This module is the contract between the measured
 occupancy high-water marks and the knobs the constructors accept:
 
   scripts/density_autotune.py   probes each registered protocol config
-                                with run_ms_occupancy() (wheel/overflow
-                                HWMs) plus the Handel candidate-slot
-                                occupancy probe, and writes the results
-                                into CAPACITY.json at the repo root.
+                                with run_ms() (the census's wheel and
+                                lane peaks) plus the Handel candidate-
+                                slot occupancy probe, and writes the
+                                results into CAPACITY.json at the repo
+                                root.
   engine/capacity.py (here)     loads/validates that table and turns an
                                 entry into constructor overrides
                                 (sized_overrides()).

@@ -40,7 +40,6 @@ delivery kernels are layout-agnostic.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, NamedTuple, Optional
@@ -87,10 +86,10 @@ DEFAULT_WHEEL_ROWS = 512
 # named-scope phase map (docs/profiling.md): every engine phase is wrapped
 # in jax.named_scope so jaxprs, HLO metadata and device profiles attribute
 # ops to the phase that traced them.  Scopes are TRACE-TIME metadata only —
-# they cannot change a single computed bit (simlint SL601 pins this with a
-# concrete annotate-on vs annotate-off bitwise cross-check).  Sub-phases
-# nest (e.g. a fault send check inside the send path shows up as
-# "witt.send/witt.faults.send"), so consumers should substring-match.
+# they cannot change a single computed bit.  Sub-phases nest (e.g. a fault
+# send check inside the send path shows up as "witt.send/witt.faults.send"),
+# so consumers should substring-match.  A protocol's own scopes live beside
+# the protocol, which states them as `REQUIRED_SCOPES` (simlint SL601).
 ENGINE_PHASE_SCOPES = {
     "delivery": "witt.delivery",
     "fused_step": "witt.fused_step",
@@ -105,34 +104,10 @@ ENGINE_PHASE_SCOPES = {
     "faults_deliver": "witt.faults.deliver",
 }
 
-# sub-scopes of the aggregation protocols' channel send path
-# (protocols/_agg_batched.py `_send_stacked`), nested under the engine
-# phase that sends (witt.protocol_tick, witt.beat) and switched by the
-# same `annotate`.  They name what the ops are FOR, not how XLA spells
-# them, so a rewrite of the send path keeps its time under the same name.
-CHANNEL_SCOPES = {
-    "arrivals": "witt.channel.arrivals",  # who arrives when: latency, counters, keys, slot
-    "readdress": "witt.channel.readdress",  # content from sender to receiver bit space
-    "claim": "witt.channel.claim",  # which offer wins which slot; displacement
-    "compact": "witt.channel.compact",  # an every-tick send: the firing rows (then the landing ones) to the front, a round's reads
-    "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
-}
-
-# what Handel's byzantineSuicide attack adds to a tick
-# (protocols/handel_batched.py, live only where `track_bad` carries the
-# `bl` and `byz` planes), nested under the phase that runs it and
-# switched by the same `annotate`; an attack-free program has none.
-ATTACK_SCOPES = {
-    "inject": "witt.attack.inject",  # the forged full-block sig that wins a level's choice
-    "blacklist": "witt.attack.blacklist",  # the bl plane: written at commit, read in curation
-    "emission": "witt.attack.emission",  # dissemination moves on past blacklisted peers
-}
-
 # sub-scopes of the generic message store (the time wheel, its overflow
 # lane and the delivery view: every protocol that sends through
 # `apply_emission`), nested under the engine phase that runs them
-# (witt.send, witt.delivery, witt.fused_step, a protocol's tick) and
-# switched by the same `annotate`.
+# (witt.send, witt.delivery, witt.fused_step, a protocol's tick).
 STORE_SCOPES = {
     "insert": "witt.store.insert",  # slot ranks, the wheel and overflow planes' scatters
     "view": "witt.store.view",  # the due rows and the overflow lane gathered flat
@@ -142,8 +117,8 @@ STORE_SCOPES = {
 # the store's fan-out form of a broadcast (`FanOut`, `apply_fanout`): the
 # firing senders to the front, a round's `capacity x receivers` rows made
 # and inserted (the insert's own scope nests inside), nested under
-# witt.send and switched by the same `annotate`.  Required of the
-# protocols that emit a `FanOut` (their `REQUIRED_SCOPES`).
+# witt.send.  Required of the protocols that emit a `FanOut` (their
+# `REQUIRED_SCOPES`).
 FANOUT_SCOPES = {
     "expand": "witt.store.fanout",  # a broadcast's rows, for the senders that fire
 }
@@ -151,9 +126,8 @@ FANOUT_SCOPES = {
 # a plain emission that states a `capacity` (`Emission`,
 # `_apply_emission_rounds`): the firing rows numbered to the front and a
 # round's columns read at their numbers, OUTSIDE the insert's own scope
-# (each round's insert nests under witt.send beside it), switched by the
-# same `annotate`.  Required of the protocols whose emissions state one
-# (their `REQUIRED_SCOPES`).
+# (each round's insert nests under witt.send beside it).  Required of the
+# protocols whose emissions state one (their `REQUIRED_SCOPES`).
 EMISSION_SCOPES = {
     "compact": "witt.store.compact",  # the firing rows to the front, a round's reads
 }
@@ -163,20 +137,10 @@ EMISSION_SCOPES = {
 # time.  Asked where a row is sent (`latency_arrivals` through
 # `_apply_emission_impl`, and a fan-out round's grid, `_store_grid`) and
 # again where it is due (`delivery_view`'s `checked`), nested under the
-# phase that asks and switched by the same `annotate`.
+# phase that asks.
 REACH_SCOPES = {
     "send": "witt.reach.send",  # the `ok` mask of a send's rows, and the count of what it masks
     "deliver": "witt.reach.deliver",  # the delivery's re-check of the due rows
-}
-
-# the deliver phase of an aggregation protocol (protocols/handel_batched.py
-# `_channel_deliver`, on every (node, level) of every tick): the due
-# candidates' rank (`_rank` and the sender's bit of `ind`, `_level_bit`)
-# and the candidate merge (ops/select.py `top_k_merge`), nested under
-# the phase that delivers and switched by the same `annotate`.
-DELIVER_SCOPES = {
-    "rank": "witt.deliver.rank",  # the 2 due candidates' reception rank and verified-sender demotion
-    "merge": "witt.deliver.merge",  # keep the best K of the K resident and the 2 due candidates
 }
 
 
@@ -504,12 +468,10 @@ class BatchedNetwork:
         overflow_capacity: Optional[int] = None,
         telemetry: Optional[TelemetryConfig] = None,
         faults: Optional["FaultConfig"] = None,
-        annotate: bool = True,
         fuse_step: bool = False,
         narrow_lanes: Optional[bool] = None,
         batched_jumps: bool = False,
         due_view_rows: "Optional[int | tuple]" = None,
-        dense_fanout: bool = False,
     ):
         self.protocol = protocol
         self.latency = latency
@@ -517,12 +479,6 @@ class BatchedNetwork:
         self.capacity = capacity
         self.msg_discard_time = msg_discard_time
         self.throughput = throughput
-        # STATIC switch for the named-scope phase annotations (see
-        # ENGINE_PHASE_SCOPES): True wraps every phase in jax.named_scope
-        # (trace-time metadata, zero runtime ops); False traces the bare
-        # program — kept only so simlint SL601 can prove the two are
-        # bit-identical
-        self.annotate = bool(annotate)
         # STATIC switch for the fused delivery+tick step (_step_core_fused,
         # docs/engine_fused_step.md): one traced phase instead of
         # delivery -> send -> tick with full-state round-trips between
@@ -559,10 +515,6 @@ class BatchedNetwork:
             self.due_view_rows = tuple(int(k) for k in due_view_rows)
         else:
             self.due_view_rows = int(due_view_rows)
-        # STATIC switch: True stores a `FanOut` as its plain spelling
-        # (`FanOut.dense()`: every sender that could fire, masked), the form
-        # the fan-out is held against leaf for leaf (tests/test_dfinity_batched.py)
-        self.dense_fanout = bool(dense_fanout)
         # STATIC switch: None compiles the exact pre-telemetry program
         # (state.tele is an empty pytree); a TelemetryConfig threads the
         # counter side-car through every send/deliver/jump site below
@@ -720,7 +672,7 @@ class BatchedNetwork:
         )
         if partition is not None:
             state = self.partition(state, partition)
-        for em in self._spelled(self.protocol.initial_emissions(self, state)):
+        for em in self.protocol.initial_emissions(self, state):
             state = self._apply_one(state, em)
         return census_add(state, **self._store_fill(state))
 
@@ -753,18 +705,19 @@ class BatchedNetwork:
         time advance, from every loop)."""
         return census_add(state, steps=1, **self._store_fill(state))
 
-    def cache_key(self) -> tuple:
-        """Explicit identity for compiled-program caches (parallel
-        .replica_shard): protocol name + the static knobs that shape the
-        trace.  id(protocol)/id(latency) disambiguate instances carrying
-        different behavior params; cached programs keep those objects
-        alive, so the ids cannot be recycled while an entry lives."""
-        mesh = getattr(self, "node_mesh", None)
+    def stable_cache_key(self) -> tuple:
+        """Explicit identity for compiled-program caches: protocol name +
+        the static knobs that shape the trace, free of process-lifetime
+        id()s, so it is also the cross-process identity the durable
+        compile store keys on.  Two engines with equal stable keys trace
+        the same program *provided* their behavior params round-trip
+        through repr/str — true for the dataclass params and named
+        latency models this codebase builds; an exotic latency whose
+        str() hides state must not be served from the store (give it a
+        distinguishing __str__)."""
         return (
             type(self.protocol).__name__,
             repr(getattr(self.protocol, "params", None)),
-            id(self.protocol),
-            id(self.latency),
             str(self.latency),
             self.n_nodes,
             self.capacity,
@@ -774,14 +727,11 @@ class BatchedNetwork:
             int(self.msg_discard_time),
             type(self.throughput).__name__ if self.throughput else None,
             getattr(self, "node_axis", None),
-            id(mesh) if mesh is not None else None,
             self.telemetry.key() if self.telemetry is not None else None,
             self.faults.key() if self.faults is not None else None,
-            self.annotate,
             self.fuse_step,
             self.batched_jumps,
             self.due_view_rows,
-            self.dense_fanout,
             self.lanes.key(),
             # the bitset-kernel backend is read from the environment at
             # trace time (WITT_BITOPS) — fold it in so a flipped override
@@ -789,45 +739,24 @@ class BatchedNetwork:
             bitops_backend(),
         )
 
-    def stable_cache_key(self) -> tuple:
-        """cache_key minus the process-lifetime id() components: the
-        cross-process identity the durable compile store keys on.  Two
-        engines with equal stable keys trace the same program *provided*
-        their behavior params round-trip through repr/str — true for the
-        dataclass params and named latency models this codebase builds;
-        an exotic latency whose str() hides state must not be served
-        from the store (give it a distinguishing __str__)."""
-        return (
-            type(self.protocol).__name__,
-            repr(getattr(self.protocol, "params", None)),
-            str(self.latency),
-            self.n_nodes,
-            self.capacity,
-            self.wheel_rows,
-            self.wheel_slots,
-            self.overflow_capacity,
-            int(self.msg_discard_time),
-            type(self.throughput).__name__ if self.throughput else None,
-            getattr(self, "node_axis", None),
-            self.telemetry.key() if self.telemetry is not None else None,
-            self.faults.key() if self.faults is not None else None,
-            self.annotate,
-            self.fuse_step,
-            self.batched_jumps,
-            self.due_view_rows,
-            self.dense_fanout,
-            self.lanes.key(),
-            bitops_backend(),
+    def cache_key(self) -> tuple:
+        """The in-process identity (parallel.replica_shard's run cache):
+        `stable_cache_key` plus id(protocol), id(latency) and the node
+        mesh's id, which disambiguate instances carrying different
+        behavior params; cached programs keep those objects alive, so
+        the ids cannot be recycled while an entry lives."""
+        mesh = getattr(self, "node_mesh", None)
+        return self.stable_cache_key() + (
+            id(self.protocol),
+            id(self.latency),
+            id(mesh) if mesh is not None else None,
         )
 
     def _scope(self, name: str, scopes: dict = ENGINE_PHASE_SCOPES):
-        """jax.named_scope for engine phase `name` (ENGINE_PHASE_SCOPES,
-        or STORE_SCOPES, a protocol's CHANNEL_SCOPES, ATTACK_SCOPES,
-        DELIVER_SCOPES)
-        when annotation is on; a no-op context otherwise."""
-        if self.annotate:
-            return jax.named_scope(scopes[name])
-        return contextlib.nullcontext()
+        """jax.named_scope for phase `name` of `scopes`: the engine's
+        (ENGINE_PHASE_SCOPES, STORE_SCOPES, ...) or a table a protocol
+        keeps beside itself."""
+        return jax.named_scope(scopes[name])
 
     def with_telemetry(
         self, state: SimState, telemetry: TelemetryConfig
@@ -1498,20 +1427,13 @@ class BatchedNetwork:
             )
         return state, masked
 
-    def _spelled(self, emissions) -> list:
-        """A step's emissions as this store takes them: under
-        `dense_fanout` every `FanOut` in its plain spelling."""
-        if not self.dense_fanout:
-            return list(emissions)
-        return [em for fo in emissions for em in (fo.dense() if isinstance(fo, FanOut) else [fo])]
-
     def _apply_one(self, state: SimState, em) -> SimState:
         if isinstance(em, FanOut):
             return self.apply_fanout(state, em)
         return self.apply_emission(state, em)
 
     def apply_emissions(self, state: SimState, emissions) -> SimState:
-        emissions = self._spelled(emissions)
+        emissions = list(emissions)
         if (
             self.due_view_rows is None
             or self.telemetry is not None
@@ -2356,27 +2278,6 @@ class BatchedNetwork:
         donate=True: see run_ms — the input pytree is consumed."""
         fn = self._run_ms_batched_donated if donate else self._run_ms_batched
         return fn(states, ms, stop_when_done)
-
-    @functools.partial(jax.jit, static_argnums=(0, 2))
-    def run_ms_occupancy(self, state: SimState, ms: int):
-        """Instrumented single-replica run: `ms` plain per-tick steps (no
-        empty-ms jumps, so every tick's occupancy is sampled) returning
-        (state, {wheel_fill_hwm, overflow_hwm}) — the wheel's high-water
-        marks (scripts/density_autotune.py sizes capacities from them)."""
-
-        def body(_, carry):
-            s, hw_fill, hw_ovf = carry
-            s = self.step(s)
-            hw_fill = jnp.maximum(hw_fill, jnp.max(s.whl_fill))
-            hw_ovf = jnp.maximum(
-                hw_ovf, jnp.sum(s.ovf_valid.astype(jnp.int32))
-            )
-            return (s, hw_fill, hw_ovf)
-
-        state, hw_fill, hw_ovf = lax.fori_loop(
-            0, ms, body, (state, jnp.int32(0), jnp.int32(0))
-        )
-        return state, {"wheel_fill_hwm": hw_fill, "overflow_hwm": hw_ovf}
 
 
 def replicate_state(state: SimState, n_replicas: int, seeds=None) -> SimState:

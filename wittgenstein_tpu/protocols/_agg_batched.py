@@ -135,12 +135,24 @@ import numpy as np
 from jax import lax
 
 from ..engine import BatchedProtocol
-from ..engine.core import CHANNEL_SCOPES, census_add
+from ..engine.core import census_add
 from ..ops.bitops import lowest_set_bit, popcount_words, xor_shuffle
 from ..ops.select import run_rank, sort_with_order
 
 INT32_MAX = np.int32(2**31 - 1)
 MAX_NODES = 1 << 14  # int32 key-packing headroom
+
+# sub-scopes of the channel send path (`_send_stacked`), nested under the
+# engine phase that sends (witt.protocol_tick, witt.beat).  They name what
+# the ops are FOR, not how XLA spells them, so a rewrite of the send path
+# keeps its time under the same name.
+CHANNEL_SCOPES = {
+    "arrivals": "witt.channel.arrivals",  # who arrives when: latency, counters, keys, slot
+    "readdress": "witt.channel.readdress",  # content from sender to receiver bit space
+    "claim": "witt.channel.claim",  # which offer wins which slot; displacement
+    "compact": "witt.channel.compact",  # an every-tick send: the firing rows (then the landing ones) to the front, a round's reads
+    "commit": "witt.channel.commit",  # the in_sig / in_aux content planes' writes
+}
 
 
 def landing_capacity(m: int) -> int:
@@ -202,6 +214,17 @@ class BitsetAggBase(BatchedProtocol):
     PAYLOAD_WIDTH = 0  # messaging bypasses the generic ring entirely
     CHANNEL_DEPTH = 8  # D: arrival-keyed in-flight slots per (receiver, level)
     BEAT_SEND_CALLS = 1  # _dissemination makes one stacked send
+
+    @property
+    def REQUIRED_SCOPES(self) -> tuple:
+        """The scopes this protocol's step must carry (simlint SL601):
+        the channel's, `compact` where it makes an every-tick send whose
+        firing rows it brings to the front (one that states a
+        `firing_peak` limit)."""
+        return tuple(
+            scope for name, scope in CHANNEL_SCOPES.items()
+            if name != "compact" or self.census_limits().get("firing_peak")
+        )
 
     def tick_beat(self, net, state):
         """Periodic dissemination as the engine's beat hook (subclasses
@@ -996,7 +1019,7 @@ class BitsetAggBase(BatchedProtocol):
         bounded-loss semantics as channel displacement, which the
         protocols' periodic re-offers are already designed to absorb
         (bit identity then becomes distribution parity).  `scope` is
-        _send_stacked's CHANNEL_SCOPES switch: the claim and the commit
+        _send_stacked's CHANNEL_SCOPES context: the claim and the commit
         carry the names they carry unsharded; the exchange before them
         exists only here and stays under the engine's phase scope."""
         from functools import partial as _partial

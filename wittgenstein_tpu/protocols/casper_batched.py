@@ -67,7 +67,7 @@ from .casper import SLOT_DURATION, Attester, BlockProducer, CasperIMD, CasperPar
 
 # what this family's deliver does beside the store (the global block table
 # by height, the dense ancestry matrix, the attestation planes), nested
-# under the phase that delivers and switched by the network's `annotate`
+# under the phase that delivers
 CHAIN_SCOPES = {
     "forkchoice": "witt.chain.forkchoice",  # best / countAttestations: the [N, mH] x [mH, mA] product, the rec_att reads
     "build": "witt.chain.build",  # buildBlock: the included-attestation product, the block table and ancestry writes, the BLOCK rows

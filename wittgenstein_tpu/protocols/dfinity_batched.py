@@ -42,8 +42,8 @@ TPU-first design:
     read in one ms 83 pairs at 4096 attesters in committees of 64:
     `_vote_capacity`).  A step that fires more takes another round and
     is counted (census `fanout_overflows`), never cut; the state is the
-    dense spelling's bit for bit (`BatchedNetwork(dense_fanout=True)`,
-    held in tests/test_dfinity_batched.py).
+    dense spelling's bit for bit (`FanOut.dense()`, held in
+    tests/test_dfinity_batched.py).
 
 Same-tick semantics deltas (documented engine-wide): same-ms deliveries
 are simultaneous; a beacon advances at most one height per tick (the
@@ -73,7 +73,7 @@ from .dfinity_part import PartitionedDfinity, PartitionedDfinityParameters
 
 
 # sub-scopes of Dfinity's deliver, by role (what the ops are FOR), nested
-# under witt.delivery/witt.protocol_deliver and switched by `net.annotate`
+# under witt.delivery/witt.protocol_deliver
 ROLE_SCOPES = {
     "propose": "witt.chain.propose",  # the producers: the beacon's arrival, the block table, PROPOSAL
     "notarize": "witt.chain.notarize",  # the attesters: block arrivals, committees, votes, the crossing
@@ -519,14 +519,13 @@ def make_dfinity(
     capacity: Optional[int] = None,
     seed: int = 0,
     latency_name: Optional[str] = None,
-    dense_fanout: bool = False,
     population_seed: Optional[int] = None,
 ):
     """Host-side construction: the oracle builds the node population (same
     RNG stream — observer, attesters, producers, beacons in id order).
     `capacity` None sizes the store by `store_plan`; an int is the
     engine's historical in-flight budget (its default wheel, no due
-    view).  `dense_fanout` stores every broadcast in its plain spelling.
+    view).
     `population_seed` seeds the oracle's generator before it builds the
     nodes, as a caller of the oracle does (`network().rd.set_seed(s)`,
     then `init()`): the same positions and producer order on both sides.
@@ -589,9 +588,7 @@ def make_dfinity(
         capacity = store["wheel_rows"] * store["wheel_slots"] // 2
     else:
         store = {}
-    net = BatchedNetwork(
-        proto, latency, n, capacity=capacity, dense_fanout=dense_fanout, **store
-    )
+    net = BatchedNetwork(proto, latency, n, capacity=capacity, **store)
     line = (params.partition or None) if partitioned else None  # 0, or no such parameter: no line
     state = net.init_state(cols, seed=seed, proto=proto.proto_init(n), partition=line)
     return net, state

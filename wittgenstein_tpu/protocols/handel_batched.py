@@ -115,13 +115,30 @@ from jax import lax
 from ..core.node import Node, build_node_columns
 from ..core.registries import registry_network_latencies, registry_node_builders
 from ..engine import BatchedNetwork
-from ..engine.core import ATTACK_SCOPES, DELIVER_SCOPES
 from ..engine.rng import hash32
 from ..ops.bitops import popcount_words, xor_shuffle
 from ..ops.select import take_slot, top_k_merge
 from ..utils.javarand import JavaRandom
 from ._agg_batched import INT32_MAX, BitsetAggBase, firing_capacity, landing_capacity
 from .handel import HandelParameters
+
+# what the byzantineSuicide attack adds to a tick (live only where
+# `track_bad` carries the `bl` and `byz` planes), nested under the phase
+# that runs it; an attack-free program has none.
+ATTACK_SCOPES = {
+    "inject": "witt.attack.inject",  # the forged full-block sig that wins a level's choice
+    "blacklist": "witt.attack.blacklist",  # the bl plane: written at commit, read in curation
+    "emission": "witt.attack.emission",  # dissemination moves on past blacklisted peers
+}
+
+# the deliver phase (`_channel_deliver`, on every (node, level) of every
+# tick): the due candidates' rank (`_rank` and the sender's bit of `ind`,
+# `_level_bit`) and the candidate merge (ops/select.py `top_k_merge`),
+# nested under the phase that delivers.
+DELIVER_SCOPES = {
+    "rank": "witt.deliver.rank",  # the 2 due candidates' reception rank and verified-sender demotion
+    "merge": "witt.deliver.merge",  # keep the best K of the K resident and the 2 due candidates
+}
 
 
 class BatchedHandel(BitsetAggBase):
@@ -175,6 +192,19 @@ class BatchedHandel(BitsetAggBase):
             params.byzantine_suicide or params.hidden_byzantine
         )
         self.NARROW_LEAVES = self._narrow_plan()
+
+    @property
+    def REQUIRED_SCOPES(self) -> tuple:
+        """The channel's scopes, the deliver phase's and, built with an
+        attack, those of what the attack runs (`inject` under
+        byzantineSuicide)."""
+        scopes = super().REQUIRED_SCOPES + tuple(DELIVER_SCOPES.values())
+        if self.track_bad:
+            scopes += tuple(
+                scope for name, scope in ATTACK_SCOPES.items()
+                if name != "inject" or self.params.byzantine_suicide
+            )
+        return scopes
 
     def _narrow_plan(self) -> tuple:
         """NARROW_LEAVES for this instance's geometry (engine.density,
@@ -1180,7 +1210,6 @@ def make_handel(
     seed: int = 0,
     wheel_rows: int = 0,  # flat by default; >0 = time wheel (parity tests)
     telemetry=None,  # telemetry.TelemetryConfig (None = uninstrumented)
-    annotate: bool = True,  # False = strip named-scope phase markers
     fuse_step: bool = False,  # True = engine's fused delivery+tick path
 ):
     """Host-side construction: build the node population with the oracle's
@@ -1239,7 +1268,7 @@ def make_handel(
     # scan minimal
     net = BatchedNetwork(
         proto, latency, n, capacity=capacity, wheel_rows=wheel_rows,
-        telemetry=telemetry, annotate=annotate, fuse_step=fuse_step,
+        telemetry=telemetry, fuse_step=fuse_step,
     )
     state = net.init_state(
         cols,
